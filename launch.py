@@ -54,14 +54,13 @@ def main() -> None:
         "--set", nargs="*", default=[], metavar="KEY=VALUE",
         help="config field overrides, dotted paths allowed",
     )
-    from midgpt_tpu.utils.platform_pin import add_platform_arg, apply_platform
-
-    add_platform_arg(parser)
     args = parser.parse_args()
 
     import jax
 
-    apply_platform(args.platform)
+    from midgpt_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.multihost:
         jax.distributed.initialize()  # (parity: launch.py:22-23)

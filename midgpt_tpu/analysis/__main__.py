@@ -21,8 +21,7 @@ CPU mesh instead of a TPU run.
 
 Platform note: env setup must precede the first jax import, which is why
 this module parses args and sets ``JAX_PLATFORMS``/``XLA_FLAGS`` before
-touching the harness; on hosts whose site config pins a platform the
-in-process ``jax.config.update`` fallback (utils.platform_pin) applies.
+touching the harness.
 """
 
 from __future__ import annotations
@@ -334,17 +333,16 @@ def _ensure_devices(platform: str, n: int) -> None:
     When jax is already initialized in-process (tests), just verify the
     existing device pool is big enough for the requested mesh.
     """
-    already = "jax" in sys.modules
-    if platform == "cpu" and not already:
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" not in sys.modules:
+        os.environ["JAX_PLATFORMS"] = platform
         flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
+        if (
+            platform == "cpu"
+            and "xla_force_host_platform_device_count" not in flags
+        ):
             os.environ["XLA_FLAGS"] = (
                 flags + f" --xla_force_host_platform_device_count={n}"
             ).strip()
-    from midgpt_tpu.utils.platform_pin import apply_platform
-
-    apply_platform(platform)
     import jax
 
     jax.config.update("jax_threefry_partitionable", True)  # train.py parity

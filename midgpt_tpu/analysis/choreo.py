@@ -81,13 +81,14 @@ _PASSTHRU = frozenset({
     "sharding_constraint",
 })
 
-# sub-jaxpr-carrying primitives the flattener recurses into; params are
-# scanned generically for ClosedJaxpr/Jaxpr values so new call prims
-# (or renamed ones across jax versions) degrade to unaligned recursion
-# instead of silently dropping a body
+# sub-jaxpr-carrying primitives the flattener recurses into with
+# operands aligned to the body's invars (the names jax 0.9 emits);
+# params are scanned generically for ClosedJaxpr/Jaxpr values, so any
+# other call prim degrades to unaligned recursion instead of silently
+# dropping a body
 _ALIGNED_CALLS = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "custom_jvp_call",
-    "custom_vjp_call", "remat", "checkpoint", "scan", "while",
+    "jit", "closed_call", "custom_jvp_call", "custom_vjp_call", "scan",
+    "while",
 })
 
 _FLOAT_DTYPES = frozenset({"bfloat16", "float16", "float32", "float64"})
@@ -666,12 +667,25 @@ def band_accumulation_order(
             elif c.prim == "mul":
                 for c2 in graph.consumers.get(c.out_ids[0], []):
                     if c2.prim == "reduce_sum":
-                        partials[c2.out_ids[0]] = off
-    if len(partials) < 2:
+                        # the partial, and any re-view of it on the way
+                        # to the fold (a keepdims reduce trails a
+                        # reshape): the last view is what the adds see
+                        vid = c2.out_ids[0]
+                        while vid is not None:
+                            partials[vid] = off
+                            views = [
+                                v for v in graph.consumers.get(vid, [])
+                                if v.prim in _PASSTHRU
+                            ]
+                            vid = (
+                                views[0].out_ids[0]
+                                if len(views) == 1 else None
+                            )
+    if len(set(partials.values())) < 2:
         return None
     # find the fold's root by climbing add-consumers from one partial
     # (the fold is a left spine: each add's output feeds the next)
-    cur = next(iter(partials))
+    cur = next(reversed(partials))
     climbed = False
     for _ in range(len(partials) + 8):
         nxt = next(

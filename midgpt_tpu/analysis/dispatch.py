@@ -177,8 +177,8 @@ class TrainDispatchReport:
 
     - the whole window is ONE XLA dispatch — a depth-0 scan of trip
       count K carrying the optimizer state (``window_scan_length``);
-      K separate launches would re-pay the relay/dispatch latency the
-      fused window exists to amortize (PERF.md r5);
+      K separate launches would re-pay the dispatch latency the
+      fused window exists to amortize;
     - the grad-accum loop inside each step is a ``lax.scan`` of trip
       count G (``accum_scan_length``) — re-unrolling it (the PR 11
       serving bug class, training-side) moves zero wire bytes but

@@ -381,30 +381,43 @@ def prove_window_choreography(
     ))
 
     # -- 7. remat: checkpointed segments recompute the forward ---------
+    # The un-remat'd trace is a linearized forward: its first region
+    # is the layer's forward arithmetic with the by-products the
+    # backward will want (2x beside x**2, the softmax's x**-2)
+    # interleaved. Under remat the primal pass saves nothing, so its
+    # region is that same forward WITHOUT the by-products — an ordered
+    # subsequence — and a later (checkpointed) segment must hold the
+    # whole linearized forward again.
     if remat_closed is not None:
         programs.append(program + "+remat")
         base_regions = attention_regions(graph)
         remat_regions = attention_regions(flatten_jaxpr(remat_closed))
-        preserved = all(r in remat_regions for r in base_regions)
-        extra = [r for r in remat_regions if r not in base_regions]
-        fwd = Counter(
-            collapse_dot_kinds(r) for r in (base_regions[0] if base_regions else ())
-        )
+        fwd = [
+            collapse_dot_kinds(r)
+            for r in (base_regions[0] if base_regions else ())
+        ]
+        primal = [
+            collapse_dot_kinds(r)
+            for r in (remat_regions[0] if remat_regions else ())
+        ]
+        rest = iter(fwd)
+        preserved = bool(primal) and all(r in rest for r in primal)
         recompute_ok = any(
-            not (fwd - Counter(collapse_dot_kinds(r) for r in e))
-            for e in extra
+            not (Counter(fwd) - Counter(collapse_dot_kinds(r) for r in e))
+            for e in remat_regions[1:]
         )
-        ok = bool(base_regions) and preserved and bool(extra) and recompute_ok
+        ok = bool(base_regions) and preserved and recompute_ok
         checks.append(ChoreoCheck(
             "remat-recompute", ok,
             (
-                f"{len(base_regions)} forward/backward regions preserved "
-                f"verbatim; {len(extra)} checkpointed segment(s), one "
-                "contains the forward region op-for-op"
+                f"primal forward ({len(primal)} ops) is the linearized "
+                f"forward ({len(fwd)} ops) in order; "
+                f"{len(remat_regions) - 1} checkpointed segment(s), one "
+                "contains the linearized forward op-for-op"
                 if ok
-                else f"base regions={len(base_regions)} "
-                f"(preserved={preserved}), extra segments={len(extra)} "
-                f"(forward-containing={recompute_ok}) — the remat "
+                else f"base regions={len(base_regions)}, remat regions="
+                f"{len(remat_regions)} (primal-is-forward={preserved}, "
+                f"forward-recomputed={recompute_ok}) — the remat "
                 "policy recomputes something other than the forward"
             ),
         ))

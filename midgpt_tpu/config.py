@@ -152,9 +152,8 @@ class ExperimentConfig:
     g_accum_iters: int = 1
     # optimizer steps fused into ONE jitted lax.scan dispatch
     # (train.make_train_window): amortizes the fixed per-dispatch host/
-    # runtime latency over K steps (PERF.md r5 measured +25-50 ms/step of
-    # pure dispatch overhead on a bad relay day). 1 = today's one-dispatch-
-    # per-step loop, bit-for-bit. K > 1 requires eval/ckpt intervals to be
+    # runtime latency over K steps. 1 = today's one-dispatch-per-step
+    # loop, bit-for-bit. K > 1 requires eval/ckpt intervals to be
     # multiples of K (resolve_dispatch_intervals — intervals get window
     # granularity) and holds a K-deep batch window in HBM.
     steps_per_dispatch: int = 1

@@ -26,8 +26,8 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from midgpt_tpu.compat import shard_map
 from midgpt_tpu.config import ModelConfig
 from midgpt_tpu.models.layers import (
     Embedding,

@@ -2,16 +2,18 @@
 
 The compute path is JAX/XLA/Pallas; the host-side runtime around it —
 batch gather for the data feed — is C++ (midgpt_tpu/native/gather.cpp),
-built on first use with g++ (no pybind11 required). Every native entry
-point has a numpy fallback so the framework runs where no toolchain
-exists.
+built on first use with g++ (no pybind11 required) on the machine that
+runs it. Where no toolchain exists the numpy path serves the same
+windows; :func:`gather_backend` says which one is in use and why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 import typing as tp
 
@@ -19,52 +21,69 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "gather.cpp")
-_LIB = os.path.join(_HERE, "libdatagather.so")
+# portable code generation: the tree (binary included) is copied between
+# machines, so the build may not assume the CPU it happens to run on
+_FLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17", "-pthread")
 
 _lock = threading.Lock()
 _lib: tp.Optional[ctypes.CDLL] = None
 _tried = False
+_why_numpy: tp.Optional[str] = None
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    """The binary is named after the source and flags it was built from:
+    a library left behind by other source is a different file, never
+    loaded, instead of one trusted by its modification time."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(_FLAGS).encode())
+    return os.path.join(_HERE, f"libdatagather-{digest.hexdigest()[:16]}.so")
+
+
+def _build(lib_path: str) -> tp.Optional[str]:
+    """Compile gather.cpp to ``lib_path``; the failure, or None."""
     # build to a process-unique temp path and rename into place: publication
     # is atomic, so concurrent builders can't hand a half-written .so to a
     # loader, and a rebuild never truncates a file another process has
     # already dlopen'd
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        "-pthread", _SRC, "-o", tmp,
-    ]
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB)
-        return True
-    except Exception:
+        os.replace(tmp, lib_path)
+        return None
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return False
+        detail = getattr(e, "stderr", b"") or b""
+        return f"{type(e).__name__}: {e} {detail.decode(errors='replace')[-400:]}"
 
 
 def load_library() -> tp.Optional[ctypes.CDLL]:
-    """The compiled gather library, building it on first call; None if no
-    toolchain is available (callers fall back to numpy)."""
-    global _lib, _tried
+    """The compiled gather library, building it on first call; None if it
+    cannot be built or loaded here (said once on stderr; callers then
+    take the numpy path)."""
+    global _lib, _tried, _why_numpy
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_LIB)
-        ):
-            if not _build():
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _why_numpy = _build(lib_path)
+        if _why_numpy is None:
+            try:
+                lib = ctypes.CDLL(lib_path)
+            except OSError as e:
+                _why_numpy = f"OSError: {e}"
+        if _why_numpy is not None:
+            print(
+                f"midgpt_tpu.native: gather falls back to numpy "
+                f"({_why_numpy.strip()})",
+                file=sys.stderr,
+            )
             return None
         lib.dg_gather.restype = ctypes.c_int
         lib.dg_gather.argtypes = [
@@ -83,6 +102,13 @@ def load_library() -> tp.Optional[ctypes.CDLL]:
 
 def native_available() -> bool:
     return load_library() is not None
+
+
+def gather_backend() -> str:
+    """``"native"``, or ``"numpy (<why the library is not there>)"``."""
+    if load_library() is not None:
+        return "native"
+    return f"numpy ({(_why_numpy or '').strip()})"
 
 
 def gather_windows(
@@ -113,7 +139,6 @@ def gather_windows(
         if rc == 0:
             return x, y
         raise IndexError("gather window out of range")
-    # numpy fallback
     if np.any(offsets < 0) or np.any(offsets + block_size + 1 > len(tokens)):
         raise IndexError("gather window out of range")
     idx = offsets[:, None] + np.arange(block_size + 1)[None, :]

@@ -19,8 +19,7 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
-
-from midgpt_tpu.compat import shard_map
+from jax import shard_map
 
 Array = jax.Array
 

@@ -28,7 +28,6 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.compat import tpu_compiler_params
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -130,8 +129,7 @@ def _struct(shape, dtype, like) -> jax.ShapeDtypeStruct:
     parallel/pipeline.py:169, and the data/TP wrap in ops/attention.py) a
     plain ShapeDtypeStruct fails pallas type-checking; carrying the input
     operand's vma keeps the output varying over the same manual axes."""
-    typeof = getattr(jax, "typeof", None)  # absent (with vma) pre-0.6 jax
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -285,7 +283,7 @@ def _flash_forward(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*operands)
@@ -499,7 +497,7 @@ def _flash_backward(
         out_specs=_act_spec(bq, c, row_q34, q_head),
         out_shape=_struct((b, h, t, c), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((bq, c), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*seed_ops, q, k, v, do, lse, delta)
@@ -537,7 +535,7 @@ def _flash_backward(
             pltpu.VMEM((bk, c), jnp.float32),
             pltpu.VMEM((bk, c), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*seed_ops, q, k, v, do, lse, delta)

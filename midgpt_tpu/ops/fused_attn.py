@@ -43,7 +43,6 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.compat import tpu_compiler_params
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -273,7 +272,7 @@ def _fused_forward(q, k, v, wq, wk, sin, cos, *, n_head, n_kv_head, causal,
             pltpu.VMEM((hpb, bq, 128), jnp.float32),
             pltpu.VMEM((hpb, bq, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
             # the hpb==2 bodies carry two [bq,bk] f32 temp sets; the default
             # 16M scoped-VMEM budget rejects 1024 blocks (17.03M measured)
@@ -586,7 +585,7 @@ def _fused_backward_combined(q, k, v, wq, wk, sin, cos, lse, do, out, *,
             pltpu.VMEM((t, c), jnp.float32),
             pltpu.VMEM((t, c), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -687,7 +686,7 @@ def _fused_backward(q, k, v, wq, wk, sin, cos, out, lse, do, *, n_head,
             pltpu.VMEM((bq, lanes), jnp.float32),
             pltpu.VMEM((bq, c), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
             # the hpb==2 bodies carry two [bq,bk] f32 temp sets; the default
             # 16M scoped-VMEM budget rejects 1024 blocks (17.03M measured)
@@ -743,7 +742,7 @@ def _fused_backward(q, k, v, wq, wk, sin, cos, out, lse, do, *, n_head,
             pltpu.VMEM((bk, lanes), jnp.float32),
             pltpu.VMEM((bk, c), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
             # the hpb==2 bodies carry two [bq,bk] f32 temp sets; the default
             # 16M scoped-VMEM budget rejects 1024 blocks (17.03M measured)

@@ -1,33 +1,40 @@
-"""Pallas TPU ragged paged-attention kernels for serving decode/verify.
+"""Pallas TPU paged-attention kernels for serving decode/verify.
 
 The XLA paged-attention path (models.gpt.decode_paged_at /
 verify_paged_at) reads the KV pool through a block-table gather:
 ``jnp.take(pool[layer], bt)`` materializes a ``[S, Pmax, Hkv, C, PS]``
-intermediate in HBM — the pool bytes are read once, written once into
-the gathered copy, and read again by the attention contraction, i.e.
-the HBM-bound decode step pays the K+V stream ~3x. These kernels walk
-each slot's block table IN-KERNEL over its ragged ``pooled_len`` (the
-"Ragged Paged Attention" formulation, PAPERS.md — the TPU kernel
-purpose-built for exactly this paged layout): every resident page is
-DMA'd from HBM into VMEM exactly once, nothing page-shaped ever lands
-back in HBM, and the whole joint softmax + weighted-value contraction
-runs out of VMEM. Per decode step the pool traffic drops to the
-roofline minimum — each live K and V byte crosses HBM once.
+intermediate in HBM, which the attention contraction then reads again.
+These kernels read each slot's pages straight through its block table:
+the grid runs over (slot x KV head), every block-table column is one
+``BlockSpec`` whose index map looks the page id up in the
+scalar-prefetched table, and the Pallas pipeline brings the pages of
+the next grid step in behind the compute of this one. Nothing
+page-shaped lands back in HBM, and the joint softmax + weighted-value
+contraction run out of VMEM.
 
-BANDED STREAMING (PR 20): the walk no longer assembles every resident
-page at once. The grid runs over (slot x KV head), and each program
-streams its head's pages in ascending PAGE BANDS of ``band_pages``
-pages, double-buffered at ``DMA_DEPTH``: while band *i* computes, band
-*i+1*'s DMA is already in flight. VMEM residency is
-O(DMA_DEPTH x band x page_size) per pass — independent of Pmax — so
-``supported()`` now says yes at 100k-token contexts (6250 pages @
-ps16) where the old whole-pool assembly needed ~940 MB. Band sizing:
-``band_pages`` picks the largest divisor of Pmax whose per-band
-working set (K+V band buffers at DMA depth, plus the f32 dequant/
-upcast views a sub-f32 pool materializes) fits ``BAND_VMEM_BUDGET``,
-capped at ``MAX_BANDS`` bands (the band loop is Python-unrolled into
-the trace). No divisor fits -> ``band_pages`` returns None, the gate
-reports the honest single-band cost, and ``auto`` falls back to XLA.
+WHAT THE CHIP'S COMPILER ACCEPTS (PR 21 — the earlier in-kernel DMA
+walk had only ever run interpreted): a pool page is ``[C, PS=16]``,
+narrower than a 128-lane tile, and Mosaic refuses every manual DMA
+slice of such an array; a block spanning the array's last two dims
+whole is the one window it takes. So all ``Pmax`` pages of a (slot,
+head) are VMEM-resident per grid step — each padded out to a lane tile
+and double-buffered — and ``vmem_bytes`` is O(Pmax) again: the gate
+accepts GPT-2-small's 64-page table and rejects 100k-token tables,
+which ``auto`` serves through the XLA gather. Pages past a slot's
+length are still fetched (clipped ids, masked). The same layout costs
+a whole-pool layout copy in front of the custom call (XLA keeps the
+pool page-minor in HBM; see PERF.md "Bring-up on v5e"). Streaming
+pages at O(band) VMEM needs a pool layout whose pages can be DMA'd;
+that is a layout change, not a kernel repair.
+
+BANDS: the page walk is computed in ascending PAGE BANDS of
+``band_pages`` pages. ``band_pages`` picks the largest divisor of Pmax
+whose band working set fits ``BAND_VMEM_BUDGET``, capped at
+``MAX_BANDS`` bands (the band loop is Python-unrolled into the trace).
+The plan bounds the f32 ``[G, T, C, BW]`` product the compute holds at
+once, and — because f32 addition is not associative — it is part of
+the numerical contract below: the sizing rule is frozen as PR 20 wrote
+it.
 
 EXACTNESS CONTRACT (the reason this kernel looks the way it does): the
 serving suite's landing gate is greedy token-identity against the XLA
@@ -36,11 +43,9 @@ path, and the repo has twice shipped attention variants that drifted by
 (PR 4/PR 5, see analysis.choreo). A classic flash-style online-softmax
 accumulator — running max with ``exp(m_old - m_new)`` rescales folded
 into the accumulator — can NEVER be bitwise against the XLA joint
-softmax: the rescale multiplies are extra roundings. Banding does not
-change that decision (the PR 9 design decision stands): the f32 score
-row for the FULL context is small (~0.4 MB per head-group at 100k) and
-stays VMEM-resident, so normalization remains ONE flat f32 softmax.
-Concretely the kernel makes two streaming passes per program:
+softmax: the rescale multiplies are extra roundings. So the f32 score
+row for the FULL context stays resident and normalization is ONE flat
+f32 softmax. Concretely the kernel makes two passes over the bands:
 
   pass 1 (K): each band's scores are per-column sums over C — banding
     is invisible to them bitwise — concatenated with the recent/self
@@ -50,24 +55,26 @@ Concretely the kernel makes two streaming passes per program:
     order is the ONE place banding touches f32 summation order, so
     the XLA reference path runs the IDENTICAL chunked reduction
     (models.gpt banded PV fold, same ``banded_fold``, same band plan)
-    and the kernel stays BITWISE equal to the XLA path (asserted by
-    tests/test_paged_attn.py down to the f32 pattern) across decode +
-    verify, MHA + GQA, ragged lengths, both pool precisions, and the
-    greedy/sampled token-identity matrix. The accumulation order is
-    machine-checked: analysis.choreo's banded-accumulation-order
-    clause extracts the fold's add-tree leaf order from the jaxpr and
-    fails if any band lands out of ascending order.
+    and the interpreted kernel is BITWISE equal to the XLA path on the
+    CPU (asserted by tests/test_paged_attn.py down to the f32 pattern)
+    across decode + verify, MHA + GQA, ragged lengths, both pool
+    precisions, and the greedy/sampled token-identity matrix. The
+    accumulation order is machine-checked: analysis.choreo's
+    banded-accumulation-order clause extracts the fold's add-tree leaf
+    order from the jaxpr and fails if any band lands out of ascending
+    order. On the chip the compiled kernel and the XLA program order
+    their reductions as their compilers choose; chip_smoke.py holds
+    them to a stated tolerance there.
 
 INT8 KV (``scale_k``/``scale_v`` given): the pool payload is int8 with
 one f32 power-of-two scale per (page, KV-head) plane
 (serving.paged — the KV analogue of quant.py's po2 exactness contract).
-Dequantization happens in-kernel at the VMEM boundary, per band:
-``f32(q) * scale`` with ``|q| <= 127`` and a po2 scale is EXACT and
-elementwise, so the band slice of the dequantized stream equals the
-dequantized band slice — an int8 pool behaves like a bf16 pool whose
-values happen to lie on the page grid, and the greedy token streams
-stay invariant across every engine feature combination (unit-tested at
-the page level).
+Dequantization happens in-kernel, per band: ``f32(q) * scale`` with
+``|q| <= 127`` and a po2 scale is EXACT and elementwise, so the band
+slice of the dequantized stream equals the dequantized band slice — an
+int8 pool behaves like a bf16 pool whose values happen to lie on the
+page grid, and the greedy token streams stay invariant across every
+engine feature combination (unit-tested at the page level).
 
 Dtype choreography (machine-checked: analysis.choreo extracts the
 kernel body's softmax signature and proves it equal to the decode
@@ -77,11 +84,11 @@ accumulation, additive mask before the in-softmax scale, one joint f32
 exp per layer, f32 probs through the PV sums, output rounded to the
 compute dtype once at the end.
 
-CPU/tier-1: the kernels run under the Pallas interpreter (no TPU
-required) — ``interpret`` defaults to "not on a TPU backend", so the
-tier-1 suite and the CI serving gates execute the very same kernel
-bodies the hardware runs. The XLA gather path stays available as a
-config-selected fallback (``ServingEngine(paged_kernel="xla")``),
+Interpret mode is the caller's choice (``interpret=True``, or the
+tests' ``pallas_interpret`` fixture): the program never picks it from
+a device probe, so a kernel the engine reports as "pallas" is a
+compiled one. The XLA gather path stays available as a
+config-selected backend (``ServingEngine(paged_kernel="xla")``),
 exactly as ops/flash.py keeps naive attention for training.
 """
 
@@ -105,33 +112,20 @@ Array = jax.Array
 # the decode choreography contract.
 SCORE_ACC_DTYPE = jnp.float32
 
+# What the kernels ask the compiler for, and what ``supported`` lets
+# the estimate reach. A v5e core has 128 MiB of VMEM; the compiler's
+# default scoped limit (16 MiB) is below the page-block residency of a
+# 128-page table, so the call raises it. The gap between the two is
+# headroom for what the estimate cannot see (Mosaic's own temporaries).
+VMEM_LIMIT = 96 * 1024 * 1024
+VMEM_BUDGET = 64 * 1024 * 1024
 
-def _interpret_default() -> bool:
-    from midgpt_tpu.utils.platform import is_tpu_backend
-
-    return not is_tpu_backend()
-
-
-# Conservative fit budget for the kernel's total VMEM working set
-# (band stream buffers + the full-context f32 score/prob rows), out of
-# ~16 MB/core. Module-level so the long-context gate tests can pin the
-# acceptance arithmetic against the same constant the ``auto`` path
-# uses.
-VMEM_BUDGET = 12 * 1024 * 1024
-
-# Double-buffer depth of the banded page stream: band i's compute
-# overlaps band i+1's DMA. Depth 2 is the classic ping-pong (the
-# Pallas double-buffering idiom); the band working-set arithmetic in
-# ``_band_bytes`` scales with it, so raising the depth automatically
-# shrinks the auto-sized band.
-DMA_DEPTH = 2
-
-# Per-pass band working-set budget: DMA_DEPTH band buffers for K and V
-# at pool dtype, plus the f32 dequant/upcast views of the compute
-# band. 2 MB keeps the stream buffers a small fraction of VMEM_BUDGET
-# so the full-context f32 score row — the flat-softmax contract's
-# residency cost — gets the rest.
+# Band plan sizing, frozen as PR 20 wrote it for its double-buffered
+# K/V band stream: the plan fixes the PV fold order of every
+# multi-band stream on BOTH the kernel and the XLA reference, so
+# moving these constants moves bits, not just memory.
 BAND_VMEM_BUDGET = 2 * 1024 * 1024
+_PLAN_DEPTH = 2
 
 # The band loop is Python-unrolled into the kernel trace (that is what
 # keeps the choreography extractable and the softmax flat), so cap the
@@ -171,13 +165,11 @@ def banded_fold(parts: tp.Sequence[Array]) -> Array:
 
 def _band_bytes(band_pages_: int, page_size: int, c: int,
                 itemsize: int) -> int:
-    """VMEM bytes of ONE streaming pass's band working set at this
-    band size: K and V band buffers (2x) at DMA_DEPTH slots each, pool
-    dtype, plus — for a sub-f32 pool (bf16, int8) — the f32
-    dequant/upcast views of the K and V compute bands that
-    ``_dequant_band`` materializes."""
+    """The band plan's sizing rule: K and V band buffers at pool dtype,
+    ``_PLAN_DEPTH`` of each, plus — for a sub-f32 pool (bf16, int8) —
+    the f32 upcast views of the K and V compute bands."""
     bw = band_pages_ * page_size
-    total = 2 * DMA_DEPTH * c * bw * itemsize
+    total = 2 * _PLAN_DEPTH * c * bw * itemsize
     if itemsize < 4:
         total += 2 * c * bw * 4
     return total
@@ -191,12 +183,10 @@ def band_pages(pmax: int, page_size: int, c: int,
     Returns None when no divisor satisfies both — e.g. a head dim so
     large even one page overflows the band budget, or a
     pathologically-factored Pmax whose only fitting divisors need too
-    many bands — and the gate then reports the honest single-band
-    (whole-table) cost, which is exactly the pre-banding arithmetic.
-    The plan depends ONLY on (pmax, page_size, c, itemsize): never on
-    head counts, groups, or spec length, so the fold order — and with
-    it the f32 bit pattern — is invariant across TP degree and
-    spec on/off."""
+    many bands. The plan depends ONLY on (pmax, page_size, c,
+    itemsize): never on head counts, groups, or spec length, so the
+    fold order — and with it the f32 bit pattern — is invariant across
+    TP degree and spec on/off."""
     if _FORCE_BAND_PAGES is not None:
         assert pmax % _FORCE_BAND_PAGES == 0, (
             f"_FORCE_BAND_PAGES={_FORCE_BAND_PAGES} must divide "
@@ -218,10 +208,10 @@ def resolved_band_pages(pmax: int, page_size: int, c: int,
                         itemsize: int) -> int:
     """The band plan the kernels AND the XLA reference fold actually
     use: the auto-sized (or test-forced) band, falling back to one
-    whole-table band when no plan fits — the honest degenerate case
-    the gate keeps off the ``auto`` path but a forced kernel can still
-    run. Shared between ops.paged_attn and models.gpt so the two PV
-    fold orders can never diverge."""
+    whole-table band when no plan fits — the degenerate case the gate
+    keeps off the ``auto`` path but the XLA reference still folds by.
+    Shared between ops.paged_attn and models.gpt so the two PV fold
+    orders can never diverge."""
     bp = band_pages(pmax, page_size, c, itemsize)
     if bp is None:
         bp = pmax
@@ -229,210 +219,240 @@ def resolved_band_pages(pmax: int, page_size: int, c: int,
     return bp
 
 
-def vmem_bytes(pmax: int, page_size: int, hkv: int, c: int,
-               itemsize: int, groups: int = 8, spec_t: int = 1) -> int:
-    """Worst-case VMEM demand of the BANDED kernel at this geometry,
-    in bytes: one streaming pass's band working set (``_band_bytes``
-    at the auto-sized band — K + V band buffers at DMA_DEPTH, plus the
-    f32 dequant/upcast views for a sub-f32 pool), the full-context f32
-    score + prob rows ([G, T, W] x2 — the flat-softmax residency
-    cost), and the int8 pool's per-page f32 scale planes. ``hkv`` is
-    accepted for signature stability but no longer enters the
-    arithmetic: the grid runs over (slot x KV head), so per-program
-    residency is head-count-free — that grid axis is half of what
-    makes 100k contexts fit. When ``band_pages`` finds no plan the
-    arithmetic falls back to the single whole-table band, i.e. the
-    honest pre-banding cost, and the gate rejects from the byte count
-    exactly as before. Exposed separately from :func:`supported` so
-    the long-context tests can pin the arithmetic itself."""
-    del hkv  # grid over KV heads: residency is per-head already
-    bp = band_pages(pmax, page_size, c, itemsize)
-    if bp is None:
-        bp = pmax
+def _ceil_to(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
+               groups: int = 8, spec_t: int = 1) -> int:
+    """Estimated VMEM demand of one (slot, KV head) grid step, in
+    bytes. Head count does not enter: the grid runs over KV heads.
+
+    - page blocks: K and V, ``pmax`` each, every ``[C, PS]`` block
+      padded to its dtype's (sublane, 128-lane) tile and held twice by
+      the pipeline — the O(Pmax) term;
+    - one band's compute: the band at pool dtype, its f32 view, and
+      the ``[G, T, C, BW]`` f32 product the reductions consume;
+    - the full-context f32 score and prob rows ``[G, T, 1, W]`` — the
+      flat-softmax residency — whose unit sublane dim pads 8x;
+    - an int8 pool's per-position f32 scale rows (K and V), padded and
+      double-buffered like any block.
+
+    Checked against the compiler at G4/C128/Pmax128/bf16: this says
+    19.3 MiB where Mosaic's scoped allocation was 18.02 MiB."""
+    g, t = max(1, groups), max(1, spec_t)
     w = pmax * page_size
-    # [G, T, W] f32 score row + prob row, both resident across pass 2
-    scores = 2 * max(1, groups) * max(1, spec_t) * w * 4
-    total = _band_bytes(bp, page_size, c, itemsize) + scores
+    sublanes = 8 * 4 // itemsize
+    page = _ceil_to(c, sublanes) * _ceil_to(page_size, 128) * itemsize
+    pages = 2 * 2 * pmax * page
+    bp = band_pages(pmax, page_size, c, itemsize)
+    bw = (pmax if bp is None else bp) * page_size
+    band = c * bw * (itemsize + 4) + g * t * c * bw * 4
+    scores = 2 * g * t * 8 * w * 4
+    total = pages + band + scores
     if itemsize == 1:
-        # int8 pool: the gathered per-page scale planes ride along as
-        # [Pmax]-shaped f32 VMEM blocks (K and V)
-        total += 2 * pmax * 4
+        total += 2 * 2 * 8 * w * 4
     return total
 
 
-def supported(pmax: int, page_size: int, hkv: int, c: int,
-              itemsize: int, groups: int = 8, spec_t: int = 1) -> bool:
-    """Does the banded working set for this geometry fit comfortably
-    in VMEM? Band stream buffers + full-context f32 score/prob rows
-    (``groups`` = query heads per KV head — the [G, W] score and prob
-    rows scale with it; ``spec_t`` = candidate rows per slot in the
-    verify kernel, whose rows are [G, T, W] — pass ``speculate + 1``
-    when speculation is on), against a conservative 12 MB budget (of
-    ~16 MB/core). A sub-f32 pool (bf16, and worst int8 — 1 counted
-    byte vs 4 materialized) also pays for the f32 dequant/upcast
-    copies of the K and V compute bands that ``_dequant_band`` builds
-    on top of the pool-dtype stream; omitting them let ``auto`` pick
-    the kernel on geometries whose real VMEM demand overflowed Mosaic
-    (code-review finding, PR 9 — the accounting survives banding,
-    per-band). Because the band working set is O(band) rather than
-    O(Pmax), this now returns True at 100k-token Pmax (6250 pages @
-    ps16) for both bf16 and int8 pools — the gate that used to reject
-    from a ~940 MB whole-pool assembly."""
+def supported(pmax: int, page_size: int, c: int, itemsize: int,
+              groups: int = 8, spec_t: int = 1) -> bool:
+    """Will the chip's compiler take the kernels at this geometry?
+    (``groups`` = query heads per KV head; ``spec_t`` = candidate rows
+    per slot in the verify kernel — pass ``speculate + 1`` when
+    speculation is on.) Two conditions, both learned from compiling
+    for a described v5e: a band plan exists (else the unrolled trace
+    is unbounded), and the ``vmem_bytes`` estimate fits
+    ``VMEM_BUDGET``. ``ServingEngine(paged_kernel="auto")`` serves
+    every geometry this rejects through the XLA gather."""
+    if band_pages(pmax, page_size, c, itemsize) is None:
+        return False
     return vmem_bytes(
-        pmax, page_size, hkv, c, itemsize, groups=groups, spec_t=spec_t
+        pmax, page_size, c, itemsize, groups=groups, spec_t=spec_t
     ) <= VMEM_BUDGET
 
 
-def _dequant_band(buf: Array, sc: tp.Optional[Array], b: int, bp: int,
-                  ps: int) -> Array:
-    """One band's VMEM buffer [C, BW] -> f32 stream values. For an
-    int8 pool the band's slice of the per-page scale vector broadcasts
-    to per-position columns and the dequant multiply is exact
-    (|q| <= 127, po2 scale — quant.py's epilogue contract, applied to
-    the KV stream). Dequantization is elementwise, so the band slice
-    of the dequantized stream is bitwise the dequantized band slice —
+def _band_view(page_refs, sc, b: int, bp: int, ps: int):
+    """Band ``b``'s logical [C, BW] f32 stream view: its ``bp`` page
+    blocks laid side by side on the lane axis in page order (what the
+    XLA path's gathered view holds in columns [b*BW, (b+1)*BW)). For an
+    int8 pool the band's columns of the per-position scale row multiply
+    the upcast codes — exact (|q| <= 127, po2 scale — quant.py's
+    epilogue contract, applied to the KV stream) and elementwise, so
     banding cannot perturb it."""
+    pages = [r[...] for r in page_refs[b * bp:(b + 1) * bp]]
+    band = pages[0] if bp == 1 else jnp.concatenate(pages, axis=-1)
     if sc is None:
-        return buf.astype(jnp.float32)
-    sc_b = sc[b * bp:(b + 1) * bp]  # [BP] f32, static band slice
-    scw = jnp.broadcast_to(sc_b[:, None], (bp, ps)).reshape(1, bp * ps)
-    return buf.astype(jnp.float32) * scw
+        return band.astype(jnp.float32)
+    return band.astype(jnp.float32) * sc[:, b * bp * ps:(b + 1) * bp * ps]
 
 
 def _decode_kernel(
     # scalar prefetch
-    bt_ref,      # [S, Pmax] int32
+    bt_ref,      # [S, Pmax] int32 — consumed by the page index maps
     len_ref,     # [S] int32 — pooled_len
     r_ref,       # [1] int32 — step index within the window
-    # inputs
-    q_ref,       # [1, 1, G, C] block — this (slot, KV head)'s queries
-    rk_ref,      # [1, 1, R, C] block — recent K rows (this layer/head)
-    rv_ref,      # [1, 1, R, C] block
-    sk_ref,      # [1, 1, Pmax] f32 block or None (int8 pool only)
-    sv_ref,
-    pk_ref,      # [L, NP, Hkv, C, PS] pool K, HBM/ANY
-    pv_ref,
-    # outputs / scratch
-    out_ref,     # [1, 1, G, C] block
-    kband,       # VMEM [DMA_DEPTH, C, BP*PS] pool dtype
-    vband,
-    sem,         # DMA semaphores [2, DMA_DEPTH] (K row 0, V row 1)
-    *,
-    layer: int,
-    ps: int,
-    nb: int,
+    *refs,
+    # q [G, C, 1]; recent K/V rows TRANSPOSED [C, R]; the int8 pool's
+    # per-position scale rows [1, W] f32 (K, V) when ``quant``; then
+    # Pmax K page blocks and Pmax V page blocks [C, PS]; the output
+    # block [G, C, 1] last. Blocks are squeezed to these shapes.
+    pmax: int,
+    bp: int,
+    quant: bool,
 ):
+    del bt_ref
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    _, c, bw = kband.shape
-    bp = bw // ps
-    w = nb * bw
-    rr = rk_ref.shape[2]
-    np_total = pk_ref.shape[1]
-    npages = pl.cdiv(len_ref[s], ps)
-
-    def _band_dma(pref, buf, row, b, start):
-        """Start (or wait for) band ``b``'s page DMAs into buffer slot
-        b % DMA_DEPTH: each live page of the band crosses HBM exactly
-        once, into its [.., i*PS:(i+1)*PS] band columns. Page ids are
-        clipped like the XLA path's ``mode="clip"`` gather (pads
-        beyond ``npages`` are never walked; the clip is defense
-        against a corrupt table, and clipped garbage is erased by the
-        -inf mask before the softmax). The zero-fill on start is what
-        makes un-DMA'd columns safe: masked scores become exactly
-        ``0 + (-inf)`` and masked value columns contribute exactly
-        ``0.0 * 0.0`` — finite, so no NaN can leak through
-        ``0 * garbage``. Waits re-construct the same descriptors and
-        pair one wait per started page on the band's semaphore."""
-        slot = b % DMA_DEPTH
-        lo = b * bp
-        live = jnp.clip(npages - lo, 0, bp)
-        if start:
-            buf[slot] = jnp.zeros_like(buf[slot])
-
-        def body(i, carry):
-            page = jnp.clip(bt_ref[s, lo + i], 0, np_total - 1)
-            cp = pltpu.make_async_copy(
-                pref.at[layer, page, j],
-                buf.at[slot, :, pl.ds(i * ps, ps)],
-                sem.at[row, slot],
-            )
-            if start:
-                cp.start()
-            else:
-                cp.wait()
-            return carry
-
-        jax.lax.fori_loop(0, live, body, 0)
-
-    qs = q_ref[0, 0]  # [G, C]
-    sc_k = None if sk_ref is None else sk_ref[0, 0]  # [Pmax] f32
-    sc_v = None if sv_ref is None else sv_ref[0, 0]
-    # PASS 1 (K): stream the bands, double-buffered — band b's scores
-    # compute while band b+1's DMA is in flight. Each band's scores
-    # are per-column sums over C, so banding is bitwise-invisible to
-    # them; the masked parts concatenate into the ONE full-context f32
-    # score row (the flat-softmax contract — no online rescaling).
-    for d in range(min(DMA_DEPTH - 1, nb)):
-        _band_dma(pk_ref, kband, 0, d, start=True)
+    q_ref, rkt_ref, rvt_ref = refs[:3]
+    refs = refs[3:]
+    sc_k = sc_v = None
+    if quant:
+        sc_k, sc_v = refs[0][...], refs[1][...]  # [1, W] f32
+        refs = refs[2:]
+    k_pages, v_pages, out_ref = refs[:pmax], refs[pmax:2 * pmax], refs[-1]
+    c, ps = k_pages[0].shape
+    bw = bp * ps
+    nb = pmax // bp
+    w = pmax * ps
+    rr = rkt_ref.shape[-1]
+    # every contraction keeps C on the sublane axis and time on the
+    # lane axis, reductions keep their dim: the shapes Mosaic lays out
+    # without a layout change (a [G, C] -> [G, C, 1] value reshape is the
+    # cast its layout inference refuses)
+    qs = q_ref[...]  # [G, C, 1]
+    # PASS 1 (K): each band's scores are per-column sums over C, so
+    # banding is bitwise-invisible to them; the masked parts
+    # concatenate into the ONE full-context f32 score row (the
+    # flat-softmax contract — no online rescaling).
     parts = []
     for b in range(nb):
-        nxt = b + DMA_DEPTH - 1
-        if nxt < nb:
-            _band_dma(pk_ref, kband, 0, nxt, start=True)
-        _band_dma(pk_ref, kband, 0, b, start=False)
-        ck_b = _dequant_band(kband[b % DMA_DEPTH], sc_k, b, bp, ps)
+        ck_b = _band_view(k_pages, sc_k, b, bp, ps)  # [C, BW] f32
         # the decode choreography, op for op (decode_paged_at): f32
         # upcast-multiplies, f32 accumulation, mask BEFORE the
         # in-softmax scale
         s_b = jnp.sum(
-            qs[:, :, None].astype(SCORE_ACC_DTYPE)
-            * ck_b[None].astype(SCORE_ACC_DTYPE),
-            axis=-2, dtype=SCORE_ACC_DTYPE,
-        )  # [G, BW]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, bw), 1)[0] + b * bw
+            qs.astype(SCORE_ACC_DTYPE) * ck_b[None].astype(SCORE_ACC_DTYPE),
+            axis=-2, keepdims=True, dtype=SCORE_ACC_DTYPE,
+        )  # [G, 1, BW]
+        idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, bw), 2) + b * bw
         mask_b = jnp.where(idx < len_ref[s], 0.0, -jnp.inf).astype(
             jnp.float32
         )
         parts.append(s_b + mask_b)
-    rkl = rk_ref[0, 0]  # [R, C]
-    rvl = rv_ref[0, 0]
     s_rec = jnp.sum(
-        qs[:, None, :].astype(SCORE_ACC_DTYPE)
-        * rkl[None].astype(SCORE_ACC_DTYPE),
-        axis=-1, dtype=SCORE_ACC_DTYPE,
-    )  # [G, R]
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (1, rr), 1)[0]
+        qs.astype(SCORE_ACC_DTYPE)
+        * rkt_ref[...][None].astype(SCORE_ACC_DTYPE),
+        axis=-2, keepdims=True, dtype=SCORE_ACC_DTYPE,
+    )  # [G, 1, R]
+    ridx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rr), 2)
     mask_rec = jnp.where(ridx <= r_ref[0], 0.0, -jnp.inf).astype(
         jnp.float32
     )
     s_all = jnp.concatenate(parts + [s_rec + mask_rec], axis=-1)
     probs = jax.nn.softmax(s_all / math.sqrt(c), axis=-1)  # f32, joint
-    # PASS 2 (V): stream the bands again (each V byte still crosses
-    # HBM exactly once), each band's PV partial summed over its band
-    # width, folded in PINNED ascending-band order — the one place
-    # banding touches f32 summation order, matched bitwise by the XLA
+    # PASS 2 (V): each band's PV partial summed over its band width,
+    # folded in PINNED ascending-band order — the one place banding
+    # touches f32 summation order, matched bitwise by the XLA
     # reference's banded_fold.
-    for d in range(min(DMA_DEPTH - 1, nb)):
-        _band_dma(pv_ref, vband, 1, d, start=True)
     opars = []
     for b in range(nb):
-        nxt = b + DMA_DEPTH - 1
-        if nxt < nb:
-            _band_dma(pv_ref, vband, 1, nxt, start=True)
-        _band_dma(pv_ref, vband, 1, b, start=False)
-        cv_b = _dequant_band(vband[b % DMA_DEPTH], sc_v, b, bp, ps)
-        p_b = probs[:, b * bw:(b + 1) * bw]  # [G, BW] f32
+        cv_b = _band_view(v_pages, sc_v, b, bp, ps)  # [C, BW] f32
+        p_b = probs[:, :, b * bw:(b + 1) * bw]  # [G, 1, BW] f32
         opars.append(
-            jnp.sum(p_b[:, None, :] * cv_b[None].astype(jnp.float32),
-                    axis=-1)
-        )  # [G, C]
+            jnp.sum(p_b * cv_b[None].astype(jnp.float32), axis=-1,
+                    keepdims=True)
+        )  # [G, C, 1]
     o_pool = banded_fold(opars)
-    p_rec = probs[:, w:]
+    p_rec = probs[:, :, w:]  # [G, 1, R]
     o_rec = jnp.sum(
-        p_rec[..., None] * rvl[None].astype(jnp.float32), axis=-2
+        p_rec * rvt_ref[...][None].astype(jnp.float32), axis=-1,
+        keepdims=True,
     )
-    out_ref[0, 0] = (o_pool + o_rec).astype(out_ref.dtype)
+    out_ref[...] = (o_pool + o_rec).astype(out_ref.dtype)
+
+
+def _page_specs(layer: int, pmax: int, np_total: int, c: int, ps: int):
+    """One BlockSpec per block-table column: grid step (slot i, KV head
+    j) receives page ``bt[i, p]`` of this layer and head as a whole
+    [C, PS] block, fetched by the Pallas pipeline (which double-buffers
+    the next step's pages behind this step's compute). The pool's
+    16-wide time-minor pages are smaller than a lane tile, and Mosaic
+    refuses every manual DMA slice of such an array ("Slice shape along
+    dimension 2 must be aligned to tiling (128), but is 16"); a block
+    that spans the array's last two dims whole is the one window it
+    accepts. Page ids are clipped like the XLA path's ``mode="clip"``
+    gather: pads beyond the slot's live pages fetch a valid page whose
+    garbage the -inf mask erases before the softmax."""
+
+    def spec(p):
+        def index_map(i, j, bt_ref, *_):
+            return (layer, jnp.clip(bt_ref[i, p], 0, np_total - 1), j, 0, 0)
+
+        return pl.BlockSpec((None, None, None, c, ps), index_map)
+
+    return [spec(p) for p in range(pmax)]
+
+
+def _position_scales(scale: Array, ps: int) -> Array:
+    """Gathered per-page scales [S, Pmax, Hkv] -> per-position rows
+    [S, Hkv, 1, W]: each page's scale repeated over its PS columns, in
+    XLA, where the page-to-lane expansion is free of Mosaic's reshape
+    rules (a [BP, PS] -> [BP*PS] merge of sub-tile rows is refused)."""
+    sc = jnp.repeat(jnp.transpose(scale, (0, 2, 1)), ps, axis=-1)
+    return sc[:, :, None, :]
+
+
+def _slot_head_spec(shape: tp.Sequence[int]) -> pl.BlockSpec:
+    """Grid step (slot i, KV head j) sees ``array[i, j]`` whole, the two
+    grid dims squeezed away."""
+    rest = tuple(shape[2:])
+    return pl.BlockSpec(
+        (None, None) + rest, lambda i, j, *_: (i, j) + (0,) * len(rest)
+    )
+
+
+def _paged_call(
+    body, scalars, q: Array, rows_t: tp.Sequence[Array], pool_k: Array,
+    pool_v: Array, bt: Array, layer: int, scale_k: tp.Optional[Array],
+    scale_v: tp.Optional[Array], interpret: bool,
+) -> Array:
+    """The call both kernels share: grid over (slot, KV head); operands
+    are the scalar-prefetched ``scalars`` (block table first), ``q``
+    with a unit lane dim, the row buffers pre-transposed to [.., C, R],
+    an int8 pool's per-position scale rows, then one block per
+    block-table column for K and for V. Returns ``q``-shaped output."""
+    s, hkv = q.shape[:2]
+    c = q.shape[-1]
+    _, np_total, _, _, ps = pool_k.shape
+    pmax = bt.shape[1]
+    quant = scale_k is not None
+    bp = resolved_band_pages(pmax, ps, c, jnp.dtype(pool_k.dtype).itemsize)
+    args = [q[..., None], *rows_t]
+    if quant:
+        args += [_position_scales(scale_k, ps), _position_scales(scale_v, ps)]
+    in_specs = [_slot_head_spec(a.shape) for a in args]
+    in_specs += 2 * _page_specs(layer, pmax, np_total, c, ps)
+    args += pmax * [pool_k] + pmax * [pool_v]
+    out_shape = q.shape + (1,)
+    # ``interpret`` is only forwarded when asked for: ``interpret=False``
+    # spelled out would override the tests' ``pallas_interpret`` fixture,
+    # which binds the keyword on ``pl.pallas_call`` itself
+    out = pl.pallas_call(
+        functools.partial(body, pmax=pmax, bp=bp, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(s, hkv),
+            in_specs=in_specs,
+            out_specs=_slot_head_spec(out_shape),
+        ),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT,
+        ),
+        **({"interpret": True} if interpret else {}),
+    )(*scalars, *args)
+    return out[..., 0]
 
 
 def paged_decode_attention(
@@ -447,201 +467,88 @@ def paged_decode_attention(
     layer: int,      # STATIC layer index
     scale_k: tp.Optional[Array] = None,  # [S, Pmax, Hkv] f32 gathered
     scale_v: tp.Optional[Array] = None,  # per-page scales (int8 pool)
-    interpret: tp.Optional[bool] = None,
+    interpret: bool = False,
 ) -> Array:  # [S, Hkv, G, C] compute dtype
-    """One decode step's paged attention for all slots: pool part
-    streamed by the banded in-kernel ragged block-table walk, recent
-    part from the window's write buffer, one joint softmax — bitwise
-    the (banded-fold) XLA gather path's result without the gathered
-    HBM intermediate, at O(band) VMEM."""
-    s, hkv, g, c = q.shape
-    l, np_total, _, _, ps = pool_k.shape
-    pmax = bt.shape[1]
-    quant = scale_k is not None
-    if interpret is None:
-        interpret = _interpret_default()
-    bp = resolved_band_pages(pmax, ps, c, jnp.dtype(pool_k.dtype).itemsize)
-    nb = pmax // bp
-    kern = functools.partial(_decode_kernel, layer=layer, ps=ps, nb=nb)
-    if not quant:
-        kern = _drop_scale_refs(kern, n_scalar=3)
-    in_specs = [
-        pl.BlockSpec((1, 1, g, c), lambda i, j, *_: (i, j, 0, 0)),
-        pl.BlockSpec(
-            (1, 1, rk_l.shape[2], c), lambda i, j, *_: (i, j, 0, 0)
-        ),
-        pl.BlockSpec(
-            (1, 1, rk_l.shape[2], c), lambda i, j, *_: (i, j, 0, 0)
-        ),
-    ]
-    args = [q, rk_l, rv_l]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, pmax), lambda i, j, *_: (i, j, 0)),
-            pl.BlockSpec((1, 1, pmax), lambda i, j, *_: (i, j, 0)),
-        ]
-        # [S, Pmax, Hkv] -> [S, Hkv, Pmax]: a head's scale vector as a
-        # contiguous last-dim block (a [.., Pmax, 1] block would pad
-        # its unit lane dim out to the tile width — ~3 MB at 100k Pmax)
-        args += [
-            jnp.transpose(scale_k, (0, 2, 1)),
-            jnp.transpose(scale_v, (0, 2, 1)),
-        ]
-    in_specs += [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-    ]
-    args += [pool_k, pool_v]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s, hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, g, c), lambda i, j, *_: (i, j, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((DMA_DEPTH, c, bp * ps), pool_k.dtype),
-            pltpu.VMEM((DMA_DEPTH, c, bp * ps), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2, DMA_DEPTH)),
-        ],
+    """One decode step's paged attention for all slots: pool part read
+    page by page through the block table, recent part from the window's
+    write buffer, one joint softmax — bitwise the (banded-fold) XLA
+    gather path's result without the gathered HBM intermediate."""
+    return _paged_call(
+        _decode_kernel, (bt, pooled_len, jnp.reshape(r, (1,))), q,
+        (jnp.swapaxes(rk_l, 2, 3), jnp.swapaxes(rv_l, 2, 3)),
+        pool_k, pool_v, bt, layer, scale_k, scale_v, interpret,
     )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, hkv, g, c), q.dtype),
-        interpret=interpret,
-    )(bt, pooled_len, jnp.reshape(r, (1,)), *args)
-
-
-def _drop_scale_refs(kern, n_scalar: int):
-    """Adapt a kernel written for the quantized operand list (scale
-    blocks present) to the float-pool call (scales absent): insert None
-    where the scale refs would sit. Positions: scalars, then 3 tensor
-    blocks (q + two row buffers), then [sk, sv], then pool refs."""
-
-    @functools.wraps(kern)
-    def wrapped(*refs):
-        pre = refs[: n_scalar + 3]
-        post = refs[n_scalar + 3:]
-        return kern(*pre, None, None, *post)
-
-    return wrapped
 
 
 def _verify_kernel(
     # scalar prefetch
-    bt_ref,      # [S, Pmax] int32
+    bt_ref,      # [S, Pmax] int32 — consumed by the page index maps
     start_ref,   # [S] int32 — per-slot write watermark
-    # inputs
-    q_ref,       # [1, 1, G, T, C] block
-    kc_ref,      # [1, 1, T, C] block — cache-rounded self K rows
-    vc_ref,      # [1, 1, T, C] block
-    sk_ref,      # [1, 1, Pmax] f32 block or None
-    sv_ref,
-    pk_ref,      # [L, NP, Hkv, C, PS] pool, HBM/ANY
-    pv_ref,
-    out_ref,     # [1, 1, G, T, C] block
-    kband,       # VMEM [DMA_DEPTH, C, BP*PS] pool dtype
-    vband,
-    sem,
-    *,
-    layer: int,
-    ps: int,
-    nb: int,
+    *refs,
+    # q [G, T, C, 1]; cache-rounded self K/V rows TRANSPOSED [C, T];
+    # scale rows [1, W] when ``quant``; Pmax K then Pmax V page blocks
+    # [C, PS]; the output block [G, T, C, 1] last.
+    pmax: int,
+    bp: int,
+    quant: bool,
 ):
+    del bt_ref
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    _, c, bw = kband.shape
-    bp = bw // ps
-    w = nb * bw
-    t = kc_ref.shape[2]
-    np_total = pk_ref.shape[1]
-    npages = pl.cdiv(start_ref[s], ps)
-
-    def _band_dma(pref, buf, row, b, start):
-        # identical walk to _decode_kernel's _band_dma (see its
-        # docstring for the clip/zero-fill contract)
-        slot = b % DMA_DEPTH
-        lo = b * bp
-        live = jnp.clip(npages - lo, 0, bp)
-        if start:
-            buf[slot] = jnp.zeros_like(buf[slot])
-
-        def body(i, carry):
-            page = jnp.clip(bt_ref[s, lo + i], 0, np_total - 1)
-            cp = pltpu.make_async_copy(
-                pref.at[layer, page, j],
-                buf.at[slot, :, pl.ds(i * ps, ps)],
-                sem.at[row, slot],
-            )
-            if start:
-                cp.start()
-            else:
-                cp.wait()
-            return carry
-
-        jax.lax.fori_loop(0, live, body, 0)
-
-    qs = q_ref[0, 0]  # [G, T, C]
-    kc = kc_ref[0, 0]  # [T, C]
-    vc = vc_ref[0, 0]
-    sc_k = None if sk_ref is None else sk_ref[0, 0]  # [Pmax] f32
-    sc_v = None if sv_ref is None else sv_ref[0, 0]
+    q_ref, kct_ref, vct_ref = refs[:3]
+    refs = refs[3:]
+    sc_k = sc_v = None
+    if quant:
+        sc_k, sc_v = refs[0][...], refs[1][...]  # [1, W] f32
+        refs = refs[2:]
+    k_pages, v_pages, out_ref = refs[:pmax], refs[pmax:2 * pmax], refs[-1]
+    c, ps = k_pages[0].shape
+    bw = bp * ps
+    nb = pmax // bp
+    w = pmax * ps
+    t = kct_ref.shape[-1]
+    qs = q_ref[...]  # [G, T, C, 1]
     # the decode choreography over T candidate rows (verify_paged_at
     # op for op): f32 upcast-multiplies, f32 accumulation, one joint
     # exp, f32 probs through the PV sums — banded exactly like
     # _decode_kernel (pass 1 K scores, flat softmax, pass 2 V fold)
-    for d in range(min(DMA_DEPTH - 1, nb)):
-        _band_dma(pk_ref, kband, 0, d, start=True)
     parts = []
     for b in range(nb):
-        nxt = b + DMA_DEPTH - 1
-        if nxt < nb:
-            _band_dma(pk_ref, kband, 0, nxt, start=True)
-        _band_dma(pk_ref, kband, 0, b, start=False)
-        ck_b = _dequant_band(kband[b % DMA_DEPTH], sc_k, b, bp, ps)
+        ck_b = _band_view(k_pages, sc_k, b, bp, ps)  # [C, BW] f32
         s_b = jnp.sum(
-            qs[..., :, None].astype(SCORE_ACC_DTYPE)
+            qs.astype(SCORE_ACC_DTYPE)
             * ck_b[None, None].astype(SCORE_ACC_DTYPE),
-            axis=-2, dtype=SCORE_ACC_DTYPE,
-        )  # [G, T, BW]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, bw), 1)[0] + b * bw
+            axis=-2, keepdims=True, dtype=SCORE_ACC_DTYPE,
+        )  # [G, T, 1, BW]
+        idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, bw), 3) + b * bw
         mask_b = jnp.where(idx < start_ref[s], 0.0, -jnp.inf).astype(
             jnp.float32
         )
         parts.append(s_b + mask_b)
     s_self = jnp.sum(
-        qs[:, :, None, :].astype(SCORE_ACC_DTYPE)
-        * kc[None, None].astype(SCORE_ACC_DTYPE),
-        axis=-1, dtype=SCORE_ACC_DTYPE,
-    )  # [G, T, T]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+        qs.astype(SCORE_ACC_DTYPE)
+        * kct_ref[...][None, None].astype(SCORE_ACC_DTYPE),
+        axis=-2, keepdims=True, dtype=SCORE_ACC_DTYPE,
+    )  # [G, T, 1, T]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1, t, 1, t), 1)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, t, 1, t), 3)
     mask_self = jnp.where(cols <= rows, 0.0, -jnp.inf).astype(jnp.float32)
     s_all = jnp.concatenate(parts + [s_self + mask_self], axis=-1)
     probs = jax.nn.softmax(s_all / math.sqrt(c), axis=-1)  # f32
-    for d in range(min(DMA_DEPTH - 1, nb)):
-        _band_dma(pv_ref, vband, 1, d, start=True)
     opars = []
     for b in range(nb):
-        nxt = b + DMA_DEPTH - 1
-        if nxt < nb:
-            _band_dma(pv_ref, vband, 1, nxt, start=True)
-        _band_dma(pv_ref, vband, 1, b, start=False)
-        cv_b = _dequant_band(vband[b % DMA_DEPTH], sc_v, b, bp, ps)
-        p_b = probs[:, :, b * bw:(b + 1) * bw]  # [G, T, BW] f32
+        cv_b = _band_view(v_pages, sc_v, b, bp, ps)  # [C, BW] f32
+        p_b = probs[:, :, :, b * bw:(b + 1) * bw]  # [G, T, 1, BW] f32
         opars.append(
-            jnp.sum(
-                p_b[:, :, None, :] * cv_b[None, None].astype(jnp.float32),
-                axis=-1,
-            )
-        )  # [G, T, C]
+            jnp.sum(p_b * cv_b[None, None].astype(jnp.float32), axis=-1,
+                    keepdims=True)
+        )  # [G, T, C, 1]
     o_pool = banded_fold(opars)
-    p_self = probs[:, :, w:]
+    p_self = probs[:, :, :, w:]  # [G, T, 1, T]
     o_self = jnp.sum(
-        p_self[..., None] * vc[None, None].astype(jnp.float32), axis=-2
-    )  # [G, T, C]
-    out_ref[0, 0] = (o_pool + o_self).astype(out_ref.dtype)
+        p_self * vct_ref[...][None, None].astype(jnp.float32), axis=-1,
+        keepdims=True,
+    )  # [G, T, C, 1]
+    out_ref[...] = (o_pool + o_self).astype(out_ref.dtype)
 
 
 def paged_verify_attention(
@@ -655,60 +562,15 @@ def paged_verify_attention(
     layer: int,
     scale_k: tp.Optional[Array] = None,  # [S, Pmax, Hkv] f32 gathered
     scale_v: tp.Optional[Array] = None,
-    interpret: tp.Optional[bool] = None,
+    interpret: bool = False,
 ) -> Array:  # [S, Hkv, G, T, C]
     """Speculative-verify paged attention: all T candidate rows of every
-    slot against its ragged resident pages plus themselves (causal), one
-    joint softmax, decode choreography — the kernel twin of
-    ``Attention.verify_paged_at`` with the same banded in-kernel walk as
+    slot against its resident pages plus themselves (causal), one joint
+    softmax, decode choreography — the kernel twin of
+    ``Attention.verify_paged_at`` with the same page-block walk as
     :func:`paged_decode_attention`."""
-    s, hkv, g, t, c = q.shape
-    l, np_total, _, _, ps = pool_k.shape
-    pmax = bt.shape[1]
-    quant = scale_k is not None
-    if interpret is None:
-        interpret = _interpret_default()
-    bp = resolved_band_pages(pmax, ps, c, jnp.dtype(pool_k.dtype).itemsize)
-    nb = pmax // bp
-    kern = functools.partial(_verify_kernel, layer=layer, ps=ps, nb=nb)
-    if not quant:
-        kern = _drop_scale_refs(kern, n_scalar=2)
-    in_specs = [
-        pl.BlockSpec((1, 1, g, t, c), lambda i, j, *_: (i, j, 0, 0, 0)),
-        pl.BlockSpec((1, 1, t, c), lambda i, j, *_: (i, j, 0, 0)),
-        pl.BlockSpec((1, 1, t, c), lambda i, j, *_: (i, j, 0, 0)),
-    ]
-    args = [q, kc, vc]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, pmax), lambda i, j, *_: (i, j, 0)),
-            pl.BlockSpec((1, 1, pmax), lambda i, j, *_: (i, j, 0)),
-        ]
-        args += [
-            jnp.transpose(scale_k, (0, 2, 1)),
-            jnp.transpose(scale_v, (0, 2, 1)),
-        ]
-    in_specs += [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-    ]
-    args += [pool_k, pool_v]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s, hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, g, t, c), lambda i, j, *_: (i, j, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((DMA_DEPTH, c, bp * ps), pool_k.dtype),
-            pltpu.VMEM((DMA_DEPTH, c, bp * ps), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2, DMA_DEPTH)),
-        ],
+    return _paged_call(
+        _verify_kernel, (bt, start), q,
+        (jnp.swapaxes(kc, 2, 3), jnp.swapaxes(vc, 2, 3)),
+        pool_k, pool_v, bt, layer, scale_k, scale_v, interpret,
     )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, hkv, g, t, c), q.dtype),
-        interpret=interpret,
-    )(bt, start, *args)

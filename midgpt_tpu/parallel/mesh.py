@@ -93,12 +93,15 @@ def hybrid_device_layout(
     return arr.reshape(sizes)
 
 
-def create_mesh(
-    cfg: MeshConfig, devices: tp.Optional[tp.Sequence[jax.Device]] = None
-) -> Mesh:
-    devices = list(devices) if devices is not None else jax.devices()
+def device_layout(
+    cfg: MeshConfig, devices: tp.Sequence[jax.Device]
+) -> tp.Tuple[np.ndarray, str]:
+    """The device array for ``cfg`` over ``devices`` and the name of the
+    branch that laid it out. TPU devices go through ``mesh_utils``, which
+    knows the physical topology, and a layout it refuses is an error:
+    there is no reshape behind it. Only simulated (CPU) devices, which
+    have no topology, are laid out in listing order."""
     sizes = cfg.sizes(len(devices))
-
     if cfg.num_slices > 1:
         assert sizes[1] % cfg.num_slices == 0, (
             f"replica axis {sizes[1]} must be a multiple of num_slices "
@@ -112,26 +115,30 @@ def create_mesh(
             ici_parallelism = (
                 sizes[0], sizes[1] // cfg.num_slices,
             ) + sizes[2:]
-            device_array = mesh_utils.create_hybrid_device_mesh(
+            return mesh_utils.create_hybrid_device_mesh(
                 ici_parallelism,
                 dcn_parallelism,
                 devices=devices,
                 allow_split_physical_axes=True,
-            )
-        else:
-            # simulated slices (CPU mesh / single-slice testbed): same
-            # axis-split contract via the pure layout above
-            device_array = hybrid_device_layout(devices, sizes, cfg.num_slices)
-    else:
-        try:
-            device_array = mesh_utils.create_device_mesh(
-                sizes, devices=devices, allow_split_physical_axes=True
-            )
-        except (ValueError, AssertionError, NotImplementedError):
-            # CPU-simulated or irregular topologies: plain reshape
-            device_array = np.asarray(devices).reshape(sizes)
+            ), "create_hybrid_device_mesh"
+        # simulated slices (CPU mesh / single-slice testbed): same
+        # axis-split contract via the pure layout above
+        return (
+            hybrid_device_layout(devices, sizes, cfg.num_slices),
+            "hybrid_device_layout",
+        )
+    if devices[0].platform == "tpu":
+        return mesh_utils.create_device_mesh(
+            sizes, devices=devices, allow_split_physical_axes=True
+        ), "create_device_mesh"
+    return np.asarray(devices).reshape(sizes), "reshape"
 
-    return Mesh(device_array, AXIS_NAMES)
+
+def create_mesh(
+    cfg: MeshConfig, devices: tp.Optional[tp.Sequence[jax.Device]] = None
+) -> Mesh:
+    devices = list(devices) if devices is not None else jax.devices()
+    return Mesh(device_layout(cfg, devices)[0], AXIS_NAMES)
 
 
 def single_device_mesh(device: tp.Optional[jax.Device] = None) -> Mesh:

@@ -33,23 +33,17 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from midgpt_tpu.compat import shard_map
 
 Array = jax.Array
 
 
 def _to_varying(x: Array, axis: str) -> Array:
-    """Promote ``x`` to VARYING along the mesh axis. ``jax.lax.pcast``
-    replaced ``pvary`` in newer JAX; jax before ~0.5 has neither (the
-    varying-manual-axes annotation didn't exist yet), and there the
-    promotion is a value-level no-op — identity keeps old pins working."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    return x
+    """Promote ``x`` to VARYING along the mesh axis — a type-system
+    annotation for ``check_vma`` regions, a value-level no-op."""
+    return jax.lax.pcast(x, axis, to="varying")
+
 
 StageFn = tp.Callable[..., Array]
 """(stage_params, activation [Bm, ...][, keys [L/S, 2]]) -> activation

@@ -34,9 +34,8 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from midgpt_tpu.compat import shard_map
 
 Array = jax.Array
 

@@ -32,9 +32,8 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from midgpt_tpu.compat import shard_map
 
 Array = jax.Array
 

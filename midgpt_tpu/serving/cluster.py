@@ -23,8 +23,7 @@ this TPxDP composition).
   its latency — which the cluster test asserts directly.
 - **Per-replica health + failover** (serving.faults): every replica is
   ``healthy``, ``suspect``, or ``dead``. A wall-clock dispatch watchdog
-  (``dispatch_timeout_s``) catches the wedged-relay case (the r4/r5
-  BENCH post-mortems: a dispatch that never returns); a
+  (``dispatch_timeout_s``) catches a dispatch that never returns; a
   ``TransientDispatchError`` is retried on the same replica with capped
   exponential backoff (``max_retries``/``backoff_s``/``backoff_cap_s``,
   suspect while retrying); a ``ReplicaCrash``, a watchdog trip, or

@@ -16,24 +16,37 @@ import jax
 
 from midgpt_tpu.config import ExperimentConfig, ModelConfig, to_dict
 
-# bf16 peak FLOPs/s per chip by device kind substring
+# bf16 peak FLOP/s per chip, keyed by a substring of ``device_kind``
+# (spaces dropped, lower case; "TPU v5 lite" is the v5e). Source: Google
+# Cloud TPU documentation, the "System architecture" page of each
+# generation (v5e: 197 TFLOP/s).
 _PEAK_FLOPS = {
     "v6": 918e12,
     "v5p": 459e12,
-    "v5": 197e12,  # v5e / v5 lite
+    "v5": 197e12,
     "v4": 275e12,
     "v3": 123e12,
     "v2": 45e12,
 }
 
 
+class UnknownDevicePeak(LookupError):
+    """The device has no entry in the peak table (the CPU, a new chip).
+    A utilization against an assumed chip is a made-up number, so there
+    is no default: callers that only log say "not measured"."""
+
+
 def device_peak_flops(device: tp.Optional[jax.Device] = None) -> float:
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower().replace(" ", "")
-    for key, val in _PEAK_FLOPS.items():
-        if key in kind:
-            return val
-    return 197e12  # assume v5e-class if unknown
+    kind = device.device_kind.lower().replace(" ", "")
+    if device.platform == "tpu":
+        for key, val in _PEAK_FLOPS.items():
+            if key in kind:
+                return val
+    raise UnknownDevicePeak(
+        f"no peak FLOP/s for {device.platform} device kind "
+        f"{device.device_kind!r}"
+    )
 
 
 def flops_per_token(model: ModelConfig, seq_len: tp.Optional[int] = None) -> float:
@@ -60,6 +73,8 @@ def flops_per_token(model: ModelConfig, seq_len: tp.Optional[int] = None) -> flo
 
 
 def mfu(tokens_per_sec: float, model: ModelConfig, n_devices: int) -> float:
+    """Model FLOP/s utilization against this device's peak; raises
+    :class:`UnknownDevicePeak` where there is none to divide by."""
     achieved = tokens_per_sec * flops_per_token(model)
     peak = device_peak_flops() * n_devices
     return achieved / peak
@@ -96,8 +111,9 @@ def train_floor(
     this device's peak FLOPs): compute + HBM floors and the
     tokens-per-step needed to turn a measured tokens_per_sec into
     step_ms and an attainment fraction. None when the analytic floor
-    doesn't cover the config (e.g. MoE) — logging then proceeds without
-    the attainment keys rather than with wrong ones."""
+    doesn't cover the config (e.g. MoE) or the device has no known
+    peak (the CPU) — logging then proceeds without the attainment keys
+    rather than with wrong ones."""
     from midgpt_tpu.analysis.traffic import train_floor_decomposition
 
     try:
@@ -108,7 +124,7 @@ def train_floor(
             flops_per_token=flops_per_token(cfg.model),
             peak_flops_per_device=device_peak_flops(),
         )
-    except AssertionError:
+    except (AssertionError, UnknownDevicePeak):
         return None
 
 

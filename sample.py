@@ -131,18 +131,16 @@ def main() -> None:
         "--eos_id", type=int, default=None,
         help="stop a request early at this token id (--serve mode only)",
     )
-    from midgpt_tpu.utils.platform_pin import add_platform_arg, apply_platform
-
-    add_platform_arg(ap)
     args = ap.parse_args()
 
     import jax
-
-    apply_platform(args.platform)
     import jax.numpy as jnp
     import numpy as np
 
     from midgpt_tpu.checkpoint import Checkpointer
+    from midgpt_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from midgpt_tpu.pytree import cast_floating
     from midgpt_tpu.sampling import make_sampler
 

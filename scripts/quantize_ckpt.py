@@ -35,11 +35,7 @@ def main() -> None:
     ap.add_argument("--out", required=True)
     ap.add_argument("--step", type=int, default=None)
     ap.add_argument("--mode", choices=("po2", "absmax"), default="po2")
-    from midgpt_tpu.utils.platform_pin import add_platform_arg, apply_platform
-
-    add_platform_arg(ap)
     args = ap.parse_args()
-    apply_platform(args.platform)
 
     import dataclasses
 

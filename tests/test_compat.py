@@ -1,35 +1,27 @@
-"""Direct unit tests for the midgpt_tpu.compat shims (and the related
-per-module version guards they document): the new-style ``shard_map``
-surface routed onto whatever this jax pin provides, the
-``tpu_compiler_params`` dataclass rename, and the pvary/pcast varying-
-promotion fallback in parallel.pipeline. Until PR 5 these were only
-exercised transitively through the 54 repaired tier-1 tests — a shim
-regression surfaced as a wall of unrelated failures instead of one
-pointed one."""
+"""The installed JAX's surface this package calls directly (there is no
+shim layer: ``requirements.txt`` names the one installation). A JAX
+upgrade that moves one of these fails here with one pointed test
+instead of a wall of unrelated failures: the keyword surface of
+``jax.shard_map`` (``axis_names`` = the MANUAL axes, ``check_vma``),
+``pltpu.CompilerParams`` and its fields, ``pl.ANY``, the varying-axes
+promotion in parallel.pipeline, and the jaxpr call-primitive names the
+analysis provers recurse through."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from midgpt_tpu import compat
-from midgpt_tpu.compat import shard_map, tpu_compiler_params
 
 
 def _mesh1d():
     return Mesh(np.array(jax.devices()[:8]).reshape(8), ("x",))
 
 
-# ---------------------------------------------------------------------------
-# shard_map: the new-style surface on any pin
-# ---------------------------------------------------------------------------
-
-
 def test_shard_map_basic_map_and_collective():
-    """The plain surface (mesh/in_specs/out_specs keywords) maps per-shard
-    and runs collectives — on the old pin this must route through
-    jax.experimental.shard_map with check_vma translated to check_rep."""
+    """The plain surface (mesh/in_specs/out_specs keywords) maps
+    per-shard and runs collectives; a psum'd output passes the
+    replication check (check_vma=True is the default)."""
     mesh = _mesh1d()
     double = shard_map(
         lambda a: a * 2, mesh=mesh, in_specs=(P("x"),), out_specs=P("x")
@@ -37,8 +29,6 @@ def test_shard_map_basic_map_and_collective():
     np.testing.assert_array_equal(
         np.asarray(double(jnp.arange(8))), 2 * np.arange(8)
     )
-    # a replicated output through psum passes the replication check
-    # (check_vma=True is the default — the renamed check_rep)
     total = shard_map(
         lambda a: jax.lax.psum(a, "x"),
         mesh=mesh,
@@ -50,11 +40,9 @@ def test_shard_map_basic_map_and_collective():
 
 
 def test_shard_map_axis_names_with_axis_index():
-    """``axis_names`` (the partial-manual surface) with a body that takes
-    ``jax.lax.axis_index`` — exactly the combination 0.4.x's experimental
-    partial-auto lowering rejects (PartitionId in the SPMD partitioner),
-    which is why the shim runs it fully manual there. The observable
-    contract is value-level: per-shard axis indices come out right."""
+    """``axis_names`` (the partial-manual surface) with a body that
+    takes ``jax.lax.axis_index`` — the PP stage id and the
+    sharded-dropout offsets are exactly this combination."""
     mesh = _mesh1d()
     f = shard_map(
         lambda a: a + jax.lax.axis_index("x").astype(a.dtype),
@@ -68,76 +56,75 @@ def test_shard_map_axis_names_with_axis_index():
     )
 
 
-def test_shard_map_old_pin_translation_kwargs():
-    """On a pin without ``jax.shard_map`` the shim must call the
-    experimental entry point with the TRANSLATED kwargs: check_vma ->
-    check_rep, and axis_names forcing check_rep off (the partial-auto
-    semantics predate the replication checker). Asserted by intercepting
-    the experimental symbol the shim dispatches to."""
-    if compat._HAS_TOP_LEVEL:
-        pytest.skip("new jax: the shim passes through to jax.shard_map")
+def test_shard_map_partial_manual_leaves_other_axes_to_gspmd():
+    """Manual over 'p' only: the body sees the operand's FULL 'd' extent
+    (GSPMD keeps sharding it underneath), which is what lets the PP
+    region leave fsdp/tensor sharding to the partitioner."""
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("p", "d"))
     seen = {}
 
-    def fake(f, *, mesh, in_specs, out_specs, check_rep):
-        seen["check_rep"] = check_rep
-        return lambda *a: a[0]
+    def body(a):
+        seen["shape"] = a.shape
+        return a + jax.lax.axis_index("p").astype(a.dtype)
 
-    orig = compat._shard_map_experimental
-    compat._shard_map_experimental = fake
-    try:
-        shard_map(
-            lambda a: a, mesh=None, in_specs=(P(),), out_specs=P(),
-            check_vma=True,
-        )(0)
-        assert seen["check_rep"] is True  # check_vma -> check_rep
-        shard_map(
-            lambda a: a, mesh=None, in_specs=(P(),), out_specs=P(),
-            check_vma=True, axis_names={"x"},
-        )(0)
-        assert seen["check_rep"] is False  # axis_names forces it off
-    finally:
-        compat._shard_map_experimental = orig
-
-
-# ---------------------------------------------------------------------------
-# tpu_compiler_params: the CompilerParams/TPUCompilerParams rename
-# ---------------------------------------------------------------------------
-
-
-def test_tpu_compiler_params_constructs_on_this_pin():
-    p = tpu_compiler_params(
-        dimension_semantics=("parallel",), vmem_limit_bytes=1 << 20
+    x = jax.device_put(
+        jnp.zeros((2, 8), jnp.int32),
+        jax.sharding.NamedSharding(mesh, P("p", "d")),
     )
-    # both the old and new dataclass expose the two fields the kernels use
-    assert p.dimension_semantics == ("parallel",)
-    assert p.vmem_limit_bytes == 1 << 20
+    out = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(P("p"),), out_specs=P("p"),
+        axis_names={"p"},
+    ))(x)
+    assert seen["shape"] == (1, 8)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.repeat(np.arange(2)[:, None], 8, axis=1)
+    )
 
 
-def test_tpu_compiler_params_picks_whichever_class_exists():
+def test_pallas_tpu_names_the_kernels_use():
+    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    expected = getattr(pltpu, "CompilerParams", None) or (
-        pltpu.TPUCompilerParams
+    p = pltpu.CompilerParams(
+        dimension_semantics=("parallel",), vmem_limit_bytes=1 << 20
     )
-    assert isinstance(tpu_compiler_params(), expected)
+    assert p.dimension_semantics == ("parallel",)
+    assert p.vmem_limit_bytes == 1 << 20
+    assert pl.ANY is not None
+    assert callable(pltpu.PrefetchScalarGridSpec)
 
 
-# ---------------------------------------------------------------------------
-# pvary/pcast fallback (parallel.pipeline._to_varying)
-# ---------------------------------------------------------------------------
+def test_call_primitive_names_the_provers_align():
+    """analysis.choreo recurses ALIGNED (operand origins carried into
+    the body) only through the call primitives it lists by name; a
+    rename degrades the provers to "no attention region found" — the
+    jax 0.9 ``pjit`` -> ``jit`` rename did exactly that."""
+    from midgpt_tpu.analysis.choreo import _ALIGNED_CALLS
+
+    def prog(x):
+        y = jax.jit(lambda a: a + 1)(x)
+        y, _ = jax.lax.scan(lambda c, _: (c * 2, None), y, None, length=2)
+        return jax.lax.while_loop(
+            lambda c: c.sum() < 0, lambda c: c + 1, y
+        )
+
+    names = [
+        e.primitive.name for e in jax.make_jaxpr(prog)(jnp.ones(3)).eqns
+    ]
+    assert names == ["jit", "scan", "while"]
+    assert set(names) <= _ALIGNED_CALLS
 
 
 def test_to_varying_is_value_identity():
-    """The varying-axes promotion is a type-system annotation in new jax
-    and must be a value-level no-op on every pin — on jax without
-    pcast/pvary (this 0.4.37 pin) the fallback is literal identity."""
     from midgpt_tpu.parallel.pipeline import _to_varying
 
-    x = jnp.arange(6.0).reshape(2, 3)
-    y = _to_varying(x, "pipeline")
-    np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
-    if not hasattr(jax.lax, "pcast") and not hasattr(jax.lax, "pvary"):
-        assert y is x  # the old-pin branch is exactly identity
+    mesh = _mesh1d()
+    x = jnp.arange(8.0)
+    y = shard_map(
+        lambda a: _to_varying(a, "x"), mesh=mesh, in_specs=(P(),),
+        out_specs=P("x"),
+    )(x)
+    np.testing.assert_array_equal(np.asarray(y), np.tile(np.asarray(x), 8))
 
 
 def test_to_varying_inside_manual_region():

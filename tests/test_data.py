@@ -108,10 +108,43 @@ def test_native_gather_bounds_check(token_file):
 
 def test_native_library_builds():
     """The toolchain is baked into the image, so the native path (not the
-    fallback) must be what tests exercise."""
+    fallback) must be what tests exercise — built here, from the
+    committed source, under a name that says so."""
+    import hashlib
+    import os
+
     from midgpt_tpu import native
 
     assert native.native_available()
+    assert native.gather_backend() == "native"
+    with open(native._SRC, "rb") as f:
+        src = f.read()
+    want = hashlib.sha256(src + " ".join(native._FLAGS).encode())
+    assert os.path.basename(native._lib_path()) == (
+        f"libdatagather-{want.hexdigest()[:16]}.so"
+    )
+    assert os.path.exists(native._lib_path())
+    assert "-march=native" not in native._FLAGS
+
+
+def test_native_build_failure_is_said_not_hidden(monkeypatch, capsys):
+    """No toolchain: the numpy path serves the same windows, and the
+    reason is on stderr and in gather_backend() — not a silent switch."""
+    from midgpt_tpu import native
+
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_why_numpy", None)
+    monkeypatch.setattr(
+        native, "_lib_path", lambda: "/nonexistent-dir/libdatagather-x.so"
+    )
+    assert not native.native_available()
+    assert native.gather_backend().startswith("numpy (")
+    assert "falls back to numpy" in capsys.readouterr().err
+    tokens = np.arange(100, dtype=np.uint16)
+    x, y = native.gather_windows(tokens, np.array([3]), 8)
+    np.testing.assert_array_equal(x[0], np.arange(3, 11))
+    np.testing.assert_array_equal(y[0], np.arange(4, 12))
 
 
 def test_prefetch_loader_matches_sync(token_file):

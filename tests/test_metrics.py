@@ -35,3 +35,33 @@ def test_flops_per_token_gpt2_small():
     # the 124M config (sanity: within 10% of 6 * 130M)
     f = flops_per_token(model)
     assert 7.0e8 < f < 9.0e8
+
+
+def test_device_peak_flops_has_no_default():
+    """A device that is not in the peak table is an error, not a v5e: a
+    CPU run must not be able to print a chip-relative utilization."""
+    import types
+
+    import jax
+    import pytest
+
+    from midgpt_tpu.utils.metrics import (
+        UnknownDevicePeak,
+        device_peak_flops,
+        mfu,
+    )
+    from midgpt_tpu.config import get_config
+
+    with pytest.raises(UnknownDevicePeak):
+        device_peak_flops()  # this suite runs on the CPU
+    with pytest.raises(UnknownDevicePeak):
+        mfu(1e5, get_config("openwebtext").model, jax.device_count())
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peak_flops(v5e) == 197e12
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v9x")
+    with pytest.raises(UnknownDevicePeak):
+        device_peak_flops(unknown)
+    # a CPU whose kind string happens to contain a table key is still a CPU
+    odd = types.SimpleNamespace(platform="cpu", device_kind="v5 emulator")
+    with pytest.raises(UnknownDevicePeak):
+        device_peak_flops(odd)

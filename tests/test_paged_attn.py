@@ -18,8 +18,9 @@
   stable, and wrong; the prefix-cache-hit identity test pins it).
 
 Kernels execute through the Pallas CPU interpreter on this tier (the
-same bodies the TPU runs — ops/paged_attn resolves ``interpret`` off
-the backend)."""
+same bodies the TPU compiles; tests/test_chip_compile.py holds the
+Mosaic compiles) — asked for by the ``pallas_interpret`` fixture, never
+chosen by the program."""
 
 import dataclasses
 
@@ -46,6 +47,11 @@ CFG = ModelConfig(
 )
 # GQA shape: 4 query heads sharing 2 KV heads — the grouped walk
 GQA_CFG = dataclasses.replace(CFG, n_kv_head=2)
+
+
+@pytest.fixture(autouse=True)
+def _interpret_mode(pallas_interpret):
+    yield
 
 
 def _model(cfg=CFG):
@@ -583,53 +589,41 @@ def test_paged_kernel_auto_resolves_to_xla_on_cpu():
 
 
 def test_kernel_supported_gates_on_vmem():
-    """Band-aware gate (ISSUE 20): the working set is one band's
-    double-buffered K/V stream (+ its f32 dequant views) plus the
-    full-context f32 score/prob rows — O(band), not O(Pmax) — so the
-    contexts the whole-pool assembly used to reject now fit, while the
-    residency that CANNOT band (the flat-softmax score rows, scaled by
-    the REAL group count and spec length) still rejects honestly."""
+    """The gate is what the chip's compiler was seen to accept (PR 21,
+    tests/test_chip_compile.py holds the compiles): every block-table
+    page is a VMEM-resident block, so the working set is O(Pmax) plus
+    one band's f32 compute and the flat-softmax score rows."""
     from midgpt_tpu.ops.paged_attn import supported
 
-    assert supported(pmax=64, page_size=16, hkv=12, c=64, itemsize=2,
-                     groups=1)
-    # pre-banding this overflowed (~600 MB whole-pool assembly); the
-    # banded stream makes it a ~2.5 MB working set
-    assert supported(pmax=4096, page_size=16, hkv=12, c=64,
-                     itemsize=2, groups=1)
-    # int8 pool: the per-band f32 dequant views (4 counted bytes per
-    # 1-byte element) and the [Pmax] f32 scale planes still ride the
-    # arithmetic — per-band now, so this fits too (PR 9's accounting
-    # survives banding, applied to the band)
-    assert supported(pmax=256, page_size=16, hkv=8, c=64,
-                     itemsize=1, groups=8)
-    # what banding CANNOT shrink: the flat-softmax f32 score + prob
-    # rows are [G, T, W]-resident. Wide GQA groups scale them past the
-    # budget — the gate must count the REAL group size, not a cap
-    assert supported(pmax=256, page_size=16, hkv=2, c=64, itemsize=2,
-                     groups=128)
-    assert not supported(pmax=4096, page_size=16, hkv=2, c=64,
-                         itemsize=2, groups=128)
-    # ... and speculation multiplies the rows by T = speculate + 1: a
-    # geometry that fits for decode can overflow for verify
-    assert supported(pmax=4096, page_size=16, hkv=2, c=64, itemsize=2,
-                     groups=12)
-    assert not supported(pmax=4096, page_size=16, hkv=2, c=64,
-                         itemsize=2, groups=12, spec_t=5)
+    # the shapes compiled for the described v5e
+    assert supported(pmax=64, page_size=16, c=64, itemsize=2, groups=1)
+    assert supported(pmax=64, page_size=16, c=64, itemsize=1, groups=1)
+    assert supported(pmax=128, page_size=16, c=128, itemsize=2, groups=4)
+    assert supported(pmax=128, page_size=16, c=128, itemsize=1, groups=4,
+                     spec_t=4)
+    # the page blocks scale with Pmax ...
+    assert not supported(pmax=4096, page_size=16, c=64, itemsize=2,
+                         groups=1)
+    # ... the f32 product and the score rows with the REAL group count
+    # and spec length, not a cap
+    assert supported(pmax=256, page_size=16, c=64, itemsize=2, groups=12)
+    assert not supported(pmax=256, page_size=16, c=64, itemsize=2,
+                         groups=128)
+    assert supported(pmax=512, page_size=16, c=64, itemsize=2, groups=4)
+    assert not supported(pmax=512, page_size=16, c=64, itemsize=2,
+                         groups=4, spec_t=8)
 
 
-def test_kernel_gate_accepts_100k_token_pmax():
-    """Long-context decode (ISSUE 20): at a 100k-token context the
-    block table spans ``pages_needed(100_000, 16) = 6250`` pages. The
-    whole-pool assembly was ~0.9 GB (the old gate's rejection); the
-    banded working set is band-stream + score rows, and ``supported()``
-    now returns True for BOTH pool dtypes at a 12-wide GQA group. The
-    byte arithmetic is pinned exactly — band auto-sizing included —
-    so a regression in the plan (band too big, a dropped dequant view,
-    lost scale planes) moves a literal."""
+def test_kernel_gate_rejects_100k_token_pmax():
+    """At a 100k-token context the block table spans
+    ``pages_needed(100_000, 16) = 6250`` pages. PR 20's manual band DMA
+    would have held O(band) of them; Mosaic refuses that DMA for
+    16-wide pages, the page-block walk that does compile holds all of
+    them, and the gate says no. The byte arithmetic is pinned exactly
+    so a dropped term moves a literal; the band PLAN (which fixes the
+    PV fold order on the XLA side too) is pinned unchanged."""
     from midgpt_tpu.ops.paged_attn import (
         BAND_VMEM_BUDGET,
-        DMA_DEPTH,
         VMEM_BUDGET,
         band_pages,
         supported,
@@ -640,74 +634,57 @@ def test_kernel_gate_accepts_100k_token_pmax():
     pmax = pages_needed(100_000, 16)
     assert pmax == 6250
     w = pmax * 16  # 100_000 resident positions
-    # band plan, bf16: largest divisor of 6250 whose K+V stream
-    # buffers (x DMA_DEPTH) + f32 dequant views fit the band budget
-    assert DMA_DEPTH == 2
-    assert band_pages(pmax, 16, 64, 2) == 125  # 50 bands of 2000 pos
-    band_bf16 = 2 * DMA_DEPTH * 64 * (125 * 16) * 2 \
-        + 2 * 64 * (125 * 16) * 4
-    assert band_bf16 == 2_048_000 <= BAND_VMEM_BUDGET
-    # the residency banding cannot shrink: [G, T, W] f32 score + prob
-    # rows, G=12 query heads per KV head, decode T=1
-    scores = 2 * 12 * 1 * w * 4
-    assert vmem_bytes(pmax, 16, 12, 64, 2, groups=12) \
-        == band_bf16 + scores == 11_648_000 <= VMEM_BUDGET
-    assert supported(pmax, 16, 12, 64, 2, groups=12)
-    # int8 pool: thinner stream, same dequant views, plus the [Pmax]
-    # f32 scale planes (K and V)
+    # band plan, bf16 and int8: 50 bands of 2000 positions
+    assert band_pages(pmax, 16, 64, 2) == 125
     assert band_pages(pmax, 16, 64, 1) == 125
-    band_int8 = 2 * DMA_DEPTH * 64 * (125 * 16) * 1 \
-        + 2 * 64 * (125 * 16) * 4
-    assert vmem_bytes(pmax, 16, 12, 64, 1, groups=12) \
-        == band_int8 + scores + 2 * pmax * 4 == 11_186_000
-    assert supported(pmax, 16, 12, 64, 1, groups=12)
-    # hkv no longer enters: the grid runs over (slot x KV head), so
-    # per-program residency is head-count-free
-    for hkv in (12, 6, 3, 1):
-        assert vmem_bytes(pmax, 16, hkv, 64, 2, groups=1) == 2_848_000
-        assert vmem_bytes(pmax, 16, hkv, 64, 1, groups=1) == 2_386_000
-    # verify still gated: speculation multiplies the score rows by T
-    assert not supported(pmax, 16, 12, 64, 2, groups=12, spec_t=2)
-    # adversarial geometry overflowing even a ONE-page band (C so wide
-    # the smallest stream buffer exceeds the band budget): band_pages
-    # finds no plan and the gate reports the honest whole-table cost
+    assert 2 * 2 * 64 * 2000 * 2 + 2 * 64 * 2000 * 4 <= BAND_VMEM_BUDGET
+    # bf16: [64, 16] pages pad to [64, 128] tiles, K and V, twice each
+    pages_bf16 = 2 * 2 * pmax * (64 * 128 * 2)
+    band_bf16 = 64 * 2000 * (2 + 4) + 12 * 64 * 2000 * 4
+    scores = 2 * 12 * 8 * w * 4
+    assert vmem_bytes(pmax, 16, 64, 2, groups=12) \
+        == pages_bf16 + band_bf16 + scores == 493_312_000 > VMEM_BUDGET
+    assert not supported(pmax, 16, 64, 2, groups=12)
+    # int8: same padded page bytes per element, plus the scale rows
+    pages_int8 = 2 * 2 * pmax * (64 * 128 * 1)
+    band_int8 = 64 * 2000 * (1 + 4) + 12 * 64 * 2000 * 4
+    assert vmem_bytes(pmax, 16, 64, 1, groups=12) \
+        == pages_int8 + band_int8 + scores + 2 * 2 * 8 * w * 4 \
+        == 301_184_000
+    assert not supported(pmax, 16, 64, 1, groups=12)
+    # no band plan, no kernel: a head dim so wide one page overflows
+    # the band budget, and a prime page count whose only fitting
+    # divisor needs > MAX_BANDS bands
     assert band_pages(pmax, 16, 16384, 2) is None
-    assert not supported(pmax, 16, 1, 16384, 2)
-    # pathologically-factored Pmax: a prime page count's only fitting
-    # divisor is 1, which needs > MAX_BANDS bands — no plan, honest
-    # whole-table fallback, rejected
+    assert not supported(pmax, 16, 16384, 2)
     assert band_pages(6247, 16, 64, 2) is None
-    assert not supported(6247, 16, 12, 64, 2, groups=12)
+    assert not supported(6247, 16, 64, 2, groups=12)
 
 
-def test_auto_kernel_selects_pallas_at_long_context(monkeypatch):
-    """``auto`` consults the band-aware gate with the LONG-context
-    Pmax: with the backend forced to TPU, a 100k-block model now
-    resolves to the Pallas kernel (the banded working set fits) —
-    while a block size whose prime page count defeats the band plan
-    still falls back to XLA honestly. Resolution gates on geometry,
-    not platform alone."""
+def test_auto_kernel_follows_the_gate_on_tpu(monkeypatch):
+    """``auto`` resolves from the platform AND the geometry gate: with
+    the backend forced to TPU a 64-token table takes the kernel, a
+    100k-token one is served by the XLA gather."""
     import midgpt_tpu.utils.platform as platform
 
     monkeypatch.setattr(platform, "is_tpu_backend", lambda: True)
+    eng_short = ServingEngine(
+        _model(), slots=1, page_size=16, window=2, paged_kernel="auto"
+    )
+    assert eng_short.paged_kernel == "pallas"
     long_cfg = dataclasses.replace(CFG, block_size=100_000)
     eng = ServingEngine(
         _model(long_cfg), slots=1, page_size=16, window=2,
         num_pages=8, paged_kernel="auto",
     )
-    assert eng.paged_kernel == "pallas"
-    eng_short = ServingEngine(
-        _model(), slots=1, page_size=16, window=2, paged_kernel="auto"
-    )
-    assert eng_short.paged_kernel == "pallas"
-    # 99_952 tokens -> 6247 pages (prime): no band plan fits MAX_BANDS,
-    # the gate reports the whole-table cost, auto falls back
-    prime_cfg = dataclasses.replace(CFG, block_size=99_952)
-    eng_prime = ServingEngine(
-        _model(prime_cfg), slots=1, page_size=16, window=2,
-        num_pages=8, paged_kernel="auto",
-    )
-    assert eng_prime.paged_kernel == "xla"
+    assert eng.paged_kernel == "xla"
+    # asked for by name where it cannot compile: an error, not a
+    # quiet switch to another path under the kernel's name
+    with pytest.raises(ValueError, match="does not take the kernels"):
+        ServingEngine(
+            _model(long_cfg), slots=1, page_size=16, window=2,
+            num_pages=8, paged_kernel="pallas",
+        )
 
 
 def test_engine_rejects_unknown_kv_quant():

@@ -12,7 +12,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from midgpt_tpu.config import ModelConfig
 from midgpt_tpu.models.gpt import GPT
 from midgpt_tpu.ops.attention import naive_attention
-from midgpt_tpu.compat import shard_map
+from jax import shard_map
 from midgpt_tpu.parallel.ring import ring_attention
 from midgpt_tpu.parallel.sharding import axis_rules
 

@@ -380,8 +380,8 @@ def test_cluster_transient_exhaustion_fails_over(model, cluster_case):
 
 
 def test_cluster_wedge_watchdog_failover(model, cluster_case):
-    """The wedged-relay case (r4/r5 BENCH post-mortems), scripted: a
-    dispatch stalls past the wall-clock watchdog; the replica is
+    """A dispatch that never returns, scripted: it stalls past the
+    wall-clock watchdog; the replica is
     abandoned (dead, never re-stepped) and its backlog fails over
     bit-identically."""
     prompts, kw, refs = cluster_case

@@ -563,7 +563,8 @@ def test_bench_serving_telemetry_record_contract(tmp_path):
         rec["serve_floor_ms_per_tok_static"] / rec["serve_ms_per_tok"],
         rel=1e-2,
     )
-    assert rec["serve_mfu"] is not None and rec["serve_mfu"] > 0
+    # utilization against a chip's peak is not measured on the CPU
+    assert rec["serve_mfu"] is None
     assert rec["serve_hbm_floor_ms_static"] > 0
     # --metrics_out: Prometheus text exposition over the cluster
     # registry, path recorded in-band
@@ -670,11 +671,10 @@ def test_bench_serving_longctx_record_contract(tmp_path):
     # overall p99 and must be populated
     assert rec["serve_ttft_long_p99"] is not None
     assert rec["serve_ttft_long_p99"] == rec["serve_ttft_p99_ms"]
-    # static floor pair: sp divides the per-chip prefill compute by tp
-    assert rec["serve_prefill_floor_ms_static"] > 0
-    assert rec["serve_prefill_sp_floor_ms_static"] == pytest.approx(
-        rec["serve_prefill_floor_ms_static"] / 2, rel=0.5
-    )
+    # the prefill compute floors divide by the chip's peak FLOP/s: the
+    # keys ride the record, not measured on the CPU
+    assert rec["serve_prefill_floor_ms_static"] is None
+    assert rec["serve_prefill_sp_floor_ms_static"] is None
     # the 10-page pool is smaller than the 6-request working set: cold
     # chains must have spilled to host RAM, and the host store's
     # cumulative residency may legitimately exceed the pool itself

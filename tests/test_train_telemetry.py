@@ -311,12 +311,17 @@ def test_window_drive_with_telemetry_attached_is_bitwise(mesh8):
 
 
 @pytest.mark.slow
-def test_train_e2e_telemetry_on_off_bitwise_and_artifacts(tmp_path):
+def test_train_e2e_telemetry_on_off_bitwise_and_artifacts(tmp_path, monkeypatch):
     """train() end to end, K=4: telemetry on vs off logs the identical
     per-step loss sequence, resolves the SAME cached window program
     (module-level cache gains no new entries on the second run), and
     the traced run writes the timeline + flight artifacts with the
     attainment keys riding every throughput record."""
+    # the CPU has no peak FLOP/s; the floor context needs one, so the
+    # test names the chip it wants the floors against
+    monkeypatch.setattr(
+        "midgpt_tpu.utils.metrics.device_peak_flops", lambda: 197e12
+    )
     data_dir = _data_dir(tmp_path)
     cfg_off = _base_cfg(
         rundir=str(tmp_path / "off"), data_dir=data_dir,
