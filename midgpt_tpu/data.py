@@ -28,6 +28,7 @@ import typing as tp
 import numpy as np
 
 from midgpt_tpu.native import gather_windows
+from midgpt_tpu.telemetry import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -228,18 +229,18 @@ class PrefetchLoader:
             if stop.is_set():
                 return
             try:
-                if self._windowed:
-                    draws = [
-                        self.loader.peek(begin_step + produced + i)
-                        for i in range(w)
-                    ]
-                    batch = self._transform(
-                        *(np.stack(col) for col in zip(*draws))
-                    )
-                else:
-                    batch = self._transform(
-                        *self.loader.peek(begin_step + produced)
-                    )
+                with span("midgpt.loader.produce", k=w):
+                    with span("midgpt.loader.gather"):
+                        if self._windowed:
+                            draws = [
+                                self.loader.peek(begin_step + produced + i)
+                                for i in range(w)
+                            ]
+                            cols = [np.stack(col) for col in zip(*draws)]
+                        else:
+                            cols = self.loader.peek(begin_step + produced)
+                    with span("midgpt.loader.transfer"):
+                        batch = self._transform(*cols)
                 produced += w
                 item = (w, batch)
             except BaseException as exc:  # propagate to the consumer
@@ -279,10 +280,14 @@ class PrefetchLoader:
             self._thread.start()
         return self
 
-    def next(self):
+    def next(self, log=None, step: int = 0):
+        """The next item; blocks while the worker has none ready. A
+        consumer that traces passes its ``TelemetryLog`` and the step the
+        item is for: the wait is then also its ``prefetch_wait`` record."""
         if self._thread is None:
             self.start()
-        w, batch = self._queue.get()
+        with span("midgpt.loader.wait", log, "prefetch_wait", step=step):
+            w, batch = self._queue.get()
         if isinstance(batch, _PrefetchError):
             self.stop()
             raise batch.exc

@@ -91,6 +91,7 @@ import numpy as np
 
 from midgpt_tpu.serving.engine import HandoffRecord, Request, ServingEngine
 from midgpt_tpu.serving.telemetry import EngineTelemetry
+from midgpt_tpu.telemetry import span
 from midgpt_tpu.serving.faults import (
     AdmissionRejected,
     ClusterUnavailable,
@@ -782,9 +783,14 @@ class ServingCluster:
                 grid = rev.get((i, req.rid))
                 if grid is None:
                     continue  # not cluster-routed (direct engine use)
-                t0 = eng.clock()
                 try:
-                    rec = eng.export_request(s)
+                    with span(
+                        "midgpt.cluster.handoff", eng.telemetry, "handoff",
+                        clock=eng.clock, rids=(req.rid,),
+                        step=eng.fault_step, rid=req.rid,
+                    ) as sp:
+                        rec = eng.export_request(s)
+                        sp.data.update(pages=rec.n_pages, bytes=rec.nbytes)
                 except HandoffFailed:
                     self.handoff_failures += 1
                     # the export raised BEFORE any state left the slot:
@@ -796,12 +802,6 @@ class ServingCluster:
                     del self._route[grid]
                     self._requeue_cold(grid)
                     continue
-                if eng.telemetry is not None:
-                    eng.telemetry.record_dispatch(
-                        "handoff", step=eng.fault_step, t=t0,
-                        dur=eng.clock() - t0, rids=(req.rid,), tokens=0,
-                        pages=rec.n_pages, bytes=rec.nbytes,
-                    )
                 del self._route[grid]
                 self._handoff[grid] = rec
         for grid in list(self._handoff):

@@ -111,6 +111,7 @@ from midgpt_tpu.serving.telemetry import (
     MetricsRegistry,
     write_json,
 )
+from midgpt_tpu.telemetry import span
 from midgpt_tpu.serving.paged import (
     HostSpillStore,
     PageAllocator,
@@ -1627,66 +1628,72 @@ class ServingEngine:
         resubmit; the front door turns it into awaitable backpressure).
         Both are counted in :meth:`stats` — overload must show up in
         telemetry, not as a crash."""
-        if max_new_tokens < 1:
-            self._reject("bad_budget", f"max_new_tokens {max_new_tokens} < 1")
-        if max_new_tokens >= self.block:
-            self._reject(
-                "budget_exceeds_block",
-                f"max_new_tokens {max_new_tokens} must leave room for at "
-                f"least one prompt token in block_size {self.block}",
-            )
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if prompt.size < 1:
-            self._reject("empty_prompt", "prompt has no tokens")
-        keep = self.block - max_new_tokens
-        if prompt.size > keep:
-            prompt = prompt[-keep:]
-        lifetime = pages_needed(
-            int(prompt.size) + max_new_tokens, self.page_size
-        )
-        if lifetime > self.alloc.num_pages:
-            self._reject(
-                "lifetime_exceeds_pool",
-                f"request needs {lifetime} pages over its lifetime but the "
-                f"pool holds {self.alloc.num_pages}; raise num_pages",
-            )
-        if self.max_queue is not None and len(self.queue) >= self.max_queue:
-            if self.overload_policy == "shed":
-                self.shed_requests += 1
-                self._emit("shed", reason="queue_full")
+        with span("midgpt.engine.submit"):
+            if max_new_tokens < 1:
                 self._reject(
-                    "queue_full",
-                    f"wait queue at max_queue={self.max_queue}; shed",
+                    "bad_budget", f"max_new_tokens {max_new_tokens} < 1"
                 )
-            self.deferred_submits += 1
-            self._emit("deferred", reason="queue_full")
-            raise PoolOverloaded(
-                "queue_full",
-                f"wait queue at max_queue={self.max_queue}; retry later",
+            if max_new_tokens >= self.block:
+                self._reject(
+                    "budget_exceeds_block",
+                    f"max_new_tokens {max_new_tokens} must leave room for at "
+                    f"least one prompt token in block_size {self.block}",
+                )
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            if prompt.size < 1:
+                self._reject("empty_prompt", "prompt has no tokens")
+            keep = self.block - max_new_tokens
+            if prompt.size > keep:
+                prompt = prompt[-keep:]
+            lifetime = pages_needed(
+                int(prompt.size) + max_new_tokens, self.page_size
             )
-        if deadline is None and deadline_s is not None:
-            deadline = self.clock() + deadline_s
-        req = self.make_request(
-            prompt, max_new_tokens, eos_id=eos_id, seed=seed,
-            priority=priority, deadline=deadline,
-        )
-        # the rid resubmit is about to assign — emitted here so the
-        # lifecycle reads submit -> queued in order. Scheduling fields
-        # ride in the event data only when non-default, so existing
-        # replay signatures are untouched (priority is a deterministic
-        # caller input; the absolute deadline is a clock value and
-        # stays out — has_deadline is the deterministic projection).
-        extra: tp.Dict[str, tp.Any] = {}
-        if priority:
-            extra["priority"] = int(priority)
-        if deadline is not None:
-            extra["has_deadline"] = True
-        self._emit(
-            "submit", rid=self._next_rid, t=req.submit_time,
-            prompt_tokens=int(req.prompt.size), budget=int(max_new_tokens),
-            **extra,
-        )
-        return self.resubmit(req)
+            if lifetime > self.alloc.num_pages:
+                self._reject(
+                    "lifetime_exceeds_pool",
+                    f"request needs {lifetime} pages over its lifetime but "
+                    f"the pool holds {self.alloc.num_pages}; raise num_pages",
+                )
+            if (
+                self.max_queue is not None
+                and len(self.queue) >= self.max_queue
+            ):
+                if self.overload_policy == "shed":
+                    self.shed_requests += 1
+                    self._emit("shed", reason="queue_full")
+                    self._reject(
+                        "queue_full",
+                        f"wait queue at max_queue={self.max_queue}; shed",
+                    )
+                self.deferred_submits += 1
+                self._emit("deferred", reason="queue_full")
+                raise PoolOverloaded(
+                    "queue_full",
+                    f"wait queue at max_queue={self.max_queue}; retry later",
+                )
+            if deadline is None and deadline_s is not None:
+                deadline = self.clock() + deadline_s
+            req = self.make_request(
+                prompt, max_new_tokens, eos_id=eos_id, seed=seed,
+                priority=priority, deadline=deadline,
+            )
+            # the rid resubmit is about to assign — emitted here so the
+            # lifecycle reads submit -> queued in order. Scheduling fields
+            # ride in the event data only when non-default, so existing
+            # replay signatures are untouched (priority is a deterministic
+            # caller input; the absolute deadline is a clock value and
+            # stays out — has_deadline is the deterministic projection).
+            extra: tp.Dict[str, tp.Any] = {}
+            if priority:
+                extra["priority"] = int(priority)
+            if deadline is not None:
+                extra["has_deadline"] = True
+            self._emit(
+                "submit", rid=self._next_rid, t=req.submit_time,
+                prompt_tokens=int(req.prompt.size), budget=int(max_new_tokens),
+                **extra,
+            )
+            return self.resubmit(req)
 
     def make_request(
         self,
@@ -2389,42 +2396,40 @@ class ServingEngine:
             else min(self.prefill_chunk, remaining)
         )
         bucket = self._prefill_bucket(clen)
-        toks = np.full((1, bucket), self.pad_id, np.int32)
-        toks[0, :clen] = req.prompt[start : start + clen]
-        if bucket not in self._chunk_fns:
-            self._chunk_fns[bucket] = make_prefill_chunk_program(
-                self.model,
-                chunk_len=bucket,
-                pmax=self.pmax,
-                rope_len=self.block,
-                mesh=self._mesh,
-                layer_scan=self.layer_scan,
-                prefill_sp=self.prefill_sp,
-            )
         tele = self.telemetry
-        t0 = self.clock() if tele is not None else 0.0
-        self.pool, self.logits = self._chunk_fns[bucket](
-            self.model,
-            self.pool,
-            self.logits,
-            jnp.asarray(s, jnp.int32),
-            jnp.asarray(toks),
-            jnp.asarray(start, jnp.int32),
-            jnp.asarray(clen, jnp.int32),
-            jnp.asarray(self.bt[s]),
-        )
+        with span(
+            "midgpt.engine.prefill_dispatch", tele, "prefill_chunk",
+            clock=self.clock, rids=(req.rid,), step=self.fault_step,
+            rid=req.rid, slot=s, start=start, chunk=clen, bucket=bucket,
+        ) as sp:
+            toks = np.full((1, bucket), self.pad_id, np.int32)
+            toks[0, :clen] = req.prompt[start : start + clen]
+            if bucket not in self._chunk_fns:
+                self._chunk_fns[bucket] = make_prefill_chunk_program(
+                    self.model,
+                    chunk_len=bucket,
+                    pmax=self.pmax,
+                    rope_len=self.block,
+                    mesh=self._mesh,
+                    layer_scan=self.layer_scan,
+                    prefill_sp=self.prefill_sp,
+                )
+            self.pool, self.logits = self._chunk_fns[bucket](
+                self.model,
+                self.pool,
+                self.logits,
+                jnp.asarray(s, jnp.int32),
+                jnp.asarray(toks),
+                jnp.asarray(start, jnp.int32),
+                jnp.asarray(clen, jnp.int32),
+                jnp.asarray(self.bt[s]),
+            )
         self.prefill_dispatches += 1
         self.prefill_tokens_computed += clen
         if tele is not None:
-            t1 = self.clock()
-            tele.record_dispatch(
-                "prefill_chunk", step=self.fault_step, t=t0, dur=t1 - t0,
-                rids=(req.rid,), tokens=0, slot=s, start=start,
-                chunk=clen, bucket=bucket,
-            )
             tele.emit(
-                "prefill_chunk", step=self.fault_step, t=t1, rid=req.rid,
-                slot=s, start=start, chunk=clen, bucket=bucket,
+                "prefill_chunk", step=self.fault_step, t=sp.t0 + sp.dur,
+                rid=req.rid, slot=s, start=start, chunk=clen, bucket=bucket,
             )
         self.pooled_len[s] = start + clen
         self._register_pages(s)
@@ -2725,90 +2730,175 @@ class ServingEngine:
     def _run_verify(self, decoding: tp.List[int]) -> None:
         """One speculative verify dispatch + harvest (the spec-mode
         replacement for the K-step decode window)."""
-        drafts, n_draft, draft_probs = self._draft(decoding)
         tele = self.telemetry
-        if tele is not None:
-            t0 = self.clock()
-            rids = tuple(self.slot_req[s].rid for s in decoding)
-        args = [
-            self.model,
-            self.pool,
-            self.logits,
-            jnp.asarray(self.bt),
-            jnp.asarray(self.pooled_len),
-            jnp.asarray(self.done),
-            jnp.asarray(self.emitted),
-            jnp.asarray(self.budget),
-            jnp.asarray(self.eos),
-            jnp.asarray(drafts),
-            jnp.asarray(n_draft),
-        ]
-        if self.temperature > 0.0:
-            # sampled verify: per-slot request seeds + the engine's base
-            # key — the program derives every categorical/acceptance
-            # stream from (seed, stream position) alone, so the same
-            # discipline that makes the plain sampled window scheduling-
-            # invariant carries over to speculation unchanged
-            args += [jnp.asarray(self.seeds), self._key]
-            if draft_probs is not None:
-                args.append(jnp.asarray(draft_probs))
-        (
-            self.pool, self.logits, cand, emit, done_d, new_len,
-            emitted_d, n_acc,
-        ) = self._verify_fn(*args)
-        self.decode_dispatches += 1
+        with span(
+            "midgpt.engine.decode_dispatch", tele, clock=self.clock
+        ) as disp:
+            drafts, n_draft, draft_probs = self._draft(decoding)
+            args = [
+                self.model,
+                self.pool,
+                self.logits,
+                jnp.asarray(self.bt),
+                jnp.asarray(self.pooled_len),
+                jnp.asarray(self.done),
+                jnp.asarray(self.emitted),
+                jnp.asarray(self.budget),
+                jnp.asarray(self.eos),
+                jnp.asarray(drafts),
+                jnp.asarray(n_draft),
+            ]
+            if self.temperature > 0.0:
+                # sampled verify: per-slot request seeds + the engine's
+                # base key — the program derives every categorical/
+                # acceptance stream from (seed, stream position) alone, so
+                # the same discipline that makes the plain sampled window
+                # scheduling-invariant carries over to speculation
+                # unchanged
+                args += [jnp.asarray(self.seeds), self._key]
+                if draft_probs is not None:
+                    args.append(jnp.asarray(draft_probs))
+            (
+                self.pool, self.logits, cand, emit, done_d, new_len,
+                emitted_d, n_acc,
+            ) = self._verify_fn(*args)
         self.verify_dispatches += 1
-        self.windows += 1
-        self.occupancy_sum += len(decoding)
 
         # ONE device->host sync per dispatch: the [S, T] outputs
-        cand_h = np.asarray(cand)
-        emit_h = np.asarray(emit)
-        n_acc_h = np.asarray(n_acc)
-        self.done = np.array(done_d)
-        self.pooled_len = np.array(new_len, np.int32)
-        self.emitted = np.array(emitted_d, np.int32)
-        now = self.clock()
-        if tele is not None:
-            # timestamped at the existing harvest sync — tracing adds
-            # no device round-trip of its own
-            n_window = int(emit_h[np.asarray(decoding)].sum())
-            tele.record_dispatch(
-                "verify_dispatch", step=self.fault_step, t=t0,
-                dur=now - t0, rids=rids, tokens=n_window,
-                drafted=int(np.asarray(n_draft)[np.asarray(decoding)].sum()),
-                accepted=int(n_acc_h[np.asarray(decoding)].sum()),
-            )
-            self.metrics.histogram("dispatch_s").observe(now - t0)
-            tele.emit(
-                "verify_dispatch", step=self.fault_step, t=now,
-                slots=len(decoding), tokens=n_window,
-            )
-        finished_any = False
-        for s in decoding:
-            req = self.slot_req[s]
-            new = [
+        with self._harvest_span(disp, "verify_dispatch", decoding) as hw:
+            cand_h = np.asarray(cand)
+            emit_h = np.asarray(emit)
+            n_acc_h = np.asarray(n_acc)
+            self._harvest_state(done_d, new_len, emitted_d)
+            if tele is not None:
+                live = np.asarray(decoding)
+                hw.tokens = int(emit_h[live].sum())
+                hw.data.update(
+                    drafted=int(np.asarray(n_draft)[live].sum()),
+                    accepted=int(n_acc_h[live].sum()),
+                )
+
+        def harvested(s: int, req: Request) -> tp.List[int]:
+            self._adapt_spec(req, int(n_draft[s]), int(n_acc_h[s]))
+            return [
                 int(cand_h[s, j])
                 for j in range(self.speculate + 1)
                 if emit_h[s, j]
             ]
-            if new and req.first_token_time is None:
-                req.first_token_time = now
-            req.tokens.extend(new)
-            self.slot_ctx[s].extend(new)
-            self.tokens_generated += len(new)
-            self._adapt_spec(req, int(n_draft[s]), int(n_acc_h[s]))
-            self._register_pages(s)
-            if tele is not None:
-                tele.emit(
-                    "tokens", step=self.fault_step, t=now, rid=req.rid,
-                    n=len(new), total=len(req.tokens), slot=s,
-                )
-            if self.done[s]:
-                self._finish_request(req, now, s)
-                finished_any = True
-        if finished_any and self.parked:
-            self._unpark()  # freed pages: parked requests get another shot
+
+        self._harvest_apply(hw, decoding, harvested)
+
+    def _run_window(self, decoding: tp.List[int]) -> None:
+        """One K-step decode window dispatch + harvest."""
+        with span(
+            "midgpt.engine.decode_dispatch", self.telemetry,
+            clock=self.clock,
+        ) as disp:
+            (
+                self.pool, self.logits, toks, emit, done_d, new_len,
+                emitted_d,
+            ) = self._window_fn(
+                self.model,
+                self.pool,
+                self.logits,
+                jnp.asarray(self.bt),
+                jnp.asarray(self.pooled_len),
+                jnp.asarray(self.done),
+                jnp.asarray(self.emitted),
+                jnp.asarray(self.budget),
+                jnp.asarray(self.eos),
+                jnp.asarray(self.seeds),
+                self._key,
+            )
+
+        # ONE device->host sync per window: the stacked [K, S] outputs
+        with self._harvest_span(
+            disp, "decode_window", decoding, window=self.window
+        ) as hw:
+            toks_h = np.asarray(toks)
+            emit_h = np.asarray(emit)
+            self._harvest_state(done_d, new_len, emitted_d)
+            if self.telemetry is not None:
+                hw.tokens = int(emit_h[:, np.asarray(decoding)].sum())
+
+        def harvested(s: int, req: Request) -> tp.List[int]:
+            return [
+                int(toks_h[r, s]) for r in range(self.window) if emit_h[r, s]
+            ]
+
+        self._harvest_apply(hw, decoding, harvested)
+
+    def _harvest_span(
+        self, disp: span, kind: str, decoding: tp.List[int], **stats
+    ) -> span:
+        """Counts the dispatch just enqueued and opens the span in which
+        the host blocks on its outputs. Under tracing the record ``kind``
+        runs from the dispatch's start (``disp``) to the end of these
+        reads, the existing sync: tracing adds no device round-trip of its
+        own."""
+        self.decode_dispatches += 1
+        self.windows += 1
+        self.occupancy_sum += len(decoding)
+        tele = self.telemetry
+        return span(
+            "midgpt.engine.harvest_wait", tele, kind, clock=self.clock,
+            t0=disp.t0, step=self.fault_step,
+            rids=(
+                tuple(self.slot_req[s].rid for s in decoding)
+                if tele is not None else ()
+            ),
+            **stats,
+        )
+
+    def _harvest_state(self, done_d, new_len, emitted_d) -> None:
+        # np.array (copy): zero-copy views of jax buffers are read-only,
+        # and the scheduler mutates these in place
+        self.done = np.array(done_d)
+        self.pooled_len = np.array(new_len, np.int32)
+        self.emitted = np.array(emitted_d, np.int32)
+
+    def _harvest_apply(
+        self,
+        hw: span,
+        decoding: tp.List[int],
+        harvested: tp.Callable[[int, Request], tp.List[int]],
+    ) -> None:
+        """The bookkeeping after the harvest ``hw``: each decoding slot's
+        new tokens (``harvested(slot, request)``), its pages registered,
+        and finished requests retired."""
+        now = self.clock()
+        tele = self.telemetry
+        if tele is not None:
+            self.metrics.histogram("dispatch_s").observe(hw.dur)
+            tele.emit(
+                hw.kind, step=self.fault_step, t=now,
+                slots=len(decoding), tokens=hw.tokens,
+            )
+        with span("midgpt.engine.harvest_apply"):
+            finished_any = False
+            for s in decoding:
+                req = self.slot_req[s]
+                new = harvested(s, req)
+                if new and req.first_token_time is None:
+                    req.first_token_time = now
+                req.tokens.extend(new)
+                self.slot_ctx[s].extend(new)
+                self.tokens_generated += len(new)
+                # generated tokens fill pages too — register them so
+                # shared-context traffic (multi-turn chat) hits on
+                # earlier turns
+                self._register_pages(s)
+                if tele is not None:
+                    tele.emit(
+                        "tokens", step=self.fault_step, t=now, rid=req.rid,
+                        n=len(new), total=len(req.tokens), slot=s,
+                    )
+                if self.done[s]:
+                    self._finish_request(req, now, s)
+                    finished_any = True
+            if finished_any and self.parked:
+                # freed pages: parked requests get another shot
+                self._unpark()
 
     def _finish_request(self, req: Request, now: float, slot: int) -> None:
         """Retire a finished request from its slot and observe the
@@ -2853,96 +2943,34 @@ class ServingEngine:
             # host-driven start/stop at step boundaries, no effect on
             # the compiled programs
             self.telemetry.maybe_profile(self.fault_step)
-        if self._fault_hook is not None:
-            self._fault_hook(self)
-        if self.parked and not self.queue and not self._active_slots():
-            # nothing else can free pages — parked work must retry now
-            self._unpark()
-        self._spill_prefetch()
-        self._admit()
-        self._run_prefills()
-        decoding = self._decoding_slots()
-        if not decoding:
-            # progress was prefill-only (or nothing runnable yet)
-            return self.has_work
-        self._ensure_growth()
-        decoding = self._decoding_slots()  # eviction may have changed it
-        if not decoding:
+        with span("midgpt.engine.step", step=self.fault_step):
+            with span("midgpt.engine.schedule"):
+                if self._fault_hook is not None:
+                    self._fault_hook(self)
+                if (
+                    self.parked and not self.queue
+                    and not self._active_slots()
+                ):
+                    # nothing else can free pages — parked work must
+                    # retry now
+                    self._unpark()
+                self._spill_prefetch()
+                self._admit()
+            self._run_prefills()
+            decoding = self._decoding_slots()
+            if not decoding:
+                # progress was prefill-only (or nothing runnable yet)
+                return self.has_work
+            with span("midgpt.engine.grow"):
+                self._ensure_growth()
+                # eviction may have changed it
+                decoding = self._decoding_slots()
+            if decoding:
+                if self.speculate:
+                    self._run_verify(decoding)
+                else:
+                    self._run_window(decoding)
             return True
-
-        if self.speculate:
-            self._run_verify(decoding)
-            return True
-
-        tele = self.telemetry
-        if tele is not None:
-            t0 = self.clock()
-            rids = tuple(self.slot_req[s].rid for s in decoding)
-        (
-            self.pool, self.logits, toks, emit, done_d, new_len, emitted_d
-        ) = self._window_fn(
-            self.model,
-            self.pool,
-            self.logits,
-            jnp.asarray(self.bt),
-            jnp.asarray(self.pooled_len),
-            jnp.asarray(self.done),
-            jnp.asarray(self.emitted),
-            jnp.asarray(self.budget),
-            jnp.asarray(self.eos),
-            jnp.asarray(self.seeds),
-            self._key,
-        )
-        self.decode_dispatches += 1
-        self.windows += 1
-        self.occupancy_sum += len(decoding)
-
-        # ONE device->host sync per window: the stacked [K, S] outputs
-        toks_h = np.asarray(toks)
-        emit_h = np.asarray(emit)
-        # np.array (copy): zero-copy views of jax buffers are read-only,
-        # and the scheduler mutates these in place
-        self.done = np.array(done_d)
-        self.pooled_len = np.array(new_len, np.int32)
-        self.emitted = np.array(emitted_d, np.int32)
-        now = self.clock()
-        if tele is not None:
-            # timestamped at the existing harvest sync — tracing adds
-            # no device round-trip of its own
-            n_window = int(emit_h[:, np.asarray(decoding)].sum())
-            tele.record_dispatch(
-                "decode_window", step=self.fault_step, t=t0, dur=now - t0,
-                rids=rids, tokens=n_window, window=self.window,
-            )
-            self.metrics.histogram("dispatch_s").observe(now - t0)
-            tele.emit(
-                "decode_window", step=self.fault_step, t=now,
-                slots=len(decoding), tokens=n_window,
-            )
-        finished_any = False
-        for s in decoding:
-            req = self.slot_req[s]
-            new = [int(t) for r in range(self.window)
-                   for t in [toks_h[r, s]] if emit_h[r, s]]
-            if new and req.first_token_time is None:
-                req.first_token_time = now
-            req.tokens.extend(new)
-            self.slot_ctx[s].extend(new)
-            self.tokens_generated += len(new)
-            # generated tokens fill pages too — register them so shared-
-            # context traffic (multi-turn chat) hits on earlier turns
-            self._register_pages(s)
-            if tele is not None:
-                tele.emit(
-                    "tokens", step=self.fault_step, t=now, rid=req.rid,
-                    n=len(new), total=len(req.tokens), slot=s,
-                )
-            if self.done[s]:
-                self._finish_request(req, now, s)
-                finished_any = True
-        if finished_any and self.parked:
-            self._unpark()  # freed pages: parked requests get another shot
-        return True
 
     def warm_prefill(self, max_tokens: int) -> tp.List[int]:
         """Pre-compile every prefill-chunk bucket a trace of prompts up

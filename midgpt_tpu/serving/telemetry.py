@@ -8,7 +8,7 @@ percentiles, and the two wedged hardware sessions (r4/r5) produced *no*
 timing data at all. This module is the serving half of the observability
 substrate — the registry/event-ring/flight-recorder core now lives in
 :mod:`midgpt_tpu.telemetry` (shared with the training loop's
-:mod:`midgpt_tpu.train_telemetry`) and is re-exported here unchanged.
+:mod:`midgpt_tpu.train_telemetry`).
 Four pieces, one design constraint:
 
 1. **Per-request lifecycle tracing** (:class:`EngineTelemetry`): typed
@@ -72,32 +72,19 @@ from __future__ import annotations
 
 import typing as tp
 
-from midgpt_tpu.telemetry import (  # noqa: F401 — the shared substrate,
-    # re-exported so every pre-split import path keeps working
-    Counter,
-    DispatchRecord,
-    Event,
-    Gauge,
-    Histogram,
-    LATENCY_BUCKETS_S,
+from midgpt_tpu.telemetry import (  # noqa: F401 — the names the engine,
+    # the cluster and bench_serving import from here
     MetricsRegistry,
     TelemetryLog,
     percentile,
-    prometheus_text,
     write_json,
 )
 
 __all__ = [
     "CLUSTER_STATS_KEYS",
-    "Counter",
-    "DispatchRecord",
     "ENGINE_STATS_KEYS",
     "EngineTelemetry",
-    "Event",
     "EVENT_KINDS",
-    "Gauge",
-    "Histogram",
-    "LATENCY_BUCKETS_S",
     "MetricsRegistry",
     "chrome_trace",
     "percentile",

@@ -5,7 +5,10 @@ Extracted from ``midgpt_tpu.serving.telemetry`` (PR 12) so the training
 loop can build on the same core (``midgpt_tpu.train_telemetry``) without
 importing the serving stack. The split:
 
-- **Here (domain-free, jax-free at import time)**: :class:`Counter`,
+- **Here (domain-free)**: :class:`span`, the one way the program times
+  a phase (a ``jax.profiler.TraceAnnotation`` on the profiler's clock
+  and, for an owner that traces, a :class:`DispatchRecord` on the
+  owner's); :class:`Counter`,
   :class:`Gauge`, :class:`Histogram`, :class:`MetricsRegistry`,
   :func:`percentile`, the :class:`Event`/:class:`DispatchRecord` record
   types, the :class:`TelemetryLog` base (bounded recency ring +
@@ -35,7 +38,10 @@ import dataclasses
 import json
 import os
 import re
+import time
 import typing as tp
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter",
@@ -48,6 +54,7 @@ __all__ = [
     "TelemetryLog",
     "percentile",
     "prometheus_text",
+    "span",
     "write_json",
 ]
 
@@ -80,23 +87,19 @@ class Counter:
 
 
 class Gauge:
-    """A point-in-time reading: either ``set()`` explicitly or backed by
-    a zero-arg callback evaluated at snapshot time (the registry's way
-    of exporting live engine state — pool occupancy, queue depth —
-    without mirroring writes into the hot path)."""
+    """A point-in-time reading, backed by a zero-arg callback evaluated at
+    snapshot time (the registry's way of exporting live engine state —
+    pool occupancy, queue depth — without mirroring writes into the hot
+    path)."""
 
-    __slots__ = ("name", "fn", "value")
+    __slots__ = ("name", "fn")
 
-    def __init__(self, name: str, fn: tp.Optional[tp.Callable[[], float]] = None):
+    def __init__(self, name: str, fn: tp.Callable[[], float]):
         self.name = name
         self.fn = fn
-        self.value: float = 0.0
-
-    def set(self, v: float) -> None:
-        self.value = v
 
     def read(self) -> float:
-        return self.fn() if self.fn is not None else self.value
+        return self.fn()
 
 
 class Histogram:
@@ -157,13 +160,11 @@ class MetricsRegistry:
             c = self.counters[name] = Counter(name)
         return c
 
-    def gauge(
-        self, name: str, fn: tp.Optional[tp.Callable[[], float]] = None
-    ) -> Gauge:
+    def gauge(self, name: str, fn: tp.Callable[[], float]) -> Gauge:
         g = self.gauges.get(name)
         if g is None:
             g = self.gauges[name] = Gauge(name, fn)
-        elif fn is not None:
+        else:
             g.fn = fn
         return g
 
@@ -356,10 +357,12 @@ class TelemetryLog:
         step: int,
         t: float,
         dur: float,
-        rids: tp.Sequence[int],
-        tokens: int,
+        rids: tp.Sequence[int] = (),
+        tokens: int = 0,
         **data,
     ) -> DispatchRecord:
+        """One timed span onto the dispatch ring. The program's phases
+        come here through :class:`span` and nowhere else."""
         rec = DispatchRecord(
             self._seq, step, kind, t, dur, tuple(rids), tokens, data
         )
@@ -434,6 +437,65 @@ class TelemetryLog:
             "events": [ev.to_json() for ev in list(self.events)],
             "dispatches": [d.to_json() for d in list(self.dispatches)],
         }
+
+
+class span:
+    """One named phase of the program, into two sinks with one call.
+
+    It always enters a ``jax.profiler.TraceAnnotation(name, **stats)``:
+    whenever a profiler session is open (the benchmark's ``--trace 1``,
+    :meth:`TelemetryLog.maybe_profile`, ``cfg.debug``'s one-window trace,
+    ``scripts/profile_step.py``) the phase is on the host plane of the
+    trace, on the clock the device's operations are on; with no session it
+    costs a microsecond or two. ``stats`` are for a human in xprof or
+    Perfetto. Names start ``midgpt.``.
+
+    Where the owner passes its :class:`TelemetryLog` (``None`` when it is
+    not tracing) and a ``kind``, leaving the phase also writes the
+    :class:`DispatchRecord` ``kind`` on the owner's ``clock``: ``step`` of
+    ``stats`` is the record's step, the rest its ``data``. ``t0`` starts
+    the record at an earlier span's start (a window's record runs from its
+    launch to the end of its harvest read); ``tokens`` and ``data`` may be
+    filled in before the phase is left, and ``t0``/``dur`` read after.
+    Nothing is recorded for a phase that raises."""
+
+    __slots__ = ("_ann", "_log", "_clock", "kind", "t0", "dur", "rids",
+                 "tokens", "data")
+
+    def __init__(
+        self,
+        name: str,
+        log: tp.Optional[TelemetryLog] = None,
+        kind: tp.Optional[str] = None,
+        *,
+        clock: tp.Callable[[], float] = time.perf_counter,
+        t0: tp.Optional[float] = None,
+        rids: tp.Sequence[int] = (),
+        tokens: int = 0,
+        **stats,
+    ):
+        self._ann = TraceAnnotation(name, **stats)
+        self._log, self._clock, self.kind = log, clock, kind
+        self.t0, self.dur = t0, 0.0
+        self.rids, self.tokens, self.data = rids, tokens, stats
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        if self._log is not None and self.t0 is None:
+            self.t0 = self._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._log is not None and exc_type is None:
+            self.dur = self._clock() - self.t0
+            if self.kind is not None:
+                data = dict(self.data)
+                self._log.record_dispatch(
+                    self.kind, step=data.pop("step", 0), t=self.t0,
+                    dur=self.dur, rids=self.rids, tokens=self.tokens,
+                    **data,
+                )
+        self._ann.__exit__(exc_type, exc, tb)
 
 
 def write_json(path: str, payload: tp.Dict[str, tp.Any]) -> str:

@@ -16,6 +16,7 @@ Capability parity with /root/reference/src/train.py, redesigned:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -44,6 +45,7 @@ from midgpt_tpu.parallel.sharding import (
     make_global_array,
 )
 from midgpt_tpu.pytree import cast_floating, module
+from midgpt_tpu.telemetry import span
 from midgpt_tpu.utils.metrics import (
     MetricLogger,
     UnknownDevicePeak,
@@ -132,23 +134,24 @@ def loss_fn(
         )
     else:
         h = model.hidden(x, key=key, deterministic=deterministic)
-    if loss_chunk is not None:
-        from midgpt_tpu.ops.loss import chunked_softmax_xent
+    with jax.named_scope("head_loss"):
+        if loss_chunk is not None:
+            from midgpt_tpu.ops.loss import chunked_softmax_xent
 
-        xent = chunked_softmax_xent(
-            h, model.head_weight(h.dtype), y, chunk_t=loss_chunk,
-            unroll=loss_chunk_unroll,
-        )
-    else:
-        from midgpt_tpu.parallel.sharding import shard_act
+            xent = chunked_softmax_xent(
+                h, model.head_weight(h.dtype), y, chunk_t=loss_chunk,
+                unroll=loss_chunk_unroll,
+            )
+        else:
+            from midgpt_tpu.parallel.sharding import shard_act
 
-        logits = h @ model.head_weight(h.dtype)  # [B, T, V]
-        logits = shard_act(
-            logits, "batch", "seq", "vocab"
-        ).astype(jnp.float32)
-        xent = optax.softmax_cross_entropy_with_integer_labels(
-            logits, y
-        ).mean()
+            logits = h @ model.head_weight(h.dtype)  # [B, T, V]
+            logits = shard_act(
+                logits, "batch", "seq", "vocab"
+            ).astype(jnp.float32)
+            xent = optax.softmax_cross_entropy_with_integer_labels(
+                logits, y
+            ).mean()
     if aux is not None and include_moe_aux:
         # the OPTIMIZED loss; eval passes include_moe_aux=False so
         # reported train/val losses stay pure cross-entropy, comparable
@@ -249,17 +252,21 @@ def _make_step_core(
         # average + promote to param dtype for the f32 optimizer update
         grads = jax.tree.map(lambda gr: (gr / g).astype(param_dtype), grads)
         grad_norm = optax.global_norm(grads)  # CSE'd with clip_by_global_norm
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        # constrain the NEW opt state like params (the Adam moments are
-        # param-shaped subtrees, so the same rule table resolves them;
-        # re.search matches the param path inside the opt-state path).
-        # Without this, GSPMD may give the output moments a different
-        # sharding than the input ones and jit silently DROPS their
-        # donation — the step then holds two copies of m/v in HBM
-        # (found by the analysis subsystem's donation-intact rule).
-        new_opt = constrain_params(new_opt, mesh, param_rules)
-        new_params = optax.apply_updates(state.params, updates)
-        new_params = constrain_params(new_params, mesh, param_rules)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(
+                grads, state.opt_state, state.params
+            )
+            # constrain the NEW opt state like params (the Adam moments
+            # are param-shaped subtrees, so the same rule table resolves
+            # them; re.search matches the param path inside the opt-state
+            # path). Without this, GSPMD may give the output moments a
+            # different sharding than the input ones and jit silently
+            # DROPS their donation — the step then holds two copies of
+            # m/v in HBM (found by the analysis subsystem's
+            # donation-intact rule).
+            new_opt = constrain_params(new_opt, mesh, param_rules)
+            new_params = optax.apply_updates(state.params, updates)
+            new_params = constrain_params(new_params, mesh, param_rules)
         aux = {
             "loss": loss,
             "grad_norm": grad_norm,
@@ -630,14 +637,29 @@ def window_plan(first_step: int, max_steps: int, k: int) -> tp.List[int]:
     return plan
 
 
-def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
+def train(
+    cfg: ExperimentConfig,
+    on_window: tp.Optional[
+        tp.Callable[[int, int, "TrainState", tp.Any], tp.Any]
+    ] = None,
+) -> tp.Dict[str, float]:
     """The orchestrator (parity: train.py:127-225). Returns final metrics.
 
     Preemption-safe: on SIGTERM (the TPU-VM maintenance/preemption signal)
     the loop finishes the in-flight step, force-saves a checkpoint, and
     returns cleanly — resume loses at most one step instead of
     ``ckpt_interval`` steps. The reference's recovery story is
-    restart-from-last-interval-checkpoint only (SURVEY.md 5.3)."""
+    restart-from-last-interval-checkpoint only (SURVEY.md 5.3).
+
+    ``on_window(step, k, state, out)`` is called after every window's
+    dispatch (a step of the K=1 loop is a window of one): the window's
+    first optimizer step, its length, the state it returned and its
+    outputs (``loss``, and ``lr``/``grad_norm`` from a fused window), all
+    still on the device — no read is added. A true return ends the loop
+    there with no save of that window and no final evaluation or save
+    (``final["stopped_at"]`` is the window's last step). A caller that
+    takes the windows decides what is kept: the save after the loop's
+    first window is left out; interval and SIGTERM saves stay."""
     import signal
 
     assert cfg.rundir, "rundir required"
@@ -1022,6 +1044,141 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
             if cfg.ckpt_interval is not None
             else cfg.eval_interval
         )
+        profile_dir = (
+            os.path.join(cfg.rundir, "profile")
+            if cfg.debug and not cfg.rundir.startswith("gs://")
+            else None
+        )
+
+        # The loop's phases, shared by the window loop and the K=1 loop.
+        # Each is one span(): a midgpt.train.* annotation in any profiler
+        # trace and, under cfg.train_telemetry, the ring's record on
+        # time.perf_counter. Every clock read sits at a host read the loop
+        # makes anyway.
+
+        def _eval_and_log(step: int, state) -> None:
+            n_eval = 1 if cfg.debug else cfg.eval_batches
+            eoff = 0 if cfg.eval_fixed else step
+            # evaluate() ends in a float() host read either way
+            with span(
+                "midgpt.train.eval", tele, "eval_pause", step=step,
+                batches=n_eval,
+            ):
+                train_loss = evaluate(
+                    eval_step, state.params, train_eval_loader, mesh,
+                    n_eval, eoff,
+                )
+                val_loss = evaluate(
+                    eval_step, state.params, val_loader, mesh, n_eval, eoff
+                )
+            logger.log(
+                step,
+                {
+                    "loss/train": train_loss,
+                    "loss/val": val_loss,
+                    **moe_telemetry(step, state.params),
+                },
+            )
+            final.update({"train_loss": train_loss, "val_loss": val_loss})
+
+        def _launch(step: int, k_eff: int, profile: bool, run):
+            """Dispatch one window under its launch span (with cfg.debug,
+            one post-warmup window inside a profiler trace of its own:
+            parity train.py:205-211). Returns ``run()``'s
+            ``(state, outputs)`` and the span, whose ``t0`` starts the
+            window's ``train_window`` record."""
+            nonlocal dispatch_count
+            if tele is not None:
+                tele.emit(
+                    "window_launch", step=step, t=time.perf_counter(),
+                    k=k_eff,
+                )
+                tele.metrics.counter("windows_dispatched").inc()
+                tele.metrics.counter("steps_completed").inc(k_eff)
+            profile = profile and profile_dir is not None
+            with (
+                jax.profiler.trace(profile_dir) if profile
+                else contextlib.nullcontext()
+            ):
+                with span(
+                    "midgpt.train.launch", tele, step=step, k=k_eff
+                ) as launch:
+                    out = run()
+                if profile:
+                    jax.block_until_ready(out[1])
+            dispatch_count += 1
+            return out, launch
+
+        def _harvest(launch, step: int, k_eff: int, read):
+            """THE existing device->host read of a logging window: the
+            only place window wall time legitimately exists. Returns what
+            ``read()`` pulled and the clock after it."""
+            with span(
+                "midgpt.train.harvest", tele, "train_window", t0=launch.t0,
+                step=step, k=k_eff,
+            ):
+                got = read()
+            t_harvest = time.perf_counter()
+            if tele is not None:
+                tele.emit(
+                    "window_harvest", step=step + k_eff - 1, t=t_harvest,
+                    k=k_eff,
+                )
+            return got, t_harvest
+
+        def _save(step: int, state, force: bool) -> None:
+            # a forced save is a save; the K=1 loop's unforced call no-ops
+            # between intervals and stays off the ring. async_save: the
+            # record covers the enqueue (exact only in cfg.debug's
+            # synchronous mode); the flush lands on ckpt_wait at close
+            with span(
+                "midgpt.train.ckpt_save", tele if force else None,
+                "ckpt_save", step=step,
+            ):
+                ckpt.save(
+                    step,
+                    _ckpt_items(state),
+                    meta={
+                        "step": step,
+                        "loader": prefetch.state_dict(),
+                        "model_fingerprint": fingerprint,
+                        "config": to_dict(cfg),
+                    },
+                    force=force,
+                )
+
+        def _close(last_step: int) -> tp.Dict[str, float]:
+            with span(
+                "midgpt.train.ckpt_wait", tele, "ckpt_wait", step=last_step
+            ):
+                ckpt.close()  # async-save flush: the real checkpoint wait
+            _finalize_tele(last_step)
+            logger.close()
+            return final
+
+        def _ends_here(step: int, last: int, state, out) -> bool:
+            """After a window's dispatch: the caller's ``on_window`` may
+            end the loop before anything of this window is read or
+            saved."""
+            if on_window is not None and on_window(
+                step, last - step + 1, state, out
+            ):
+                final["stopped_at"] = last
+                return True
+            return False
+
+        def _interrupted(last: int) -> bool:
+            """SIGTERM ends the loop after the forced save of the
+            completed window: an exact step boundary, so resume replays
+            nothing partially."""
+            if not stop_requested["flag"]:
+                return False
+            if tele is not None:
+                tele.emit("interrupt", step=last, t=time.perf_counter())
+            if proc == 0:
+                print(f"SIGTERM: checkpointed step {last}, exiting")
+            final["interrupted_at"] = last
+            return True
 
         def _run_window_loop(state):
             """steps_per_dispatch > 1: one fused K-step dispatch per
@@ -1029,7 +1186,7 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
             window boundaries are exact optimizer-step boundaries, and
             eval/ckpt intervals were validated as multiples of K, so the
             eval/ckpt cadence lands exactly where the K=1 loop puts it."""
-            nonlocal dispatch_count, last_log_time, last_log_step
+            nonlocal last_log_time, last_log_step
             try:
                 from tqdm import tqdm
 
@@ -1042,68 +1199,20 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
             w_start = first_step
             for wi, k_eff in enumerate(plan):
                 if w_start % cfg.eval_interval == 0 or w_start == first_step:
-                    n_eval = 1 if cfg.debug else cfg.eval_batches
-                    eoff = 0 if cfg.eval_fixed else w_start
-                    # evaluate() ends in a float() host read either way —
-                    # the span's clock stamps add no sync
-                    t_ev = time.perf_counter()
-                    train_loss = evaluate(
-                        eval_step, state.params, train_eval_loader, mesh,
-                        n_eval, eoff,
-                    )
-                    val_loss = evaluate(
-                        eval_step, state.params, val_loader, mesh, n_eval,
-                        eoff,
-                    )
-                    if tele is not None:
-                        tele.metrics.counter("evals").inc()
-                        tele.span(
-                            "eval_pause", step=w_start, t=t_ev,
-                            dur=time.perf_counter() - t_ev,
-                            batches=n_eval,
-                        )
-                    logger.log(
-                        w_start,
-                        {
-                            "loss/train": train_loss,
-                            "loss/val": val_loss,
-                            **moe_telemetry(w_start, state.params),
-                        },
-                    )
-                    final.update(
-                        {"train_loss": train_loss, "val_loss": val_loss}
-                    )
+                    _eval_and_log(w_start, state)
 
-                # prefetch.next() is the loop's existing host block on the
-                # loader queue; timing it classifies who owned the wait
-                t_pf = time.perf_counter()
-                xs, ys = prefetch.next()  # [k_eff, G, B, T] global arrays
-                t_launch = time.perf_counter()
-                if tele is not None:
-                    tele.prefetch_wait(
-                        step=w_start, t=t_pf, dur=t_launch - t_pf
-                    )
-                    tele.emit(
-                        "window_launch", step=w_start, t=t_launch, k=k_eff
-                    )
-                    tele.metrics.counter("windows_dispatched").inc()
-                    tele.metrics.counter("steps_completed").inc(k_eff)
-                if (
-                    cfg.debug and wi == 1
-                    and not cfg.rundir.startswith("gs://")
-                ):
-                    # profile exactly one post-warmup window
-                    with jax.profiler.trace(
-                        os.path.join(cfg.rundir, "profile")
-                    ):
-                        state, wout = exec_window(k_eff, state, xs, ys, key)
-                        jax.block_until_ready(wout["loss"])
-                else:
-                    state, wout = exec_window(k_eff, state, xs, ys, key)
-                dispatch_count += 1
+                # [k_eff, G, B, T] global arrays; the loop's existing
+                # host block on the loader queue
+                xs, ys = prefetch.next(tele, w_start)
+                (state, wout), launch = _launch(
+                    w_start, k_eff, wi == 1,
+                    lambda: exec_window(k_eff, state, xs, ys, key),
+                )
                 w_end = w_start + k_eff - 1
                 if wbar is not None:
                     wbar.update(k_eff)
+                if _ends_here(w_start, w_end, state, wout):
+                    break
 
                 log_steps = [
                     s
@@ -1114,22 +1223,14 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
                     # per-step (loss, grad-norm, lr) come out of the scan
                     # STACKED; they cross to the host once per logging
                     # window — no added syncs vs the K=1 loop
-                    losses_h = np.asarray(wout["loss"])
-                    lrs_h = np.asarray(wout["lr"])
-                    gnorms_h = np.asarray(wout["grad_norm"])
+                    (losses_h, lrs_h, gnorms_h), t_harvest = _harvest(
+                        launch, w_start, k_eff,
+                        lambda: tuple(
+                            np.asarray(wout[n])
+                            for n in ("loss", "lr", "grad_norm")
+                        ),
+                    )
                     now = time.time()
-                    # THE existing device->host harvest read: the only
-                    # place window wall time legitimately exists
-                    t_harvest = time.perf_counter()
-                    if tele is not None:
-                        tele.emit(
-                            "window_harvest", step=w_end, t=t_harvest,
-                            k=k_eff,
-                        )
-                        tele.span(
-                            "train_window", step=w_start, t=t_launch,
-                            dur=t_harvest - t_launch, k=k_eff,
-                        )
                     for s in log_steps:
                         i = s - w_start
                         loss_v = float(losses_h[i])
@@ -1170,45 +1271,16 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
                         wbar.set_postfix(loss=f"{final['loss']:.3f}")
 
                 if not cfg.debug and (
-                    (wi == 0 and first_step == 0)
+                    (wi == 0 and first_step == 0 and on_window is None)
                     or (w_end + 1) % ckpt_every == 0
                     or stop_requested["flag"]
                 ):
                     # window ends sit on the K grid, never on orbax's
                     # step % interval == 0 grid — interval saves are gated
                     # here (ckpt_every is a validated multiple of K) and
-                    # forced through the manager. A SIGTERM force-save
-                    # lands on the completed window: an exact step
-                    # boundary, so resume replays nothing partially.
-                    t_ck = time.perf_counter()
-                    ckpt.save(
-                        w_end,
-                        _ckpt_items(state),
-                        meta={
-                            "step": w_end,
-                            "loader": prefetch.state_dict(),
-                            "model_fingerprint": fingerprint,
-                            "config": to_dict(cfg),
-                        },
-                        force=True,
-                    )
-                    if tele is not None:
-                        # async_save: dur covers the enqueue (exact only
-                        # in cfg.debug's synchronous mode); the flush
-                        # wait lands on the ckpt_wait span at close
-                        tele.metrics.counter("ckpt_saves").inc()
-                        tele.span(
-                            "ckpt_save", step=w_end, t=t_ck,
-                            dur=time.perf_counter() - t_ck,
-                        )
-                if stop_requested["flag"]:
-                    if tele is not None:
-                        tele.emit(
-                            "interrupt", step=w_end, t=time.perf_counter()
-                        )
-                    if proc == 0:
-                        print(f"SIGTERM: checkpointed step {w_end}, exiting")
-                    final["interrupted_at"] = w_end
+                    # forced through the manager
+                    _save(w_end, state, True)
+                if _interrupted(w_end):
                     break
                 w_start += k_eff
             if wbar is not None:
@@ -1231,57 +1303,27 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
             except ImportError:  # pragma: no cover
                 pbar = range(first_step, cfg.max_steps)
 
-        loss = None
         for itr in pbar:
             # evaluate whenever the interval hits — including step 0 and the
             # first step after a resume, so the loss series always has a
             # pre-training / post-restore point (parity: train.py:195-201)
             if itr % cfg.eval_interval == 0 or itr == first_step:
-                n_eval = 1 if cfg.debug else cfg.eval_batches
-                eoff = 0 if cfg.eval_fixed else itr
-                t_ev = time.perf_counter()
-                train_loss = evaluate(
-                    eval_step, state.params, train_eval_loader, mesh, n_eval, eoff
-                )
-                val_loss = evaluate(eval_step, state.params, val_loader, mesh, n_eval, eoff)
-                if tele is not None:
-                    tele.metrics.counter("evals").inc()
-                    tele.span(
-                        "eval_pause", step=itr, t=t_ev,
-                        dur=time.perf_counter() - t_ev, batches=n_eval,
-                    )
-                logger.log(
-                    itr,
-                    {
-                        "loss/train": train_loss,
-                        "loss/val": val_loss,
-                        **moe_telemetry(itr, state.params),
-                    },
-                )
-                final.update({"train_loss": train_loss, "val_loss": val_loss})
+                _eval_and_log(itr, state)
 
-            t_pf = time.perf_counter()
-            xg, yg = prefetch.next()
-            t_launch = time.perf_counter()
-            if tele is not None:
-                tele.prefetch_wait(step=itr, t=t_pf, dur=t_launch - t_pf)
-                tele.emit("window_launch", step=itr, t=t_launch, k=1)
-                tele.metrics.counter("windows_dispatched").inc()
-                tele.metrics.counter("steps_completed").inc()
+            xg, yg = prefetch.next(tele, itr)
             step_key = jax.random.fold_in(key, itr)
-
-            if cfg.debug and itr == first_step + 1 and not cfg.rundir.startswith("gs://"):
-                # profile exactly one post-warmup step (parity: train.py:205-211)
-                with jax.profiler.trace(os.path.join(cfg.rundir, "profile")):
-                    state, loss = exec_step(state, xg, yg, step_key)
-                    jax.block_until_ready(loss)
-            else:
-                state, loss = exec_step(state, xg, yg, step_key)
-            dispatch_count += 1
+            (state, loss), launch = _launch(
+                itr, 1, itr == first_step + 1,
+                lambda: exec_step(state, xg, yg, step_key),
+            )
+            if _ends_here(itr, itr, state, {"loss": loss}):
+                break
 
             if itr % cfg.log_interval == 0 and itr > 0:
-                loss_v = float(loss)  # THE existing host read (K=1 path)
-                t_harvest = time.perf_counter()
+                # THE existing host read (K=1 path)
+                loss_v, t_harvest = _harvest(
+                    launch, itr, 1, lambda: float(loss)
+                )
                 now = time.time()
                 tps = tokens_per_step * (itr - last_log_step) / max(now - last_log_time, 1e-9)
                 last_log_time, last_log_step = now, itr
@@ -1291,12 +1333,6 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
                     "tokens_per_sec": tps,
                     **_mfu_if_measurable(tps, cfg.model),
                 }
-                if tele is not None:
-                    tele.emit("window_harvest", step=itr, t=t_harvest, k=1)
-                    tele.span(
-                        "train_window", step=itr, t=t_launch,
-                        dur=t_harvest - t_launch, k=1,
-                    )
                 # the K=1 path logs no grad_norm (it rides the window
                 # scan outputs only) — the monitors skip that detector
                 _report_trips(
@@ -1323,43 +1359,20 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
                 # force on preemption: the completed step becomes durable
                 # even off the save interval (Checkpointer no-ops the force
                 # when the interval save already owns this step)
-                ckpt.save(
-                    itr,
-                    _ckpt_items(state),
-                    meta={
-                        "step": itr,
-                        "loader": prefetch.state_dict(),
-                        "model_fingerprint": fingerprint,
-                        "config": to_dict(cfg),
-                    },
-                    force=stop_requested["flag"],
-                )
-
-            if stop_requested["flag"]:
-                if tele is not None:
-                    tele.emit("interrupt", step=itr, t=time.perf_counter())
-                if proc == 0:
-                    print(f"SIGTERM: checkpointed step {itr}, exiting")
-                final["interrupted_at"] = itr
+                _save(itr, state, stop_requested["flag"])
+            if _interrupted(itr):
                 break
 
         prefetch.stop()
         # steady-state launch count: ceil(steps / K) fused dispatches
         # (tested by tests/test_train_window.py)
         final["train_dispatches"] = dispatch_count
-        if "interrupted_at" in final:
-            # preempted: the in-loop force-save owns the last completed step;
-            # a max_steps-1 save here would mislabel partial progress
-            t_cw = time.perf_counter()
-            ckpt.close()  # async-save flush: the real checkpoint wait
-            if tele is not None:
-                tele.span(
-                    "ckpt_wait", step=int(final["interrupted_at"]),
-                    t=t_cw, dur=time.perf_counter() - t_cw,
-                )
-            _finalize_tele(int(final["interrupted_at"]))
-            logger.close()
-            return final
+        for ended in ("interrupted_at", "stopped_at"):
+            if ended in final:
+                # preempted: the in-loop force-save owns the last completed
+                # step; a max_steps-1 save here would mislabel partial
+                # progress. Stopped by on_window: the caller keeps nothing
+                return _close(int(final[ended]))
 
         # final eval + forced save of the last completed step (max_steps - 1;
         # the in-loop convention is "meta step == completed itr")
@@ -1374,34 +1387,8 @@ def train(cfg: ExperimentConfig) -> tp.Dict[str, float]:
             and cfg.max_steps > first_step
             and ckpt.latest_step() != cfg.max_steps - 1  # in-loop save may own it
         ):
-            t_ck = time.perf_counter()
-            ckpt.save(
-                cfg.max_steps - 1,
-                _ckpt_items(state),
-                meta={
-                    "step": cfg.max_steps - 1,
-                    "loader": prefetch.state_dict(),
-                    "model_fingerprint": fingerprint,
-                    "config": to_dict(cfg),
-                },
-                force=True,
-            )
-            if tele is not None:
-                tele.metrics.counter("ckpt_saves").inc()
-                tele.span(
-                    "ckpt_save", step=cfg.max_steps - 1, t=t_ck,
-                    dur=time.perf_counter() - t_ck,
-                )
-        t_cw = time.perf_counter()
-        ckpt.close()  # async-save flush: the real checkpoint wait
-        if tele is not None:
-            tele.span(
-                "ckpt_wait", step=cfg.max_steps, t=t_cw,
-                dur=time.perf_counter() - t_cw,
-            )
-        _finalize_tele(cfg.max_steps)
-        logger.close()
-        return final
+            _save(cfg.max_steps - 1, state, True)
+        return _close(cfg.max_steps)
     finally:
         # restore the previous handler only once everything that must
         # complete under our protection (async checkpoint flush in

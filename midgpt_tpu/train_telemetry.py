@@ -88,7 +88,7 @@ TRAIN_EVENT_KINDS: tp.Tuple[str, ...] = (
     "rung_error",
 )
 
-#: Span records (``TrainTelemetry.span`` -> the dispatch ring).
+#: Span records (``span(name, tele, kind)`` -> the dispatch ring).
 TRAIN_SPAN_KINDS: tp.Tuple[str, ...] = (
     "prefetch_wait",
     "train_window",
@@ -109,6 +109,14 @@ TRAIN_COUNTERS: tp.Tuple[str, ...] = (
     "ckpt_saves",
     "anomalies_tripped",
 )
+
+
+#: The counter a span kind bumps when its record is written.
+_SPAN_COUNTERS = {
+    "prefetch_wait": "prefetch_waits",
+    "eval_pause": "evals",
+    "ckpt_save": "ckpt_saves",
+}
 
 
 class TrainTelemetry(TelemetryLog):
@@ -137,29 +145,27 @@ class TrainTelemetry(TelemetryLog):
 
     # -- recording ---------------------------------------------------------
 
-    def span(
-        self, kind: str, *, step: int, t: float, dur: float, **data
-    ) -> None:
-        """One timed loop phase onto the dispatch ring (+ its latency
-        histogram). ``data`` must stay deterministic — wall clock rides
-        only in ``t``/``dur``."""
+    def record_dispatch(self, kind: str, *, step: int, t: float, dur: float,
+                        **data):
+        """One timed loop phase (``midgpt_tpu.telemetry.span`` brings it)
+        onto the dispatch ring, into its latency histogram and its
+        counter. ``data`` must stay deterministic — wall clock rides only
+        in ``t``/``dur``. A ``prefetch_wait`` is the loop blocked ``dur``
+        seconds on ``prefetch.next()``: above ``starvation_s`` it counts
+        as loader starvation — the input pipeline, not the device, owned
+        the critical path."""
         assert kind in TRAIN_SPAN_KINDS, kind
-        self.record_dispatch(
-            kind, step=step, t=t, dur=dur, rids=(), tokens=0, **data
-        )
+        rec = super().record_dispatch(kind, step=step, t=t, dur=dur, **data)
         h = self.metrics.histograms.get(f"{kind}_s")
         if h is not None:
             h.observe(dur)
-
-    def prefetch_wait(self, *, step: int, t: float, dur: float) -> None:
-        """The loop blocked ``dur`` seconds on ``prefetch.next()``.
-        Above ``starvation_s`` the wait counts as loader starvation —
-        the input pipeline, not the device, owned the critical path."""
-        self.metrics.counter("prefetch_waits").inc()
-        self.span("prefetch_wait", step=step, t=t, dur=dur)
-        if dur > self.starvation_s:
+        counter = _SPAN_COUNTERS.get(kind)
+        if counter is not None:
+            self.metrics.counter(counter).inc()
+        if kind == "prefetch_wait" and dur > self.starvation_s:
             self.metrics.counter("prefetch_starved").inc()
             self.emit("prefetch_starved", step=step, t=t + dur)
+        return rec
 
     def metrics_snapshot(self) -> tp.Dict[str, tp.Any]:
         """The registry view (counters + histograms) — same shape as

@@ -30,11 +30,8 @@ from midgpt_tpu.serving import (
     ServingEngine,
     chrome_trace,
 )
-from midgpt_tpu.serving.telemetry import (
-    EVENT_KINDS,
-    Histogram,
-    percentile,
-)
+from midgpt_tpu.serving.telemetry import EVENT_KINDS, percentile
+from midgpt_tpu.telemetry import Histogram
 
 CFG = ModelConfig(
     block_size=64, vocab_size=96, n_layer=2, n_head=4, n_embd=32,
@@ -86,7 +83,8 @@ def test_metrics_registry_units():
     c.inc()
     c.inc(3)
     assert reg.counter("hits") is c and c.value == 4
-    reg.gauge("depth").set(7.0)
+    depth = [7.0]
+    reg.gauge("depth", fn=lambda: depth[0])
     reg.gauge("live", fn=lambda: 42.0)
     labels = {"a": 1}
     reg.attach_labels("reasons", labels)
@@ -703,21 +701,16 @@ def test_bench_serving_longctx_record_contract(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_serving_reexports_shared_substrate():
-    """The PR 15 extraction contract: every substrate name the serving
-    module exposed before the split must still resolve to the SAME
-    object through midgpt_tpu.serving.telemetry (engine/cluster/bench
-    imports keep working verbatim), and EngineTelemetry is the
-    serving-taxonomy specialization of the shared TelemetryLog."""
+def test_engine_telemetry_is_the_serving_taxonomy_of_the_shared_log():
+    """EngineTelemetry is the serving-taxonomy specialization of the
+    shared TelemetryLog; the names the engine, the cluster and
+    bench_serving import from serving.telemetry are the substrate's own
+    objects."""
     import midgpt_tpu.serving.telemetry as serving_tele
     import midgpt_tpu.telemetry as core
     from midgpt_tpu.telemetry import TelemetryLog
 
-    for name in (
-        "Counter", "Gauge", "Histogram", "MetricsRegistry", "Event",
-        "DispatchRecord", "percentile", "write_json",
-        "LATENCY_BUCKETS_S", "prometheus_text",
-    ):
+    for name in ("MetricsRegistry", "percentile", "write_json"):
         assert getattr(serving_tele, name) is getattr(core, name), name
     assert issubclass(EngineTelemetry, TelemetryLog)
     assert EngineTelemetry.event_kinds == EVENT_KINDS
@@ -737,7 +730,7 @@ def test_prometheus_text_format_units():
     reg = MetricsRegistry()
     reg.counter("hits").inc(3)
     reg.attach_labels("reasons", {"full": 2})
-    reg.gauge("depth").set(1.5)
+    reg.gauge("depth", fn=lambda: 1.5)
     h = reg.histogram("lat", bounds=(0.1, 1.0))
     h.observe(0.05)
     h.observe(5.0)

@@ -29,6 +29,7 @@ import pytest
 import midgpt_tpu.train as train_mod
 from midgpt_tpu.config import ExperimentConfig, MeshConfig, ModelConfig
 from midgpt_tpu.data import write_tokens
+from midgpt_tpu.telemetry import span
 from midgpt_tpu.train import (
     get_train_window,
     init_state,
@@ -79,11 +80,11 @@ def _data_dir(tmp_path) -> str:
 def test_taxonomy_spans_and_starvation_counter():
     tele = TrainTelemetry(starvation_s=0.01)
     tele.emit("run_start", step=0, t=0.0)
-    tele.span("eval_pause", step=0, t=0.1, dur=0.2, batches=1)
+    tele.record_dispatch("eval_pause", step=0, t=0.1, dur=0.2, batches=1)
     # fast prefetch: counted, not starved
-    tele.prefetch_wait(step=0, t=0.3, dur=0.001)
+    tele.record_dispatch("prefetch_wait", step=0, t=0.3, dur=0.001)
     # slow prefetch: starved — counter + event
-    tele.prefetch_wait(step=4, t=0.4, dur=0.5)
+    tele.record_dispatch("prefetch_wait", step=4, t=0.4, dur=0.5)
     snap = tele.metrics_snapshot()
     assert snap["counters"]["prefetch_waits"] == 2
     assert snap["counters"]["prefetch_starved"] == 1
@@ -98,7 +99,7 @@ def test_taxonomy_spans_and_starvation_counter():
     with pytest.raises(AssertionError):
         tele.emit("decode_window", step=0, t=0.0)
     with pytest.raises(AssertionError):
-        tele.span("decode_window", step=0, t=0.0, dur=0.0)
+        tele.record_dispatch("decode_window", step=0, t=0.0, dur=0.0)
     for name in TRAIN_COUNTERS:
         assert name in snap["counters"], name
 
@@ -106,9 +107,9 @@ def test_taxonomy_spans_and_starvation_counter():
 def test_chrome_trace_train_structure():
     tele = TrainTelemetry()
     tele.emit("run_start", step=0, t=1.0)
-    tele.span("prefetch_wait", step=0, t=1.0, dur=0.1)
+    tele.record_dispatch("prefetch_wait", step=0, t=1.0, dur=0.01)
     tele.emit("window_launch", step=0, t=1.1, k=4)
-    tele.span("train_window", step=0, t=1.1, dur=0.4, k=4)
+    tele.record_dispatch("train_window", step=0, t=1.1, dur=0.4, k=4)
     tele.emit("anomaly", step=3, t=1.6, kind_detail="loss_spike")
     tr = chrome_trace_train(tele)
     names = [e.get("name") for e in tr["traceEvents"]]
@@ -285,15 +286,23 @@ def test_window_drive_with_telemetry_attached_is_bitwise(mesh8):
         for w in range(0, 8, 4):
             xg = make_global_array(xs[w:w + 4], mesh8, wspec)
             yg = make_global_array(ys[w:w + 4], mesh8, wspec)
-            t0 = time.perf_counter()
             if tele is not None:
-                tele.emit("window_launch", step=w, t=t0, k=4)
-            state, out = window(state, xg, yg, base)
-            arr = np.asarray(out["loss"])
+                tele.emit(
+                    "window_launch", step=w, t=time.perf_counter(), k=4
+                )
+            # the program's one way to time a phase: the ring's record
+            # comes from span(), as in train()
+            with span("midgpt.train.launch", tele, step=w, k=4) as launch:
+                state, out = window(state, xg, yg, base)
+            with span(
+                "midgpt.train.harvest", tele, "train_window",
+                t0=launch.t0, step=w, k=4,
+            ):
+                arr = np.asarray(out["loss"])
             if tele is not None:
-                t1 = time.perf_counter()
-                tele.emit("window_harvest", step=w + 3, t=t1, k=4)
-                tele.span("train_window", step=w, t=t0, dur=t1 - t0, k=4)
+                tele.emit(
+                    "window_harvest", step=w + 3, t=time.perf_counter(), k=4
+                )
             losses.append(arr)
         return np.concatenate(losses).astype(np.float32)
 
