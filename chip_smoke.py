@@ -2,7 +2,8 @@
 """Does the system still start on the chip? One process, the normal entry
 points, random weights from ``--seed``, nothing measured.
 
-    python chip_smoke.py             # one TPU chip: phases 1-5
+    python chip_smoke.py             # one TPU chip: phases 1-6
+    python chip_smoke.py --only 6    # phase 1 and the phases named
     python chip_smoke.py --chips 4   # four chips: the two sharded paths only
 
 One chip, in order — any failed assertion ends the run non-zero:
@@ -20,6 +21,14 @@ One chip, in order — any failed assertion ends the run non-zero:
 5. serve    — the restored weights in ``ServingEngine``: defaults, then
    speculative, then int8 weights + int8 KV. Every emitted token is held
    to one full-context forward of the same weights (``LOGIT_TOL_ULPS``).
+
+6. block    — the block-diffusion forward (``verify_tokens_paged`` with
+   ``block_len=4``: T = 4 rows a slot that all see each other) at 32 query
+   heads over 4 KV heads of 128, half-split RoPE and QK-RMSNorm, the
+   expert layer behind it (every expert chosen, so that no near-tie of the
+   router widens the comparison), bf16, ragged lengths: the Pallas kernel under the
+   block mask against the XLA gather path (``KERNEL_TOL``), and under the
+   causal mask it must NOT agree (the mask is really another).
 
 Four chips: ``openwebtext`` on an fsdp=2 x tensor=2 mesh against a
 one-device mesh (same seed, data, global batch), and a tp=2 x 2-replica
@@ -427,6 +436,69 @@ def paged_kernels_vs_gather(seed: int) -> None:
                   f"{label}: rel. error vs the XLA gather {err} > {KERNEL_TOL}")
             say(f"  {label}: logits agree with the XLA gather path "
                 f"(max rel. error {err:.2e}, shape {tuple(got.shape)})")
+
+
+def block_forward_vs_gather(seed: int) -> None:
+    """Phase 6: the block-diffusion forward, kernel against gather."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from midgpt_tpu.config import ModelConfig
+    from midgpt_tpu.models.gpt import GPT, verify_tokens_paged
+    from midgpt_tpu.pytree import cast_floating
+    from midgpt_tpu.serving import PagedKVPool, pages_needed
+
+    blk = 4
+    cfg = ModelConfig(
+        block_size=768, vocab_size=8192, n_layer=2, n_head=32, n_kv_head=4,
+        head_width=128, n_embd=2048, qk_norm_kind="rms", rope_style="half",
+        rope_base=1e6, norm_scale=True, norm_eps=1e-6, mlp="experts",
+        experts=4, experts_per_token=4, expert_hidden=768, block_len=blk,
+        block_steps=blk, mask_token=8191,
+    )
+    model = cast_floating(
+        GPT.init(jax.random.PRNGKey(seed), cfg), jnp.bfloat16
+    )
+    s, ps = 8, 16
+    pmax = pages_needed(cfg.block_size, ps)
+    npool = 2 * pmax
+    ks = jax.random.split(jax.random.PRNGKey(seed + 11), 4)
+    bt = jax.random.randint(ks[0], (s, pmax), 0, npool).astype(jnp.int32)
+    # ragged, whole blocks: empty, inside a page, page-aligned, ..., full
+    start = jnp.asarray(
+        [0, 12, 32, 100, 256, 500, 640, pmax * ps - blk], jnp.int32
+    )
+    cand = jax.random.randint(ks[1], (s, blk), 0, cfg.vocab_size, jnp.int32)
+    pool = PagedKVPool.init(cfg, npool, ps, jnp.bfloat16)
+    pool = dataclasses.replace(
+        pool,
+        k=jax.random.normal(ks[2], pool.k.shape).astype(pool.k.dtype),
+        v=jax.random.normal(ks[3], pool.v.shape).astype(pool.v.dtype),
+    )
+
+    def forward(kernel, block_len):
+        return jax.jit(lambda mod, c_, pk, pv, b_, st: verify_tokens_paged(
+            mod, c_, st, pk, pv, b_, cfg.block_size, paged_kernel=kernel,
+            block_len=block_len,
+        )[0])
+
+    args = (model, cand, pool.k, pool.v, bt, start)
+    label = "paged_verify_attention[block mask, 32 over 4 heads of 128]"
+    run = forward("pallas", blk)
+    check_compiled_kernels(label, run, *args)
+    got, want = run(*args), forward("xla", blk)(*args)
+    check(np.isfinite(np.asarray(got, np.float32)).all(),
+          f"{label}: non-finite logits")
+    err = rel_err(got, want)
+    check(err <= KERNEL_TOL,
+          f"{label}: rel. error vs the XLA gather {err} > {KERNEL_TOL}")
+    other = rel_err(forward("pallas", 0)(*args), want)
+    check(other > 10 * KERNEL_TOL,
+          f"{label}: the causal kernel agrees with the block-mask gather "
+          f"({other}): the mask changed nothing")
+    say(f"  {label}: logits agree with the XLA gather path (max rel. error "
+        f"{err:.2e}; the causal kernel is {other:.2e} away)")
 
 
 # ---------------------------------------------------------------------------
@@ -927,7 +999,14 @@ def main() -> int:
         "--workdir", default=os.path.join(REPO, "chip_smoke_out"),
         help="corpus, run directory and checkpoint land here",
     )
+    ap.add_argument(
+        "--only", default="",
+        help="one chip: after phase 1, only the phases numbered here "
+             "(2 and 6 stand alone; 4 needs 3, 5 needs 4), e.g. 2,6",
+    )
     args = ap.parse_args()
+    only = {int(n) for n in args.only.split(",") if n}
+    want = lambda n: not only or n in only  # noqa: E731
     t_start = time.perf_counter()
     try:
         with phase("1 device"):
@@ -939,14 +1018,21 @@ def main() -> int:
         write_corpus(os.path.join(args.workdir, "data"))
         if args.chips == 1:
             cfg = smoke_config(args.workdir, args.seed, TRAIN_SET)
-            with phase("2 kernels"):
-                phase_kernels(args.seed)
-            with phase("3 train"):
-                final = phase_train(cfg)
-            with phase("4 resume"):
-                params = phase_resume(cfg, final)
-            with phase("5 serve"):
-                phase_serve(params, cfg, args.seed)
+            if want(2):
+                with phase("2 kernels"):
+                    phase_kernels(args.seed)
+            if want(3):
+                with phase("3 train"):
+                    final = phase_train(cfg)
+            if want(4):
+                with phase("4 resume"):
+                    params = phase_resume(cfg, final)
+            if want(5):
+                with phase("5 serve"):
+                    phase_serve(params, cfg, args.seed)
+            if want(6):
+                with phase("6 block-diffusion forward"):
+                    block_forward_vs_gather(args.seed)
         else:
             cfg = smoke_config(args.workdir, args.seed, SHARDED_SET)
             with phase("sharded training: fsdp=2 x tensor=2 vs one device"):

@@ -927,6 +927,130 @@ ChoreoReport`.
     return report
 
 
+def prove_block_choreography(
+    model_cfg,
+    *,
+    slots: int = 4,
+    window: int = 2,
+    chunk_len: int = 16,
+    page_size: int = 16,
+    paged_kernel: str = "xla",
+):
+    """The choreography suite for a BLOCK-DIFFUSION model
+    (``model_cfg.block_len`` > 0), whose engine has no token-at-a-time
+    decode window to hold the verify program to. The verify-mirrors-decode
+    clause is kept with the roles moved up one: the block window's forward
+    (serving.engine._build_block_window) must mirror the VERIFY program's
+    of the same model at T = block_len OP FOR OP — it is the verify
+    forward under another mask, and the mask is an operand, so the two
+    attention traces and softmax signatures are equal or something other
+    than the mask changed. The prefill chunk is still held to
+    ``naive_attention``, and the shared clauses (f32 softmax and
+    accumulation, mask before scale, one lm-head choreography, banded
+    order) to all three. Traced at choreography size (2 layers, block 64,
+    vocab 128), no compilation."""
+    import dataclasses as _dc
+
+    import jax
+    import jax.numpy as jnp
+
+    from midgpt_tpu.analysis.choreo import (
+        ChoreoCheck,
+        ChoreoReport,
+        extract_choreography,
+        prove_choreography,
+    )
+    from midgpt_tpu.models.gpt import GPT
+    from midgpt_tpu.ops.attention import naive_attention
+    from midgpt_tpu.pytree import cast_floating
+    from midgpt_tpu.serving.engine import (
+        make_block_window,
+        make_prefill_chunk_program,
+        make_verify_program,
+    )
+    from midgpt_tpu.serving.paged import PagedKVPool, pages_needed
+
+    assert model_cfg.block_len > 0, "not a block-diffusion model"
+    blk = model_cfg.block_len
+    cfg = _dc.replace(
+        model_cfg, n_layer=2, block_size=64, vocab_size=128,
+        mask_token=127, remat="none", scan_unroll=1,
+    )
+    model = cast_floating(GPT.init(jax.random.PRNGKey(0), cfg), jnp.bfloat16)
+    pmax = pages_needed(cfg.block_size, page_size)
+    pool = jax.eval_shape(
+        lambda: PagedKVPool.init(cfg, slots * pmax, page_size)
+    )
+    sds = jax.ShapeDtypeStruct
+    i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
+    pred = lambda *s: sds(s, jnp.bool_)  # noqa: E731
+    logits = sds((slots, cfg.vocab_size), jnp.float32)
+    geometry = dict(pmax=pmax, rope_len=cfg.block_size)
+    block_jaxpr = jax.make_jaxpr(make_block_window(
+        model, slots=slots, window=window, paged_kernel=paged_kernel,
+        **geometry,
+    ))(
+        model, pool, i32(slots, pmax), i32(slots), pred(slots), i32(slots),
+        i32(slots), i32(slots), i32(slots, blk), pred(slots, blk),
+        i32(slots, blk),
+    )
+    verify_jaxpr = jax.make_jaxpr(make_verify_program(
+        model, slots=slots, spec_len=blk - 1, paged_kernel=paged_kernel,
+        **geometry,
+    ))(
+        model, pool, logits, i32(slots, pmax), i32(slots), pred(slots),
+        i32(slots), i32(slots), i32(slots), i32(slots, blk - 1), i32(slots),
+    )
+    chunk_jaxpr = jax.make_jaxpr(make_prefill_chunk_program(
+        model, chunk_len=chunk_len, **geometry,
+    ))(
+        model, pool, logits, i32(), i32(1, chunk_len), i32(), i32(),
+        i32(pmax),
+    )
+    h, hkv, c = cfg.n_head, cfg.kv_heads, cfg.head_dim
+
+    def naive_ref(x):
+        one = jnp.asarray(1.0, x.dtype)
+        return naive_attention(
+            x[:, :h] * one, x[:, h : h + hkv] * one, x[:, h + hkv :] * one,
+            causal=True,
+        )
+
+    naive_jaxpr = jax.make_jaxpr(naive_ref)(
+        sds((1, h + 2 * hkv, 8, c), jnp.bfloat16)
+    )
+    report = prove_choreography(
+        decode=extract_choreography("verify", verify_jaxpr),
+        prefill=extract_choreography("prefill_chunk", chunk_jaxpr),
+        verify=extract_choreography("block_window", block_jaxpr),
+        naive=extract_choreography("naive_reference", naive_jaxpr),
+    )
+    # a block-diffusion prompt's chunk leaves K/V and projects nothing (its
+    # first block opens from the mask token): the lm-head clause is the
+    # two programs' that have a head
+    heads = {
+        p.name: (p.lm_head, p.lm_head_epilogue) for p in report.programs
+        if p.name in ("verify", "block_window")
+    }
+    renamed = {
+        "verify-mirrors-decode": lambda c_: ChoreoCheck(
+            "block-window-mirrors-verify", c_.ok, c_.detail
+        ),
+        "shared: lm-head projection choreography is identical "
+        "everywhere": lambda c_: ChoreoCheck(
+            c_.name, len(set(heads.values())) == 1, str(heads)
+        ),
+    }
+    checks = tuple(
+        renamed.get(c_.name, lambda x: x)(c_) for c_ in report.checks
+    )
+    assert len({c_.name for c_ in checks} & {
+        "block-window-mirrors-verify",
+        "shared: lm-head projection choreography is identical everywhere",
+    }) == 2, [c_.name for c_ in checks]
+    return ChoreoReport(checks=checks, programs=report.programs)
+
+
 def prove_sp_prefill_choreography(
     name_or_cfg: tp.Union[str, ExperimentConfig],
     *,

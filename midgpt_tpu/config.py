@@ -35,7 +35,8 @@ class ModelConfig:
     n_kv_head: tp.Optional[int] = None  # None => MHA (= n_head); < n_head => GQA
     mlp: str = "gelu"  # "gelu" (GPT-2, 4x) | "swiglu" (Llama) | "moe"
     # (Switch-style top-1 mixture of GELU experts; expert-parallel over
-    # the 'tensor' mesh axis — see models/gpt.MoEMLP)
+    # the 'tensor' mesh axis — see models/gpt.MoEMLP) | "experts"
+    # (dropless top-k SwiGLU experts — models/gpt.ExpertMLP, below)
     moe_experts: int = 8  # experts per MoE layer (mlp="moe")
     moe_top_k: int = 1  # experts per token: 1 = Switch, 2 = GShard-style
     # (renormalized top-2 gates; aux loss tracks first choices)
@@ -70,6 +71,33 @@ class ModelConfig:
     # fits (PERF.md); outside train() (sampling) "auto" behaves as none
     remat: str = "full"  # auto | full | dots | none  (model.py:149 uses full)
     scan_unroll: int = 1  # lax.scan unroll over layers; 0 = n_layer (full)
+    # -- what follows leaves every config above byte-for-byte what it was --
+    # a head width that is its own number (None = n_embd // n_head): the
+    # projections are then D -> H*C and H*C -> D with H*C != D
+    head_width: tp.Optional[int] = None
+    qk_norm_kind: str = "layer"  # "layer" (LayerNorm) | "rms" (RMSNorm, eps 1e-6)
+    # "interleaved": pairs (2i, 2i+1) rotate (GPT-J); "half": rotate_half,
+    # the pairs are (i, i + C/2)
+    rope_style: str = "interleaved"
+    # the block norms and the final norm: learned scale or none; one eps for
+    # all three (None = 1e-6 in the blocks, 1e-5 at the end, as midGPT)
+    norm_scale: bool = False
+    norm_eps: tp.Optional[float] = None
+    # mlp="experts": dropless top-k routed SwiGLU experts (models/gpt
+    # .ExpertMLP): `experts` of width `expert_hidden`, `experts_per_token`
+    # chosen by an f32 softmax router, gates renormalised over the chosen
+    experts: int = 0
+    experts_per_token: int = 0
+    expert_hidden: int = 0
+    expert_renorm: bool = True
+    # generation by diffusion over blocks (serving only): block_len > 0
+    # makes attention causal across blocks of that many positions and
+    # bidirectional inside one, and a decode step a denoising forward of a
+    # block; block_steps denoising forwards reveal a block, block_len //
+    # block_steps positions each; mask_token stands at unrevealed positions
+    block_len: int = 0
+    block_steps: int = 0
+    mask_token: int = -1
 
     @property
     def kv_heads(self) -> int:
@@ -77,6 +105,8 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_width is not None:
+            return self.head_width
         assert self.n_embd % self.n_head == 0
         return self.n_embd // self.n_head
 

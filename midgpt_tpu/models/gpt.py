@@ -39,6 +39,7 @@ from midgpt_tpu.models.layers import (
     rope_tables,
 )
 from midgpt_tpu.ops.attention import attention
+from midgpt_tpu.ops.grouped import grouped_matmul
 from midgpt_tpu.parallel.sharding import current_mesh, shard_act
 from midgpt_tpu.pytree import module, static
 
@@ -150,7 +151,7 @@ def _gathered_pool_scales(scale_x, bt, layer):
     return jnp.take(scale_x[layer], bt, axis=0, mode="clip")
 
 
-def _paged_kernel_dispatch(kind: str, layer: int, tensors, scales):
+def _paged_kernel_dispatch(kind: str, layer: int, tensors, scales, block=1):
     """Run a serving paged-attention kernel (ops.paged_attn), wrapped in
     ``shard_map`` under a live TP mesh: a bare ``pallas_call`` is an
     opaque custom call, and GSPMD would gather the KV-head-sharded pool
@@ -186,7 +187,7 @@ def _paged_kernel_dispatch(kind: str, layer: int, tensors, scales):
         q, kc, vc, pool_k, pool_v, bt, start = tensors
         call = lambda *a: paged_verify_attention(  # noqa: E731
             a[0], a[1], a[2], a[3], a[4], a[5], a[6], layer,
-            *(a[7:] or (None, None)),
+            *(a[7:] or (None, None)), block=block,
         )
         specs = [
             ("tensor", 1), ("tensor", 1), ("tensor", 1), ("tensor", 3),
@@ -225,12 +226,13 @@ class Attention:
 
     wqkv: Linear  # [D, (H + 2*Hkv) * C]
     wo: Linear  # [H*C, D]
-    q_norm: tp.Optional[LayerNorm]
-    k_norm: tp.Optional[LayerNorm]
+    q_norm: tp.Optional[tp.Union[LayerNorm, RMSNorm]]
+    k_norm: tp.Optional[tp.Union[LayerNorm, RMSNorm]]
     n_head: int = static()
     n_kv_head: int = static()
     dropout_rate: float = static(default=0.0)
     ring_schedule: str = static(default="zigzag")
+    rope_style: str = static(default="interleaved")
 
     @staticmethod
     def init(key: KeyArray, cfg: ModelConfig) -> "Attention":
@@ -238,15 +240,26 @@ class Attention:
         c = cfg.head_dim
         hkv = cfg.kv_heads
         qkv_out = (cfg.n_head + 2 * hkv) * c
+        assert cfg.qk_norm_kind in ("layer", "rms"), cfg.qk_norm_kind
+        assert cfg.rope_style in ("interleaved", "half"), cfg.rope_style
+
+        def head_norm():
+            if not cfg.qk_norm:
+                return None
+            if cfg.qk_norm_kind == "rms":
+                return RMSNorm.init(c, use_weight=True, eps=1e-6, impl="jnp")
+            return LayerNorm.init(c, eps=1e-6)
+
         return Attention(
             wqkv=Linear.init(k1, cfg.n_embd, qkv_out),
             wo=Linear.init(k2, cfg.n_head * c, cfg.n_embd),
-            q_norm=LayerNorm.init(c, eps=1e-6) if cfg.qk_norm else None,
-            k_norm=LayerNorm.init(c, eps=1e-6) if cfg.qk_norm else None,
+            q_norm=head_norm(),
+            k_norm=head_norm(),
             n_head=cfg.n_head,
             n_kv_head=hkv,
             dropout_rate=cfg.dropout,
             ring_schedule=cfg.ring_schedule,
+            rope_style=cfg.rope_style,
         )
 
     def __call__(
@@ -262,11 +275,14 @@ class Attention:
     ) -> tp.Union[Array, tp.Tuple[Array, tp.Tuple[Array, Array]]]:
         b, t, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
-        c = d // h
+        c = self.head_dim()
         adrop_key, pdrop_key = (
             jax.random.split(key) if key is not None else (None, None)
         )
-        if impl == "fused" and (return_kv or self.q_norm is None):
+        if impl == "fused" and (
+            return_kv or not isinstance(self.q_norm, LayerNorm)
+            or self.rope_style != "interleaved"
+        ):
             # return_kv needs per-head K/V (prefill), and the kernel requires
             # qk-norm; same math either way, so degrade to auto dispatch
             impl = "auto"
@@ -284,8 +300,8 @@ class Attention:
             q = jnp.transpose(q, (0, 2, 1, 3))
             k = jnp.transpose(k, (0, 2, 1, 3))
             v = jnp.transpose(v, (0, 2, 1, 3))
-            q = apply_rotary(q, sin, cos)
-            k = apply_rotary(k, sin, cos)
+            q = apply_rotary(q, sin, cos, self.rope_style)
+            k = apply_rotary(k, sin, cos, self.rope_style)
             q = shard_act(q, "batch", "heads", "seq", "head_dim")
             k = shard_act(k, "batch", "kv_heads", "seq", "head_dim")
             v = shard_act(v, "batch", "kv_heads", "seq", "head_dim")
@@ -363,7 +379,11 @@ class Attention:
         takes it on TPU under the same conditions flash requires."""
         from midgpt_tpu.ops.fused_attn import supported
 
-        if impl not in ("fused", "auto") or self.q_norm is None:
+        if impl not in ("fused", "auto") or not (
+            # the kernel is QK-LayerNorm + interleaved RoPE and nothing else
+            isinstance(self.q_norm, LayerNorm)
+            and self.rope_style == "interleaved"
+        ):
             return False
         shape_ok = (
             supported(self.n_head, self.n_kv_head, self.head_dim())
@@ -443,7 +463,7 @@ class Attention:
         token's absolute position). q: [B, H, 1, C]; k/v: [B, Hkv, 1, C]."""
         b, one, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
-        c = d // h
+        c = self.head_dim()
         qkv = self.wqkv(x)  # [B, 1, (H+2Hkv)C]
         q = qkv[..., : h * c].reshape(b, 1, h, c)
         k = qkv[..., h * c : (h + hkv) * c].reshape(b, 1, hkv, c)
@@ -454,8 +474,8 @@ class Attention:
         q = jnp.transpose(q, (0, 2, 1, 3))  # [B, H, 1, C]
         k = jnp.transpose(k, (0, 2, 1, 3))  # [B, Hkv, 1, C]
         v = jnp.transpose(v, (0, 2, 1, 3))
-        q = apply_rotary(q, sin_row, cos_row)
-        k = apply_rotary(k, sin_row, cos_row)
+        q = apply_rotary(q, sin_row, cos_row, self.rope_style)
+        k = apply_rotary(k, sin_row, cos_row, self.rope_style)
         # no sharding constraints HERE: this helper is shared with the
         # fixed-batch sampler's ring paths (decode_at/decode_recent_at),
         # which run under the TRAINING rule table with the batch dim
@@ -494,7 +514,7 @@ class Attention:
         term in the measured 2.9 ms/token, PERF.md 'Serving bench')."""
         b, one, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
-        c = d // h
+        c = self.head_dim()
         q, k, v = self._decode_qkv(x, sin_row, cos_row)
         # cache is time-minor ([B, Hkv, C, W] per layer — see KVCache): the
         # new row lands as a single-lane column write
@@ -581,7 +601,7 @@ class Attention:
         rests on)."""
         b, one, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
-        c = d // h
+        c = self.head_dim()
         q, k, v = self._decode_qkv(x, sin_rows, cos_rows)
         # whole-head TP (serving meshes, serving_logical_rules): the
         # slot dim stays replicated — DP is shared-nothing engine
@@ -769,7 +789,7 @@ class Attention:
         turns that gate red before anything compiles."""
         b, t, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
-        c = d // h
+        c = self.head_dim()
         qkv = self.wqkv(x)  # [1, T, (H+2Hkv)C]
         q = qkv[..., : h * c].reshape(b, t, h, c)
         k = qkv[..., h * c : (h + hkv) * c].reshape(b, t, hkv, c)
@@ -780,8 +800,8 @@ class Attention:
         q = jnp.transpose(q, (0, 2, 1, 3))  # [1, H, T, C]
         k = jnp.transpose(k, (0, 2, 1, 3))  # [1, Hkv, T, C]
         v = jnp.transpose(v, (0, 2, 1, 3))
-        q = apply_rotary(q, sin_rows, cos_rows)
-        k = apply_rotary(k, sin_rows, cos_rows)
+        q = apply_rotary(q, sin_rows, cos_rows, self.rope_style)
+        k = apply_rotary(k, sin_rows, cos_rows, self.rope_style)
         # whole-head TP: per-head tensors split over 'tensor', the slot
         # dim replicated (see _decode_qkv)
         q = shard_act(q, None, "heads", None, None)
@@ -855,6 +875,8 @@ class Attention:
         pool_sk: tp.Optional[Array] = None,  # [L, NP, Hkv] f32 (int8 pool)
         pool_sv: tp.Optional[Array] = None,
         paged_kernel: str = "xla",
+        block: int = 1,  # STATIC: > 1 = rows see their whole block (the
+        # kernel's own-rows mask; ``mask_self`` carries the XLA path's)
     ) -> tp.Tuple[Array, Array, Array]:
         """Multi-query attention for SPECULATIVE VERIFICATION: all T
         candidate rows of every slot attend jointly to the slot's
@@ -881,7 +903,7 @@ class Attention:
         that gate red before anything compiles."""
         b, t, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
-        c = d // h
+        c = self.head_dim()
         qkv = self.wqkv(x)  # [S, T, (H+2Hkv)C]
         q = qkv[..., : h * c].reshape(b, t, h, c)
         k = qkv[..., h * c : (h + hkv) * c].reshape(b, t, hkv, c)
@@ -892,8 +914,8 @@ class Attention:
         q = jnp.transpose(q, (0, 2, 1, 3))  # [S, H, T, C]
         k = jnp.transpose(k, (0, 2, 1, 3))  # [S, Hkv, T, C]
         v = jnp.transpose(v, (0, 2, 1, 3))
-        q = apply_rotary(q, sin_rows, cos_rows)
-        k = apply_rotary(k, sin_rows, cos_rows)
+        q = apply_rotary(q, sin_rows, cos_rows, self.rope_style)
+        k = apply_rotary(k, sin_rows, cos_rows, self.rope_style)
         # whole-head TP: per-head tensors split over 'tensor', the slot
         # dim replicated (see _decode_qkv)
         q = shard_act(q, None, "heads", None, None)
@@ -937,6 +959,7 @@ class Attention:
                 (qg, kc, vc, pool_k, pool_v, bt, start),
                 (_gathered_pool_scales(pool_sk, bt, layer),
                  _gathered_pool_scales(pool_sv, bt, layer)),
+                block=block,
             )  # [S, Hkv, G, T, C]
             out = shard_act(out, None, "kv_heads", None, None, None)
             out = out.reshape(b, h, t, c)
@@ -1038,7 +1061,7 @@ class Attention:
         not an approximation."""
         b, one, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
-        c = d // h
+        c = self.head_dim()
         q, k, v = self._decode_qkv(x, sin_row, cos_row)
         zero = jnp.zeros((), r.dtype)
         at = (jnp.asarray(layer, r.dtype), zero, zero, r, zero)
@@ -1346,16 +1369,156 @@ class MoEMLP:
             return y, aux, dropped
 
 
+@module
+class ExpertMLP:
+    """Dropless top-k mixture of SwiGLU experts (``mlp="experts"``): the
+    sparse layer of the serving path, and the one to extend (MoEMLP above
+    is the capacity-factor layer ``train()`` grew up with).
+
+    ``p = softmax_f32(h Wr)`` over all E experts; the k largest are
+    chosen; ``g = p / sum(chosen p)`` where ``renorm``; ``y = sum_e g_e
+    (silu(h W1_e) * (h W3_e)) W2_e``. No capacity and no dropped token at
+    any skew: the (token, expert) claims are SORTED BY EXPERT and the
+    three projections are two grouped matmuls over the sorted rows
+    (``ops.grouped.grouped_matmul``: rows [N*k, .] against [E, ., .] with
+    the per-expert row counts; ``jax.lax.ragged_dot``, or on a TPU the
+    Pallas grouped matmul that ships with JAX, by measurement) — work and
+    memory are O(N k), there is no
+    ``[tokens, experts, capacity]`` tensor, and an expert no claim chose
+    costs nothing. ``w_in`` holds W1 | W3 side by side so that gate and up
+    are one pass over the sorted rows. One body for every call shape: a
+    prefill chunk's hundreds of rows, a denoising forward's slots x block,
+    a ``train()`` batch (``ragged_dot`` differentiates).
+
+    Not here (ROADMAP Reach A1): an expert axis over a mesh (under a
+    tensor mesh the experts ride replicated), shared experts, int8 expert
+    tensors."""
+
+    router: Linear  # [D, E]
+    w_in: Array  # [E, D, 2F]: W1 (gate) | W3 (up)
+    w_out: Array  # [E, F, D]: W2
+    top_k: int = static()
+    renorm: bool = static(default=True)
+
+    @staticmethod
+    def init(key: KeyArray, cfg: ModelConfig) -> "ExpertMLP":
+        kr, ki, ko = jax.random.split(key, 3)
+        e, d, f = cfg.experts, cfg.n_embd, cfg.expert_hidden
+        assert e >= 1 and f >= 1 and 1 <= cfg.experts_per_token <= e, (
+            e, f, cfg.experts_per_token
+        )
+        w_in = (1.0 / math.sqrt(d)) * jax.random.truncated_normal(
+            ki, lower=-2, upper=2, shape=(e, d, 2 * f), dtype=jnp.float32
+        )
+        w_out = (1.0 / math.sqrt(f)) * jax.random.truncated_normal(
+            ko, lower=-2, upper=2, shape=(e, f, d), dtype=jnp.float32
+        )
+        return ExpertMLP(
+            router=Linear.init(kr, d, e), w_in=w_in, w_out=w_out,
+            top_k=cfg.experts_per_token, renorm=cfg.expert_renorm,
+        )
+
+    def __call__(
+        self,
+        x: Array,  # [..., D]
+        *,
+        return_rows: bool = False,
+        stacked: tp.Optional[tp.Tuple[Array, Array, tp.Any]] = None,
+    ) -> tp.Tuple[Array, ...]:
+        """(y, aux), and with ``return_rows`` the rows routed to each
+        expert ``[E]`` int32 (they sum to N k: nothing is dropped). ``aux``
+        is Switch's load-balance loss over first choices, as MoEMLP's.
+
+        ``stacked`` = (every layer's ``w_in`` [L, E, D, 2F], ``w_out``
+        [L, E, F, D], this layer's index): the serving programs' way to
+        the expert tensors. They hold the model stacked over layers and
+        reach a layer by slicing; a grouped matmul is a kernel call that
+        no slice fuses into, so the compiler would COPY each layer's
+        0.8 + 0.4 GB in front of it (hoisted out of the window's scan, all
+        layers at once: 6.5 GB of temporaries at 6 x 128 experts, found by
+        compiling for a described v5e). Instead the stack is read as it
+        lies, as L x E groups of which only this layer's have rows."""
+        d = x.shape[-1]
+        e, k = self.router.weight.shape[-1], self.top_k
+        w_in, w_out = (self.w_in, self.w_out) if stacked is None else (
+            a.reshape((-1,) + a.shape[2:]) for a in stacked[:2]
+        )
+        f = w_out.shape[1]
+        xf = x.reshape(-1, d)
+        with jax.named_scope("expert_route"):
+            # the router in f32 on every backend: a default-precision f32
+            # product is bf16 passes on the TPU, and the 8th-against-9th
+            # expert decision is the layer's near-tie surface
+            probs = jax.nn.softmax(
+                jnp.matmul(
+                    xf.astype(jnp.float32),
+                    self.router.weight.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST,
+                ),
+                axis=-1,
+            )  # [N, E]
+            topv, topi = jax.lax.top_k(probs, k)  # [N, k]
+            gates = (
+                topv / jnp.sum(topv, axis=-1, keepdims=True)
+                if self.renorm else topv
+            )
+            claims = topi.reshape(-1)  # [N k]: claim j is token j // k's
+            order = jnp.argsort(claims, stable=True)  # sorted by expert
+            rows = jnp.zeros((e,), jnp.int32).at[claims].add(1)
+            first = jax.nn.one_hot(topi[:, 0], e, dtype=jnp.float32)
+            aux = e * jnp.sum(
+                jnp.mean(first, axis=0) * jnp.mean(probs, axis=0)
+            )
+            xs = jnp.take(xf, order // k, axis=0)  # [N k, D]
+        with jax.named_scope("expert_matmul"):
+            groups = rows
+            if stacked is not None:
+                at = jnp.asarray(stacked[2], jnp.int32) * e
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros((w_in.shape[0],), jnp.int32), rows, (at,)
+                )
+            h = grouped_matmul(xs, w_in.astype(x.dtype), groups)
+            act = jax.nn.silu(h[:, :f]) * h[:, f:]
+            ys = grouped_matmul(act, w_out.astype(x.dtype), groups)
+            # back to claim order, then the gated sum over a token's k
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype)
+            )
+            yk = jnp.take(ys, back, axis=0).reshape(-1, k, d)
+            y = jnp.sum(
+                yk.astype(jnp.float32) * gates[..., None], axis=1
+            ).astype(x.dtype).reshape(x.shape)
+        if return_rows:
+            return y, aux, rows
+        return y, aux
+
+
 def make_mlp(key: KeyArray, cfg: ModelConfig):
-    """MLP factory: dense (gelu/swiglu) or MoE by cfg.mlp."""
+    """MLP factory: dense (gelu/swiglu), MoE or routed experts by cfg.mlp."""
     if cfg.mlp == "moe":
         return MoEMLP.init(key, cfg)
+    if cfg.mlp == "experts":
+        return ExpertMLP.init(key, cfg)
     return MLP.init(key, cfg)
 
 
-def mlp_call(mlp, x, *, key=None, deterministic=True, with_stats=False):
-    """(y, aux) for either MLP kind — dense returns aux = 0. With
-    ``with_stats``: (y, aux, dropped_frac), dense dropped = 0."""
+def stacked_experts(model, layer) -> tp.Optional[tp.Tuple]:
+    """``ExpertMLP``'s ``stacked`` argument for ``layer`` of ``model``
+    (None for every other kind of MLP)."""
+    mlp = model.blocks.mlp
+    if not isinstance(mlp, ExpertMLP):
+        return None
+    return mlp.w_in, mlp.w_out, layer
+
+
+def mlp_call(mlp, x, *, key=None, deterministic=True, with_stats=False,
+             stacked=None):
+    """(y, aux) for every MLP kind — dense returns aux = 0. With
+    ``with_stats``: (y, aux, dropped_frac), 0 for the kinds that drop
+    nothing."""
+    if isinstance(mlp, ExpertMLP):
+        y, aux = mlp(x, stacked=stacked)
+        return (y, aux, jnp.zeros((), jnp.float32)) if with_stats else (y, aux)
     if with_stats:
         if isinstance(mlp, MoEMLP):
             return mlp(
@@ -1375,7 +1538,7 @@ class Block:
     """Pre-norm residual block (parity: model.py:84-105)."""
 
     attn: Attention
-    mlp: tp.Union[MLP, "MoEMLP"]
+    mlp: tp.Union[MLP, "MoEMLP", "ExpertMLP"]
     ln1: RMSNorm
     ln2: RMSNorm
 
@@ -1386,8 +1549,15 @@ class Block:
             attn=Attention.init(k1, cfg),
             mlp=make_mlp(k2, cfg),
             # weightless block norms (model.py:94-95, layers.py:64-68)
-            ln1=RMSNorm.init(cfg.n_embd, use_weight=False, impl=cfg.norm_impl),
-            ln2=RMSNorm.init(cfg.n_embd, use_weight=False, impl=cfg.norm_impl),
+            # unless the config asks for the learned scale
+            ln1=RMSNorm.init(
+                cfg.n_embd, use_weight=cfg.norm_scale,
+                eps=cfg.norm_eps or 1e-6, impl=cfg.norm_impl,
+            ),
+            ln2=RMSNorm.init(
+                cfg.n_embd, use_weight=cfg.norm_scale,
+                eps=cfg.norm_eps or 1e-6, impl=cfg.norm_impl,
+            ),
         )
 
     def __call__(
@@ -1460,7 +1630,7 @@ class Block:
     def prefill_paged_at(
         self, x, pool_k, pool_v, bt, layer, mask_pool, mask_self,
         sin_rows, cos_rows, start=None, pool_sk=None, pool_sv=None,
-        sp=False,
+        sp=False, experts=None,
     ):
         if not sp:
             with jax.named_scope("attention"):
@@ -1470,7 +1640,7 @@ class Block:
                     pool_sk=pool_sk, pool_sv=pool_sv,
                 )
             x = x + attn_out
-            x = x + mlp_call(self.mlp, self.ln2(x))[0]
+            x = x + mlp_call(self.mlp, self.ln2(x), stacked=experts)[0]
             return x, k, v
         # Sequence-parallel prefill (Megatron-SP style): the per-token
         # segments that tensor parallelism leaves REPLICATED — ln1/ln2,
@@ -1506,15 +1676,25 @@ class Block:
     def verify_paged_at(
         self, x, pool_k, pool_v, bt, layer, mask_pool, mask_self,
         sin_rows, cos_rows, start=None, pool_sk=None, pool_sv=None,
-        paged_kernel="xla",
+        paged_kernel="xla", block=1, expert_rows=False, experts=None,
     ):
+        """``block`` > 1: the rows' own mask is causal across blocks of
+        that many and bidirectional inside one (``mask_self`` says the
+        same to the XLA path; the kernel builds its own from ``block``).
+        ``expert_rows`` (an ExpertMLP model): also the rows routed to each
+        expert here, ``[E]``. ``experts``: ExpertMLP's ``stacked``."""
         attn_out, k, v = self.attn.verify_paged_at(
             self.ln1(x), pool_k, pool_v, bt, layer, mask_pool, mask_self,
             sin_rows, cos_rows, start=start, pool_sk=pool_sk,
-            pool_sv=pool_sv, paged_kernel=paged_kernel,
+            pool_sv=pool_sv, paged_kernel=paged_kernel, block=block,
         )
         x = x + attn_out
-        x = x + mlp_call(self.mlp, self.ln2(x))[0]
+        if expert_rows:
+            y, _, rows = self.mlp(
+                self.ln2(x), return_rows=True, stacked=experts
+            )
+            return x + y, k, v, rows
+        x = x + mlp_call(self.mlp, self.ln2(x), stacked=experts)[0]
         return x, k, v
 
 
@@ -1568,7 +1748,8 @@ class GPT:
             wte=Embedding(weight=wte_wt),
             blocks=blocks,
             ln_f=RMSNorm.init(
-                cfg.n_embd, use_weight=False, eps=1e-5, impl=cfg.norm_impl
+                cfg.n_embd, use_weight=cfg.norm_scale,
+                eps=cfg.norm_eps or 1e-5, impl=cfg.norm_impl,
             ),
             lm_head=lm_head,
             config=cfg,
@@ -1994,6 +2175,7 @@ def prefill_chunk_paged(
     pool_sv: tp.Optional[Array] = None,
     layer_scan: str = "off",
     sp: bool = False,
+    block_len: int = 0,
 ) -> tp.Tuple[Array, Array, Array]:
     """Suffix-only prefill of one chunk against a pre-populated block
     table: the chunk's tokens (context positions ``start .. start+T-1``)
@@ -2041,10 +2223,13 @@ def prefill_chunk_paged(
     # position w; resident (and < any chunk position) iff w < start
     idx = jnp.arange(pmax * ps)
     mask_pool = jnp.where(idx < start, 0.0, -jnp.inf).astype(jnp.float32)
-    # in-chunk causal mask; row i may attend chunk rows j <= i
+    # in-chunk causal mask; row i may attend chunk rows j <= i — or, for
+    # a block-diffusion model (``block_len`` > 0; ``start`` is then a
+    # multiple of it), every row of its own block and of those before
     ii = jnp.arange(t)
+    bi = ii // block_len if block_len > 1 else ii
     mask_self = jnp.where(
-        ii[None, :] <= ii[:, None], 0.0, -jnp.inf
+        bi[None, :] <= bi[:, None], 0.0, -jnp.inf
     ).astype(jnp.float32)  # [T, T]
     pos = jnp.clip(start + ii, 0, rope_len - 1)  # pad tail clips harmlessly
     sin_rows = jnp.take(sin_t, pos, axis=0)  # [T, C//2]
@@ -2072,13 +2257,15 @@ def prefill_chunk_paged(
             hc, k, v = block.prefill_paged_at(
                 hc, pk_l[None], pv_l[None], bt, 0, mask_pool, mask_self,
                 sin_h, cos_h, start=start, pool_sk=sk_l, pool_sv=sv_l,
-                sp=sp,
+                sp=sp, experts=stacked_experts(model, xs[-1]),
             )
             return hc, (k, v)
 
         xs = (model.blocks, pool_k, pool_v)
         if quant:
             xs = xs + (pool_sk, pool_sv)
+        if stacked_experts(model, 0) is not None:
+            xs = xs + (jnp.arange(cfg.n_layer, dtype=jnp.int32),)
         h, (ks, vs) = jax.lax.scan(body, h, xs)
     else:
         ks, vs = [], []
@@ -2087,7 +2274,7 @@ def prefill_chunk_paged(
             h, k, v = block.prefill_paged_at(
                 h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
                 cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
-                sp=sp,
+                sp=sp, experts=stacked_experts(model, i),
             )
             ks.append(k)
             vs.append(v)
@@ -2116,7 +2303,9 @@ def verify_tokens_paged(
     pool_sv: tp.Optional[Array] = None,
     paged_kernel: str = "xla",
     layer_scan: str = "off",
-) -> tp.Tuple[Array, Array, Array]:
+    block_len: int = 0,
+    expert_rows: bool = False,
+) -> tp.Tuple[Array, ...]:
     """Speculative-decoding VERIFICATION forward: score every slot's
     ``[T = spec_len + 1]`` candidate rows (the true next token + the
     drafted continuation) in one batched multi-query pass over the
@@ -2141,9 +2330,20 @@ def verify_tokens_paged(
     ``0..j`` are the true context, which is precisely what acceptance
     checks) and the rows' post-rope K / raw V [L, S, Hkv, T, C] for the
     watermark-masked page write (only accepted rows' K/V ever lands;
-    rejected rows are dropped by the scatter mask — the rollback)."""
+    rejected rows are dropped by the scatter mask — the rollback).
+
+    ``block_len`` > 0 is the BLOCK-DIFFUSION forward (denoising and
+    commit alike — serving.engine's block window): ``start`` is a
+    multiple of the block length, the T rows are whole blocks, and a row
+    sees every row of its own block and of the blocks before it — the
+    rows' own mask is the only thing that changes; logits of row j then
+    predict position ``start + j`` ITSELF. ``expert_rows`` (a model whose
+    MLP is an ExpertMLP): a fourth result, the rows routed to each expert
+    in each layer ``[L, E]`` int32."""
     cfg = model.config
     s, t = tokens.shape
+    block = max(1, block_len)
+    assert t % block == 0, (t, block)
     pmax = bt.shape[1]
     ps = pool_k.shape[2]
     pool_k = shard_act(pool_k, None, None, None, "kv_heads")
@@ -2162,8 +2362,9 @@ def verify_tokens_paged(
         idx[None, :] < start[:, None], 0.0, -jnp.inf
     ).astype(jnp.float32)[:, None, None, None, :]  # [S, 1, 1, 1, W]
     ii = jnp.arange(t)
+    bi = ii // block if block > 1 else ii
     mask_self = jnp.where(
-        ii[None, :] <= ii[:, None], 0.0, -jnp.inf
+        bi[None, :] <= bi[:, None], 0.0, -jnp.inf
     ).astype(jnp.float32)  # [T, T]
     pos = jnp.clip(start[:, None] + ii[None, :], 0, rope_len - 1)  # [S, T]
     sin_rows = jnp.take(sin_t, pos, axis=0)[:, None]  # [S, 1, T, C//2]
@@ -2178,38 +2379,45 @@ def verify_tokens_paged(
         quant = pool_sk is not None
 
         def body(hc, xs):
-            block, pk_l, pv_l = xs[:3]
+            blk, pk_l, pv_l = xs[:3]
             sk_l = xs[3][None] if quant else None
             sv_l = xs[4][None] if quant else None
-            hc, k, v = block.verify_paged_at(
+            hc, *out = blk.verify_paged_at(
                 hc, pk_l[None], pv_l[None], bt, 0, mask_pool, mask_self,
                 sin_h, cos_h, start=start, pool_sk=sk_l, pool_sv=sv_l,
-                paged_kernel=paged_kernel,
+                paged_kernel=paged_kernel, block=block,
+                expert_rows=expert_rows,
+                experts=stacked_experts(model, xs[-1]),
             )
-            return hc, (k, v)
+            return hc, tuple(out)
 
         xs = (model.blocks, pool_k, pool_v)
         if quant:
             xs = xs + (pool_sk, pool_sv)
-        h, (ks, vs) = jax.lax.scan(body, h, xs)
+        if stacked_experts(model, 0) is not None:
+            xs = xs + (jnp.arange(cfg.n_layer, dtype=jnp.int32),)
+        h, outs = jax.lax.scan(body, h, xs)
     else:
-        ks, vs = [], []
+        per_layer = []
         for i in range(cfg.n_layer):
-            block = jax.tree.map(lambda a: a[i], model.blocks)  # static
-            h, k, v = block.verify_paged_at(
+            blk = jax.tree.map(lambda a: a[i], model.blocks)  # static
+            h, *out = blk.verify_paged_at(
                 h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
                 cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
-                paged_kernel=paged_kernel,
+                paged_kernel=paged_kernel, block=block,
+                expert_rows=expert_rows, experts=stacked_experts(model, i),
             )
-            ks.append(k)
-            vs.append(v)
-        ks, vs = jnp.stack(ks), jnp.stack(vs)
+            per_layer.append(out)
+        outs = tuple(jnp.stack(o) for o in zip(*per_layer))
+    ks, vs = outs[:2]
     h = model.ln_f(h)
     # vocab-sharded per-row logits (column-parallel head) — acceptance
     # argmaxes partition over 'tensor', no gathered [S, T, V] buffer
     logits = shard_act(model.project(h), None, None, "vocab")  # [S, T, V]
     ks = shard_act(ks, None, None, "kv_heads", None, None)
     vs = shard_act(vs, None, None, "kv_heads", None, None)
+    if expert_rows:
+        return logits, ks, vs, outs[2]
     return logits, ks, vs  # ks/vs: [L, S, Hkv, T, C]
 
 
@@ -2338,6 +2546,9 @@ GPT_PARAM_RULES: tp.Sequence[tp.Tuple[str, P]] = (
     (r"mlp/expert_up", P("tensor", "fsdp", None)),
     (r"mlp/expert_down", P("tensor", None, "fsdp")),
     (r"mlp/router/weight", P()),
+    # routed experts (mlp="experts"): replicated — an expert axis over a
+    # mesh is ROADMAP Reach A1
+    (r"mlp/w_in|mlp/w_out", P()),
     (r"ln_f/weight|ln1/weight|ln2/weight", P()),
     # [D, V]: embed over fsdp, vocab over tensor
     (r"lm_head/weight", P("fsdp", "tensor")),

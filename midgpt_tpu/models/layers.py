@@ -172,8 +172,9 @@ def rotate_every_two(x: Array) -> Array:
 
 
 @functools.lru_cache(maxsize=None)
-def _rotation_matrix(c: int, dtype_name: str) -> np.ndarray:
-    """[C, C] constant R with (x @ R) == rotate_every_two(x).
+def _rotation_matrix(c: int, dtype_name: str, style: str = "interleaved") -> np.ndarray:
+    """[C, C] constant R with (x @ R) == rotate_every_two(x) — or, for
+    ``style="half"``, == rotate_half(x).
 
     Strided even/odd slicing on the minor (lane) dim lowers to a gather on
     TPU — and its transpose (the VJP) to a scatter-add, which profiling
@@ -182,10 +183,22 @@ def _rotation_matrix(c: int, dtype_name: str) -> np.ndarray:
     matmul). Each output element receives exactly one +-x term, so the
     result is bit-identical to the slicing form in any dtype."""
     r = np.zeros((c, c), dtype=np.float32)
-    idx = np.arange(0, c, 2)
-    r[idx + 1, idx] = -1.0  # y[2i] = -x[2i+1]
-    r[idx, idx + 1] = 1.0  # y[2i+1] = x[2i]
+    if style == "half":
+        idx = np.arange(c // 2)
+        r[idx + c // 2, idx] = -1.0  # y[i] = -x[i + C/2]
+        r[idx, idx + c // 2] = 1.0  # y[i + C/2] = x[i]
+    else:
+        idx = np.arange(0, c, 2)
+        r[idx + 1, idx] = -1.0  # y[2i] = -x[2i+1]
+        r[idx, idx + 1] = 1.0  # y[2i+1] = x[2i]
     return r.astype(dtype_name)
+
+
+def rotate_half(x: Array) -> Array:
+    """[x1 | x2] -> [-x2 | x1] over the two halves of the last dim: the
+    oracle of ``apply_rotary(style="half")``."""
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate((-x2, x1), axis=-1)
 
 
 def _duplicate_interleaved(t: Array) -> Array:
@@ -198,15 +211,21 @@ def apply_rotary(
     x: Array,
     sin: tp.Union[Array, np.ndarray],
     cos: tp.Union[Array, np.ndarray],
+    style: str = "interleaved",
 ) -> Array:
-    """Apply interleaved RoPE. ``x``: [..., T, C]; sin/cos: [T, C//2]
-    (parity: layers.py:92-99)."""
+    """Apply RoPE. ``x``: [..., T, C]; sin/cos: [T, C//2] (parity:
+    layers.py:92-99). ``style``: which lanes pair up — "interleaved"
+    (2i, 2i+1), or "half" (i, i + C/2: ``rotate_half``)."""
     with jax.named_scope("rope"):
         sin = jnp.asarray(sin, dtype=x.dtype)
         cos = jnp.asarray(cos, dtype=x.dtype)
-        sin_full = _duplicate_interleaved(sin)  # [T, C]
-        cos_full = _duplicate_interleaved(cos)
-        rot = jnp.asarray(_rotation_matrix(x.shape[-1], x.dtype.name))
+        if style == "half":
+            sin_full = jnp.concatenate((sin, sin), axis=-1)  # [T, C]
+            cos_full = jnp.concatenate((cos, cos), axis=-1)
+        else:
+            sin_full = _duplicate_interleaved(sin)  # [T, C]
+            cos_full = _duplicate_interleaved(cos)
+        rot = jnp.asarray(_rotation_matrix(x.shape[-1], x.dtype.name, style))
         return x * cos_full + (x @ rot) * sin_full
 
 

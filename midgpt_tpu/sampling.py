@@ -164,6 +164,32 @@ def acceptance_mask(u: Array, q_sel: Array, p_sel: Array) -> Array:
     return (u * q_sel) <= p_sel
 
 
+def token_confidence(logits: Array) -> tp.Tuple[Array, Array]:
+    """Greedy pick and its confidence for every row of ``logits`` [..., V]:
+    ``x = argmax`` and ``c = softmax(logits)[x]``, in f32 — the
+    block-diffusion reveal rule's two inputs (temperature 0). The
+    confidence is ``1 / sum(exp(l - max l))``: the pick's own exponent is
+    ``exp(0)``."""
+    lg = logits.astype(jnp.float32)
+    top = jnp.max(lg, axis=-1, keepdims=True)
+    conf = 1.0 / jnp.sum(jnp.exp(lg - top), axis=-1)
+    return jnp.argmax(lg, axis=-1).astype(jnp.int32), conf
+
+
+def reveal_most_confident(conf: Array, masked: Array, n: int) -> Array:
+    """``low_confidence_static`` selection: a bool mask over the last axis
+    choosing the ``n`` still-``masked`` positions of highest ``conf``
+    (all of them where fewer are masked; ties go to the lower index, as
+    ``lax.top_k`` orders them). Revealed positions are never chosen."""
+    n = min(n, conf.shape[-1])
+    ranked = jnp.where(masked, conf, -1.0)  # a confidence is > 0
+    _, idx = jax.lax.top_k(ranked, n)  # [..., n]
+    chosen = jnp.any(
+        jax.nn.one_hot(idx, conf.shape[-1], dtype=jnp.bool_), axis=-2
+    )
+    return chosen & masked
+
+
 def residual_logits(
     p: Array, q: Array, temperature: float
 ) -> tp.Tuple[Array, Array]:

@@ -18,6 +18,10 @@ Public surface:
   fused K-step decode program and the suffix-prefill chunk program
   (both audited for donation and host-sync regressions:
   ``python -m midgpt_tpu.analysis --serving``).
+- :func:`~midgpt_tpu.serving.engine.make_block_window` — the window of
+  a block-diffusion model (``ModelConfig.block_len``): K forwards of
+  every slot's current block, denoising or commit as its state says,
+  the reveal rule on the device (README "Block-diffusion generation").
 - :func:`~midgpt_tpu.serving.engine.make_verify_program`,
   :class:`~midgpt_tpu.serving.speculate.NgramProposer` — self-speculative
   decoding: draft-model-free n-gram drafting plus the single-dispatch
@@ -107,6 +111,7 @@ from midgpt_tpu.serving.engine import (
     Request,
     ServingEngine,
     make_copy_page_program,
+    make_block_window,
     make_decode_window,
     make_prefill_chunk_program,
     make_verify_program,
@@ -169,6 +174,7 @@ __all__ = [
     "generate_served",
     "import_pages",
     "make_copy_page_program",
+    "make_block_window",
     "make_decode_window",
     "make_prefill_chunk_program",
     "make_verify_program",

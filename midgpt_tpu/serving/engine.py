@@ -415,7 +415,15 @@ def _build_prefill_chunk_program(
                 model, tokens, start, pool.k, pool.v, bt_row[None, :],
                 rope_len, pool_sk=pool.scale_k, pool_sv=pool.scale_v,
                 layer_scan=layer_scan, sp=(prefill_sp == "on"),
+                block_len=cfg.block_len,
             )  # h: [1, T, D]; ks/vs: [L, 1, Hkv, T, C]
+            if cfg.block_len:
+                # a block-diffusion prompt's whole blocks leave K/V and
+                # nothing else: its first block opens all masked, and no
+                # logits row is carried (the head is 0.6 GB at 152k ids)
+                return write_token_rows(
+                    pool, ks[:, 0], vs[:, 0], bt_row, start, real_n
+                ), logits
             h_last = jax.lax.dynamic_slice_in_dim(
                 h, real_n - 1, 1, axis=1
             )[:, 0]  # [1, D]
@@ -803,6 +811,172 @@ def _build_verify_program(
     return jax.jit(verify_fn, donate_argnums=(1, 2))
 
 
+def make_block_window(
+    model: GPT,
+    *,
+    slots: int,
+    window: int,
+    pmax: int,
+    rope_len: int,
+    mesh=None,
+    paged_kernel: str = "xla",
+    layer_scan: str = "off",
+):
+    key = (
+        "block_window", model.config, slots, window, pmax, rope_len,
+        paged_kernel, layer_scan, _mesh_key(mesh),
+    )
+    return _cached_program(
+        key,
+        lambda: _build_block_window(
+            model.config, slots=slots, window=window, pmax=pmax,
+            rope_len=rope_len, mesh=mesh, paged_kernel=paged_kernel,
+            layer_scan=layer_scan,
+        ),
+    )
+
+
+def _build_block_window(
+    cfg, *, slots: int, window: int, pmax: int, rope_len: int, mesh,
+    paged_kernel: str = "xla", layer_scan: str = "off",
+):
+    """The decode window of a BLOCK-DIFFUSION model (``cfg.block_len`` =
+    B > 0): ONE jitted, pool-donating ``lax.scan`` over ``window``
+    forwards, each a forward of every slot's current block of B rows
+    through ``models.gpt.verify_tokens_paged`` under the block mask —
+    the verify program's forward with another mask, not another one.
+
+    A slot's unit of work is not a token. Its block state is
+    (``tok`` [B] the block's tokens, the mask token where nothing is
+    revealed yet; ``rev`` [B] the revealed set, kept explicitly — with
+    random weights the argmax IS the mask token once in V tokens, and a
+    comparison of ids would read that as still masked; ``at`` [B] the
+    denoising step at which each position was revealed, -1 for a prompt
+    position and for one not yet revealed). Each forward is, per slot,
+    as its state says:
+
+    - a DENOISING forward (some position still masked): nothing is
+      written; at every masked position the greedy pick and its
+      confidence (``sampling.token_confidence``), and the ``B //
+      block_steps`` masked positions of highest confidence are revealed
+      (``sampling.reveal_most_confident``: ``low_confidence_static`` at
+      temperature 0). Logits of row i predict position i itself. When the
+      last position is revealed the block's generated tokens are emitted
+      (a prompt's ``P mod B`` remainder opens the first block already
+      revealed and is not emitted), up to the request's budget;
+    - a COMMIT forward (no position masked): the B final rows' K/V land
+      in the pages (``flush_recent``), the resident length grows by B and
+      a fresh all-masked block begins; its logits are not used. The block
+      that ends a request is not committed: no token follows it.
+
+    So a block costs ``block_steps + 1`` forwards, and ``tokens`` grow by
+    whole blocks. Done and empty slots ride along masked, as in the
+    decode window. Returns the pool, the slots' state after the window,
+    and per forward what the host harvests: the blocks as they stood, the
+    forwards that completed one and how many of its tokens count, plus the
+    window's counters (forwards by kind, positions revealed, and — for an
+    ExpertMLP model — the rows routed to each expert of each layer)."""
+    from midgpt_tpu.parallel.sharding import axis_rules
+    from midgpt_tpu.sampling import reveal_most_confident, token_confidence
+
+    blk, n_steps = cfg.block_len, cfg.block_steps
+    assert blk >= 1 and n_steps >= 1 and blk % n_steps == 0, (blk, n_steps)
+    n_reveal = blk // n_steps
+    mask_tok = jnp.int32(cfg.mask_token)
+    with_rows = cfg.mlp == "experts"
+
+    def window_fn(
+        model: GPT,  # ENTRY PARAMETER (see make_decode_window)
+        pool: PagedKVPool,  # DONATED
+        bt: Array,  # [S, Pmax] int32 block tables
+        pooled_len: Array,  # [S] int32 — committed tokens, a multiple of B
+        done: Array,  # [S] bool — finished or empty slot
+        emitted: Array,  # [S] int32 — tokens emitted so far per request
+        budget: Array,  # [S] int32 — max_new_tokens per request
+        eos: Array,  # [S] int32 — per-request EOS id (-1 = none)
+        tok: Array,  # [S, B] int32 — the current block
+        rev: Array,  # [S, B] bool — its revealed set
+        at: Array,  # [S, B] int32 — reveal step per position
+    ):
+        assert bt.shape == (slots, pmax), (
+            f"block table {bt.shape} != declared geometry ({slots}, {pmax})"
+        )
+        with axis_rules(mesh, serving_logical_rules()):
+
+            def body(carry, _):
+                pool, pooled_len, done, emitted, tok, rev, at = carry
+                commit = ~done & jnp.all(rev, axis=1)
+                denoise = ~done & ~commit
+                out = verify_tokens_paged(
+                    model, tok, pooled_len, pool.k, pool.v, bt, rope_len,
+                    paged_kernel=paged_kernel, layer_scan=layer_scan,
+                    block_len=blk, expert_rows=with_rows,
+                )
+                logits, ks, vs = out[:3]
+                with jax.named_scope("block_reveal"):
+                    pick, conf = token_confidence(logits)  # [S, B]
+                    chosen = reveal_most_confident(
+                        conf, ~rev, n_reveal
+                    ) & denoise[:, None]
+                    step = jnp.max(at, axis=1) + 1  # this block's forward no.
+                    tok = jnp.where(chosen, pick, tok)
+                    rev = rev | chosen
+                    at = jnp.where(chosen, step[:, None], at)
+                    complete = denoise & jnp.all(rev, axis=1)
+                    # the block's generated positions are its tail (a
+                    # prompt remainder is its head); the first n_emit
+                    # of them are the request's, the rest is past budget
+                    gen = at >= 0
+                    n_emit = jnp.where(
+                        complete,
+                        jnp.minimum(
+                            jnp.sum(gen.astype(jnp.int32), axis=1),
+                            budget - emitted,
+                        ),
+                        0,
+                    )
+                    rank = jnp.cumsum(gen.astype(jnp.int32), axis=1) - 1
+                    counted = gen & (rank < n_emit[:, None])
+                    hit_eos = jnp.any(counted & (tok == eos[:, None]), axis=1)
+                    emitted = emitted + n_emit
+                    done = done | (
+                        complete & ((emitted >= budget) | hit_eos)
+                    )
+                # the commit: B rows land, a fresh block begins
+                wide = jnp.broadcast_to(commit[:, None], tok.shape)
+                pool = flush_recent(pool, ks, vs, bt, pooled_len, wide)
+                pooled_len = pooled_len + jnp.where(commit, blk, 0)
+                ys = (
+                    tok, at, complete, n_emit, denoise, commit,
+                    jnp.sum(chosen.astype(jnp.int32)),
+                ) + ((out[3],) if with_rows else ())
+                tok = jnp.where(wide, mask_tok, tok)
+                rev = rev & ~wide
+                at = jnp.where(wide, -1, at)
+                return (pool, pooled_len, done, emitted, tok, rev, at), ys
+
+            carry, ys = jax.lax.scan(
+                body, (pool, pooled_len, done, emitted, tok, rev, at),
+                None, length=window,
+            )
+            toks, ats, complete, n_emit, denoise, commit, revealed = ys[:7]
+            counters = {
+                "denoise_forwards": jnp.sum(denoise.astype(jnp.int32)),
+                "commit_forwards": jnp.sum(commit.astype(jnp.int32)),
+                "tokens_revealed": jnp.sum(revealed),
+            }
+            if with_rows:
+                rows = ys[7]  # [K, L, E]
+                counters["expert_rows"] = jnp.sum(rows, axis=0)  # [L, E]
+                counters["expert_rows_max"] = jnp.sum(jnp.max(rows, axis=-1))
+                counters["experts_touched"] = jnp.sum(
+                    (rows > 0).astype(jnp.int32)
+                )
+        return carry, (toks, ats, complete, n_emit), counters
+
+    return jax.jit(window_fn, donate_argnums=(1,))
+
+
 def trace_serving_programs(
     model: GPT,
     *,
@@ -923,6 +1097,9 @@ class Request:
     first_token_time: tp.Optional[float] = None
     finish_time: tp.Optional[float] = None
     tokens: tp.List[int] = dataclasses.field(default_factory=list)
+    # block-diffusion engines: per token, the denoising step of its block
+    # at which it was revealed (empty otherwise)
+    reveal_steps: tp.List[int] = dataclasses.field(default_factory=list)
     evictions: int = 0
     cached_tokens: int = 0  # prompt tokens served from the prefix cache
     # (summed over admissions — re-admissions typically re-hit)
@@ -1031,6 +1208,20 @@ _ENGINE_COUNTERS = (
     "cancelled_requests",
     "deadline_shed_requests",
     "faults_injected",
+    # block-diffusion windows (all zero otherwise): slot-forwards by kind,
+    # blocks whose K/V landed, positions revealed; and of an expert
+    # model's window forwards, per layer and forward: rows routed, rows
+    # not computed (always 0: the layer is dropless), the busiest
+    # expert's rows, experts with a row, and how many (layer, forward)s
+    "denoise_forwards",
+    "commit_forwards",
+    "blocks_committed",
+    "tokens_revealed",
+    "expert_rows_routed",
+    "expert_rows_dropped",
+    "expert_rows_max",
+    "experts_touched",
+    "expert_layer_forwards",
 )
 
 
@@ -1286,7 +1477,8 @@ class ServingEngine:
             geometry = dict(
                 pmax=pages_needed(cfg.block_size, page_size),
                 page_size=page_size, c=cfg.head_dim, itemsize=itemsize,
-                groups=cfg.n_head // cfg.kv_heads, spec_t=speculate + 1,
+                groups=cfg.n_head // cfg.kv_heads,
+                spec_t=cfg.block_len or speculate + 1,
                 heads=max(1, cfg.kv_heads // tp_sz),
             )
             kernel_ok = pk_supported(**geometry)
@@ -1432,6 +1624,42 @@ class ServingEngine:
         # window, spec_len + 1 candidate rows for the verify program —
         # page growth provisions this many
         self._grow = (self.speculate + 1) if self.speculate else window
+        # generation by diffusion over blocks (cfg.block_len > 0): a
+        # slot's unit of work is a forward of its current block — see
+        # _build_block_window. What it does not compose with yet is
+        # refused here, by name (ROADMAP Reach)
+        self.block_len = int(cfg.block_len)
+        if self.block_len:
+            b = self.block_len
+            unsupported = {
+                "temperature > 0": temperature > 0.0,
+                "speculate": bool(speculate),
+                "kv_quant": kv_quant is not None,
+                "quant": quant is not None,
+                "a mesh": mesh is not None,
+                "role != 'both'": role != "both",
+                "spill": spill == "on",
+            }
+            bad = [k for k, v in unsupported.items() if v]
+            if bad:
+                raise ValueError(
+                    "block-diffusion generation does not support "
+                    + ", ".join(bad)
+                )
+            assert cfg.block_steps >= 1 and b % cfg.block_steps == 0, (
+                b, cfg.block_steps
+            )
+            assert 0 <= cfg.mask_token < cfg.vocab_size, cfg.mask_token
+            assert page_size % b == 0, (
+                f"a page of {page_size} rows must hold whole blocks of {b}"
+            )
+            assert prefill_chunk is None or prefill_chunk % b == 0, (
+                f"prefill_chunk {prefill_chunk} must be whole blocks of {b}"
+            )
+            # the fewest forwards a block takes is 2 (one position to
+            # reveal, then the commit), so a window commits at most this
+            # many rows a slot
+            self._grow = b * (window // 2 + 1)
         self.pool = PagedKVPool.init(
             cfg, num_pages, page_size, cache_dtype, mesh=mesh,
             kv_quant=kv_quant,
@@ -1483,6 +1711,17 @@ class ServingEngine:
         # identical-content page): pinned so LRU reclaim can never leave
         # slot_node/parent ids dangling in the index
         self.slot_pins: tp.List[tp.List[int]] = [[] for _ in range(slots)]
+        # block-diffusion: each slot's current block (tokens, revealed
+        # set, reveal step per position) and, per layer and expert, the
+        # rows the window forwards routed there
+        if self.block_len:
+            blk = (slots, self.block_len)
+            self.blk_tok = np.full(blk, cfg.mask_token, np.int32)
+            self.blk_rev = np.zeros(blk, bool)
+            self.blk_at = np.full(blk, -1, np.int32)
+            self.expert_rows = np.zeros(
+                (cfg.n_layer, max(1, cfg.experts)), np.int64
+            )
         # round-robin cursor over prefilling slots (persists across
         # windows so a one-chunk budget still alternates slots)
         self._prefill_rr = 0
@@ -1508,7 +1747,14 @@ class ServingEngine:
         # under a deep backlog
         self._live: tp.Dict[int, Request] = {}
 
-        if self.speculate:
+        if self.block_len:
+            self._verify_fn = None
+            self._window_fn = make_block_window(
+                model, slots=slots, window=window, pmax=self.pmax,
+                rope_len=self.block, mesh=mesh,
+                paged_kernel=self.paged_kernel, layer_scan=self.layer_scan,
+            )
+        elif self.speculate:
             # speculation REPLACES the K-step window: every decode
             # dispatch is a verify dispatch (1 + accepted tokens/slot)
             self._verify_fn = make_verify_program(
@@ -1650,7 +1896,8 @@ class ServingEngine:
             if prompt.size > keep:
                 prompt = prompt[-keep:]
             lifetime = pages_needed(
-                int(prompt.size) + max_new_tokens, self.page_size
+                self._lifetime_tokens(int(prompt.size), max_new_tokens),
+                self.page_size,
             )
             if lifetime > self.alloc.num_pages:
                 self._reject(
@@ -1698,6 +1945,13 @@ class ServingEngine:
                 **extra,
             )
             return self.resubmit(req)
+
+    def _lifetime_tokens(self, prompt: int, new: int) -> int:
+        """Rows a request's K/V can come to: its tokens, in whole blocks
+        for a block-diffusion model (generation runs to the end of the
+        block that holds the last token asked for)."""
+        b = self.block_len or 1
+        return -(-(prompt + new) // b) * b
 
     def make_request(
         self,
@@ -2287,8 +2541,18 @@ class ServingEngine:
             full: tp.List[int] = []
             cow_src: tp.Optional[int] = None
             matched = 0
+            target = self._prefill_target(p)
             if self.index is not None:
-                full, cow_src, matched = self.index.match(req.prompt[: p - 1])
+                # (a block-diffusion prompt may be resident whole: its
+                # first block opens from the mask token, not from logits)
+                full, cow_src, matched = self.index.match(
+                    req.prompt[: target if self.block_len else p - 1]
+                )
+                if self.block_len and cow_src is not None:
+                    # a block's K/V are those of its final tokens, ALL of
+                    # them: a page matched in part may end inside a block,
+                    # so only whole pages (whole blocks) are reused
+                    cow_src, matched = None, len(full) * self.page_size
             # PIN the matched chain (and the COW source, until its copy
             # lands) before reserving: revived out of the LRU, the
             # reservation below can never reclaim (or spill) them out
@@ -2357,7 +2621,7 @@ class ServingEngine:
             self.bt[s, :n_pages] = pages
             self.pooled_len[s] = matched
             self.done[s] = True  # not decodable until prefill completes
-            self.prefilling[s] = True
+            self.prefilling[s] = matched < target
             self.emitted[s] = len(req.tokens)
             self.budget[s] = req.max_new_tokens
             self.eos[s] = req.eos_id
@@ -2381,16 +2645,41 @@ class ServingEngine:
                 "admitted", rid=req.rid, t=now, slot=s, prompt_tokens=p,
                 cached_tokens=matched, pages=n_pages,
             )
+            if not self.prefilling[s]:
+                # a block-diffusion prompt with no whole block left to
+                # prefill (shorter than a block, or all of it cached)
+                self._open_first_block(s)
             admitted += 1
 
     # -- chunked prefill ----------------------------------------------------
+
+    def _prefill_target(self, p: int) -> int:
+        """Rows of a ``p``-token prompt that prefill makes resident: all
+        of it — or, for a block-diffusion model, its whole blocks (the
+        ``p mod B`` tokens left over open the first generated block,
+        already revealed)."""
+        return p - p % self.block_len if self.block_len else p
+
+    def _open_first_block(self, s: int) -> None:
+        """Slot ``s``'s prompt blocks are resident: it decodes from the
+        next window on, its first block holding the prompt's remainder
+        revealed and everything else masked."""
+        ctx = self.slot_ctx[s]
+        rem = len(ctx) - self._prefill_target(len(ctx))
+        self.blk_tok[s] = self.model.config.mask_token
+        self.blk_tok[s, :rem] = ctx[len(ctx) - rem:]
+        self.blk_rev[s] = np.arange(self.block_len) < rem
+        self.blk_at[s] = -1
+        self.done[s] = False
 
     def _prefill_one_chunk(self, s: int) -> bool:
         """Run ONE prefill chunk for slot ``s``; returns True when the
         slot's prompt is fully resident (the slot becomes decodable)."""
         req = self.slot_req[s]
         assert req is not None and self.prefilling[s]
-        p = len(self.slot_ctx[s])  # == req.prompt.size at admission
+        # == req.prompt.size at admission (its whole blocks, for a
+        # block-diffusion model)
+        p = self._prefill_target(len(self.slot_ctx[s]))
         start = int(self.pooled_len[s])
         remaining = p - start
         assert remaining >= 1, (s, p, start)
@@ -2451,6 +2740,8 @@ class ServingEngine:
                 # it) until the cluster exports its pages to a
                 # decode-class engine
                 self.handoff_ready[s] = True
+            elif self.block_len:
+                self._open_first_block(s)
             else:
                 self.done[s] = False  # decodable from the next window on
             return True
@@ -2631,6 +2922,14 @@ class ServingEngine:
             # healthy requests for tokens that will never be written
             remaining = int(self.budget[s]) - int(self.emitted[s])
             tokens = int(self.pooled_len[s]) + min(self._grow, remaining)
+            if self.block_len:
+                # commits land whole blocks, never past the request's last
+                tokens = min(
+                    int(self.pooled_len[s]) + self._grow,
+                    self._lifetime_tokens(
+                        len(self.slot_ctx[s]), remaining
+                    ),
+                )
             need = min(
                 pages_needed(tokens, self.page_size), self.pmax
             ) - len(self.slot_pages[s])
@@ -2838,6 +3137,84 @@ class ServingEngine:
 
         self._harvest_apply(hw, decoding, harvested)
 
+    def _run_block_window(self, decoding: tp.List[int]) -> None:
+        """One window of block-diffusion forwards + harvest: tokens arrive
+        by whole blocks, from the forwards that revealed a block's last
+        position."""
+        with span(
+            "midgpt.engine.decode_dispatch", self.telemetry,
+            clock=self.clock,
+        ) as disp:
+            carry, (toks, ats, complete, n_emit), counters = self._window_fn(
+                self.model,
+                self.pool,
+                jnp.asarray(self.bt),
+                jnp.asarray(self.pooled_len),
+                jnp.asarray(self.done),
+                jnp.asarray(self.emitted),
+                jnp.asarray(self.budget),
+                jnp.asarray(self.eos),
+                jnp.asarray(self.blk_tok),
+                jnp.asarray(self.blk_rev),
+                jnp.asarray(self.blk_at),
+            )
+            self.pool = carry[0]
+
+        # ONE device->host sync per window, and one read: ``device_get`` of
+        # the whole tree starts every leaf's copy before it waits for the
+        # first (a read a leaf is ~1 ms of idle device each, PERF.md PR 25)
+        with self._harvest_span(
+            disp, "decode_window", decoding, window=self.window
+        ) as hw:
+            (toks_h, ats_h, comp_h, n_emit_h), state, counters = (
+                jax.device_get(((toks, ats, complete, n_emit), carry[1:],
+                                counters))
+            )
+            committed = state[0] - self.pooled_len
+            self._harvest_state(state[1], state[0], state[2])
+            # np.array (copy): the scheduler writes these in place
+            self.blk_tok, self.blk_rev, self.blk_at = (
+                np.array(a) for a in state[3:6]
+            )
+            if self.telemetry is not None:
+                hw.tokens = int(n_emit_h[:, np.asarray(decoding)].sum())
+        self.denoise_forwards += int(counters["denoise_forwards"])
+        self.commit_forwards += int(counters["commit_forwards"])
+        self.tokens_revealed += int(counters["tokens_revealed"])
+        self.blocks_committed += int(committed.sum()) // self.block_len
+        if "expert_rows" in counters:
+            rows = counters["expert_rows"]  # [L, E]
+            cfg = self.model.config
+            claims = (
+                self.window * self.slots * self.block_len
+                * cfg.experts_per_token * cfg.n_layer
+            )
+            self.expert_rows += rows
+            self.expert_rows_routed += int(rows.sum())
+            self.expert_rows_dropped += claims - int(rows.sum())
+            self.expert_rows_max += int(counters["expert_rows_max"])
+            self.experts_touched += int(counters["experts_touched"])
+            self.expert_layer_forwards += self.window * cfg.n_layer
+
+        eos_id = self.eos
+
+        def harvested(s: int, req: Request) -> tp.List[int]:
+            new: tp.List[int] = []
+            for r in range(self.window):
+                if not comp_h[r, s]:
+                    continue
+                gen = np.nonzero(ats_h[r, s] >= 0)[0][: n_emit_h[r, s]]
+                block = [int(t) for t in toks_h[r, s, gen]]
+                steps = [int(a) for a in ats_h[r, s, gen]]
+                if eos_id[s] >= 0 and int(eos_id[s]) in block:
+                    cut = block.index(int(eos_id[s])) + 1
+                    block, steps = block[:cut], steps[:cut]
+                new.extend(block)
+                req.reveal_steps.extend(steps)
+            return new
+
+        self._harvest_apply(hw, decoding, harvested)
+
     def _harvest_span(
         self, disp: span, kind: str, decoding: tp.List[int], **stats
     ) -> span:
@@ -2986,7 +3363,9 @@ class ServingEngine:
                 # eviction may have changed it
                 decoding = self._decoding_slots()
             if decoding:
-                if self.speculate:
+                if self.block_len:
+                    self._run_block_window(decoding)
+                elif self.speculate:
                     self._run_verify(decoding)
                 else:
                     self._run_window(decoding)
@@ -3176,6 +3555,17 @@ class ServingEngine:
             "cancelled_requests": self.cancelled_requests,
             "deadline_shed_requests": self.deadline_shed_requests,
             "faults_injected": self.faults_injected,
+            # block-diffusion windows and the expert layer's routing in
+            # them (_build_block_window; all zero for other models)
+            "denoise_forwards": self.denoise_forwards,
+            "commit_forwards": self.commit_forwards,
+            "blocks_committed": self.blocks_committed,
+            "tokens_revealed": self.tokens_revealed,
+            "expert_rows_routed": self.expert_rows_routed,
+            "expert_rows_dropped": self.expert_rows_dropped,
+            "expert_rows_max": self.expert_rows_max,
+            "experts_touched": self.experts_touched,
+            "expert_layer_forwards": self.expert_layer_forwards,
             # the paged kernel's walk: pages read a decode step, summed,
             # and the block-table pages of the same steps
             "kv_pages_walked": self.kv_pages_walked,

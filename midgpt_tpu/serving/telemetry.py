@@ -135,6 +135,15 @@ ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "cancelled_requests",
     "deadline_shed_requests",
     "faults_injected",
+    "denoise_forwards",
+    "commit_forwards",
+    "blocks_committed",
+    "tokens_revealed",
+    "expert_rows_routed",
+    "expert_rows_dropped",
+    "expert_rows_max",
+    "experts_touched",
+    "expert_layer_forwards",
     "kv_pages_walked",
     "kv_pages_table",
 )

@@ -120,7 +120,7 @@ def loss_fn(
     if pp_mesh is not None:
         from midgpt_tpu.parallel.pipeline import gpt_pipeline_hidden
 
-        assert model.config.mlp != "moe", (
+        assert model.config.mlp not in ("moe", "experts"), (
             "MoE is not supported under pipeline parallelism (v1): the "
             "aux loss rides the layer scan, which PP replaces"
         )
@@ -128,7 +128,7 @@ def loss_fn(
             model, x, pp_mesh, n_micro=pp_microbatches, key=key,
             deterministic=deterministic, boundary_dtype=pp_boundary_dtype,
         )
-    elif model.config.mlp == "moe":
+    elif model.config.mlp in ("moe", "experts"):
         h, aux = model.hidden(
             x, key=key, deterministic=deterministic, return_aux=True
         )

@@ -1,0 +1,404 @@
+"""Generation by diffusion over blocks, and the dropless expert layer, through
+``ServingEngine`` — held to the plain reference of the architecture
+(``benchmark/reference_block.py``: float32, no kernel, no cache, no batching,
+every expert computed for every row) on seeded random weights at a small size:
+D 64, 8 query heads over 2 KV heads of 16 (so H C = 128 != D), 16 experts
+top-4, blocks of 4 with 4 denoising steps, V 512. Logits are compared, not
+sampled tokens; where tokens are compared the model is float32 and the seeds
+leave no near-tie."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_block as rb
+from benchmark import weights_block
+from benchmark.kinds.serve_block import fill_model
+from midgpt_tpu import sampling
+from midgpt_tpu.config import ModelConfig
+from midgpt_tpu.models.gpt import ExpertMLP, GPT, verify_tokens_paged
+from midgpt_tpu.models.layers import apply_rotary, rotate_half
+from midgpt_tpu.serving import ENGINE_STATS_KEYS, ServingEngine
+
+SIZES = dict(
+    n_layer=2, n_head=8, n_kv_head=2, head_width=16, n_embd=64,
+    vocab_size=512, block_size=128, experts=16, experts_per_token=4,
+    expert_hidden=32, expert_renorm=True, norm_eps=1e-6, rope_base=1e6,
+    block_len=4, block_steps=4, mask_token=511,
+)
+CFG = ModelConfig(
+    qk_norm=True, qk_norm_kind="rms", rope_style="half", norm_scale=True,
+    mlp="experts", tie_embeddings=False, attn_impl="naive", remat="none",
+    **SIZES,
+)
+B = SIZES["block_len"]
+NEW = 14  # not a multiple of the block: the last block is cut
+# P mod B = 1, 0, 2, 3, 3; one prompt shorter than a block; two chunks
+PROMPT_LENS = (9, 16, 22, 3, 35)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return weights_block.make(jax.random.PRNGKey(3), SIZES, jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def model(weights):
+    return fill_model(weights, CFG)
+
+
+@pytest.fixture(scope="module")
+def prompts():
+    rng = np.random.default_rng(0)
+    return [rng.integers(0, 510, size=n).astype(np.int32) for n in PROMPT_LENS]
+
+
+@pytest.fixture(scope="module")
+def forward():
+    return rb.make_forward(SIZES)
+
+
+@pytest.fixture(scope="module")
+def reference(weights, prompts, forward):
+    """The published loop's tokens, reveal steps and per-forward log."""
+    return [rb.generate(weights, p, NEW, SIZES, forward=forward)
+            for p in prompts]
+
+
+def engine(model, **kw):
+    kw = {"slots": 3, "page_size": 16, "window": 5, "prefill_chunk": 16,
+          "cache_dtype": jnp.float32, "paged_kernel": "xla", **kw}
+    return ServingEngine(model, **kw)
+
+
+@pytest.fixture(scope="module")
+def served(model, prompts):
+    eng = engine(model)
+    rids = [eng.submit(p, NEW) for p in prompts]
+    eng.run()
+    return eng, [eng.finished[r] for r in rids]
+
+
+# -- (b), (e): reveal order and final tokens, every P mod B ----------------
+
+
+@pytest.mark.parametrize("i", range(len(PROMPT_LENS)))
+def test_tokens_and_reveal_steps_equal_the_published_loop(served, reference, i):
+    _, reqs = served
+    toks, steps, _ = reference[i]
+    assert reqs[i].tokens == list(toks), PROMPT_LENS[i] % B
+    assert reqs[i].reveal_steps == list(steps)
+    assert len(reqs[i].tokens) == NEW
+
+
+def test_counters_count_blocks_not_steps(served):
+    eng, reqs = served
+    st = eng.stats()
+    assert tuple(st) == ENGINE_STATS_KEYS
+    # a block costs one forward a position to reveal, and its commit: the
+    # block that ends a request is not committed
+    blocks = [-(-(p + NEW) // B) - p // B for p in PROMPT_LENS]
+    revealed = [b * B - p % B for b, p in zip(blocks, PROMPT_LENS)]
+    assert st["tokens_revealed"] == sum(revealed) == st["denoise_forwards"]
+    assert st["commit_forwards"] == st["blocks_committed"] == sum(
+        b - 1 for b in blocks)
+    assert st["tokens_generated"] == NEW * len(PROMPT_LENS)
+    assert st["expert_rows_dropped"] == 0
+    per_forward = eng.slots * B * SIZES["experts_per_token"]
+    assert st["expert_rows_routed"] == per_forward * st["expert_layer_forwards"]
+    assert eng.expert_rows.sum() == st["expert_rows_routed"]
+    assert 0 < st["experts_touched"] <= SIZES["experts"] * st[
+        "expert_layer_forwards"]
+
+
+# -- (a), (c): logits of every denoising step, and the K/V committed --------
+
+
+def _pool_rows(eng, s, n):
+    """Slot ``s``'s first ``n`` resident rows of K and V, [L, n, Hkv, C]."""
+    pages = eng.bt[s, : -(-n // eng.page_size)]
+    out = []
+    for a in (eng.pool.k, eng.pool.v):
+        rows = np.asarray(a)[:, pages].reshape(a.shape[0], -1, a.shape[-1])
+        out.append(rows[:, :n].reshape(
+            a.shape[0], n, SIZES["n_kv_head"], SIZES["head_width"]))
+    return out
+
+
+@pytest.mark.parametrize("i", (0, 2))
+def test_every_denoising_forward_and_the_committed_kv(
+        model, weights, prompts, reference, forward, i):
+    """One request, a window of ONE forward: before each engine step the
+    slot's state goes through the program's own forward
+    (``verify_tokens_paged`` under the block mask, over the engine's pool)
+    and its logits are held to the reference's at that (block, step); after
+    the run's last commit the pool's rows are held to the K/V of ONE full
+    reference forward under M over the final sequence."""
+    eng = engine(model, slots=1, window=1)
+    eng.submit(prompts[i], NEW)
+    log = iter(reference[i][2])
+    fwd = jax.jit(lambda m, t, st, pk, pv, bt: verify_tokens_paged(
+        m, t, st, pk, pv, bt, CFG.block_size, block_len=B)[0])
+    real, seen = eng._window_fn, []
+
+    def window(m, pool, bt, pooled_len, done, emitted, budget, eos, tok,
+               rev, at):
+        if not bool(done[0]) and not bool(rev[0].all()):  # a denoising one
+            blk, step, want, masked, _ = next(log)
+            assert blk * B == int(pooled_len[0])
+            assert (masked == ~np.asarray(rev[0])).all()
+            got = fwd(m, tok, pooled_len, pool.k, pool.v, bt)[0]
+            np.testing.assert_allclose(
+                np.asarray(got), want, rtol=2e-4, atol=2e-4)
+            seen.append((blk, step))
+        return real(m, pool, bt, pooled_len, done, emitted, budget, eos, tok,
+                    rev, at)
+
+    eng._window_fn = window
+    kv = None
+    while eng.has_work:
+        if eng.slot_req[0] is not None:
+            n = int(eng.pooled_len[0])
+            kv = (n, _pool_rows(eng, 0, n))
+        eng.step()
+    seen = len(seen)
+    assert seen == len(reference[i][2]) and next(log, None) is None
+    n, (k_got, v_got) = kv
+    p = len(prompts[i])
+    assert n == (p + NEW - 1) // B * B  # all but the block that ended it
+    seq = np.concatenate([prompts[i], reference[i][0]])[:n].astype(np.int32)
+    _, ks, vs = forward(weights, jnp.asarray(seq), jnp.arange(n),
+                        jnp.asarray(rb.block_mask(n, B)))
+    for got, want in ((k_got, ks), (v_got, vs)):
+        np.testing.assert_allclose(
+            got, np.transpose(np.asarray(want), (0, 2, 1, 3)),
+            rtol=2e-4, atol=2e-4)
+
+
+def test_prefix_cache_reuses_whole_pages_only(model, prompts, reference):
+    """A second request with the same prompt finds its whole pages resident
+    (a page is four blocks); what a partial page would add is not taken: a
+    block's K/V are those of ALL its final tokens."""
+    eng = engine(model, slots=1)
+    first = eng.submit(prompts[4], NEW)
+    eng.run()
+    again = eng.submit(prompts[4], NEW)
+    eng.run()
+    assert eng.finished[again].cached_tokens == 32  # of 35: two pages
+    assert eng.finished[again].tokens == eng.finished[first].tokens == list(
+        reference[4][0])
+
+
+def test_eos_ends_a_request_inside_a_block(model, prompts, reference):
+    toks = list(reference[0][0])
+    eos = toks[6]
+    cut = toks.index(eos) + 1
+    eng = engine(model, slots=1)
+    rid = eng.submit(prompts[0], NEW, eos_id=int(eos))
+    eng.run()
+    assert eng.finished[rid].tokens == toks[:cut]
+    assert len(eng.finished[rid].reveal_steps) == cut
+
+
+def test_what_block_diffusion_does_not_compose_with_is_refused(model):
+    with pytest.raises(ValueError, match="temperature"):
+        engine(model, temperature=0.7)
+    with pytest.raises(ValueError, match="speculate"):
+        engine(model, speculate=2)
+    with pytest.raises(AssertionError, match="whole blocks"):
+        engine(model, prefill_chunk=6)
+
+
+# -- (f): the Pallas verify body under mask M -------------------------------
+
+
+@pytest.mark.parametrize("block_len", (4, 0))
+def test_pallas_verify_body_under_the_block_mask(
+        model, pallas_interpret, block_len):
+    """The interpreted kernel against the XLA gather path, ragged lengths,
+    T = 8 rows a slot (two blocks: causal across, bidirectional inside) —
+    and, with ``block_len=0``, the causal mask both take by default."""
+    from midgpt_tpu.serving import PagedKVPool
+
+    s, ps, t = 4, 16, 8
+    pmax = CFG.block_size // ps
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    pool = PagedKVPool.init(CFG, 2 * pmax, ps, jnp.float32)
+    pool = dataclasses.replace(
+        pool, k=jax.random.normal(ks[0], pool.k.shape),
+        v=jax.random.normal(ks[1], pool.v.shape))
+    bt = jax.random.randint(ks[2], (s, pmax), 0, 2 * pmax).astype(jnp.int32)
+    start = jnp.asarray([0, 12, 32, 100], jnp.int32)
+    cand = jax.random.randint(ks[3], (s, t), 0, 510, jnp.int32)
+    out = {
+        kernel: verify_tokens_paged(
+            model, cand, start, pool.k, pool.v, bt, CFG.block_size,
+            paged_kernel=kernel, block_len=block_len)
+        for kernel in ("pallas", "xla")
+    }
+    for got, want in zip(out["pallas"], out["xla"]):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    causal = verify_tokens_paged(
+        model, cand, start, pool.k, pool.v, bt, CFG.block_size,
+        paged_kernel="xla")[0]
+    same = np.allclose(np.asarray(causal), np.asarray(out["xla"][0]),
+                       rtol=1e-3, atol=1e-3)
+    assert same == (block_len == 0)  # the block mask is another mask
+
+
+# -- (d): the expert layer against the loop over experts --------------------
+
+
+def _layer_weights(weights, layer=0):
+    return {k: np.asarray(weights[k][layer]) for k in ("router", "w13", "w2")}
+
+
+def _reference_experts(h, lw, top_k):
+    lw = {k: jnp.asarray(v) for k, v in lw.items()}
+    return np.asarray(rb._experts(jnp.asarray(h), lw, top_k, True, None))
+
+
+@pytest.mark.parametrize("routing", ("uniform", "skewed"))
+def test_expert_layer_equals_the_loop_over_experts(weights, routing):
+    """Under the seed's routing, and with every row sent to the SAME two
+    experts of 16 (top-2): no row is dropped at any skew, and the sorted
+    grouped matmul gives what computing every expert for every row gives."""
+    lw = _layer_weights(weights)
+    top_k = 4
+    if routing == "skewed":
+        top_k = 2
+        bias = np.zeros((64, 16), np.float32)
+        bias[:, [3, 7]] = 1.0  # |h . 1| dominates: rows pick 3 and 7
+        lw["router"] = lw["router"] * 1e-3 + bias
+    rng = np.random.default_rng(1)
+    h = np.abs(rng.normal(size=(2, 24, 64))).astype(np.float32)
+    mlp = ExpertMLP(
+        router=dataclasses.replace(
+            ExpertMLP.init(jax.random.PRNGKey(0), CFG).router,
+            weight=jnp.asarray(lw["router"])),
+        w_in=jnp.asarray(lw["w13"]), w_out=jnp.asarray(lw["w2"]),
+        top_k=top_k, renorm=True)
+    y, aux, rows = mlp(jnp.asarray(h), return_rows=True)
+    assert int(rows.sum()) == 48 * top_k  # zero dropped
+    if routing == "skewed":
+        assert rows[3] == rows[7] == 48 and int(rows.sum()) == 96
+    want = _reference_experts(h.reshape(-1, 64), lw, top_k).reshape(h.shape)
+    np.testing.assert_allclose(np.asarray(y), want, rtol=2e-5, atol=2e-5)
+    assert np.isfinite(float(aux))
+
+
+def test_expert_layer_reads_the_stack_as_it_lies(weights):
+    """``stacked``: every layer's experts as L x E groups, only this
+    layer's with rows — the same result as the layer's own slice."""
+    h = jnp.asarray(np.random.default_rng(2).normal(size=(5, 64)), jnp.float32)
+    mlp = fill_model(weights, CFG).blocks.mlp  # stacked [L, ...] leaves
+    for layer in range(SIZES["n_layer"]):
+        own = jax.tree.map(lambda a: a[layer], mlp)
+        want = own(h, return_rows=True)
+        got = own(h, return_rows=True,
+                  stacked=(mlp.w_in, mlp.w_out, jnp.int32(layer)))
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                                   rtol=1e-6, atol=1e-6)
+        assert (np.asarray(got[2]) == np.asarray(want[2])).all()
+
+
+def test_grouped_matmul_kernel_equals_ragged_dot():
+    """``ops.grouped``: the Pallas grouped matmul a TPU takes (interpreted
+    here), against ``ragged_dot``, with groups that have no rows — the
+    layer stack read as L x E groups leaves most of them empty."""
+    from midgpt_tpu.ops import grouped
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    xs = jax.random.normal(ks[0], (256, 256), jnp.float32)
+    w = jax.random.normal(ks[1], (12, 256, 384), jnp.float32)
+    sizes = jnp.asarray([0, 0, 0, 0, 0, 0, 100, 0, 28, 1, 0, 127], jnp.int32)
+    assert grouped.tiling(256, 256, 384) == (128, 256, 128)
+    assert grouped.tiling(1024, 2048, 1536) == (128, 2048, 512)
+    assert grouped.tiling(1024, 768, 2048) == (128, 768, 2048)
+    assert grouped.tiling(100, 256, 384) is None
+    got = grouped.grouped_matmul(xs, w, sizes, interpret=True)
+    want = jax.lax.ragged_dot(xs, w, sizes)  # what the CPU takes
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+def test_expert_layer_trains(model):
+    """A ``train()``-shaped call: [B, T, D] through the same body, with
+    gradients into the router and both expert tensors."""
+    tok = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 510)
+    grads = jax.grad(
+        lambda m: jnp.sum(m(tok).astype(jnp.float32) ** 2))(model)
+    for leaf in (grads.blocks.mlp.w_in, grads.blocks.mlp.w_out,
+                 grads.blocks.mlp.router.weight):
+        assert np.isfinite(np.asarray(leaf)).all()
+        assert float(jnp.abs(leaf).sum()) > 0.0
+
+
+def test_block_window_mirrors_the_verify_program_op_for_op():
+    """The choreography prover's verify-equals-decode clause, adapted: a
+    block-diffusion engine has no token-at-a-time window, so its block
+    window is held to the verify program of the same model at T = B."""
+    from midgpt_tpu.analysis.harness import prove_block_choreography
+
+    report = prove_block_choreography(CFG)
+    names = [c.name for c in report.checks]
+    assert "block-window-mirrors-verify" in names
+    assert report.ok, [(c.name, c.detail) for c in report.checks if not c.ok]
+
+
+# -- the pieces ------------------------------------------------------------
+
+
+def test_half_split_rope_is_rotate_half():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 5, 16))
+    ang = jax.random.normal(jax.random.PRNGKey(1), (5, 8))
+    sin, cos = jnp.sin(ang), jnp.cos(ang)
+    want = x * jnp.concatenate((cos, cos), -1) + rotate_half(x) * (
+        jnp.concatenate((sin, sin), -1))
+    np.testing.assert_allclose(
+        np.asarray(apply_rotary(x, sin, cos, "half")), np.asarray(want),
+        rtol=1e-6, atol=1e-6)
+    assert not np.allclose(np.asarray(apply_rotary(x, sin, cos)),
+                           np.asarray(want), atol=1e-3)
+
+
+def test_confidence_and_reveal_rule():
+    logits = jnp.asarray([[[0.0, 2.0, 1.0], [3.0, 0.0, 0.0],
+                           [0.5, 0.5, 0.4], [0.0, 0.0, 9.0]]])
+    pick, conf = sampling.token_confidence(logits)
+    assert pick.tolist() == [[1, 0, 0, 2]]
+    np.testing.assert_allclose(
+        np.asarray(conf),
+        np.asarray(jnp.max(jax.nn.softmax(logits, -1), -1)), rtol=1e-6)
+    masked = jnp.asarray([[True, True, True, False]])
+    # the surest position is revealed already: of the masked, 1 then 0
+    one = sampling.reveal_most_confident(conf, masked, 1)
+    two = sampling.reveal_most_confident(conf, masked, 2)
+    assert one.tolist() == [[False, True, False, False]]
+    assert two.tolist() == [[True, True, False, False]]
+    few = sampling.reveal_most_confident(
+        conf, jnp.asarray([[False, False, True, False]]), 2)
+    assert few.tolist() == [[False, False, True, False]]
+
+
+def test_existing_configs_are_what_they_were():
+    """The new fields' defaults change nothing: no new leaf, the head width
+    D // H, LayerNorm on q and k, weightless block norms."""
+    from midgpt_tpu.models.layers import LayerNorm
+    from midgpt_tpu.pytree import tree_paths
+
+    cfg = ModelConfig(block_size=32, vocab_size=64, n_layer=1, n_head=2,
+                      n_embd=32)
+    m = GPT.init(jax.random.PRNGKey(0), cfg)
+    assert cfg.head_dim == 16 and cfg.block_len == 0
+    assert [p for p, _ in tree_paths(m)] == [
+        "wte/weight", "blocks/attn/wqkv/weight", "blocks/attn/wo/weight",
+        "blocks/attn/q_norm/weight", "blocks/attn/k_norm/weight",
+        "blocks/mlp/w_up/weight", "blocks/mlp/w_down/weight",
+        "lm_head/weight"]
+    assert isinstance(m.blocks.attn.q_norm, LayerNorm)
+    assert m.ln_f.eps == 1e-5 and m.blocks.ln1.eps == 1e-6

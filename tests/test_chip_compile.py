@@ -248,6 +248,80 @@ def test_prefill_chunk_compiles_without_a_pool_copy(one_chip):
     assert not _pool_copies(text), _pool_copies(text)
 
 
+def test_block_window_compiles_without_a_pool_or_an_expert_copy(
+        one_chip, monkeypatch):
+    """The block-diffusion window at the benchmark cell's widths (32 query
+    heads over 4 KV heads of 128, 128 experts of 768, two layers of it, 32
+    slots of 48 pages), compiled: the verify kernel takes the block mask at
+    T = 4; the pool, a carry of the window's scan that every forward reads
+    and every commit writes, is never copied; and no temporary is the size
+    of a layer's expert tensors — sliced out of the layer stack in front of
+    the grouped matmul they were, 6.5 GB of them at six layers (the stack
+    is read as it lies: ``ExpertMLP``'s ``stacked``)."""
+    import re
+
+    from midgpt_tpu.config import ModelConfig
+    from midgpt_tpu.models import GPT
+    from midgpt_tpu.ops import grouped
+    from midgpt_tpu.serving.engine import make_block_window
+    from midgpt_tpu.serving.paged import PagedKVPool
+
+    # compiled for a TPU from a process whose backend is the CPU: the
+    # grouped matmul takes what it takes on the chip
+    monkeypatch.setattr(grouped, "is_tpu_backend", lambda: True)
+    slots, pmax, blk = 32, 48, 4
+    cfg = ModelConfig(
+        block_size=pmax * PS, vocab_size=151936, n_layer=L, n_head=32,
+        n_kv_head=4, head_width=128, n_embd=2048, qk_norm_kind="rms",
+        rope_style="half", rope_base=1e6, norm_scale=True, norm_eps=1e-6,
+        mlp="experts", experts=128, experts_per_token=8, expert_hidden=768,
+        block_len=blk, block_steps=blk, mask_token=151669,
+    )
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree.map(lambda a: arr(a.shape, a.dtype), tree)
+
+    model = described(jax.eval_shape(
+        lambda k: jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a,
+            GPT.init(k, cfg),
+        ), jax.random.PRNGKey(0),
+    ))
+    pool = described(jax.eval_shape(
+        lambda: PagedKVPool.init(cfg, slots * pmax, PS, jnp.bfloat16)
+    ))
+    fn = make_block_window(
+        model, slots=slots, window=5, pmax=pmax, rope_len=cfg.block_size,
+        paged_kernel="pallas",
+    )
+    compiled = fn.lower(
+        model, pool, arr((slots, pmax), jnp.int32), arr((slots,), jnp.int32),
+        arr((slots,), jnp.bool_), arr((slots,), jnp.int32),
+        arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+        arr((slots, blk), jnp.int32), arr((slots, blk), jnp.bool_),
+        arr((slots, blk), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%paged_verify\S* = \S+ custom-call\(", text)) == L
+    # two grouped matmuls a layer, the Pallas kernel (ops/grouped.py)
+    assert len(re.findall(r"%gmm\S* = \S+ custom-call\(", text)) == 2 * L
+    pool_shape = f"bf16[{L},{slots * pmax},{PS},512]"
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(r"= \S+ copy\(", line)
+        and line.split("= ", 1)[1].startswith(pool_shape)
+    ]
+    assert not copies, copies
+    one_layer_of_experts = 128 * 2048 * 768 * 3 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        one_layer_of_experts // 2
+    )
+
+
 def test_flash_attention_compiles_fwd_bwd(one_chip):
     from midgpt_tpu.ops.flash import flash_attention
 
