@@ -27,7 +27,8 @@ One chip, in order — any failed assertion ends the run non-zero:
    heads over 4 KV heads of 128, half-split RoPE and QK-RMSNorm, the
    expert layer behind it (every expert chosen, so that no near-tie of the
    router widens the comparison), bf16, ragged lengths: the Pallas kernel under the
-   block mask against the XLA gather path (``KERNEL_TOL``), and under the
+   block mask (both of its products on the matrix unit: ops/paged_attn.py) against
+   the XLA gather path (``KERNEL_TOL``), and under the
    causal mask it must NOT agree (the mask is really another).
 
 Four chips: ``openwebtext`` on an fsdp=2 x tensor=2 mesh against a

@@ -934,7 +934,6 @@ def prove_block_choreography(
     window: int = 2,
     chunk_len: int = 16,
     page_size: int = 16,
-    paged_kernel: str = "xla",
 ):
     """The choreography suite for a BLOCK-DIFFUSION model
     (``model_cfg.block_len`` > 0), whose engine has no token-at-a-time
@@ -947,8 +946,12 @@ def prove_block_choreography(
     than the mask changed. The prefill chunk is still held to
     ``naive_attention``, and the shared clauses (f32 softmax and
     accumulation, mask before scale, one lm-head choreography, banded
-    order) to all three. Traced at choreography size (2 layers, block 64,
-    vocab 128), no compilation."""
+    order) to all three. Proved of the gather path: the Pallas kernel
+    gives the block forward a contraction of its own (the matrix unit:
+    ops.paged_attn, THE BLOCK FORWARD'S CONTRACTION), which is held to
+    this path at a tolerance by tests, not op for op by a prover. Traced
+    at choreography size (2 layers, block 64, vocab 128), no
+    compilation."""
     import dataclasses as _dc
 
     import jax
@@ -987,15 +990,14 @@ def prove_block_choreography(
     logits = sds((slots, cfg.vocab_size), jnp.float32)
     geometry = dict(pmax=pmax, rope_len=cfg.block_size)
     block_jaxpr = jax.make_jaxpr(make_block_window(
-        model, slots=slots, window=window, paged_kernel=paged_kernel,
-        **geometry,
+        model, slots=slots, window=window, paged_kernel="xla", **geometry,
     ))(
         model, pool, i32(slots, pmax), i32(slots), pred(slots), i32(slots),
         i32(slots), i32(slots), i32(slots, blk), pred(slots, blk),
         i32(slots, blk),
     )
     verify_jaxpr = jax.make_jaxpr(make_verify_program(
-        model, slots=slots, spec_len=blk - 1, paged_kernel=paged_kernel,
+        model, slots=slots, spec_len=blk - 1, paged_kernel="xla",
         **geometry,
     ))(
         model, pool, logits, i32(slots, pmax), i32(slots), pred(slots),

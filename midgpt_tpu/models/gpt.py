@@ -953,6 +953,9 @@ class Attention:
         if paged_kernel == "pallas":
             # the ragged in-kernel block-table walk (ops.paged_attn):
             # bitwise this method's arithmetic, none of its HBM gather
+            # (under the block mask, ``block`` > 1: this method's sums in
+            # another order, on the matrix unit — no decode window exists
+            # for a block-diffusion model's forward to be bitwise with)
             qg = shard_act(qg, None, "kv_heads", None, None, None)
             out = _paged_kernel_dispatch(
                 "verify", layer,

@@ -30,7 +30,8 @@ from shapes alone, and ``supported`` says whether the estimate of what
 a step then holds (``vmem_bytes``: O(band) + the O(W) f32 score rows)
 fits. ``tests/test_chip_compile.py`` compiles both kernels for a
 described v5e at two geometries, both pool precisions and a 100k-token
-table.
+table, and the block forward's contraction (below) at the
+block-diffusion cell's.
 
 BANDS: the page walk is fetched AND computed in ascending PAGE BANDS of
 ``band_pages`` pages — the narrowest divisor of Pmax of at least
@@ -46,7 +47,13 @@ wrote may hold NaN, and NaN passes the additive -inf mask and
 the plan is part of the numerical contract below: the XLA reference
 folds by the same plan (``resolved_band_pages`` is shared).
 
-EXACTNESS CONTRACT (the reason this kernel looks the way it does): the
+EXACTNESS CONTRACT (the reason this kernel looks the way it does). WHOM
+IT BINDS: the decode program and the causal verify program
+(``block`` = 1) — bitwise with the XLA gather path and, op for op, with
+each other, because speculation's acceptance compares the verify
+logits' argmax with what the decode window would have sampled. WHOM IT
+DOES NOT: the block-diffusion forward (``block`` > 1), see THE BLOCK
+FORWARD'S CONTRACTION below. The
 serving suite's landing gate is greedy token-identity against the XLA
 path, and the repo has twice shipped attention variants that drifted by
 ~2 bf16 ulps and flipped near-tied greedy argmaxes on real checkpoints
@@ -79,6 +86,46 @@ f32 softmax. Concretely the kernel makes two passes over the bands:
     their reductions as their compilers choose; chip_smoke.py holds
     them to a stated tolerance there.
 
+THE BLOCK FORWARD'S CONTRACTION (``block`` > 1, PR 29). A
+block-diffusion model's forward is the verify program under another
+mask: T = ``block_len`` rows a slot that all see each other, times G
+query heads a KV head — 32 rows a KV head at the benchmark's cell. It
+has NO DECODE TWIN: such a model has no token-at-a-time window
+(``ServingEngine`` refuses ``speculate`` with ``block_len``), so there
+is nothing for its attention to be bitwise with, and the VPU form —
+``[G, T, C, 1] * [C, BW]`` f32 multiply-sums, 32 x 128 x 128 of them a
+band a head, twice — spent 47 % of that cell's device time at 1.3 % of
+a roofline. Under the block mask both products run on the MATRIX UNIT
+instead: the ``hb`` heads' queries as ``[G*T, C]`` rows against a K band
+``[BW, C]`` (contracted over C in NT form: no band turned over), and
+the f32 probability rows ``[G*T, BW]`` against the V band. The rest is
+the contract above, unchanged in kind: bf16 operands multiply exactly
+in f32 (this is the VPU form's sum in another order, not a lower
+precision; an int8 pool's dequantized band is exact in bf16), scores
+accumulate in f32, the additive mask comes before the in-softmax scale,
+ONE flat f32 softmax over the resident ``[G*T, W + T]`` row (dense
+tiles now, where ``[G, T, 1, W]`` padded its unit sublane 8x), f32
+probabilities INTO the PV product (``Precision.HIGHEST``; rounding them
+to bf16 would be another result), band partials folded in ascending
+order and the rows' own partial added after, one rounding at the end.
+The output leaves as ``[hb, G*T, C]``, whole tiles, so the caller's
+reshape is free. What holds this path is a tolerance, not the bit: the
+gather path at ``rtol=1e-5`` over an f32 pool and within one bf16 ulp
+of the output over a bf16 or int8 pool (tests/test_paged_attn.py,
+tests/test_block_diffusion.py), and ``benchmark/reference_block.py`` at
+the cell's limits on the chip.
+
+WHY THE SWITCH IS THE MASK AND NOT THE ROW COUNT: speculative verify on
+a grouped-query model reaches the same 32 rows (G 8 x ``speculate + 1``
+= 4) and DOES have a decode twin, whose token identity with speculation
+off is a tested contract. Bit-identity with a VPU program and
+throughput at 32 rows cannot both be had from one contraction, so there
+are two behind one walk, and the kernel picks by the one thing in its
+input that says which need it serves: the mask kind (``block``, a
+static argument; ``verify_contraction``). ``decode=True`` and
+``block == 1`` keep the VPU body op for op. No knob, no environment
+variable.
+
 INT8 KV (``scale_k``/``scale_v`` given): the pool payload is int8 with
 one f32 power-of-two scale per (page, KV-head) plane
 (serving.paged — the KV analogue of quant.py's po2 exactness contract).
@@ -90,7 +137,8 @@ int8 pool behaves like a bf16 pool whose values happen to lie on the
 page grid, and the greedy token streams stay invariant across every
 engine feature combination (unit-tested at the page level).
 
-Dtype choreography (machine-checked: analysis.choreo extracts the
+Dtype choreography of the decode and causal-verify body
+(machine-checked: analysis.choreo extracts the
 kernel body's softmax signature and proves it equal to the decode
 window's — a bf16-accumulating edit here turns the serving-choreo CI
 gate red): bf16 Q/K products formed as f32 upcast-multiplies, f32 score
@@ -187,6 +235,14 @@ def banded_fold(parts: tp.Sequence[Array]) -> Array:
     return out
 
 
+def verify_contraction(block: int) -> str:
+    """Which unit the verify kernel's two products run on, by the mask
+    kind alone (``block`` as ``paged_verify_attention`` takes it):
+    ``"mxu"`` under the block-diffusion mask, ``"vpu"`` — the decode
+    window's arithmetic, bit for bit — under the causal one."""
+    return "mxu" if block > 1 else "vpu"
+
+
 def _band_bytes(band_pages_: int, page_size: int, c: int,
                 itemsize: int) -> int:
     """The band plan's sizing rule: K and V band buffers at pool dtype,
@@ -261,7 +317,8 @@ def _page_tile_bytes(page_size: int, width: int, itemsize: int) -> int:
 
 
 def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
-               groups: int = 8, spec_t: int = 1, heads: int = 1) -> int:
+               groups: int = 8, spec_t: int = 1, heads: int = 1,
+               block: int = 1) -> int:
     """Estimated VMEM demand of one grid step (one slot, ``heads`` KV
     heads), in bytes — O(band) in the table length but for the f32
     score rows:
@@ -274,7 +331,14 @@ def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
       ``[G, T, BW, C]`` f32 product the reductions consume;
     - per head, the full-context f32 score and prob rows
       ``[G, T, 1, W]`` — the flat-softmax residency — whose unit
-      sublane dim pads 8x."""
+      sublane dim pads 8x.
+
+    The block forward's contraction (``block`` > 1) forms no such
+    product and pads nothing: its band compute is the band's view and
+    one ``[G*T, BW]`` f32 tile set, and its score rows are dense
+    ``[G*T, W + T]`` f32 — priced three times over, for the masked
+    parts, the joint row they concatenate into and the probabilities
+    are alive together round the softmax."""
     g, t = max(1, groups), max(1, spec_t)
     bp = resolved_band_pages(pmax, page_size, c, itemsize)
     bw, w = bp * page_size, pmax * page_size
@@ -282,13 +346,19 @@ def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
     fetch = 2 * bp * _page_tile_bytes(page_size, heads * c, itemsize)
     if itemsize == 1:
         fetch += bp * _page_tile_bytes(page_size, heads * c, 4)
-    band = bw * lanes * 4 + g * t * bw * lanes * 4
-    scores = heads * 2 * g * t * 8 * w * 4
+    if verify_contraction(block) == "mxu":
+        rows = _ceil_to(g * t, 8)
+        band = bw * lanes * 4 + rows * _ceil_to(bw, 128) * 4
+        scores = heads * 3 * rows * (w + _ceil_to(t, 128)) * 4
+    else:
+        band = bw * lanes * 4 + g * t * bw * lanes * 4
+        scores = heads * 2 * g * t * 8 * w * 4
     return fetch + band + scores
 
 
 def head_block(hkv: int, pmax: int, page_size: int, c: int, itemsize: int,
-               groups: int = 8, spec_t: int = 1) -> tp.Optional[int]:
+               groups: int = 8, spec_t: int = 1,
+               block: int = 1) -> tp.Optional[int]:
     """KV heads one grid step serves — which is also how many heads'
     lanes of a page one DMA moves (a page row carries all heads side by
     side). The LARGEST divisor of ``hkv`` whose lane run is whole
@@ -307,19 +377,21 @@ def head_block(hkv: int, pmax: int, page_size: int, c: int, itemsize: int,
         if hb < hkv and (hb * c) % 128:
             continue  # Mosaic DMAs whole lane tiles or the whole row
         if vmem_bytes(pmax, page_size, c, itemsize, groups=groups,
-                      spec_t=spec_t, heads=hb) <= VMEM_BUDGET:
+                      spec_t=spec_t, heads=hb, block=block) <= VMEM_BUDGET:
             best = hb
     return best
 
 
 def supported(pmax: int, page_size: int, c: int, itemsize: int,
               groups: int = 8, spec_t: int = 1,
-              heads: tp.Optional[int] = None) -> bool:
+              heads: tp.Optional[int] = None, block: int = 1) -> bool:
     """Will the chip's compiler take the kernels at this geometry?
     (``groups`` = query heads per KV head; ``spec_t`` = candidate rows
     per slot in the verify kernel — pass ``speculate + 1`` when
     speculation is on; ``heads`` = the KV heads one device holds, so
-    ``kv_heads / tp``.) Two conditions, both learned from compiling for
+    ``kv_heads / tp``; ``block`` = a block-diffusion model's
+    ``block_len``, whose forward takes the other contraction and is
+    priced as that.) Two conditions, both learned from compiling for
     a described v5e: a band plan exists (else the unrolled trace is
     unbounded), and some ``head_block`` of ``heads`` fits
     ``VMEM_BUDGET`` and ``MAX_UNROLL`` — the very block ``_paged_call``
@@ -331,8 +403,28 @@ def supported(pmax: int, page_size: int, c: int, itemsize: int,
     if heads is None:
         heads = 128 // c if c < 128 and 128 % c == 0 else 1
     return head_block(
-        heads, pmax, page_size, c, itemsize, groups=groups, spec_t=spec_t
+        heads, pmax, page_size, c, itemsize, groups=groups, spec_t=spec_t,
+        block=block,
     ) is not None
+
+
+def _mxu(a: Array, b: Array, dims) -> Array:
+    """One product on the matrix unit, accumulated in f32: the block
+    forward's contraction. Operands narrower than f32 multiply exactly
+    there (a bf16 x bf16 product fits f32), so this is the VPU form's
+    sum in another order and not a lower precision; f32 operands — a
+    test's f32 pool, and always the probabilities — take the
+    full-precision passes."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(
+        a.astype(dt), b.astype(dt), (dims, ((), ())),
+        precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None,
+        preferred_element_type=jnp.float32,
+    )
+
+
+_NT = ((1,), (1,))  # [M, K] x [N, K]: no operand turned over in VMEM
+_NN = ((1,), (0,))  # [M, K] x [K, N]
 
 
 def _attend_kernel(
@@ -346,7 +438,8 @@ def _attend_kernel(
     # inputs q as columns [HB, G, T, C, 1], the K and V rows
     # [HB, R, C] (decode: the window's recent rows; verify: the T
     # candidates' own), the K and V pools whole in HBM; the output
-    # [HB, G, T, 1, C]; scratch:
+    # [HB, G, T, 1, C] (the block forward, ``block`` > 1: q and the
+    # output as rows [HB, G*T, C]); scratch:
     # the fetch buffer [2, BP, PS, HB*C], an int8 pool's dequantized
     # band [BP, PS, HB*C] f32, 2 DMA semaphores.
     nb: int,
@@ -366,7 +459,15 @@ def _attend_kernel(
         deq = refs[7]
     sem = refs[-1]
     _, bp, ps, _ = buf.shape
-    hb, g, t, c, _ = q_ref.shape
+    # THE CONTRACTION follows the mask kind, the one thing in the
+    # operands that says whether the rows have a decode twin (module
+    # docstring): under the block-diffusion mask both products run on
+    # the matrix unit, over all G*T rows of a KV head at once
+    mxu = verify_contraction(block) == "mxu"
+    if mxu:
+        hb, gt, c = q_ref.shape
+    else:
+        hb, g, t, c, _ = q_ref.shape
     rr = rv_ref.shape[1]
     pmax = nb * bp
     bw, w = bp * ps, pmax * ps
@@ -423,17 +524,20 @@ def _attend_kernel(
 
         jax.lax.fori_loop(0, bp, page_scale, 0)
 
-    def band(u: int, hh: int, turned: bool = False):
+    def band(u: int, hh: int, turned: bool = False, dtype=jnp.float32):
         """Unit ``u``'s band for head ``hh`` as the logical f32 stream
         (what the XLA path's gathered view holds in columns
         [b*BW, (b+1)*BW)): the fetched rows [BW, C], or turned over,
         [C, BW] — in the pool's own dtype, before the upcast, so that a
         bf16 band is half the vregs to turn. A transpose moves values
-        and rounds nothing."""
+        and rounds nothing. (The matrix unit takes the band in the
+        ``dtype`` of the rows' own K/V: the pool's, or bf16 over an
+        int8 pool, whose dequantized values — ``|q| <= 127`` times a
+        power of two — bf16 holds exactly.)"""
         lanes = slice(hh * c, (hh + 1) * c)
         x = deq[:, :, lanes] if quant else buf[u % 2, :, :, lanes]
         x = x.reshape(bw, c)
-        return (x.T if turned else x).astype(jnp.float32)
+        return (x.T if turned else x).astype(dtype)
 
     # The score row keeps TIME ON THE LANE AXIS — a full-context row is
     # W/128 vregs a (g, t), where a column (what a page's own rows-by-C
@@ -445,6 +549,36 @@ def _attend_kernel(
     # well, and summing over lanes as the XLA path does, measured half
     # again as slow a kernel).
     qs = [q_ref[hh] for hh in range(hb)]  # [G, T, C, 1]
+
+    def scores_mxu(u: int):
+        """PASS 1 on the matrix unit: a head's G*T query rows against
+        the band's K rows, contracted over C — [G*T, BW] f32, a dense
+        tile set where the VPU form's [G, T, 1, BW] rows pad their unit
+        sublane 8x."""
+        if quant:
+            dequant(u)
+        idx = jax.lax.broadcasted_iota(jnp.int32, (1, bw), 1) + u * bw
+        mask_b = jnp.where(idx < len_ref[i], 0.0, -jnp.inf).astype(
+            jnp.float32
+        )
+        return [
+            _mxu(qs[hh], band(u, hh, dtype=rk_ref.dtype), _NT) + mask_b
+            for hh in range(hb)
+        ]
+
+    def pv_mxu(u: int):
+        """PASS 2 on the matrix unit: the band's slice of the f32
+        probability rows against its V rows, [G*T, C] f32. The
+        probabilities go in as f32 — rounding them to bf16 would be
+        another result — and V as the pool has it."""
+        if quant:
+            dequant(u)
+        lo = (u - nb) * bw
+        return [
+            _mxu(probs[hh][:, lo:lo + bw], band(u, hh, dtype=rv_ref.dtype),
+                 _NN)
+            for hh in range(hb)
+        ]
 
     def scores(u: int):
         """PASS 1 (K): a band's scores are per-position sums over C, so
@@ -487,9 +621,14 @@ def _attend_kernel(
     # are the mask's -inf and its PV partial the zero that a zero
     # probability leaves — exactly what the XLA path computes there, so
     # the skip moves no bit (the fold below still adds the zero).
+    if mxu:
+        scores, pv = scores_mxu, pv_mxu
+        s_dead, o_dead = (gt, bw), (gt, c)
+    else:
+        s_dead, o_dead = (g, t, 1, bw), (g, t, 1, c)
     dead = (
-        lambda: hb * [jnp.full((g, t, 1, bw), -jnp.inf, jnp.float32)],
-        lambda: hb * [jnp.zeros((g, t, 1, c), jnp.float32)],
+        lambda: hb * [jnp.full(s_dead, -jnp.inf, jnp.float32)],
+        lambda: hb * [jnp.zeros(o_dead, jnp.float32)],
     )
     parts = [[] for _ in range(hb)]
     opars = [[] for _ in range(hb)]
@@ -505,18 +644,29 @@ def _attend_kernel(
         )
         for hh in range(hb):
             (parts if u < nb else opars)[hh].append(out[hh])
-        if u == nb - 1:
+        if u == nb - 1 and mxu:
+            # the same ONE flat f32 softmax, over [G*T, W + T] rows:
+            # row i of a head's block is candidate i mod T of its slot
+            rows = jax.lax.rem(
+                jax.lax.broadcasted_iota(jnp.int32, (gt, rr), 0), rr
+            )
+            cols = jax.lax.broadcasted_iota(jnp.int32, (gt, rr), 1)
+            mask_rows = jnp.where(
+                cols // block <= rows // block, 0.0, -jnp.inf
+            ).astype(jnp.float32)
+            for hh in range(hb):
+                s_rows = _mxu(qs[hh], rk_ref[hh], _NT)  # [G*T, R]
+                s_all = jnp.concatenate(
+                    parts[hh] + [s_rows + mask_rows], axis=-1
+                )
+                probs.append(jax.nn.softmax(s_all / math.sqrt(c), axis=-1))
+        elif u == nb - 1:
             # the masked parts concatenate into the ONE full-context
             # f32 score row (the flat-softmax contract — no online
             # rescaling); the rows' own scores close it
             rows = jax.lax.broadcasted_iota(jnp.int32, (1, t, 1, rr), 1)
             cols = jax.lax.broadcasted_iota(jnp.int32, (1, t, 1, rr), 3)
-            if block > 1:
-                # block diffusion: a row sees every row of its own block
-                # of ``block`` rows and of the blocks before it
-                seen = cols // block <= rows // block
-            else:
-                seen = cols <= (r_ref[0] if decode else rows)
+            seen = cols <= (r_ref[0] if decode else rows)
             mask_rows = jnp.where(seen, 0.0, -jnp.inf).astype(jnp.float32)
             for hh in range(hb):
                 s_rows = jnp.sum(
@@ -534,11 +684,14 @@ def _attend_kernel(
         # the one place banding touches f32 summation order, matched
         # bitwise by the XLA reference's banded_fold
         o_pool = banded_fold(opars[hh])
-        p_rows = jnp.swapaxes(probs[hh][..., w:], -1, -2)  # [G, T, R, 1]
-        o_rows = jnp.sum(
-            p_rows * rv_ref[hh][None, None].astype(jnp.float32),
-            axis=-2, keepdims=True,
-        )
+        if mxu:
+            o_rows = _mxu(probs[hh][:, w:], rv_ref[hh], _NN)
+        else:
+            p_rows = jnp.swapaxes(probs[hh][..., w:], -1, -2)  # [G,T,R,1]
+            o_rows = jnp.sum(
+                p_rows * rv_ref[hh][None, None].astype(jnp.float32),
+                axis=-2, keepdims=True,
+            )
         out_ref[hh] = (o_pool + o_rows).astype(out_ref.dtype)
 
 
@@ -562,7 +715,9 @@ def _paged_call(
     bp = resolved_band_pages(pmax, ps, c, itemsize)
     # where ``supported(..., heads=hkv)`` says no (a test's geometry,
     # interpreted) a step moves whole rows
-    hb = head_block(hkv, pmax, ps, c, itemsize, groups=g, spec_t=t) or hkv
+    hb = head_block(
+        hkv, pmax, ps, c, itemsize, groups=g, spec_t=t, block=block
+    ) or hkv
     pallas_call = pl.pallas_call
     if interpret:
         pallas_call = functools.partial(pallas_call, interpret=True)
@@ -580,8 +735,13 @@ def _paged_call(
     # rows' K is turned over in the kernel: turned in XLA, the window's
     # recent buffer takes the turned layout and every step's row write
     # into it pays for that.)
-    q_col = q[..., None]
-    out_shape = (s, hkv, g, t, 1, c)
+    # The block forward (``block`` > 1) hands the matrix unit a KV
+    # head's G*T rows as they lie, and takes its output the same way —
+    # whole (sublane, 128) tiles, so both reshapes are free and nothing
+    # re-lays the output behind the call.
+    mxu = verify_contraction(block) == "mxu"
+    q_in = q.reshape(s, hkv, g * t, c) if mxu else q[..., None]
+    out_shape = q_in.shape if mxu else (s, hkv, g, t, 1, c)
     scratch = [pltpu.VMEM((2, bp, ps, hb * c), pool_k.dtype)]
     if quant:
         scratch.append(pltpu.VMEM((bp, ps, hb * c), jnp.float32))
@@ -594,7 +754,7 @@ def _paged_call(
             num_scalar_prefetch=len(scalars),
             grid=(s, hkv // hb),
             in_specs=[
-                head_spec(q_col.shape), head_spec(rows_k.shape),
+                head_spec(q_in.shape), head_spec(rows_k.shape),
                 head_spec(rows_v.shape),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -607,8 +767,8 @@ def _paged_call(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT,
         ),
-    )(*scalars, q_col, rows_k, rows_v, pool_k, pool_v)
-    return out[..., 0, :]
+    )(*scalars, q_in, rows_k, rows_v, pool_k, pool_v)
+    return out.reshape(q.shape) if mxu else out[..., 0, :]
 
 
 def _jitted(name: str):
@@ -693,7 +853,10 @@ def paged_verify_attention(
     bidirectional inside one: the block-diffusion forward), one joint
     softmax, decode choreography — the kernel twin of
     ``Attention.verify_paged_at``, the same kernel body and page walk as
-    :func:`paged_decode_attention`."""
+    :func:`paged_decode_attention`. Under the block mask the two
+    products run on the matrix unit (``verify_contraction``): the same
+    sums in another order, held to the gather path at a tolerance and
+    not to the bit — the module docstring says why."""
     return _VERIFY_CALL(
         _scalars(bt, start, layer), q, kc, vc, pool_k, pool_v, scale_k,
         scale_v, decode=False, interpret=interpret, block=block,

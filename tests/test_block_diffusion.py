@@ -250,6 +250,74 @@ def test_pallas_verify_body_under_the_block_mask(
     assert same == (block_len == 0)  # the block mask is another mask
 
 
+@pytest.mark.parametrize("pool_kind", ("bf16", "int8"))
+def test_pallas_block_forward_at_the_cells_head_geometry(
+        pallas_interpret, pool_kind):
+    """The whole forward at the benchmark cell's head geometry in small (8
+    query heads a KV head, heads of 128, both KV heads in one grid step),
+    a bf16 model over a bf16 and over an int8 pool, T = 8 rows (two blocks)
+    on ragged starts that include 0 and a full table: the kernel's
+    contraction on the matrix unit against the gather path's VPU sums.
+    Both multiply bf16 exactly and sum in f32, in another order: the
+    attention output differs by a bf16 ulp here and there, the logits by
+    what a layer makes of that (the kernel alone is held to the ulp in
+    ``tests/test_paged_attn.py``)."""
+    from midgpt_tpu.pytree import cast_floating
+    from midgpt_tpu.serving import PagedKVPool
+
+    cfg = dataclasses.replace(
+        CFG, n_layer=1, n_head=16, n_kv_head=2, head_width=128,
+        block_size=128, mlp="gelu", vocab_size=128,
+    )
+    model = cast_floating(GPT.init(jax.random.PRNGKey(2), cfg), jnp.bfloat16)
+    s, ps, t = 3, 16, 8
+    pmax = cfg.block_size // ps
+    ks = jax.random.split(jax.random.PRNGKey(6), 6)
+    quant = "int8" if pool_kind == "int8" else None
+    pool = PagedKVPool.init(cfg, 2 * pmax, ps, jnp.bfloat16, kv_quant=quant)
+    if quant:
+        pool = dataclasses.replace(
+            pool,
+            k=jax.random.randint(ks[0], pool.k.shape, -127, 128).astype(
+                jnp.int8),
+            v=jax.random.randint(ks[1], pool.v.shape, -127, 128).astype(
+                jnp.int8),
+            scale_k=jnp.exp2(jax.random.randint(
+                ks[2], pool.scale_k.shape, -8, -5).astype(jnp.float32)),
+            scale_v=jnp.exp2(jax.random.randint(
+                ks[3], pool.scale_v.shape, -8, -5).astype(jnp.float32)),
+        )
+    else:
+        pool = dataclasses.replace(
+            pool, k=jax.random.normal(ks[0], pool.k.shape, jnp.bfloat16),
+            v=jax.random.normal(ks[1], pool.v.shape, jnp.bfloat16))
+    bt = jax.random.randint(ks[4], (s, pmax), 0, 2 * pmax).astype(jnp.int32)
+    start = jnp.asarray([0, 12, pmax * ps - t], jnp.int32)
+    cand = jax.random.randint(ks[5], (s, t), 0, 126, jnp.int32)
+    out = {
+        kernel: np.asarray(verify_tokens_paged(
+            model, cand, start, pool.k, pool.v, bt, cfg.block_size,
+            pool_sk=pool.scale_k, pool_sv=pool.scale_v,
+            paged_kernel=kernel, block_len=B)[0], np.float32)
+        for kernel in ("pallas", "xla")
+    }
+    assert np.isfinite(out["pallas"]).all()
+    err = np.abs(out["pallas"] - out["xla"]).max() / np.abs(out["xla"]).max()
+    assert err < 2e-2, err  # chip_smoke.py's KERNEL_TOL
+
+
+def test_the_engine_names_the_contraction_its_window_was_built_with(model):
+    """The choice is static — the mask kind — so what says that it engaged
+    is what the engine resolved beside ``paged_kernel``."""
+    assert engine(model).verify_contraction is None  # the gather path
+    assert engine(model, paged_kernel="pallas").verify_contraction == "mxu"
+    plain = GPT.init(jax.random.PRNGKey(0), dataclasses.replace(
+        CFG, block_len=0, block_steps=0, mask_token=-1))
+    spec = ServingEngine(plain, slots=2, page_size=16, speculate=3,
+                         cache_dtype=jnp.float32, paged_kernel="pallas")
+    assert spec.verify_contraction == "vpu"  # a decode twin to stay bitwise with
+
+
 # -- (d): the expert layer against the loop over experts --------------------
 
 

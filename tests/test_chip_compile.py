@@ -65,14 +65,14 @@ def _scalar(out):
 GEOMETRIES = {"owt": (H, C), "xl": (16, 128)}
 
 
-def _paged_shapes(heads, c, pool_dt, pmax, q_shape, rows):
+def _paged_shapes(heads, c, pool_dt, pmax, q_shape, rows, slots=S):
     quant = pool_dt == jnp.int8
     pool = ((L, NP, PS, heads * c), pool_dt)  # a page row: all heads' C
     return quant, pool, [
         (q_shape, jnp.bfloat16),
-        ((S, heads, rows, c), jnp.bfloat16),    # K rows
-        ((S, heads, rows, c), jnp.bfloat16),    # V rows
-    ], 2 * quant * [((S, pmax, heads), jnp.float32)]
+        ((slots, heads, rows, c), jnp.bfloat16),    # K rows
+        ((slots, heads, rows, c), jnp.bfloat16),    # V rows
+    ], 2 * quant * [((slots, pmax, heads), jnp.float32)]
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
@@ -150,6 +150,65 @@ def test_paged_decode_kernel_compiles_100k_token_table(one_chip):
         return paged_decode_attention(q, pk, pv, bt, ln, rk, rv, r, 1)
 
     _compiles_to_kernel(fn, one_chip, *shapes)
+
+
+# the block-diffusion cell (serve-sdar-block4): 32 slots of 48 pages, 32
+# query heads over 4 KV heads of 128, blocks of 4
+BLOCK_CELL = dict(slots=32, hkv=4, g=8, t=4, c=128, pmax=48)
+
+
+def _block_kernel_compiles(one_chip, pool_dt, *, slots, hkv, g, t, c, pmax):
+    from midgpt_tpu.ops.paged_attn import paged_verify_attention
+
+    _, pool, rows, scales = _paged_shapes(
+        hkv, c, pool_dt, pmax, (slots, hkv, g, t, c), t, slots=slots
+    )
+    shapes = rows + [
+        pool, pool, ((slots, pmax), jnp.int32), ((slots,), jnp.int32),
+    ] + scales
+
+    def fn(q, kc, vc, pk, pv, bt, start, *scales):
+        return paged_verify_attention(
+            q, kc, vc, pk, pv, bt, start, 1, *scales, block=t
+        )
+
+    return _compiles_to_kernel(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_block_kernel_compiles(one_chip, pool):
+    """The verify kernel under the block mask — both products on the
+    matrix unit, the f32 probabilities at full precision — at the
+    benchmark cell's geometry: all four KV heads a grid step."""
+    from midgpt_tpu.ops.paged_attn import head_block, supported
+
+    pool_dt = jnp.int8 if pool == "int8" else jnp.bfloat16
+    geo = BLOCK_CELL
+    gate = dict(groups=geo["g"], spec_t=geo["t"], block=geo["t"])
+    itemsize = jnp.dtype(pool_dt).itemsize
+    assert supported(geo["pmax"], PS, geo["c"], itemsize, heads=geo["hkv"],
+                     **gate)
+    assert head_block(geo["hkv"], geo["pmax"], PS, geo["c"], itemsize,
+                      **gate) == geo["hkv"]
+    _block_kernel_compiles(one_chip, pool_dt, **geo)
+
+
+def test_paged_block_kernel_100k_token_table(one_chip):
+    """A 100k-token block table under the block mask: never a kernel the
+    gate admits and Mosaic refuses. At the cell's heads of 128 no band
+    plan fits (as for decode) and ``auto`` takes the gather path; at
+    heads of 64 the gate admits the dense ``[G*T, W + T]`` score rows
+    (two heads a grid step, 50 bands) — and this is the compile that
+    says it is right to."""
+    from midgpt_tpu.ops.paged_attn import supported
+
+    pmax = 6250
+    assert not supported(pmax, PS, 128, 2, groups=8, spec_t=4, heads=4,
+                         block=4)
+    assert supported(pmax, PS, C, 2, groups=1, spec_t=4, heads=H, block=4)
+    _block_kernel_compiles(
+        one_chip, jnp.bfloat16, slots=S, hkv=H, g=1, t=4, c=C, pmax=pmax
+    )
 
 
 def _serving_cell(one_chip):
@@ -253,7 +312,8 @@ def test_block_window_compiles_without_a_pool_or_an_expert_copy(
     """The block-diffusion window at the benchmark cell's widths (32 query
     heads over 4 KV heads of 128, 128 experts of 768, two layers of it, 32
     slots of 48 pages), compiled: the verify kernel takes the block mask at
-    T = 4; the pool, a carry of the window's scan that every forward reads
+    T = 4 and nothing re-lays its output inside the call; the pool, a carry
+    of the window's scan that every forward reads
     and every commit writes, is never copied; and no temporary is the size
     of a layer's expert tensors — sliced out of the layer stack in front of
     the grouped matmul they were, 6.5 GB of them at six layers (the stack
@@ -307,6 +367,15 @@ def test_block_window_compiles_without_a_pool_or_an_expert_copy(
     ).compile()
     text = compiled.as_text()
     assert len(re.findall(r"%paged_verify\S* = \S+ custom-call\(", text)) == L
+    # the kernel writes a KV head's G*T rows as whole tiles: nothing is
+    # re-laid inside the jitted call (the 6-d ``[S, Hkv, G, T, 1, C]``
+    # output was, 1.2 ms a forward: PERF.md section 6, PR 29); what reads
+    # the output is the attention's own transpose in front of ``wo``
+    assert not [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(r"= \S+ copy\(", line) and "paged_verify" in line
+    ]
+    assert f"bf16[{slots},4,8,{blk},1,128]" not in text
     # two grouped matmuls a layer, the Pallas kernel (ops/grouped.py)
     assert len(re.findall(r"%gmm\S* = \S+ custom-call\(", text)) == 2 * L
     pool_shape = f"bf16[{L},{slots * pmax},{PS},512]"

@@ -727,6 +727,44 @@ def test_kernel_gate_accepts_100k_token_pmax():
     assert not supported(6247, 16, 64, 2, groups=12)
 
 
+def test_kernel_gate_prices_the_block_contraction():
+    """``block`` > 1 (a block-diffusion model's ``block_len``) is priced
+    as the contraction it takes: dense ``[G*T, W + T]`` f32 score rows,
+    three sets of them, and a ``[G*T, BW]`` band tile set — no
+    ``[G, T, BW, C]`` product, no unit sublane padded 8x. Pinned at the
+    benchmark's block-diffusion cell (32 slots of 48 pages, 8 query
+    heads over T = 4 a KV head, 4 KV heads of 128); the compiles that
+    say the gate is right are tests/test_chip_compile.py's. Without
+    ``block`` every answer is the one given before."""
+    from midgpt_tpu.ops.paged_attn import (
+        VMEM_BUDGET, head_block, supported, vmem_bytes, verify_contraction,
+    )
+
+    assert [verify_contraction(b) for b in (0, 1, 2, 4)] == [
+        "vpu", "vpu", "mxu", "mxu"]
+    cell = dict(groups=8, spec_t=4)
+    # two bands of 8 pages of [16, 4*128] bf16; the band's view and one
+    # [32, 128] f32 tile set; 4 heads x 3 x 32 rows of 768 + 128 lanes
+    fetch = 2 * 8 * (16 * 512 * 2)
+    band = 128 * 128 * 4 + 32 * 128 * 4
+    scores = 4 * 3 * 32 * (768 + 128) * 4
+    assert vmem_bytes(48, 16, 128, 2, heads=4, block=4, **cell) \
+        == fetch + band + scores == 1_720_320
+    # the VPU form at the same geometry: the 8x-padded rows, and the
+    # [G, T, BW, C] product
+    assert vmem_bytes(48, 16, 128, 2, heads=4, **cell) == 8_716_288
+    assert head_block(4, 48, 16, 128, 2, block=4, **cell) == 4
+    assert supported(48, 16, 128, 2, heads=4, block=4, **cell)
+    # a 65k-token table of heads of 128 (64 bands of 64 pages): 32
+    # padded VPU rows of it overflow, the dense rows fit two heads a step
+    assert not supported(4096, 16, 128, 2, heads=4, **cell)
+    assert head_block(4, 4096, 16, 128, 2, block=4, **cell) == 2
+    assert vmem_bytes(4096, 16, 128, 2, heads=2, block=4, **cell) \
+        < VMEM_BUDGET
+    # at 100k tokens heads of 128 have no band plan under either
+    assert not supported(6250, 16, 128, 2, heads=4, block=4, **cell)
+
+
 def test_auto_kernel_follows_the_gate_on_tpu(monkeypatch):
     """``auto`` resolves from the platform AND the geometry gate: with
     the backend forced to TPU a 64-token table takes the kernel, and a
@@ -861,6 +899,138 @@ def test_live_page_walk_never_reads_unowned_pages():
     want = _decode_logits(cfg, model, zeroed, bt, pooled_len, tokens, "xla")
     assert np.isfinite(got).all()
     np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# the block forward's contraction (``block`` > 1: both products on the
+# matrix unit). It has no decode twin and is NOT bitwise the gather path:
+# it is held to the gather path's arithmetic at a stated tolerance
+# ---------------------------------------------------------------------------
+
+
+def _gather_reference(q, kc, vc, pool_k, pool_v, bt, start, layer, block,
+                      scale_k=None, scale_v=None):
+    """The gather path's attention (``Attention.verify_paged_at``, XLA
+    branch) in plain float32: every page of every table gathered, one
+    joint softmax of the masked pool scores and the rows' own, mask
+    before the scale, f32 probabilities through PV. Pages past a slot's
+    length are selected away, not multiplied by zero: the pool of
+    these tests holds NaN there."""
+    s, hkv, g, t, c = q.shape
+    w = bt.shape[1] * pool_k.shape[2]
+    hi = jax.lax.Precision.HIGHEST
+
+    def view(pool, scale):
+        x = pool[layer][bt].astype(jnp.float32)   # [S, Pmax, PS, Hkv*C]
+        x = x.reshape(s, bt.shape[1], -1, hkv, c)
+        if scale is not None:
+            x = x * scale[layer][bt][:, :, None, :, None]
+        live = (jnp.arange(w) < start[:, None])[:, :, None, None]
+        return jnp.where(live, x.reshape(s, w, hkv, c), 0.0)
+
+    qf = q.astype(jnp.float32)
+    s_pool = jnp.einsum(
+        "shgtc,swhc->shgtw", qf, view(pool_k, scale_k), precision=hi
+    ) + jnp.where(jnp.arange(w) < start[:, None], 0.0, -jnp.inf)[
+        :, None, None, None, :]
+    ii = jnp.arange(t) // block
+    s_self = jnp.einsum(
+        "shgtc,shrc->shgtr", qf, kc.astype(jnp.float32), precision=hi
+    ) + jnp.where(ii[None, :] <= ii[:, None], 0.0, -jnp.inf)
+    probs = jax.nn.softmax(
+        jnp.concatenate([s_pool, s_self], -1) / np.sqrt(c), axis=-1
+    )
+    return jnp.einsum(
+        "shgtw,swhc->shgtc", probs[..., :w], view(pool_v, scale_v),
+        precision=hi,
+    ) + jnp.einsum(
+        "shgtr,shrc->shgtc", probs[..., w:], vc.astype(jnp.float32),
+        precision=hi,
+    )
+
+
+@pytest.mark.parametrize("t", [4, 8], ids=["one-block", "two-blocks"])
+@pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
+def test_block_contraction_vs_gather_reference(pool, t):
+    """The interpreted kernel under the block mask at the benchmark
+    cell's head geometry in small — 8 query heads a KV head over T = 4
+    rows (and 8: two blocks, causal across, bidirectional inside), heads
+    of 128, both KV heads in one grid step, two bands of 128 rows — on
+    ragged starts: 0, inside the first band, on the band's edge, inside
+    the second, and one that fills the table. Every page past a slot's
+    length is NaN: a dead band computed on, or a buffer row left as
+    found, would show. An f32 pool agrees to ``rtol=1e-5``; a bf16 or
+    int8 pool's output, rounded to bf16 once at the end, within one bf16
+    ulp of the reference's (and nearly all of it to the bit)."""
+    from midgpt_tpu.ops.paged_attn import (
+        band_pages, head_block, paged_verify_attention, verify_contraction,
+    )
+
+    s, hkv, g, c, ps, pmax, layers, blk = 5, 2, 8, 128, 16, 16, 2, 4
+    dt = jnp.float32 if pool == "f32" else jnp.bfloat16
+    pool_dt = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}[pool]
+    itemsize = jnp.dtype(pool_dt).itemsize
+    assert verify_contraction(blk) == "mxu"
+    assert band_pages(pmax, ps, c, itemsize) == 8  # two bands of 128 rows
+    assert head_block(
+        hkv, pmax, ps, c, itemsize, groups=g, spec_t=t, block=blk
+    ) == hkv
+    start = jnp.asarray([0, 12, 128, 200, pmax * ps], jnp.int32)
+    live = -(-np.asarray(start) // ps)
+    npool = int(live.sum()) + 1  # the last page is nobody's: NaN
+    bt = np.full((s, pmax), npool - 1, np.int32)
+    nxt = 0
+    for i, n in enumerate(live):
+        bt[i, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    bt = jnp.asarray(bt)
+    ks = jax.random.split(jax.random.PRNGKey(17), 7)
+    shape = (layers, npool, ps, hkv * c)
+    scale_k = scale_v = None
+    if pool == "int8":
+        pk, pv = (
+            jax.random.randint(k_, shape, -127, 128, jnp.int32).astype(
+                jnp.int8) for k_ in ks[:2]
+        )
+        scale_k, scale_v = (
+            jnp.exp2(jax.random.randint(
+                k_, (layers, npool, hkv), -8, -2).astype(jnp.float32))
+            for k_ in ks[2:4]
+        )
+    else:
+        owned = (jnp.arange(npool) < npool - 1)[None, :, None, None]
+        pk, pv = (
+            jnp.where(owned, jax.random.normal(k_, shape), jnp.nan).astype(
+                pool_dt) for k_ in ks[:2]
+        )
+    q = jax.random.normal(ks[4], (s, hkv, g, t, c)).astype(dt)
+    kc = jax.random.normal(ks[5], (s, hkv, t, c)).astype(dt)
+    vc = jax.random.normal(ks[6], (s, hkv, t, c)).astype(dt)
+    gathered = tuple(
+        None if sc is None else jnp.take(sc[1], bt, axis=0)
+        for sc in (scale_k, scale_v)
+    )
+    got = paged_verify_attention(
+        q, kc, vc, pk, pv, bt, start, 1, *gathered, block=blk
+    )
+    assert got.shape == q.shape and got.dtype == dt
+    want = _gather_reference(
+        q, kc, vc, pk, pv, bt, start, 1, blk, scale_k, scale_v
+    )
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    if pool == "f32":
+        np.testing.assert_allclose(got, np.asarray(want), rtol=1e-5,
+                                   atol=1e-5)
+        return
+    want = np.asarray(want.astype(jnp.bfloat16), np.float32)
+    # (an element that cancels to ~1e-6 keeps the f32 sums' own error,
+    # which is not small against ITS ulp: the absolute floor is theirs)
+    ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+    assert (np.abs(got - want) <= ulp + 1e-6).all(), (
+        np.abs(got - want).max()
+    )
+    assert (got == want).mean() > 0.9  # and most of it to the bit
 
 
 # ---------------------------------------------------------------------------
