@@ -17,8 +17,6 @@ Auxiliary rungs:
   "measured ceilings"), tracked across rounds.
 - llama_*: llama_7b-family per-layer shape (D=4096, H=32/Hkv=8 GQA,
   SwiGLU, C=128, T=2048), depth-scaled to one chip (r3).
-- decode_*: serving — prefill + KV-cached decode tok/s (r3; skipped if
-  the training rungs consumed most of the driver budget).
 """
 
 from __future__ import annotations
@@ -528,34 +526,11 @@ def main() -> None:
             lcfg = lstate = lchain = None
             gc.collect()
 
-    # --- auxiliary rung: serving (prefill + KV-cached decode) ------------
-    # skipped when the training rungs already consumed most of the driver
-    # budget
-    if time.perf_counter() - t_start < 300:
-        try:
-            from scripts.bench_decode import measure_decode
-
-            record.update(measure_decode())
-            # decode roofline attainment: the recorded HBM floor over
-            # the measured per-token latency (1.0 = bandwidth-bound
-            # perfection; decode_vs_floor is the same ratio inverted)
-            if record.get("decode_ms_per_tok") and record.get(
-                "decode_hbm_floor_ms"
-            ):
-                record["decode_attainment_frac"] = round(
-                    record["decode_hbm_floor_ms"]
-                    / record["decode_ms_per_tok"], 4,
-                )
-        except Exception as exc:  # noqa: BLE001 — aux rung is best-effort
-            exc.__traceback__ = None
-            record["decode_error"] = repr(exc)[:120]
-            gc.collect()
-
     # --- auxiliary rung: long context (T=4096/8192, 124M family) ---------
     # flash + chunked loss at T >> the kernels' 1024 block cap: exercises
     # the multi-block backward path and the O(T) activation story that
     # ring attention + chunked xent exist for (VERDICT r4 Next #5). The
-    # 8192 attempt is budget-gated like decode.
+    # 8192 attempt is budget-gated.
     for lc_t, lc_batch, lc_remat in (
         (4096, 4 * n_dev, "none"),
         (4096, 2 * n_dev, "none"),

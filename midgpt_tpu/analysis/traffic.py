@@ -27,15 +27,14 @@ Two layers, both jax-free:
   it moved into the executable, which is exactly the PR 6 bug.
 - **Roofline floor** (:func:`floor_decomposition`): the analytic
   bytes-per-step decomposition (weights + live KV + logits) and its
-  ms floor at a given HBM bandwidth — the same arithmetic
-  ``scripts/bench_decode.py`` records as ``decode_hbm_floor_ms``, so
+  ms floor at a given HBM bandwidth (``count_params * 2`` bytes of bf16
+  weights + ``L * S * Hkv * live * C * 2 * 2`` bytes of K and V), so
   PERF.md's floor table is generated, not hand-computed.
 
 Accounting note (found by writing this auditor): PERF.md's r5 prose
 stated the 124M B=8 KV stream as ~0.12 ms, which counts the K and V
 planes as ONE stream; both are read every step (K for scores, V for
-the value sum — exactly as scripts/bench_decode.py's recorded floor
-computes), so the decomposition below reports ~0.24 ms at the same
+the value sum), so the decomposition below reports ~0.24 ms at the same
 geometry and the regenerated PERF table carries the corrected total.
 """
 
@@ -198,8 +197,7 @@ def weight_stream_bytes(cfg, *, quant: bool = False) -> int:
     projections and the lm head ([D, V] — counted once; the embedding
     side of a tied/init-tied pair is a B-row GATHER, not a stream),
     plus the small norm vectors. Matches ``count_params(model) * 2``
-    (scripts/bench_decode.py's floor numerator) to within the norm
-    vectors at bf16, and prices the int8 path as s8 matrices + f32
+    to within the norm vectors at bf16, and prices the int8 path as s8 matrices + f32
     per-output-channel scales (midgpt_tpu.quant)."""
     assert cfg.mlp in ("gelu", "swiglu"), (
         f"analytic weight stream covers dense MLPs, got {cfg.mlp!r}"
@@ -235,8 +233,7 @@ def kv_stream_bytes(
     cfg, *, slots: int, live_tokens: float, cache_bytes: int = 2
 ) -> int:
     """Bytes of KV cache ONE decode step streams: every slot's live
-    context, K for the scores and V for the value sum, all layers —
-    the same arithmetic as scripts/bench_decode.py's recorded floor."""
+    context, K for the scores and V for the value sum, all layers."""
     return int(
         cfg.n_layer * slots * cfg.kv_heads * live_tokens * cfg.head_dim
         * cache_bytes * 2  # K and V are both read

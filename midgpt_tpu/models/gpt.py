@@ -151,6 +151,103 @@ def _gathered_pool_scales(scale_x, bt, layer):
     return jnp.take(scale_x[layer], bt, axis=0, mode="clip")
 
 
+def _rows_on_pool_grid(k, v, start, bt, pool_sk, pool_sv, layer, ps):
+    """Round a dispatch's own K/V rows ``[S, Hkv, T, C]`` (row j of slot s
+    at position ``start[s] + j``) through their target pages' int8 grids,
+    so that every reader of a position — in-dispatch or from the pool
+    after the write — sees one value (the kv-quant scheduling invariance)."""
+    from midgpt_tpu.quant import round_kv_rows_to_grid
+    from midgpt_tpu.serving.paged import kv_row_scales
+
+    sk_all, sv_all = kv_row_scales(
+        k, v, start, bt, pool_sk[layer], pool_sv[layer], ps
+    )  # [S, Hkv, T]
+    return round_kv_rows_to_grid(k, sk_all), round_kv_rows_to_grid(v, sv_all)
+
+
+def _gather_attend(
+    qg,  # [S, Hkv, G, T, C] query rows, grouped by KV head
+    own_k,  # [S, Hkv, R, C] the dispatch's own rows, not yet in the pool:
+    own_v,  # verify's T candidates, or decode's recent buffer (T = 1)
+    mask_pool,  # additive f32, broadcastable to [S, Hkv, G, T, W]
+    mask_own,  # additive f32, broadcastable to [S, Hkv, G, T, R]
+    pool_k, pool_v, pool_sk, pool_sv, bt, layer,
+):
+    """The XLA gather path's attention core in the DECODE choreography,
+    shared by the decode window and the verify program: the slots' pages
+    gathered through ``bt`` (:func:`_gathered_pool_view`), scores as f32
+    broadcast-multiply + reduce (q upcast first, cache upcast first, sum
+    over C — a ``[1, C] x [C, W]`` matvec uses one MXU row per pass, the
+    VPU form streams the cache at full rate), mask added before the
+    in-softmax ``/ sqrt(c)``, ONE joint f32 softmax over [pool | own rows]
+    (exact, not an approximation), f32 probs through both P·V sums. The
+    Pallas kernel (ops.paged_attn) mirrors this op sequence and is held to
+    it bitwise. Returns f32 ``[S, Hkv, G, T, C]``."""
+    from midgpt_tpu.ops.paged_attn import banded_fold, resolved_band_pages
+
+    hkv, c = qg.shape[1], qg.shape[-1]
+    ps = pool_k.shape[2]
+    # clip-mode gather, dequantized at the view for an int8 pool. It
+    # indexes the (replicated) page dim of a KV-head-sharded pool, so it
+    # is shard-local: each device gathers its own heads' pages. Pin the
+    # view so the partitioner can never "help" by regathering heads (the
+    # no-batch-allgather-in-page-gather audit rule gates that footgun).
+    ck = _gathered_pool_view(pool_k, pool_sk, bt, layer, hkv)
+    cv = _gathered_pool_view(pool_v, pool_sv, bt, layer, hkv)
+    ck = shard_act(ck, None, "kv_heads", None, None)
+    cv = shard_act(cv, None, "kv_heads", None, None)
+    s_pool = jnp.sum(
+        qg[..., :, None].astype(jnp.float32)
+        * ck[:, :, None, None].astype(jnp.float32),
+        axis=-2,
+    )  # [S, Hkv, G, T, W]
+    s_own = jnp.sum(
+        qg[:, :, :, :, None, :].astype(jnp.float32)
+        * own_k[:, :, None, None].astype(jnp.float32),
+        axis=-1,
+    )  # [S, Hkv, G, T, R]
+    s_all = jnp.concatenate([s_pool + mask_pool, s_own + mask_own], axis=-1)
+    probs = jax.nn.softmax(s_all / math.sqrt(c), axis=-1)  # f32
+    w_pool = s_pool.shape[-1]
+    p_pool = probs[..., :w_pool]
+    p_own = probs[..., w_pool:]
+    # P·V over the pool in the banded kernel's pinned ascending-band
+    # order (ops.paged_attn.banded_fold, same band plan): f32 addition is
+    # not associative, so matching the kernel's chunked reduction order
+    # IS what keeps kernel == XLA bitwise at long contexts. One band
+    # (every small geometry) is the single unsliced reduce.
+    bw = resolved_band_pages(
+        bt.shape[1], ps, c, jnp.dtype(pool_k.dtype).itemsize
+    ) * ps
+    if bw >= w_pool:
+        o_pool = jnp.sum(
+            p_pool[:, :, :, :, None, :]
+            * cv[:, :, None, None].astype(jnp.float32),
+            axis=-1,
+        )  # [S, Hkv, G, T, C]
+    else:
+        # plain lax slices (NOT mixed None+slice indexing, which lowers
+        # to a gather and hides the band start from the choreo prover's
+        # order extractor)
+        o_pool = banded_fold([
+            jnp.sum(
+                jax.lax.slice_in_dim(
+                    p_pool, lo, lo + bw, axis=-1
+                )[:, :, :, :, None, :]
+                * jax.lax.slice_in_dim(
+                    cv, lo, lo + bw, axis=-1
+                )[:, :, None, None].astype(jnp.float32),
+                axis=-1,
+            )
+            for lo in range(0, w_pool, bw)
+        ])
+    o_own = jnp.sum(
+        p_own[..., None] * own_v[:, :, None, None].astype(jnp.float32),
+        axis=-2,
+    )  # [S, Hkv, G, T, C]
+    return o_pool + o_own
+
+
 def _paged_kernel_dispatch(kind: str, layer: int, tensors, scales, block=1):
     """Run a serving paged-attention kernel (ops.paged_attn), wrapped in
     ``shard_map`` under a live TP mesh: a bare ``pallas_call`` is an
@@ -457,53 +554,70 @@ class Attention:
             return shard_act(out, "batch", "seq", "embed")
 
     def _decode_qkv(
-        self, x: Array, sin_row: Array, cos_row: Array
+        self, x: Array, sin_rows: Array, cos_rows: Array, *, pin: bool = True
     ) -> tp.Tuple[Array, Array, Array]:
-        """Project one token's q/k/v (+ optional QK-norm + rope at the
-        token's absolute position). q: [B, H, 1, C]; k/v: [B, Hkv, 1, C]."""
-        b, one, d = x.shape
+        """The prologue of every cached-attention body: project ``x``
+        [S, T, D] (a decode step is T = 1) to q [S, H, T, C] and k/v
+        [S, Hkv, T, C], with the optional QK-norm and the rope rows of the
+        tokens' absolute positions applied.
+
+        ``pin``: the whole-head TP constraints of the serving meshes
+        (serving_logical_rules) — the slot dim stays replicated (DP is
+        shared-nothing engine replicas, not a sharded slot axis) and every
+        per-head tensor splits over 'tensor'; no-ops outside an axis_rules
+        scope. Off for :meth:`decode_at`, which runs under the TRAINING
+        rule table with the batch dim sharded: a batch-replicated pin
+        would force a per-layer-per-token reshard there."""
+        b, t, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
         c = self.head_dim()
-        qkv = self.wqkv(x)  # [B, 1, (H+2Hkv)C]
-        q = qkv[..., : h * c].reshape(b, 1, h, c)
-        k = qkv[..., h * c : (h + hkv) * c].reshape(b, 1, hkv, c)
-        v = qkv[..., (h + hkv) * c :].reshape(b, 1, hkv, c)
+        qkv = self.wqkv(x)  # [S, T, (H+2Hkv)C]
+        q = qkv[..., : h * c].reshape(b, t, h, c)
+        k = qkv[..., h * c : (h + hkv) * c].reshape(b, t, hkv, c)
+        v = qkv[..., (h + hkv) * c :].reshape(b, t, hkv, c)
         if self.q_norm is not None:
             q = self.q_norm(q)
             k = self.k_norm(k)
-        q = jnp.transpose(q, (0, 2, 1, 3))  # [B, H, 1, C]
-        k = jnp.transpose(k, (0, 2, 1, 3))  # [B, Hkv, 1, C]
+        q = jnp.transpose(q, (0, 2, 1, 3))  # [S, H, T, C]
+        k = jnp.transpose(k, (0, 2, 1, 3))  # [S, Hkv, T, C]
         v = jnp.transpose(v, (0, 2, 1, 3))
-        q = apply_rotary(q, sin_row, cos_row, self.rope_style)
-        k = apply_rotary(k, sin_row, cos_row, self.rope_style)
-        # no sharding constraints HERE: this helper is shared with the
-        # fixed-batch sampler's ring paths (decode_at/decode_recent_at),
-        # which run under the TRAINING rule table with the batch dim
-        # sharded — a serving-style batch-replicated pin would force a
-        # per-layer-per-token reshard there. The paged serving caller
-        # (decode_paged_at) applies its whole-head TP constraints itself.
+        q = apply_rotary(q, sin_rows, cos_rows, self.rope_style)
+        k = apply_rotary(k, sin_rows, cos_rows, self.rope_style)
+        if pin:
+            q = shard_act(q, None, "heads", None, None)
+            k = shard_act(k, None, "kv_heads", None, None)
+            v = shard_act(v, None, "kv_heads", None, None)
         return q, k, v
+
+    def _merge_heads_out(self, out: Array, *, pin: bool = True) -> Array:
+        """The epilogue: per-head rows [S, H, T, C] -> ``wo`` of the merged
+        [S, T, H*C]. The merged dim stays head-contiguous tensor-sharded
+        (``pin``, as in :meth:`_decode_qkv`): wo is row-parallel
+        (GPT_PARAM_RULES), so the contraction runs on local heads and
+        GSPMD inserts ONE psum on the [.., D] result."""
+        b, h, t, c = out.shape
+        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, t, h * c)
+        if pin:
+            out = shard_act(out, None, None, "heads")
+        return self.wo(out)
 
     def decode_at(
         self,
         x: Array,  # [B, 1, D] — one new token per sequence
-        cache_k: Array,  # [L, B, Hkv, C, W] FULL stacked ring buffer (time-minor)
+        cache_k: Array,  # [L, B, Hkv, C, W] FULL stacked cache (time-minor)
         cache_v: Array,  # [L, B, Hkv, C, W]
         layer: int,  # STATIC layer index into the stacked cache
-        slot: Array,  # [] int32 — ring slot to write (pos % W)
+        slot: Array,  # [] int32 — cache slot to write (the token's position)
         mask: Array,  # [W] f32 additive mask over cache slots (0 / -inf)
         sin_row: Array,  # [1, C//2] rope row at the token's ABSOLUTE position
         cos_row: Array,
     ) -> tp.Tuple[Array, Array, Array]:
-        """Single-token incremental attention against a ring-buffer KV cache.
+        """Single-token incremental attention against a contiguous KV cache.
 
         The reference has no decode path (sample.py:72-94 re-runs the full
-        forward per token); this is the TPU-native replacement: O(W) per
-        token, static shapes, jit/scan-friendly. Keys are roped at absolute
-        positions, so evicting the oldest slot implements the reference's
-        sliding window (sample.py:74 ``idx[:, -block_size:]``) exactly:
-        attention scores depend only on position DIFFERENCES (RoPE shift
-        invariance, tests/test_layers.py).
+        forward per token); this is the plain cached one: O(W) per token,
+        static shapes, jit/scan-friendly — the oracle the paged serving
+        bodies are tested against.
 
         Takes the WHOLE stacked cache and a static ``layer``: the write is
         one [B, Hkv, 1, C] dynamic_update_slice row that XLA aliases in
@@ -515,7 +629,7 @@ class Attention:
         b, one, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
         c = self.head_dim()
-        q, k, v = self._decode_qkv(x, sin_row, cos_row)
+        q, k, v = self._decode_qkv(x, sin_row, cos_row, pin=False)
         # cache is time-minor ([B, Hkv, C, W] per layer — see KVCache): the
         # new row lands as a single-lane column write
         kc = jnp.transpose(k, (0, 1, 3, 2))  # [B, Hkv, C, 1]
@@ -547,10 +661,8 @@ class Attention:
             probs[:, :, :, None, :] * cv[:, :, None].astype(jnp.float32),
             axis=-1,
         ).astype(x.dtype)  # [B, Hkv, G, C]
-        out = out[:, :, :, None, :]  # [B, Hkv, G, 1, C]
-        out = out.reshape(b, h, 1, c)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, 1, h * c)
-        return self.wo(out), cache_k, cache_v
+        out = self._merge_heads_out(out.reshape(b, h, 1, c), pin=False)
+        return out, cache_k, cache_v
 
     def decode_paged_at(
         self,
@@ -574,24 +686,26 @@ class Attention:
         """Single-token attention against a PAGED KV pool read through
         per-slot block tables, plus the write-combining recent buffer.
 
-        The serving variant of :meth:`decode_recent_at`: instead of one
-        contiguous per-batch ring cache, every slot (request) owns a list
-        of fixed-size pages in a shared pool (``midgpt_tpu.serving``) —
-        its logical KV is the concatenation of its block-table pages. The
-        gather through ``bt`` is the only new op; the two-part joint
-        softmax (exact, not an approximation) and the read-only-pool /
-        bulk-merge write discipline are identical to the chunked sampler's
-        (PERF.md r4 'Serving': per-token scattered column writes into the
-        big time-minor cache either flip its layout or pay scattered RMW).
-        Positions differ PER SLOT (continuous batching mixes requests at
-        different depths), hence per-slot rope rows and a [S, W] mask.
+        Every slot (request) owns a list of fixed-size pages in a shared
+        pool (``midgpt_tpu.serving``) — its logical KV is the
+        concatenation of its block-table pages, gathered through ``bt``.
+        The pool is READ-ONLY inside a decode window: a per-token write
+        into the big time-minor pool is a scattered column that either
+        flips its layout or pays scattered RMW (PERF.md r4 'Serving'), so
+        each step's K/V row goes into a small time-MAJOR recent buffer
+        (one contiguous tile row per (slot, kv-head)) and the window's
+        rows reach the pages in one bulk flush. The softmax runs jointly
+        over [pool | recent rows] — exact, not an approximation
+        (:func:`_gather_attend`, the core verify shares). Positions
+        differ PER SLOT (continuous batching mixes requests at different
+        depths), hence per-slot rope rows and a [S, W] mask.
 
         ``paged_kernel="pallas"`` replaces the gather + two-part softmax
         with the ragged Pallas kernel (ops.paged_attn): the block table
         is walked IN-KERNEL over each slot's ``pooled_len``, LIVE pages
         stream from HBM exactly once and dead ones not at all, and no
         ``[S, Pmax*PS, ...]`` gathered intermediate exists — BITWISE the
-        same result (the kernel mirrors this method's op sequence; tested). An int8 pool
+        same result (the kernel mirrors the core's op sequence; tested). An int8 pool
         (``pool_sk``/``pool_sv`` given) dequantizes per (page, KV-head)
         po2 scale — in-kernel on the kernel path, at the gathered view
         here — and this step's K/V row is rounded through its target
@@ -603,13 +717,6 @@ class Attention:
         h, hkv = self.n_head, self.n_kv_head
         c = self.head_dim()
         q, k, v = self._decode_qkv(x, sin_rows, cos_rows)
-        # whole-head TP (serving meshes, serving_logical_rules): the
-        # slot dim stays replicated — DP is shared-nothing engine
-        # replicas, not a sharded slot axis — and every per-head tensor
-        # splits over 'tensor'. No-ops outside an axis_rules scope.
-        q = shard_act(q, None, "heads", None, None)
-        k = shard_act(k, None, "kv_heads", None, None)
-        v = shard_act(v, None, "kv_heads", None, None)
         quant = pool_sk is not None
         ps = pool_k.shape[2]
         zero = jnp.zeros((), r.dtype)
@@ -620,7 +727,8 @@ class Attention:
             # page is born at this position, from the page's in-window
             # birth row (already rounded — derivation is rounding-
             # stable) when born earlier in this window, else the pool's
-            # recorded scale.
+            # recorded scale. (Verify and prefill see all their rows at
+            # once: _rows_on_pool_grid.)
             from midgpt_tpu.quant import round_kv_rows_to_grid
             from midgpt_tpu.serving.paged import kv_row_scales
 
@@ -652,7 +760,7 @@ class Attention:
         rkl, rvl = rk[layer], rv[layer]  # [S, Hkv, R, C]
         if paged_kernel == "pallas":
             # the ragged in-kernel block-table walk (ops.paged_attn):
-            # bitwise this method's arithmetic, none of its HBM gather
+            # bitwise the core's arithmetic, none of its HBM gather
             qs = shard_act(
                 q.reshape(b, hkv, h // hkv, c), None, "kv_heads", None, None
             )
@@ -663,86 +771,14 @@ class Attention:
                  _gathered_pool_scales(pool_sv, bt, layer)),
             )  # [S, Hkv, G, C]
             out = shard_act(out, None, "kv_heads", None, None)
-            out = out.reshape(b, h, 1, c)
         else:
-            # gather this layer's pages through the block tables: the
-            # slot's logical KV [S, Hkv, C, W] in page order (int8 pools
-            # dequantize at the view — see _gathered_pool_view)
-            ck = _gathered_pool_view(pool_k, pool_sk, bt, layer, hkv)
-            cv = _gathered_pool_view(pool_v, pool_sv, bt, layer, hkv)
-            # the block-table gather indexes the (replicated) page dim of
-            # a KV-head-sharded pool, so it is shard-local: each device
-            # gathers its own heads' pages. Pin the gathered view so the
-            # partitioner can never "help" by regathering heads (the
-            # batch-allgather footgun the
-            # no-batch-allgather-in-page-gather audit rule gates).
-            ck = shard_act(ck, None, "kv_heads", None, None)
-            cv = shard_act(cv, None, "kv_heads", None, None)
-            qg = q.reshape(b, hkv, h // hkv, 1, c)
-            qcw = jnp.transpose(qg, (0, 1, 2, 4, 3))  # [S, Hkv, G, C, 1]
-            s_pool = jnp.sum(
-                qcw.astype(jnp.float32) * ck[:, :, None].astype(jnp.float32),
-                axis=-2,
-            )  # [S, Hkv, G, W]
-            s_rec = jnp.sum(
-                qg.astype(jnp.float32) * rkl[:, :, None].astype(jnp.float32),
-                axis=-1,
-            )  # [S, Hkv, G, R]
-            s_all = jnp.concatenate(
-                [s_pool + mask_pool[:, None, None, :], s_rec + mask_rec],
-                axis=-1,
-            )
-            probs = jax.nn.softmax(s_all / math.sqrt(c), axis=-1)
-            p_pool = probs[..., : s_pool.shape[-1]]
-            p_rec = probs[..., s_pool.shape[-1]:]
-            # PV accumulation in the banded kernel's pinned
-            # ascending-band order (ops.paged_attn.banded_fold, same
-            # band plan): f32 addition is not associative, so matching
-            # the kernel's chunked reduction order IS what keeps
-            # kernel == XLA bitwise at long contexts. One band (every
-            # small geometry) folds to exactly the pre-banding single
-            # reduce — the trace is unchanged there.
-            from midgpt_tpu.ops.paged_attn import (
-                banded_fold, resolved_band_pages,
-            )
-            w_pool = s_pool.shape[-1]
-            bw = resolved_band_pages(
-                bt.shape[1], ps, c, jnp.dtype(pool_k.dtype).itemsize
-            ) * ps
-            if bw >= w_pool:
-                o_pool = jnp.sum(
-                    p_pool[:, :, :, None, :]
-                    * cv[:, :, None].astype(jnp.float32),
-                    axis=-1,
-                )  # [S, Hkv, G, C]
-            else:
-                # plain lax slices (NOT mixed None+slice indexing,
-                # which lowers to a gather and hides the band start
-                # from the choreo prover's order extractor)
-                o_pool = banded_fold([
-                    jnp.sum(
-                        jax.lax.slice_in_dim(
-                            p_pool, lo, lo + bw, axis=-1
-                        )[:, :, :, None, :]
-                        * jax.lax.slice_in_dim(
-                            cv, lo, lo + bw, axis=-1
-                        )[:, :, None].astype(jnp.float32),
-                        axis=-1,
-                    )
-                    for lo in range(0, w_pool, bw)
-                ])  # [S, Hkv, G, C]
-            o_rec = jnp.sum(
-                p_rec[..., None] * rvl[:, :, None].astype(jnp.float32),
-                axis=-2,
-            )
-            out = (o_pool + o_rec).astype(x.dtype)
-            out = out.reshape(b, h, 1, c)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, 1, h * c)
-        # merged [.., H*C] stays head-contiguous tensor-sharded: wo is
-        # row-parallel (GPT_PARAM_RULES), so the contraction runs on
-        # local heads and GSPMD inserts ONE psum on the [.., D] result
-        out = shard_act(out, None, None, "heads")
-        return self.wo(out), rk, rv
+            # the one query row's own rows are the recent buffer's
+            out = _gather_attend(
+                q.reshape(b, hkv, h // hkv, 1, c), rkl, rvl,
+                mask_pool[:, None, None, None, :], mask_rec,
+                pool_k, pool_v, pool_sk, pool_sv, bt, layer,
+            ).astype(x.dtype)  # [S, Hkv, G, 1, C]
+        return self._merge_heads_out(out.reshape(b, h, 1, c)), rk, rv
 
     def prefill_paged_at(
         self,
@@ -790,23 +826,7 @@ class Attention:
         b, t, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
         c = self.head_dim()
-        qkv = self.wqkv(x)  # [1, T, (H+2Hkv)C]
-        q = qkv[..., : h * c].reshape(b, t, h, c)
-        k = qkv[..., h * c : (h + hkv) * c].reshape(b, t, hkv, c)
-        v = qkv[..., (h + hkv) * c :].reshape(b, t, hkv, c)
-        if self.q_norm is not None:
-            q = self.q_norm(q)
-            k = self.k_norm(k)
-        q = jnp.transpose(q, (0, 2, 1, 3))  # [1, H, T, C]
-        k = jnp.transpose(k, (0, 2, 1, 3))  # [1, Hkv, T, C]
-        v = jnp.transpose(v, (0, 2, 1, 3))
-        q = apply_rotary(q, sin_rows, cos_rows, self.rope_style)
-        k = apply_rotary(k, sin_rows, cos_rows, self.rope_style)
-        # whole-head TP: per-head tensors split over 'tensor', the slot
-        # dim replicated (see _decode_qkv)
-        q = shard_act(q, None, "heads", None, None)
-        k = shard_act(k, None, "kv_heads", None, None)
-        v = shard_act(v, None, "kv_heads", None, None)
+        q, k, v = self._decode_qkv(x, sin_rows, cos_rows)
         if pool_sk is not None:
             # int8 pool: round the chunk's own K/V rows through their
             # target pages' grids BEFORE the in-chunk self-attention.
@@ -818,19 +838,12 @@ class Attention:
             # grid. (The bf16 pool keeps the naive-attention contract
             # un-rounded — rounding there is the identity at serving
             # dtype, and the choreography prover pins that path.)
-            from midgpt_tpu.quant import round_kv_rows_to_grid
-            from midgpt_tpu.serving.paged import kv_row_scales
-
-            ps_ = pool_k.shape[2]
-            sk_all, sv_all = kv_row_scales(
+            k, v = _rows_on_pool_grid(
                 k, v, jnp.reshape(start, (1,)).astype(jnp.int32), bt,
-                pool_sk[layer], pool_sv[layer], ps_,
-            )  # [1, Hkv, T]
-            k = round_kv_rows_to_grid(k, sk_all)
-            v = round_kv_rows_to_grid(v, sv_all)
-        # gather the slot's pages (clip-mode for the same NaN reason as
-        # decode_paged_at) -> logical KV [1, Hkv, C, W] in page order;
-        # int8 pools dequantize at the view (_gathered_pool_view)
+                pool_sk, pool_sv, layer, pool_k.shape[2],
+            )
+        # gather the slot's pages -> logical KV [1, Hkv, C, W] in page
+        # order; int8 pools dequantize at the view (_gathered_pool_view)
         ck = _gathered_pool_view(pool_k, pool_sk, bt, layer, hkv)
         cv = _gathered_pool_view(pool_v, pool_sv, bt, layer, hkv)
         ck = shard_act(ck, None, "kv_heads", None, None)
@@ -854,11 +867,8 @@ class Attention:
         p_self = probs[..., s_pool.shape[-1]:]
         o_pool = jnp.einsum("bhgtw,bhcw->bhgtc", p_pool, cv.astype(v.dtype))
         o_self = jnp.einsum("bhgts,bhsc->bhgtc", p_self, v)
-        out = (o_pool + o_self).reshape(b, h, t, c)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, t, h * c)
-        # head-contiguous merged dim feeds the row-parallel wo (one psum)
-        out = shard_act(out, None, None, "heads")
-        return self.wo(out.astype(x.dtype)), k, v
+        out = (o_pool + o_self).astype(x.dtype).reshape(b, h, t, c)
+        return self._merge_heads_out(out), k, v
 
     def verify_paged_at(
         self,
@@ -882,49 +892,27 @@ class Attention:
         candidate rows of every slot attend jointly to the slot's
         resident pages plus themselves (causal), one joint softmax.
 
-        The dtype choreography deliberately MIRRORS
-        :meth:`decode_paged_at` op for op — f32 upcast BEFORE the
-        score multiply-sums, f32 probs through the PV contraction,
-        mask added before the in-softmax ``/ sqrt(c)`` — NOT the
-        prefill chunk's naive_attention choreography. Acceptance
-        compares the verify logits' argmax against what the decode
-        window would have sampled; on a real bf16 checkpoint the two
-        choreographies disagree by ~2 bf16 ulps, enough to flip
-        near-tied greedy argmaxes (caught by the sample.py --serve
-        --serve_spec verify drive on a trained checkpoint — the same
-        class of flip PR 4 hit with a cast-early prefill variant).
-        Mirroring the decode arithmetic pins spec-on to the decode
-        path at f32-reduction granularity, the same equivalence class
-        as the tested K=4 vs K=1 window invariance. This contract is
-        MACHINE-CHECKED: the choreography prover
-        (midgpt_tpu.analysis.choreo, CI serving-choreo job) asserts
-        the verify program's normalized attention trace equals the
-        decode window's OP FOR OP — a prefill-flavored edit here turns
-        that gate red before anything compiles."""
+        The dtype choreography is :meth:`decode_paged_at`'s — one
+        function, :func:`_gather_attend` — NOT the prefill chunk's
+        naive_attention choreography. Acceptance compares the verify
+        logits' argmax against what the decode window would have
+        sampled; on a real bf16 checkpoint the two choreographies
+        disagree by ~2 bf16 ulps, enough to flip near-tied greedy
+        argmaxes (caught by the sample.py --serve --serve_spec verify
+        drive on a trained checkpoint — the same class of flip PR 4 hit
+        with a cast-early prefill variant). Sharing the decode
+        arithmetic pins spec-on to the decode path at f32-reduction
+        granularity, the same equivalence class as the tested K=4 vs
+        K=1 window invariance; the choreography prover
+        (midgpt_tpu.analysis.choreo, CI serving-choreo job) still
+        asserts the verify program's normalized attention trace equals
+        the decode window's OP FOR OP."""
         b, t, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
         c = self.head_dim()
-        qkv = self.wqkv(x)  # [S, T, (H+2Hkv)C]
-        q = qkv[..., : h * c].reshape(b, t, h, c)
-        k = qkv[..., h * c : (h + hkv) * c].reshape(b, t, hkv, c)
-        v = qkv[..., (h + hkv) * c :].reshape(b, t, hkv, c)
-        if self.q_norm is not None:
-            q = self.q_norm(q)
-            k = self.k_norm(k)
-        q = jnp.transpose(q, (0, 2, 1, 3))  # [S, H, T, C]
-        k = jnp.transpose(k, (0, 2, 1, 3))  # [S, Hkv, T, C]
-        v = jnp.transpose(v, (0, 2, 1, 3))
-        q = apply_rotary(q, sin_rows, cos_rows, self.rope_style)
-        k = apply_rotary(k, sin_rows, cos_rows, self.rope_style)
-        # whole-head TP: per-head tensors split over 'tensor', the slot
-        # dim replicated (see _decode_qkv)
-        q = shard_act(q, None, "heads", None, None)
-        k = shard_act(k, None, "kv_heads", None, None)
-        v = shard_act(v, None, "kv_heads", None, None)
-        quant = pool_sk is not None
-        ps = pool_k.shape[2]
+        q, k, v = self._decode_qkv(x, sin_rows, cos_rows)
         row_dt = jnp.bfloat16 if pool_k.dtype == jnp.int8 else pool_k.dtype
-        if quant:
+        if pool_sk is not None:
             # round the candidate rows through their target pages' int8
             # grids: the verify self-reads, the decode window's recent-
             # buffer reads, and the post-flush pool reads of the same
@@ -933,14 +921,9 @@ class Attention:
             # spec-off (the PR 5 bug class, int8 edition). Rows past the
             # watermark never land (flush mask) — rounding them is
             # harmless.
-            from midgpt_tpu.quant import round_kv_rows_to_grid
-            from midgpt_tpu.serving.paged import kv_row_scales
-
-            sk_all, sv_all = kv_row_scales(
-                k, v, start, bt, pool_sk[layer], pool_sv[layer], ps
-            )  # [S, Hkv, T]
-            k = round_kv_rows_to_grid(k, sk_all)
-            v = round_kv_rows_to_grid(v, sv_all)
+            k, v = _rows_on_pool_grid(
+                k, v, start, bt, pool_sk, pool_sv, layer, pool_k.shape[2]
+            )
         qg = q.reshape(b, hkv, h // hkv, t, c)  # [S, Hkv, G, T, C]
         # the decode window stores each step's K/V into the CACHE-dtype
         # recent buffer and reads it back for the in-window scores — so
@@ -952,8 +935,8 @@ class Attention:
         vc = v.astype(row_dt)
         if paged_kernel == "pallas":
             # the ragged in-kernel block-table walk (ops.paged_attn):
-            # bitwise this method's arithmetic, none of its HBM gather
-            # (under the block mask, ``block`` > 1: this method's sums in
+            # bitwise the core's arithmetic, none of its HBM gather
+            # (under the block mask, ``block`` > 1: the core's sums in
             # another order, on the matrix unit — no decode window exists
             # for a block-diffusion model's forward to be bitwise with)
             qg = shard_act(qg, None, "kv_heads", None, None, None)
@@ -965,137 +948,12 @@ class Attention:
                 block=block,
             )  # [S, Hkv, G, T, C]
             out = shard_act(out, None, "kv_heads", None, None, None)
-            out = out.reshape(b, h, t, c)
         else:
-            # gather the slots' pages (clip-mode for the same NaN reason
-            # as decode_paged_at) -> logical KV [S, Hkv, C, W] in page
-            # order; int8 pools dequantize at the view
-            ck = _gathered_pool_view(pool_k, pool_sk, bt, layer, hkv)
-            cv = _gathered_pool_view(pool_v, pool_sv, bt, layer, hkv)
-            ck = shard_act(ck, None, "kv_heads", None, None)
-            cv = shard_act(cv, None, "kv_heads", None, None)
-            # scores as f32 broadcast-multiply + reduce, exactly the
-            # decode VPU form — q upcast first, cache upcast first, sum
-            # over C
-            s_pool = jnp.sum(
-                qg[..., :, None].astype(jnp.float32)
-                * ck[:, :, None, None].astype(jnp.float32),
-                axis=-2,
-            )  # [S, Hkv, G, T, W]
-            s_self = jnp.sum(
-                qg[:, :, :, :, None, :].astype(jnp.float32)
-                * kc[:, :, None, None].astype(jnp.float32),
-                axis=-1,
-            )  # [S, Hkv, G, T, T]
-            s_all = jnp.concatenate(
-                [s_pool + mask_pool, s_self + mask_self], axis=-1
-            )
-            probs = jax.nn.softmax(s_all / math.sqrt(c), axis=-1)  # f32
-            p_pool = probs[..., : s_pool.shape[-1]]
-            p_self = probs[..., s_pool.shape[-1]:]
-            # PV fold in the banded kernel's pinned ascending-band
-            # order — same contract (and same band plan) as
-            # decode_paged_at's XLA branch; one band degenerates to
-            # the pre-banding single reduce, trace unchanged.
-            from midgpt_tpu.ops.paged_attn import (
-                banded_fold, resolved_band_pages,
-            )
-            w_pool = s_pool.shape[-1]
-            bw = resolved_band_pages(
-                bt.shape[1], ps, c, jnp.dtype(pool_k.dtype).itemsize
-            ) * ps
-            if bw >= w_pool:
-                o_pool = jnp.sum(
-                    p_pool[:, :, :, :, None, :]
-                    * cv[:, :, None, None].astype(jnp.float32),
-                    axis=-1,
-                )  # [S, Hkv, G, T, C]
-            else:
-                # plain lax slices — see decode_paged_at's banded fold
-                o_pool = banded_fold([
-                    jnp.sum(
-                        jax.lax.slice_in_dim(
-                            p_pool, lo, lo + bw, axis=-1
-                        )[:, :, :, :, None, :]
-                        * jax.lax.slice_in_dim(
-                            cv, lo, lo + bw, axis=-1
-                        )[:, :, None, None].astype(jnp.float32),
-                        axis=-1,
-                    )
-                    for lo in range(0, w_pool, bw)
-                ])  # [S, Hkv, G, T, C]
-            o_self = jnp.sum(
-                p_self[..., None] * vc[:, :, None, None].astype(jnp.float32),
-                axis=-2,
-            )  # [S, Hkv, G, T, C]
-            out = (o_pool + o_self).astype(x.dtype)
-            out = out.reshape(b, h, t, c)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, t, h * c)
-        # head-contiguous merged dim feeds the row-parallel wo (one psum)
-        out = shard_act(out, None, None, "heads")
-        return self.wo(out), k, v
-
-    def decode_recent_at(
-        self,
-        x: Array,  # [B, 1, D]
-        cache_k: Array,  # [L, B, Hkv, C, W] — READ-ONLY within the chunk
-        cache_v: Array,  # [L, B, Hkv, C, W]
-        rk: Array,  # [L, B, Hkv, R, C] recent-K write buffer (row writes)
-        rv: Array,  # [L, B, Hkv, R, C]
-        layer: int,  # STATIC layer index
-        r: Array,  # [] int32 — step index within the chunk (recent row)
-        mask_big: Array,  # [W] additive f32 over merged cache slots
-        mask_rec: Array,  # [R] additive f32 over recent rows
-        sin_row: Array,
-        cos_row: Array,
-    ) -> tp.Tuple[Array, Array, Array]:
-        """Two-part single-token attention: merged ring cache + a small
-        write-combining 'recent' buffer.
-
-        Why the split (PERF.md r4 'Serving'): a per-step write into the big
-        time-minor cache is a 1-lane column scattered over ~768 (8,128)
-        tiles — XLA either flips the cache layout to make that write cheap
-        (halving read bandwidth; reads are ~6x the writes) or pays ~24 us
-        of scattered RMW per cache per layer. Writing instead into a small
-        time-MAJOR buffer is one contiguous tile row per (b, kv-head); the
-        big cache stays read-only (keeps its streaming-friendly layout) and
-        absorbs the recent rows in one bulk aligned merge per chunk
-        (``merge_recent``). Softmax runs jointly over both parts — exact,
-        not an approximation."""
-        b, one, d = x.shape
-        h, hkv = self.n_head, self.n_kv_head
-        c = self.head_dim()
-        q, k, v = self._decode_qkv(x, sin_row, cos_row)
-        zero = jnp.zeros((), r.dtype)
-        at = (jnp.asarray(layer, r.dtype), zero, zero, r, zero)
-        rk = jax.lax.dynamic_update_slice(rk, k.astype(rk.dtype)[None], at)
-        rv = jax.lax.dynamic_update_slice(rv, v.astype(rv.dtype)[None], at)
-        ck, cv = cache_k[layer], cache_v[layer]  # [B, Hkv, C, W]
-        rkl, rvl = rk[layer], rv[layer]  # [B, Hkv, R, C]
-        qg = q.reshape(b, hkv, h // hkv, 1, c)
-        qcw = jnp.transpose(qg, (0, 1, 2, 4, 3))  # [B, Hkv, G, C, 1]
-        s_big = jnp.sum(
-            qcw.astype(jnp.float32) * ck[:, :, None].astype(jnp.float32),
-            axis=-2,
-        )  # [B, Hkv, G, W]
-        s_rec = jnp.sum(
-            qg.astype(jnp.float32) * rkl[:, :, None].astype(jnp.float32),
-            axis=-1,
-        )  # [B, Hkv, G, R]  (qg [.., 1, C] x rkl [.., R, C] summed over C)
-        s = jnp.concatenate([s_big + mask_big, s_rec + mask_rec], axis=-1)
-        probs = jax.nn.softmax(s / math.sqrt(c), axis=-1)  # [B, Hkv, G, W+R]
-        p_big, p_rec = probs[..., : s_big.shape[-1]], probs[..., s_big.shape[-1]:]
-        o_big = jnp.sum(
-            p_big[:, :, :, None, :] * cv[:, :, None].astype(jnp.float32),
-            axis=-1,
-        )  # [B, Hkv, G, C]
-        o_rec = jnp.sum(
-            p_rec[..., None] * rvl[:, :, None].astype(jnp.float32), axis=-2
-        )  # [B, Hkv, G, C]
-        out = (o_big + o_rec).astype(x.dtype)
-        out = out.reshape(b, h, 1, c)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, 1, h * c)
-        return self.wo(out), rk, rv
+            out = _gather_attend(
+                qg, kc, vc, mask_pool, mask_self,
+                pool_k, pool_v, pool_sk, pool_sv, bt, layer,
+            ).astype(x.dtype)  # [S, Hkv, G, T, C]
+        return self._merge_heads_out(out.reshape(b, h, t, c)), k, v
 
 
 def mlp_hidden_dim(cfg: ModelConfig) -> int:
@@ -1602,18 +1460,6 @@ class Block:
         x = x + mlp_call(self.mlp, self.ln2(x))[0]
         return x, cache_k, cache_v
 
-    def decode_recent_at(
-        self, x, cache_k, cache_v, rk, rv, layer, r, mask_big, mask_rec,
-        sin_row, cos_row,
-    ):
-        attn_out, rk, rv = self.attn.decode_recent_at(
-            self.ln1(x), cache_k, cache_v, rk, rv, layer, r,
-            mask_big, mask_rec, sin_row, cos_row,
-        )
-        x = x + attn_out
-        x = x + mlp_call(self.mlp, self.ln2(x))[0]
-        return x, rk, rv
-
     def decode_paged_at(
         self, x, pool_k, pool_v, bt, rk, rv, layer, r, mask_pool, mask_rec,
         sin_rows, cos_rows, pooled_len=None, pool_sk=None, pool_sv=None,
@@ -1960,13 +1806,11 @@ def decode_step(
 ) -> tp.Tuple[Array, KVCache]:
     """One incremental decoding step: logits for the next token + updated
     cache. O(W) per token vs the reference's O(T * full-forward)
-    (sample.py:72-94).
-
-    The cache is a ring buffer of W = cache length slots. While pos < W
-    this is ordinary append-at-pos decoding; past W it becomes the
-    reference's sliding window (sample.py:74): the new token evicts the
-    oldest. ``rope_len`` sizes the rope tables (>= total generation length;
-    defaults to W for the non-sliding case).
+    (sample.py:72-94). Append-at-``pos`` decoding into a cache of W slots,
+    ``pos < W``: the plain oracle of the paged serving programs
+    (``sampling.generate`` re-runs the cropped full forward once the
+    window would have to slide). ``rope_len`` sizes the rope tables
+    (defaults to W).
 
     The layer loop is STRAIGHT-LINE code over static layer slices — not a
     lax.scan. Scanning the cache through as xs/ys re-stacked every element
@@ -1980,14 +1824,8 @@ def decode_step(
     sin_np, cos_np = rope_tables(cfg.head_dim, rope_len or w, cfg.rope_base)
     sin_t, cos_t = jnp.asarray(sin_np), jnp.asarray(cos_np)
 
-    # ring arithmetic (all static-shape): write slot and per-slot validity.
-    # slot s holds absolute position abs_s = pos - ((pos - s) mod W); it is
-    # a real entry iff abs_s >= 0 — which also guarantees abs_s > pos - W
-    # (in-window) and abs_s <= pos (causal).
-    slot = jnp.mod(pos, w)
-    idx = jnp.arange(w)
-    abs_pos = pos - jnp.mod(pos - idx, w)
-    mask = jnp.where(abs_pos >= 0, 0.0, -jnp.inf).astype(jnp.float32)
+    # slot s holds position s: written (and causal) iff s <= pos
+    mask = jnp.where(jnp.arange(w) <= pos, 0.0, -jnp.inf).astype(jnp.float32)
     sin_row = jax.lax.dynamic_slice_in_dim(sin_t, pos, 1, axis=0)
     cos_row = jax.lax.dynamic_slice_in_dim(cos_t, pos, 1, axis=0)
 
@@ -1996,64 +1834,10 @@ def decode_step(
     sin_h, cos_h = sin_row.astype(h.dtype), cos_row.astype(h.dtype)
     for i in range(cfg.n_layer):
         block = jax.tree.map(lambda a: a[i], model.blocks)  # static slices
-        h, ck, cv = block.decode_at(h, ck, cv, i, slot, mask, sin_h, cos_h)
+        h, ck, cv = block.decode_at(h, ck, cv, i, pos, mask, sin_h, cos_h)
     h = model.ln_f(h)
     logits = model.project(h)[:, 0, :]  # [B, V]
     return logits, KVCache(k=ck, v=cv)
-
-
-def decode_step_recent(
-    model: GPT,
-    tokens: Array,  # [B] int32
-    pos: Array,  # [] int32 — absolute position (chunk_base + r)
-    cache: KVCache,  # merged ring cache, READ-ONLY here
-    rk: Array,  # [L, B, Hkv, R, C] recent-K buffer
-    rv: Array,
-    r: Array,  # [] int32 — step index within the chunk
-    chunk_base: tp.Union[int, Array],  # absolute position of the chunk start
-    window: int,  # STATIC sliding-window size (min(total, block_size))
-    rope_len: int,
-) -> tp.Tuple[Array, Array, Array]:
-    """One decode step of the chunked sampler: attends over the merged ring
-    cache (positions < chunk_base, masked to the sliding window) plus the
-    recent buffer (positions chunk_base..chunk_base+r), and appends this
-    token's K/V to the recent buffer. The big cache is never written — see
-    ``Attention.decode_recent_at`` for why that is the fast shape of KV
-    decoding on TPU. ``merge_recent`` folds the buffer in at chunk end."""
-    cfg = model.config
-    w = cache.k.shape[-1]
-    rr = rk.shape[3]
-    sin_np, cos_np = rope_tables(cfg.head_dim, rope_len, cfg.rope_base)
-    sin_t, cos_t = jnp.asarray(sin_np), jnp.asarray(cos_np)
-
-    # merged slot s holds the latest position < chunk_base congruent to s
-    # (mod W'); valid iff it exists and is inside the sliding window
-    idx = jnp.arange(w)
-    cb1 = chunk_base - 1
-    abs_pos = cb1 - jnp.mod(cb1 - idx, w)
-    valid_big = (abs_pos >= 0) & (abs_pos > pos - window)
-    mask_big = jnp.where(valid_big, 0.0, -jnp.inf).astype(jnp.float32)
-    # recent row j holds position chunk_base + j: causal upper bound
-    # (j <= r) AND the sliding-window lower bound (j > r - window) — a
-    # chunk longer than the window must evict its own oldest rows too
-    ridx = jnp.arange(rr)
-    mask_rec = jnp.where(
-        (ridx <= r) & (ridx > r - window), 0.0, -jnp.inf
-    ).astype(jnp.float32)
-    sin_row = jax.lax.dynamic_slice_in_dim(sin_t, pos, 1, axis=0)
-    cos_row = jax.lax.dynamic_slice_in_dim(cos_t, pos, 1, axis=0)
-
-    h = embed_tokens(model.wte, tokens[:, None])  # [B, 1, D]
-    sin_h, cos_h = sin_row.astype(h.dtype), cos_row.astype(h.dtype)
-    for i in range(cfg.n_layer):
-        block = jax.tree.map(lambda a: a[i], model.blocks)
-        h, rk, rv = block.decode_recent_at(
-            h, cache.k, cache.v, rk, rv, i, r, mask_big, mask_rec,
-            sin_h, cos_h,
-        )
-    h = model.ln_f(h)
-    logits = model.project(h)[:, 0, :]  # [B, V]
-    return logits, rk, rv
 
 
 def decode_step_paged(
@@ -2078,10 +1862,10 @@ def decode_step_paged(
     plus the shared recent buffer (window positions pooled_len[s]..r),
     and appends its token's K/V to the recent buffer. The pool is never
     written here — ``midgpt_tpu.serving.flush_recent`` folds the window's
-    rows into the pages in one bulk scatter at window end (the same
-    read-only-cache discipline as ``decode_step_recent``). Unlike the
-    ring sampler there is no sliding window: pages are append-only and
-    the engine caps each request at ``block_size`` total tokens.
+    rows into the pages in one bulk scatter at window end (why:
+    ``Attention.decode_paged_at``). There is no sliding window: pages
+    are append-only and the engine caps each request at ``block_size``
+    total tokens.
 
     ``layer_scan="on"`` folds the layer loop into ONE ``lax.scan`` over
     the stacked block params (ROADMAP item 1: the unrolled loop
@@ -2422,27 +2206,6 @@ def verify_tokens_paged(
     if expert_rows:
         return logits, ks, vs, outs[2]
     return logits, ks, vs  # ks/vs: [L, S, Hkv, T, C]
-
-
-def merge_recent(
-    cache: KVCache, rk: Array, rv: Array, slot0: tp.Union[int, Array],
-    length: int,
-) -> KVCache:
-    """Fold the first ``length`` recent rows into the ring cache at slots
-    [slot0, slot0+length) — one bulk, statically-indexed column-block write
-    per cache (the chunked sampler aligns chunk bases so the slot range
-    never wraps). The small transpose relayouts ~R columns once per chunk
-    instead of paying scattered column writes every token."""
-    kc = jnp.transpose(rk[:, :, :, :length, :], (0, 1, 2, 4, 3))
-    vc = jnp.transpose(rv[:, :, :, :length, :], (0, 1, 2, 4, 3))
-    return KVCache(
-        k=jax.lax.dynamic_update_slice_in_dim(
-            cache.k, kc.astype(cache.k.dtype), slot0, axis=4
-        ),
-        v=jax.lax.dynamic_update_slice_in_dim(
-            cache.v, vc.astype(cache.v.dtype), slot0, axis=4
-        ),
-    )
 
 
 def prefill(

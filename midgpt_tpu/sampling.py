@@ -6,13 +6,13 @@ Replaces the reference sampler's pad-to-block_size full re-forward per token
 Capability parity: temperature-scaled categorical sampling; adds greedy
 (temperature=0) and top-k.
 
-This module is the FIXED-BATCH path (one ring cache sized for the batch,
-all requests start and stop together) and the exact-parity oracle the
-serving tests compare against. Under real traffic — requests arriving and
-finishing independently — route through ``midgpt_tpu.serving`` instead:
-paged KV pool, continuous batching, and K decode steps fused per XLA
-dispatch (``serving.generate_served`` is the drop-in batch entry point;
-``sample.py --serve`` uses it)."""
+This module is the FIXED-BATCH path (one contiguous cache sized for the
+batch, all requests start and stop together): the plain, exact-parity
+oracle the serving tests compare against, not a serving path. Under real
+traffic — requests arriving and finishing independently — route through
+``midgpt_tpu.serving`` instead: paged KV pool, continuous batching, and K
+decode steps fused per XLA dispatch (``serving.generate_served`` is the
+drop-in batch entry point; ``sample.py --serve`` uses it)."""
 
 from __future__ import annotations
 
@@ -21,38 +21,9 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.models.gpt import (
-    GPT,
-    KVCache,
-    decode_step_recent,
-    merge_recent,
-    prefill,
-)
+from midgpt_tpu.models.gpt import GPT, KVCache, decode_step, prefill
 
 Array = jax.Array
-
-
-def _pin_cache_layout(cache: KVCache) -> KVCache:
-    """Constrain the ring cache to the standard streaming layout (W minor).
-
-    Without this, XLA's layout assignment sees the bulk merge writes and
-    may flip the cache to a write-friendly C-minor layout that pads C=64
-    lanes to 128 — halving read bandwidth on the decode hot loop (measured
-    on v5e, PERF.md r4 'Serving'). Single-device TPU only: under a mesh
-    GSPMD owns layouts, and on CPU it's moot."""
-    if jax.default_backend() != "tpu":
-        return cache
-    from midgpt_tpu.parallel.sharding import current_mesh
-
-    if current_mesh() is not None:
-        return cache
-    from jax.experimental.layout import Layout, with_layout_constraint
-
-    lay = Layout(tuple(range(cache.k.ndim)))
-    return KVCache(
-        k=with_layout_constraint(cache.k, lay),
-        v=with_layout_constraint(cache.v, lay),
-    )
 
 
 def _scaled_masked(
@@ -101,9 +72,6 @@ def sample_token(
     return jax.random.categorical(
         key, _scaled_masked(logits, temperature, top_k), axis=-1
     ).astype(jnp.int32)
-
-
-_sample_token = sample_token  # back-compat alias (pre-PR 5 private name)
 
 
 def derive_request_key(key: Array, seed: Array, token_index: Array) -> Array:
@@ -234,33 +202,17 @@ def generate(
     temperature: float = 1.0,
     top_k: tp.Optional[int] = None,
     cache_dtype=jnp.bfloat16,
-    sliding: str = "exact",
-    chunk_len: int = 64,
 ) -> Array:
     """Returns [B, max_new_tokens] sampled continuations (parity:
     sample.py:68-95 generate, temperature semantics sample.py:88-92).
 
     Up to ``block_size`` total tokens, decoding is KV-cached (O(W)/token vs
-    the reference's full re-forward per token). Past ``block_size`` the
-    window must slide (sample.py:74 ``idx[:, -block_size:]``) and two
-    semantics are offered:
-
-    - ``sliding="exact"`` (default): re-run the cropped-window full forward
-      per token — bit-parity with the reference, which *recomputes the
-      hidden states of past tokens under the shrunken context* each step.
-      Same O(W * fwd)/token cost the reference always pays.
-    - ``sliding="kv"``: ring-buffer cache, evict-oldest. Past tokens keep
-      the hidden states they were computed with (standard sliding-window
-      KV decoding, O(W)/token). Diverges from the reference once the
-      window slides — fast mode, not a parity mode.
-
-    Decoding runs in chunks of ``chunk_len`` tokens through a small
-    write-combining recent-KV buffer (gpt.decode_step_recent) so the ring
-    cache stays read-only between bulk merges — the layout-friendly shape
-    of KV decode on TPU (PERF.md r4). The joint softmax over both parts is
-    exact; chunking changes performance, not semantics."""
-    assert sliding in ("exact", "kv"), f"unknown sliding mode {sliding!r}"
-    assert chunk_len >= 1, f"chunk_len must be >= 1, got {chunk_len}"
+    the reference's full re-forward per token): ``prefill`` + one scan of
+    ``decode_step``. Past ``block_size`` the window must slide (sample.py:74
+    ``idx[:, -block_size:]``): the cropped-window full forward is re-run
+    per token — bit-parity with the reference, which *recomputes the hidden
+    states of past tokens under the shrunken context* each step, at the
+    same O(W * fwd)/token cost the reference always pays."""
     b, p = prompt.shape
     cfg = model.config
     if p > cfg.block_size:
@@ -269,93 +221,21 @@ def generate(
         p = cfg.block_size
     total = p + max_new_tokens
     w = min(total, cfg.block_size)  # sliding-window size (semantics)
-    # a chunk longer than the window wastes recent-buffer reads (its
-    # oldest rows are evicted mid-chunk; decode_step_recent masks them)
-    r_len = min(chunk_len, w)
-    wp = -(-w // r_len) * r_len  # ring slots, padded so merges never wrap
-    cache = KVCache.init(cfg, b, wp, dtype=cache_dtype)
+    cache = KVCache.init(cfg, b, w, dtype=cache_dtype)
     logits, cache = prefill(model, prompt, cache)
-    cache = _pin_cache_layout(cache)
 
-    rshape = (cfg.n_layer, b, cfg.kv_heads, r_len, cfg.head_dim)
+    def body(carry, pos):
+        logits, cache, k = carry
+        k, sub = jax.random.split(k)
+        tok = sample_token(logits, sub, temperature, top_k)
+        new_logits, cache = decode_step(model, tok, pos, cache)
+        return (new_logits, cache, k), tok
 
-    def one_chunk(logits, key, cache, base, clen: int):
-        """clen decode steps from traced base; returns toks [clen, B].
-        base is a TRACED scalar so every full-length chunk shares one
-        compiled body (baking it in statically made trace/compile size grow
-        linearly with max_new_tokens/chunk_len)."""
-        rk = jnp.zeros(rshape, cache.k.dtype)
-        rv = jnp.zeros(rshape, cache.k.dtype)
-
-        def body(carry, _):
-            logits, r, rk, rv, k = carry
-            k, sub = jax.random.split(k)
-            tok = sample_token(logits, sub, temperature, top_k)
-            new_logits, rk, rv = decode_step_recent(
-                model, tok, base + r, cache, rk, rv, r, base, w, total
-            )
-            return (new_logits, r + 1, rk, rv, k), tok
-
-        (logits, _, rk, rv, key), toks = jax.lax.scan(
-            body,
-            (logits, jnp.zeros((), jnp.int32), rk, rv, key),
-            None,
-            length=clen,
-        )
-        cache = merge_recent(cache, rk, rv, jnp.mod(base, wp), clen)
-        return logits, key, _pin_cache_layout(cache), toks
-
-    def run_chunked(logits, key, cache, start_pos: int, n_steps: int):
-        """n_steps of chunked decode from absolute position start_pos.
-        A partial first chunk aligns subsequent bases to r_len (merges
-        never wrap the ring); the full chunks run under ONE outer scan."""
-        toks_parts = []
-        base, remaining = start_pos, n_steps
-        l0 = min(r_len - base % r_len, remaining) if base % r_len else 0
-        if l0:
-            logits, key, cache, t0 = one_chunk(
-                logits, key, cache, jnp.asarray(base, jnp.int32), l0
-            )
-            toks_parts.append(t0)
-            base, remaining = base + l0, remaining - l0
-        n_full = remaining // r_len
-        if n_full:
-            def chunk_body(carry, _):
-                logits, key, cache, cur = carry
-                logits, key, cache, toks = one_chunk(
-                    logits, key, cache, cur, r_len
-                )
-                return (logits, key, cache, cur + r_len), toks
-
-            (logits, key, cache, _), tf = jax.lax.scan(
-                chunk_body,
-                (logits, key, cache, jnp.asarray(base, jnp.int32)),
-                None,
-                length=n_full,
-            )
-            toks_parts.append(tf.reshape(n_full * r_len, b))
-            base, remaining = base + n_full * r_len, remaining - n_full * r_len
-        if remaining:
-            logits, key, cache, t2 = one_chunk(
-                logits, key, cache, jnp.asarray(base, jnp.int32), remaining
-            )
-            toks_parts.append(t2)
-        toks = (
-            jnp.concatenate(toks_parts, axis=0)
-            if toks_parts
-            else jnp.zeros((0, b), jnp.int32)
-        )
-        return logits, key, cache, toks
-
-    n1 = w - p  # tokens decodable before the window would slide
-    if sliding == "kv":
-        # ring eviction is just the sliding-window mask in the chunked
-        # step — one unified loop over all new tokens
-        _, _, _, toks = run_chunked(logits, key, cache, p, max_new_tokens)
-        return jnp.transpose(toks)  # [B, max_new_tokens]
-
-    logits, key, cache, toks1 = run_chunked(logits, key, cache, p, n1)
-    toks1 = jnp.transpose(toks1)  # [B, n1]
+    # the w - p tokens decodable before the window would slide
+    (logits, _, key), toks1 = jax.lax.scan(
+        body, (logits, cache, key), jnp.arange(p, w, dtype=jnp.int32)
+    )
+    toks1 = jnp.transpose(toks1)  # [B, w - p]
     if total <= w:
         return toks1
 
@@ -388,8 +268,6 @@ def make_sampler(
     temperature: float = 1.0,
     top_k: tp.Optional[int] = None,
     cache_dtype=jnp.bfloat16,
-    sliding: str = "exact",
-    chunk_len: int = 64,
 ):
     """A jitted ``(model, prompt, key) -> tokens`` sampler.
 
@@ -410,8 +288,6 @@ def make_sampler(
                 temperature=temperature,
                 top_k=top_k,
                 cache_dtype=cache_dtype,
-                sliding=sliding,
-                chunk_len=chunk_len,
             )
 
     return jax.jit(fn)

@@ -72,10 +72,6 @@ def main() -> None:
         return n
 
     ap.add_argument(
-        "--chunk_len", type=_positive_int, default=64,
-        help="decode chunk length (recent-KV buffer rows; perf knob)",
-    )
-    ap.add_argument(
         "--serve", action="store_true",
         help="route generation through the continuous-batching serving "
         "engine (midgpt_tpu.serving): paged KV + fused K-step decode "
@@ -287,7 +283,6 @@ def main() -> None:
         mesh=mesh,
         temperature=args.temperature,
         top_k=args.top_k,
-        chunk_len=args.chunk_len,
     )
     toks = sampler(model, jnp.asarray(prompt), jax.random.PRNGKey(args.seed))
     for i in range(args.num_samples):

@@ -293,6 +293,63 @@ def test_verify_kernel_bitwise_vs_xla(kv_quant):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("kern", ["xla", "pallas"])
+@pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "kv8"])
+def test_verify_one_row_is_decode(kv_quant, kern):
+    """A verify dispatch of ONE candidate row is a one-step decode window
+    (``r = 0`` of a one-row recent buffer), to the bit: logits and the K/V
+    row that lands. On the gather path both run one core
+    (gpt._gather_attend: decode's recent buffer in the place of verify's
+    own rows), on the kernel one body — what speculative acceptance rests
+    on. An f32 model over a bf16 / int8 pool: verify must round its own
+    row to the pool's row dtype as the recent buffer does. (A longer
+    recent buffer adds masked columns, exact zeros, to the softmax axis;
+    XLA's CPU backend then sums that axis in another order and the logits
+    move by an ulp: R = 4 is equal in value, not to the bit.)"""
+    cfg = GQA_CFG
+    model = GPT.init(jax.random.PRNGKey(0), cfg)
+    s, ps, pmax, npool, rr = 4, 8, 8, 24, 1
+    pool = PagedKVPool.init(cfg, npool, ps, jnp.bfloat16, kv_quant=kv_quant)
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    pool = _random_pool(pool, ks)
+    if not pool.quantized:  # _random_pool fills a float pool in f32
+        pool = dataclasses.replace(
+            pool, k=pool.k.astype(jnp.bfloat16), v=pool.v.astype(jnp.bfloat16)
+        )
+    bt = jax.random.randint(ks[4], (s, pmax), 0, npool).astype(jnp.int32)
+    # empty, mid-page, a page born at this very position, full but one
+    pooled_len = jnp.asarray([0, 13, 2 * ps, pmax * ps - 1], jnp.int32)
+    tokens = jax.random.randint(
+        ks[5], (s,), 0, cfg.vocab_size
+    ).astype(jnp.int32)
+    rk = jnp.zeros(
+        (cfg.n_layer, s, cfg.kv_heads, rr, cfg.head_dim), pool.row_dtype
+    )
+    d_logits, rko, rvo = jax.jit(
+        lambda tk, pk, pv, rk_, rv_, sk, sv: decode_step_paged(
+            model, tk, pooled_len, pk, pv, bt, rk_, rv_,
+            jnp.asarray(0, jnp.int32), pooled_len, cfg.block_size,
+            pool_sk=sk, pool_sv=sv, paged_kernel=kern,
+        )
+    )(tokens, pool.k, pool.v, rk, jnp.zeros_like(rk),
+      pool.scale_k, pool.scale_v)
+    v_logits, vks, vvs = jax.jit(
+        lambda tk, pk, pv, sk, sv: verify_tokens_paged(
+            model, tk[:, None], pooled_len, pk, pv, bt, cfg.block_size,
+            pool_sk=sk, pool_sv=sv, paged_kernel=kern,
+        )
+    )(tokens, pool.k, pool.v, pool.scale_k, pool.scale_v)
+    assert np.isfinite(np.asarray(d_logits)).all()
+    np.testing.assert_array_equal(
+        np.asarray(d_logits), np.asarray(v_logits[:, 0])
+    )
+    for rec, rows in ((rko, vks), (rvo, vvs)):
+        np.testing.assert_array_equal(
+            np.asarray(rec[:, :, :, :1], np.float32),
+            np.asarray(rows.astype(pool.row_dtype), np.float32),
+        )
+
+
 # f32-nb2 (2 bands) proves the multi-band fold in tier-1; the deeper
 # band counts and the int8-pool multiband cells ride the slow tier to
 # keep tier-1 inside the 870 s verify budget (the serving-longctx CI

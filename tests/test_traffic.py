@@ -1,8 +1,8 @@
 """HBM traffic auditor tests (analysis/traffic.py + analysis/budgets.py).
 
 Fast tier: the analytic floor decomposition reproduces PERF.md's
-hand-computed 124M B=8 numbers (and bench_decode.py's recorded floor
-arithmetic), classification/budget logic against canned inputs.
+hand-computed 124M B=8 numbers, classification/budget logic against
+canned inputs.
 
 Slow tier: compile the real decode window at audit size, gate it
 against its checked-in budget, and re-introduce the PR 6
@@ -45,14 +45,14 @@ def test_floor_reproduces_perf_124m_decomposition():
     cfg = get_config("openwebtext").model
     d = floor_decomposition(cfg, slots=8, live_tokens=640)
     assert abs(d["weights_floor_ms"] - 0.31) / 0.31 < 0.05
-    # KV: scripts/bench_decode.py's recorded floor streams K AND V
-    # (both are read every step); PERF's r5 prose "~0.12 ms" counted
+    # KV: the floor streams K AND V (both are read every step:
+    # L * S * Hkv * live * C * 2 bytes * 2 planes); PERF's r5 prose "~0.12 ms" counted
     # the pair as one plane. Both conventions must be reproduced: the
     # honest stream within 5% of 2x the prose figure, and the prose
     # figure as exactly half the reported stream.
     assert abs(d["kv_floor_ms"] - 2 * 0.12) / (2 * 0.12) < 0.05
     assert abs(d["kv_floor_ms"] / 2 - 0.12) / 0.12 < 0.05
-    # the bench_decode formula, verbatim
+    # that formula, verbatim
     expect_kv = cfg.n_layer * 8 * cfg.kv_heads * 640 * cfg.head_dim * 2 * 2
     assert d["kv_bytes_per_step"] == expect_kv
 
@@ -65,8 +65,8 @@ def test_floor_reproduces_perf_quant_weights():
 
 
 def test_weight_stream_matches_count_params():
-    """The analytic weight stream is count_params(model) * 2 at bf16 —
-    bench_decode.py's floor numerator — bit-exactly at audit size."""
+    """The analytic weight stream is count_params(model) * 2 at bf16,
+    bit-exactly at audit size."""
     import jax
     import jax.numpy as jnp
 
