@@ -23,7 +23,8 @@ One chip, in order — any failed assertion ends the run non-zero:
    to one full-context forward of the same weights (``LOGIT_TOL_ULPS``).
 
 6. block    — the block-diffusion forward (``verify_tokens_paged`` with
-   ``block_len=4``: T = 4 rows a slot that all see each other) at 32 query
+   ``block_len=4`` at the block window's T = 8 rows a slot: two blocks,
+   causal across them, rows of one all see each other) at 32 query
    heads over 4 KV heads of 128, half-split RoPE and QK-RMSNorm, the
    expert layer behind it (every expert chosen, so that no near-tie of the
    router widens the comparison), bf16, ragged lengths: the Pallas kernel under the
@@ -467,10 +468,11 @@ def block_forward_vs_gather(seed: int) -> None:
     ks = jax.random.split(jax.random.PRNGKey(seed + 11), 4)
     bt = jax.random.randint(ks[0], (s, pmax), 0, npool).astype(jnp.int32)
     # ragged, whole blocks: empty, inside a page, page-aligned, ..., full
+    t = 2 * blk  # the window's rows a slot: the block that lands | the next
     start = jnp.asarray(
-        [0, 12, 32, 100, 256, 500, 640, pmax * ps - blk], jnp.int32
+        [0, 12, 32, 100, 256, 500, 640, pmax * ps - t], jnp.int32
     )
-    cand = jax.random.randint(ks[1], (s, blk), 0, cfg.vocab_size, jnp.int32)
+    cand = jax.random.randint(ks[1], (s, t), 0, cfg.vocab_size, jnp.int32)
     pool = PagedKVPool.init(cfg, npool, ps, jnp.bfloat16)
     pool = dataclasses.replace(
         pool,

@@ -939,9 +939,10 @@ def prove_block_choreography(
     (``model_cfg.block_len`` > 0), whose engine has no token-at-a-time
     decode window to hold the verify program to. The verify-mirrors-decode
     clause is kept with the roles moved up one: the block window's forward
-    (serving.engine._build_block_window) must mirror the VERIFY program's
-    of the same model at T = block_len OP FOR OP — it is the verify
-    forward under another mask, and the mask is an operand, so the two
+    (serving.engine._build_block_window: two blocks of rows a slot, the
+    one that lands and the one being denoised) must mirror the VERIFY
+    program's of the same model at T = 2 block_len OP FOR OP — it is the
+    verify forward under another mask, and the mask is an operand, so the two
     attention traces and softmax signatures are equal or something other
     than the mask changed. The prefill chunk is still held to
     ``naive_attention``, and the shared clauses (f32 softmax and
@@ -994,14 +995,15 @@ def prove_block_choreography(
     ))(
         model, pool, i32(slots, pmax), i32(slots), pred(slots), i32(slots),
         i32(slots), i32(slots), i32(slots, blk), pred(slots, blk),
-        i32(slots, blk),
+        i32(slots, blk), pred(slots), i32(slots, blk),
     )
     verify_jaxpr = jax.make_jaxpr(make_verify_program(
-        model, slots=slots, spec_len=blk - 1, paged_kernel="xla",
+        model, slots=slots, spec_len=2 * blk - 1, paged_kernel="xla",
         **geometry,
     ))(
         model, pool, logits, i32(slots, pmax), i32(slots), pred(slots),
-        i32(slots), i32(slots), i32(slots), i32(slots, blk - 1), i32(slots),
+        i32(slots), i32(slots), i32(slots), i32(slots, 2 * blk - 1),
+        i32(slots),
     )
     chunk_jaxpr = jax.make_jaxpr(make_prefill_chunk_program(
         model, chunk_len=chunk_len, **geometry,

@@ -1285,10 +1285,18 @@ class ExpertMLP:
         *,
         return_rows: bool = False,
         stacked: tp.Optional[tp.Tuple[Array, Array, tp.Any]] = None,
+        live: tp.Optional[Array] = None,  # [...] bool, one per row
     ) -> tp.Tuple[Array, ...]:
         """(y, aux), and with ``return_rows`` the rows routed to each
         expert ``[E]`` int32 (they sum to N k: nothing is dropped). ``aux``
         is Switch's load-balance loss over first choices, as MoEMLP's.
+
+        ``live`` (the block window's forward, whose rows are not all a
+        token's): a row that is not live claims no expert — its claims sort
+        past the last group and are in no group's size, so the grouped
+        matmul neither reads a matrix nor runs a tile for them — counts in
+        no ``rows`` and gets ``y`` = 0. Absent, every row is live and the
+        trace is what it was.
 
         ``stacked`` = (every layer's ``w_in`` [L, E, D, 2F], ``w_out``
         [L, E, F, D], this layer's index): the serving programs' way to
@@ -1324,6 +1332,11 @@ class ExpertMLP:
                 if self.renorm else topv
             )
             claims = topi.reshape(-1)  # [N k]: claim j is token j // k's
+            if live is not None:
+                # expert id E: past every group, dropped by the count below
+                claims = jnp.where(
+                    jnp.repeat(live.reshape(-1), k), claims, e
+                )
             order = jnp.argsort(claims, stable=True)  # sorted by expert
             rows = jnp.zeros((e,), jnp.int32).at[claims].add(1)
             first = jax.nn.one_hot(topi[:, 0], e, dtype=jnp.float32)
@@ -1349,6 +1362,9 @@ class ExpertMLP:
             y = jnp.sum(
                 yk.astype(jnp.float32) * gates[..., None], axis=1
             ).astype(x.dtype).reshape(x.shape)
+            if live is not None:
+                # (what the kernel left in rows of no group is not a number)
+                y = jnp.where(live[..., None], y, 0)
         if return_rows:
             return y, aux, rows
         return y, aux
@@ -1373,12 +1389,12 @@ def stacked_experts(model, layer) -> tp.Optional[tp.Tuple]:
 
 
 def mlp_call(mlp, x, *, key=None, deterministic=True, with_stats=False,
-             stacked=None):
+             stacked=None, live=None):
     """(y, aux) for every MLP kind — dense returns aux = 0. With
     ``with_stats``: (y, aux, dropped_frac), 0 for the kinds that drop
-    nothing."""
+    nothing. ``stacked`` and ``live`` are ExpertMLP's."""
     if isinstance(mlp, ExpertMLP):
-        y, aux = mlp(x, stacked=stacked)
+        y, aux = mlp(x, stacked=stacked, live=live)
         return (y, aux, jnp.zeros((), jnp.float32)) if with_stats else (y, aux)
     if with_stats:
         if isinstance(mlp, MoEMLP):
@@ -1526,12 +1542,14 @@ class Block:
         self, x, pool_k, pool_v, bt, layer, mask_pool, mask_self,
         sin_rows, cos_rows, start=None, pool_sk=None, pool_sv=None,
         paged_kernel="xla", block=1, expert_rows=False, experts=None,
+        live=None,
     ):
         """``block`` > 1: the rows' own mask is causal across blocks of
         that many and bidirectional inside one (``mask_self`` says the
         same to the XLA path; the kernel builds its own from ``block``).
         ``expert_rows`` (an ExpertMLP model): also the rows routed to each
-        expert here, ``[E]``. ``experts``: ExpertMLP's ``stacked``."""
+        expert here, ``[E]``. ``experts``, ``live``: ExpertMLP's
+        ``stacked`` and ``live``."""
         attn_out, k, v = self.attn.verify_paged_at(
             self.ln1(x), pool_k, pool_v, bt, layer, mask_pool, mask_self,
             sin_rows, cos_rows, start=start, pool_sk=pool_sk,
@@ -1540,10 +1558,12 @@ class Block:
         x = x + attn_out
         if expert_rows:
             y, _, rows = self.mlp(
-                self.ln2(x), return_rows=True, stacked=experts
+                self.ln2(x), return_rows=True, stacked=experts, live=live
             )
             return x + y, k, v, rows
-        x = x + mlp_call(self.mlp, self.ln2(x), stacked=experts)[0]
+        x = x + mlp_call(
+            self.mlp, self.ln2(x), stacked=experts, live=live
+        )[0]
         return x, k, v
 
 
@@ -2092,6 +2112,8 @@ def verify_tokens_paged(
     layer_scan: str = "off",
     block_len: int = 0,
     expert_rows: bool = False,
+    live: tp.Optional[Array] = None,  # [S, T] bool
+    head_block: tp.Optional[Array] = None,  # [S] int32
 ) -> tp.Tuple[Array, ...]:
     """Speculative-decoding VERIFICATION forward: score every slot's
     ``[T = spec_len + 1]`` candidate rows (the true next token + the
@@ -2119,14 +2141,23 @@ def verify_tokens_paged(
     watermark-masked page write (only accepted rows' K/V ever lands;
     rejected rows are dropped by the scatter mask — the rollback).
 
-    ``block_len`` > 0 is the BLOCK-DIFFUSION forward (denoising and
-    commit alike — serving.engine's block window): ``start`` is a
-    multiple of the block length, the T rows are whole blocks, and a row
-    sees every row of its own block and of the blocks before it — the
-    rows' own mask is the only thing that changes; logits of row j then
-    predict position ``start + j`` ITSELF. ``expert_rows`` (a model whose
-    MLP is an ExpertMLP): a fourth result, the rows routed to each expert
-    in each layer ``[L, E]`` int32."""
+    ``block_len`` > 0 is the BLOCK-DIFFUSION forward (serving.engine's
+    block window): ``start`` is a multiple of the block length, the T rows
+    are whole blocks, and a row sees every row of its own block and of the
+    blocks before it — the rows' own mask is the only thing that changes;
+    logits of row j then predict position ``start + j`` ITSELF. So the rows
+    ``[block n, its final tokens | block n+1, masked]`` are at once block
+    n's commit (its K/V as every later block will read them: it does not
+    see block n+1) and block n+1's denoising forward over the context that
+    commit leaves. ``head_block`` names, per slot, the one block of the
+    rows that the head runs on: logits are then ``[S, block_len, V]`` (the
+    head is the widest matrix there is, and a block that is only being
+    committed needs no logits). ``live``: the rows that are a token's —
+    the others (a slot with nothing to commit carries a dead second block)
+    claim no expert (``ExpertMLP``); what is computed for them is used by
+    nobody and seen by no live row, which the caller's layout has to make
+    true. ``expert_rows`` (a model whose MLP is an ExpertMLP): a fourth
+    result, the rows routed to each expert in each layer ``[L, E]`` int32."""
     cfg = model.config
     s, t = tokens.shape
     block = max(1, block_len)
@@ -2174,7 +2205,7 @@ def verify_tokens_paged(
                 sin_h, cos_h, start=start, pool_sk=sk_l, pool_sv=sv_l,
                 paged_kernel=paged_kernel, block=block,
                 expert_rows=expert_rows,
-                experts=stacked_experts(model, xs[-1]),
+                experts=stacked_experts(model, xs[-1]), live=live,
             )
             return hc, tuple(out)
 
@@ -2193,10 +2224,13 @@ def verify_tokens_paged(
                 cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
                 paged_kernel=paged_kernel, block=block,
                 expert_rows=expert_rows, experts=stacked_experts(model, i),
+                live=live,
             )
             per_layer.append(out)
         outs = tuple(jnp.stack(o) for o in zip(*per_layer))
     ks, vs = outs[:2]
+    if head_block is not None:
+        h = h.reshape(s, t // block, block, -1)[jnp.arange(s), head_block]
     h = model.ln_f(h)
     # vocab-sharded per-row logits (column-parallel head) — acceptance
     # argmaxes partition over 'tensor', no gathered [S, T, V] buffer

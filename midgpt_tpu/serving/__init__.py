@@ -20,8 +20,9 @@ Public surface:
   ``python -m midgpt_tpu.analysis --serving``).
 - :func:`~midgpt_tpu.serving.engine.make_block_window` — the window of
   a block-diffusion model (``ModelConfig.block_len``): K forwards of
-  every slot's current block, denoising or commit as its state says,
-  the reveal rule on the device (README "Block-diffusion generation").
+  every slot's current block, each with the commit of the block before
+  it where that is yet to land, the reveal rule on the device (README
+  "Block-diffusion generation").
 - :func:`~midgpt_tpu.serving.engine.make_verify_program`,
   :class:`~midgpt_tpu.serving.speculate.NgramProposer` — self-speculative
   decoding: draft-model-free n-gram drafting plus the single-dispatch

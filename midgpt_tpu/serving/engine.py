@@ -842,9 +842,9 @@ def _build_block_window(
 ):
     """The decode window of a BLOCK-DIFFUSION model (``cfg.block_len`` =
     B > 0): ONE jitted, pool-donating ``lax.scan`` over ``window``
-    forwards, each a forward of every slot's current block of B rows
-    through ``models.gpt.verify_tokens_paged`` under the block mask —
-    the verify program's forward with another mask, not another one.
+    forwards, each a forward of 2·B rows a slot through
+    ``models.gpt.verify_tokens_paged`` under the block mask — the verify
+    program's forward with another mask, not another one.
 
     A slot's unit of work is not a token. Its block state is
     (``tok`` [B] the block's tokens, the mask token where nothing is
@@ -852,30 +852,45 @@ def _build_block_window(
     random weights the argmax IS the mask token once in V tokens, and a
     comparison of ids would read that as still masked; ``at`` [B] the
     denoising step at which each position was revealed, -1 for a prompt
-    position and for one not yet revealed). Each forward is, per slot,
-    as its state says:
+    position and for one not yet revealed) and the block before it where
+    that is complete and not yet resident (``pend`` and ``pend_tok`` [B],
+    its final tokens). The published loop spends a forward of its own on
+    a complete block to write its K/V; here that COMMIT rides with the
+    next block's first denoising forward. The rows of a forward are always
+    the positions ``pooled_len + [0, 2B)``:
 
-    - a DENOISING forward (some position still masked): nothing is
-      written; at every masked position the greedy pick and its
-      confidence (``sampling.token_confidence``), and the ``B //
-      block_steps`` masked positions of highest confidence are revealed
-      (``sampling.reveal_most_confident``: ``low_confidence_static`` at
-      temperature 0). Logits of row i predict position i itself. When the
-      last position is revealed the block's generated tokens are emitted
-      (a prompt's ``P mod B`` remainder opens the first block already
-      revealed and is not emitted), up to the request's budget;
-    - a COMMIT forward (no position masked): the B final rows' K/V land
-      in the pages (``flush_recent``), the resident length grows by B and
-      a fresh all-masked block begins; its logits are not used. The block
-      that ends a request is not committed: no token follows it.
+    - with a block pending, ``[pend_tok | tok]``: under the block mask the
+      pending block's rows see the context and themselves — what a commit
+      forward computes — and land in the pages (``flush_recent``; the
+      resident length grows by B, ``pend`` clears), while the current
+      block's rows see the context, the pending block's FINAL tokens and
+      themselves: what they would see one forward after the commit;
+    - with none, ``[tok | mask tokens]``: the second half is dead rows,
+      which the first half does not see, which claim no expert and whose
+      results nobody reads.
 
-    So a block costs ``block_steps + 1`` forwards, and ``tokens`` grow by
-    whole blocks. Done and empty slots ride along masked, as in the
-    decode window. Returns the pool, the slots' state after the window,
-    and per forward what the host harvests: the blocks as they stood, the
-    forwards that completed one and how many of its tokens count, plus the
-    window's counters (forwards by kind, positions revealed, and — for an
-    ExpertMLP model — the rows routed to each expert of each layer)."""
+    Every forward of a slot in flight is a DENOISING forward of its
+    current block (a block in flight always has a masked position): the
+    head runs on those B rows only; at every masked position the greedy
+    pick and its confidence (``sampling.token_confidence``), and the ``B //
+    block_steps`` masked positions of highest confidence are revealed
+    (``sampling.reveal_most_confident``: ``low_confidence_static`` at
+    temperature 0). Logits of row i predict position i itself. When the
+    last position is revealed the block's generated tokens are emitted (a
+    prompt's ``P mod B`` remainder opens the first block already revealed
+    and is not emitted), up to the request's budget; if the request goes
+    on, the block becomes the pending one and a fresh all-masked block
+    begins. The block that ends a request is not committed: no token
+    follows it.
+
+    So a block costs ``block_steps`` forwards, and ``tokens`` grow by
+    whole blocks. Done and empty slots ride along, all their rows dead.
+    Returns the pool, the slots' state after the window, and per forward
+    what the host harvests: the blocks as they stood, the forwards that
+    completed one and how many of its tokens count, plus the window's
+    counters (slot-forwards, those that also landed a block, positions
+    revealed, and — for an ExpertMLP model — the rows routed to each
+    expert of each layer)."""
     from midgpt_tpu.parallel.sharding import axis_rules
     from midgpt_tpu.sampling import reveal_most_confident, token_confidence
 
@@ -897,6 +912,8 @@ def _build_block_window(
         tok: Array,  # [S, B] int32 — the current block
         rev: Array,  # [S, B] bool — its revealed set
         at: Array,  # [S, B] int32 — reveal step per position
+        pend: Array,  # [S] bool — the block before it is yet to land
+        pend_tok: Array,  # [S, B] int32 — that block's final tokens
     ):
         assert bt.shape == (slots, pmax), (
             f"block table {bt.shape} != declared geometry ({slots}, {pmax})"
@@ -904,15 +921,26 @@ def _build_block_window(
         with axis_rules(mesh, serving_logical_rules()):
 
             def body(carry, _):
-                pool, pooled_len, done, emitted, tok, rev, at = carry
-                commit = ~done & jnp.all(rev, axis=1)
-                denoise = ~done & ~commit
-                out = verify_tokens_paged(
-                    model, tok, pooled_len, pool.k, pool.v, bt, rope_len,
-                    paged_kernel=paged_kernel, layer_scan=layer_scan,
-                    block_len=blk, expert_rows=with_rows,
+                (pool, pooled_len, done, emitted, tok, rev, at, pend,
+                 pend_tok) = carry
+                denoise = ~done
+                land = denoise & pend
+                wide = jnp.broadcast_to(land[:, None], tok.shape)
+                rows = jnp.concatenate(
+                    (jnp.where(wide, pend_tok, tok),
+                     jnp.where(wide, tok, mask_tok)), axis=1,
+                )  # [S, 2B]
+                live = jnp.concatenate(
+                    (jnp.broadcast_to(denoise[:, None], tok.shape), wide),
+                    axis=1,
                 )
-                logits, ks, vs = out[:3]
+                out = verify_tokens_paged(
+                    model, rows, pooled_len, pool.k, pool.v, bt, rope_len,
+                    paged_kernel=paged_kernel, layer_scan=layer_scan,
+                    block_len=blk, expert_rows=with_rows, live=live,
+                    head_block=land.astype(jnp.int32),
+                )
+                logits, ks, vs = out[:3]  # logits: the current block's
                 with jax.named_scope("block_reveal"):
                     pick, conf = token_confidence(logits)  # [S, B]
                     chosen = reveal_most_confident(
@@ -942,27 +970,38 @@ def _build_block_window(
                     done = done | (
                         complete & ((emitted >= budget) | hit_eos)
                     )
-                # the commit: B rows land, a fresh block begins
-                wide = jnp.broadcast_to(commit[:, None], tok.shape)
-                pool = flush_recent(pool, ks, vs, bt, pooled_len, wide)
-                pooled_len = pooled_len + jnp.where(commit, blk, 0)
+                # the pending block's B rows land (the first half)
+                pool = flush_recent(
+                    pool, ks[:, :, :, :blk], vs[:, :, :, :blk], bt,
+                    pooled_len, wide,
+                )
+                pooled_len = pooled_len + jnp.where(land, blk, 0)
                 ys = (
-                    tok, at, complete, n_emit, denoise, commit,
+                    tok, at, complete, n_emit, denoise, land,
                     jnp.sum(chosen.astype(jnp.int32)),
                 ) + ((out[3],) if with_rows else ())
-                tok = jnp.where(wide, mask_tok, tok)
-                rev = rev & ~wide
-                at = jnp.where(wide, -1, at)
-                return (pool, pooled_len, done, emitted, tok, rev, at), ys
+                # a complete block of a request that goes on is the
+                # pending one from here, and a fresh block begins
+                move = complete & ~done
+                fresh = move[:, None]
+                pend = jnp.where(denoise, move, pend)
+                pend_tok = jnp.where(fresh, tok, pend_tok)
+                tok = jnp.where(fresh, mask_tok, tok)
+                rev = rev & ~fresh
+                at = jnp.where(fresh, -1, at)
+                return (pool, pooled_len, done, emitted, tok, rev, at, pend,
+                        pend_tok), ys
 
             carry, ys = jax.lax.scan(
-                body, (pool, pooled_len, done, emitted, tok, rev, at),
+                body,
+                (pool, pooled_len, done, emitted, tok, rev, at, pend,
+                 pend_tok),
                 None, length=window,
             )
-            toks, ats, complete, n_emit, denoise, commit, revealed = ys[:7]
+            toks, ats, complete, n_emit, denoise, land, revealed = ys[:7]
             counters = {
                 "denoise_forwards": jnp.sum(denoise.astype(jnp.int32)),
-                "commit_forwards": jnp.sum(commit.astype(jnp.int32)),
+                "commit_forwards": jnp.sum(land.astype(jnp.int32)),
                 "tokens_revealed": jnp.sum(revealed),
             }
             if with_rows:
@@ -1208,8 +1247,10 @@ _ENGINE_COUNTERS = (
     "cancelled_requests",
     "deadline_shed_requests",
     "faults_injected",
-    # block-diffusion windows (all zero otherwise): slot-forwards by kind,
-    # blocks whose K/V landed, positions revealed; and of an expert
+    # block-diffusion windows (all zero otherwise): slot-forwards (each
+    # denoises a block), those of them that also landed the block before
+    # it, blocks whose K/V landed, positions revealed, and of the 2·B rows
+    # a slot-forward runs those that were a token's; and of an expert
     # model's window forwards, per layer and forward: rows routed, rows
     # not computed (always 0: the layer is dropless), the busiest
     # expert's rows, experts with a row, and how many (layer, forward)s
@@ -1217,6 +1258,8 @@ _ENGINE_COUNTERS = (
     "commit_forwards",
     "blocks_committed",
     "tokens_revealed",
+    "block_rows_live",
+    "block_rows_run",
     "expert_rows_routed",
     "expert_rows_dropped",
     "expert_rows_max",
@@ -1478,7 +1521,8 @@ class ServingEngine:
                 pmax=pages_needed(cfg.block_size, page_size),
                 page_size=page_size, c=cfg.head_dim, itemsize=itemsize,
                 groups=cfg.n_head // cfg.kv_heads,
-                spec_t=cfg.block_len or speculate + 1,
+                # (a block model's forward carries two blocks a slot)
+                spec_t=2 * cfg.block_len or speculate + 1,
                 heads=max(1, cfg.kv_heads // tp_sz),
                 block=max(1, cfg.block_len),
             )
@@ -1666,10 +1710,10 @@ class ServingEngine:
             assert prefill_chunk is None or prefill_chunk % b == 0, (
                 f"prefill_chunk {prefill_chunk} must be whole blocks of {b}"
             )
-            # the fewest forwards a block takes is 2 (one position to
-            # reveal, then the commit), so a window commits at most this
-            # many rows a slot
-            self._grow = b * (window // 2 + 1)
+            # a block lands with the next one's first forward, and that
+            # one takes block_steps forwards, so a window commits at most
+            # this many rows a slot
+            self._grow = b * -(-window // cfg.block_steps)
         self.pool = PagedKVPool.init(
             cfg, num_pages, page_size, cache_dtype, mesh=mesh,
             kv_quant=kv_quant,
@@ -1722,13 +1766,16 @@ class ServingEngine:
         # slot_node/parent ids dangling in the index
         self.slot_pins: tp.List[tp.List[int]] = [[] for _ in range(slots)]
         # block-diffusion: each slot's current block (tokens, revealed
-        # set, reveal step per position) and, per layer and expert, the
-        # rows the window forwards routed there
+        # set, reveal step per position), the complete block before it
+        # while its K/V are yet to land (_build_block_window) and, per
+        # layer and expert, the rows the window forwards routed there
         if self.block_len:
             blk = (slots, self.block_len)
             self.blk_tok = np.full(blk, cfg.mask_token, np.int32)
             self.blk_rev = np.zeros(blk, bool)
             self.blk_at = np.full(blk, -1, np.int32)
+            self.blk_pend = np.zeros(slots, bool)
+            self.blk_pend_tok = np.full(blk, cfg.mask_token, np.int32)
             self.expert_rows = np.zeros(
                 (cfg.n_layer, max(1, cfg.experts)), np.int64
             )
@@ -2840,6 +2887,10 @@ class ServingEngine:
         self.slot_ctx[s] = []
         self.slot_registered[s] = 0
         self.slot_node[s] = PrefixIndex._ROOT
+        if self.block_len:
+            # a block still to land goes with the slot: its tokens were
+            # emitted, and a re-admission prefills them with the prompt
+            self.blk_pend[s] = False
 
     def _evict(self, s: int, park: bool = False) -> None:
         """Preempt slot ``s``: keep its progress (prompt grows by the
@@ -3167,6 +3218,8 @@ class ServingEngine:
                 jnp.asarray(self.blk_tok),
                 jnp.asarray(self.blk_rev),
                 jnp.asarray(self.blk_at),
+                jnp.asarray(self.blk_pend),
+                jnp.asarray(self.blk_pend_tok),
             )
             self.pool = carry[0]
 
@@ -3183,22 +3236,25 @@ class ServingEngine:
             committed = state[0] - self.pooled_len
             self._harvest_state(state[1], state[0], state[2])
             # np.array (copy): the scheduler writes these in place
-            self.blk_tok, self.blk_rev, self.blk_at = (
-                np.array(a) for a in state[3:6]
-            )
+            (self.blk_tok, self.blk_rev, self.blk_at, self.blk_pend,
+             self.blk_pend_tok) = (np.array(a) for a in state[3:8])
             if self.telemetry is not None:
                 hw.tokens = int(n_emit_h[:, np.asarray(decoding)].sum())
-        self.denoise_forwards += int(counters["denoise_forwards"])
-        self.commit_forwards += int(counters["commit_forwards"])
+        forwards = int(counters["denoise_forwards"])
+        landed = int(counters["commit_forwards"])
+        self.denoise_forwards += forwards
+        self.commit_forwards += landed
         self.tokens_revealed += int(counters["tokens_revealed"])
         self.blocks_committed += int(committed.sum()) // self.block_len
+        # a slot-forward runs 2·B rows: its block, and the block it lands
+        # or as many dead rows
+        live = (forwards + landed) * self.block_len
+        self.block_rows_live += live
+        self.block_rows_run += forwards * 2 * self.block_len
         if "expert_rows" in counters:
             rows = counters["expert_rows"]  # [L, E]
             cfg = self.model.config
-            claims = (
-                self.window * self.slots * self.block_len
-                * cfg.experts_per_token * cfg.n_layer
-            )
+            claims = live * cfg.experts_per_token * cfg.n_layer
             self.expert_rows += rows
             self.expert_rows_routed += int(rows.sum())
             self.expert_rows_dropped += claims - int(rows.sum())
@@ -3571,6 +3627,8 @@ class ServingEngine:
             "commit_forwards": self.commit_forwards,
             "blocks_committed": self.blocks_committed,
             "tokens_revealed": self.tokens_revealed,
+            "block_rows_live": self.block_rows_live,
+            "block_rows_run": self.block_rows_run,
             "expert_rows_routed": self.expert_rows_routed,
             "expert_rows_dropped": self.expert_rows_dropped,
             "expert_rows_max": self.expert_rows_max,

@@ -139,6 +139,8 @@ ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "commit_forwards",
     "blocks_committed",
     "tokens_revealed",
+    "block_rows_live",
+    "block_rows_run",
     "expert_rows_routed",
     "expert_rows_dropped",
     "expert_rows_max",

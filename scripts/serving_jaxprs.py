@@ -10,8 +10,9 @@ with the cell's configuration and engine settings, ``paged_kernel="pallas"``
 (what ``auto`` resolves to on a TPU), published widths, full depth (shapes
 only: no weights, nothing compiles). Writes ``<out_dir>/<program>.txt`` and
 prints a hash and a length per program: run it on ``git archive <parent>``
-and on the change, and ``diff`` the outputs, to show that a refactor left
-what the chip runs as it was."""
+and on the change (each checkout's own copy of this script: it calls the
+window with that checkout's signature), and ``diff`` the outputs, to show
+that a change left what the chip runs as it was."""
 
 import hashlib
 import json
@@ -72,7 +73,7 @@ for cell in ("serve-xl-decode", "serve-sdar-block4"):
         emit(f"{cell}.block_window",
              eng.make_block_window(model, slots=s, window=window, **geom),
              model, pool, i32(s, pmax), i32(s), flag(s), i32(s), i32(s),
-             i32(s), i32(s, b), flag(s, b), i32(s, b))
+             i32(s), i32(s, b), flag(s, b), i32(s, b), flag(s), i32(s, b))
         continue
     geom["temperature"] = kw["temperature"]
     emit(f"{cell}.decode_window",

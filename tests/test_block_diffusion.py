@@ -8,6 +8,7 @@ sampled tokens; where tokens are compared the model is float32 and the seeds
 leave no near-tie."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -98,17 +99,24 @@ def test_counters_count_blocks_not_steps(served):
     eng, reqs = served
     st = eng.stats()
     assert tuple(st) == ENGINE_STATS_KEYS
-    # a block costs one forward a position to reveal, and its commit: the
-    # block that ends a request is not committed
+    # a block costs one forward a position to reveal and no other: its
+    # commit rides with the next block's first forward, and the block that
+    # ends a request is not committed
     blocks = [-(-(p + NEW) // B) - p // B for p in PROMPT_LENS]
     revealed = [b * B - p % B for b, p in zip(blocks, PROMPT_LENS)]
     assert st["tokens_revealed"] == sum(revealed) == st["denoise_forwards"]
     assert st["commit_forwards"] == st["blocks_committed"] == sum(
         b - 1 for b in blocks)
     assert st["tokens_generated"] == NEW * len(PROMPT_LENS)
+    # of the 2 B rows a slot-forward runs, its own block's are live, and
+    # the other half where a block lands; only live rows claim experts
+    assert st["block_rows_run"] == 2 * B * st["denoise_forwards"]
+    assert st["block_rows_live"] == B * (
+        st["denoise_forwards"] + st["commit_forwards"])
+    assert 0.5 < st["block_rows_live"] / st["block_rows_run"] < 1
     assert st["expert_rows_dropped"] == 0
-    per_forward = eng.slots * B * SIZES["experts_per_token"]
-    assert st["expert_rows_routed"] == per_forward * st["expert_layer_forwards"]
+    assert st["expert_rows_routed"] == (
+        st["block_rows_live"] * SIZES["experts_per_token"] * SIZES["n_layer"])
     assert eng.expert_rows.sum() == st["expert_rows_routed"]
     assert 0 < st["experts_touched"] <= SIZES["experts"] * st[
         "expert_layer_forwards"]
@@ -133,29 +141,36 @@ def test_every_denoising_forward_and_the_committed_kv(
         model, weights, prompts, reference, forward, i):
     """One request, a window of ONE forward: before each engine step the
     slot's state goes through the program's own forward
-    (``verify_tokens_paged`` under the block mask, over the engine's pool)
-    and its logits are held to the reference's at that (block, step); after
-    the run's last commit the pool's rows are held to the K/V of ONE full
+    (``verify_tokens_paged`` under the block mask, over the engine's pool:
+    the rows the window lays out, the pending block's final tokens in front
+    of the current block where one is pending) and the current block's
+    logits are held to the reference's at that (block, step); after the
+    run's last commit the pool's rows are held to the K/V of ONE full
     reference forward under M over the final sequence."""
     eng = engine(model, slots=1, window=1)
     eng.submit(prompts[i], NEW)
     log = iter(reference[i][2])
-    fwd = jax.jit(lambda m, t, st, pk, pv, bt: verify_tokens_paged(
-        m, t, st, pk, pv, bt, CFG.block_size, block_len=B)[0])
+    fwd = jax.jit(lambda m, t, st, pk, pv, bt, hb: verify_tokens_paged(
+        m, t, st, pk, pv, bt, CFG.block_size, block_len=B, head_block=hb)[0])
     real, seen = eng._window_fn, []
 
     def window(m, pool, bt, pooled_len, done, emitted, budget, eos, tok,
-               rev, at):
-        if not bool(done[0]) and not bool(rev[0].all()):  # a denoising one
+               rev, at, pend, pend_tok):
+        if not bool(done[0]):  # every forward of a slot in flight denoises
+            assert not bool(rev[0].all())
             blk, step, want, masked, _ = next(log)
-            assert blk * B == int(pooled_len[0])
+            assert blk * B == int(pooled_len[0]) + B * int(pend[0])
             assert (masked == ~np.asarray(rev[0])).all()
-            got = fwd(m, tok, pooled_len, pool.k, pool.v, bt)[0]
+            rows = jnp.concatenate(
+                (pend_tok, tok) if bool(pend[0])
+                else (tok, jnp.full_like(tok, CFG.mask_token)), axis=1)
+            got = fwd(m, rows, pooled_len, pool.k, pool.v, bt,
+                      pend.astype(jnp.int32))[0]
             np.testing.assert_allclose(
                 np.asarray(got), want, rtol=2e-4, atol=2e-4)
             seen.append((blk, step))
         return real(m, pool, bt, pooled_len, done, emitted, budget, eos, tok,
-                    rev, at)
+                    rev, at, pend, pend_tok)
 
     eng._window_fn = window
     kv = None
@@ -176,6 +191,131 @@ def test_every_denoising_forward_and_the_committed_kv(
         np.testing.assert_allclose(
             got, np.transpose(np.asarray(want), (0, 2, 1, 3)),
             rtol=2e-4, atol=2e-4)
+
+
+# -- the fused forward: a commit and the next block's first step at once ----
+
+
+@pytest.mark.parametrize("kernel", ("xla", "pallas"))
+def test_fused_forward_is_the_commit_then_the_denoising_forward(
+        model, request, kernel):
+    """``[block n final | block n+1 masked]`` at T = 2B against the two
+    forwards of the published loop (commit at T = B, the rows flushed, then
+    the denoising forward at T = B over the longer context): the second
+    half's logits and the first half's K/V. And a dead second half (mask
+    tokens that claim no expert) leaves the first half's logits and K/V
+    what a forward of the block alone gives."""
+    from midgpt_tpu.serving import PagedKVPool
+    from midgpt_tpu.serving.paged import flush_recent
+
+    if kernel == "pallas":
+        request.getfixturevalue("pallas_interpret")
+    s, ps = 4, 16
+    pmax = CFG.block_size // ps
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    pool = PagedKVPool.init(CFG, s * pmax, ps, jnp.float32)
+    pool = dataclasses.replace(
+        pool, k=jax.random.normal(ks[0], pool.k.shape),
+        v=jax.random.normal(ks[1], pool.v.shape))
+    bt = jax.random.permutation(ks[2], s * pmax).reshape(s, pmax).astype(
+        jnp.int32)
+    start = jnp.asarray([0, 12, 32, 100], jnp.int32)
+    final = jax.random.randint(ks[3], (s, B), 0, 510, jnp.int32)
+    masked = jnp.full((s, B), CFG.mask_token, jnp.int32).at[:, 1].set(7)
+
+    @functools.partial(jax.jit, static_argnames="expert_rows")
+    def fwd(tokens, start, pool, **kw):
+        return verify_tokens_paged(
+            model, tokens, start, pool.k, pool.v, bt, CFG.block_size,
+            paged_kernel=kernel, block_len=B, **kw)
+
+    _, k1, v1 = fwd(final, start, pool)  # the commit forward
+    landed = flush_recent(pool, k1, v1, bt, start, jnp.ones((s, B), bool))
+    want, _, _ = fwd(masked, start + B, landed)
+    got, k2, v2 = fwd(
+        jnp.concatenate((final, masked), axis=1), start, pool,
+        live=jnp.ones((s, 2 * B), bool), head_block=jnp.ones((s,), jnp.int32))
+    assert got.shape == want.shape == (s, B, CFG.vocab_size)
+    tol = dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **tol)
+    for a, b in ((k2, k1), (v2, v1)):
+        np.testing.assert_allclose(
+            np.asarray(a[:, :, :, :B]), np.asarray(b), **tol)
+    # nothing to commit: [block | dead rows]
+    alone, k3, v3 = fwd(masked, start, pool)
+    half = jnp.arange(2 * B)[None, :] < B
+    got, k4, v4 = fwd(
+        jnp.concatenate((masked, jnp.full_like(masked, CFG.mask_token)), 1),
+        start, pool, live=jnp.broadcast_to(half, (s, 2 * B)),
+        head_block=jnp.zeros((s,), jnp.int32), expert_rows=True)[:3]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(alone), **tol)
+    for a, b in ((k4, k3), (v4, v3)):
+        np.testing.assert_allclose(
+            np.asarray(a[:, :, :, :B]), np.asarray(b), **tol)
+        assert np.isfinite(np.asarray(a)).all()  # dead rows: unread, finite
+
+
+def test_dead_rows_claim_no_expert(weights):
+    """``ExpertMLP(live=)``: a dead row is in no group, counts in no
+    ``rows`` and gets 0; the live rows get what they get without it."""
+    mlp = jax.tree.map(lambda a: a[0], fill_model(weights, CFG).blocks.mlp)
+    h = jnp.asarray(np.random.default_rng(4).normal(size=(3, 8, 64)),
+                    jnp.float32)
+    live = jnp.asarray(np.random.default_rng(5).random((3, 8)) < 0.6)
+    y, _, rows = mlp(h, return_rows=True, live=live)
+    want, _, every = mlp(h, return_rows=True)
+    n = int(live.sum())
+    assert 0 < n < 24 and int(rows.sum()) == n * SIZES["experts_per_token"]
+    assert int(every.sum()) == 24 * SIZES["experts_per_token"]
+    np.testing.assert_allclose(
+        np.asarray(y)[np.asarray(live)], np.asarray(want)[np.asarray(live)],
+        rtol=1e-6, atol=1e-6)
+    assert (np.asarray(y)[~np.asarray(live)] == 0).all()
+    alone, _, rows_alone = mlp(h[live], return_rows=True)
+    assert (np.asarray(rows_alone) == np.asarray(rows)).all()
+    np.testing.assert_allclose(
+        np.asarray(alone), np.asarray(y)[np.asarray(live)],
+        rtol=1e-6, atol=1e-6)
+
+
+# -- a pending block and the scheduler --------------------------------------
+
+
+@pytest.mark.parametrize("how", ("evicted", "eos", "budget"))
+def test_a_pending_block_does_not_outlive_its_slot(
+        model, prompts, reference, how):
+    """A slot evicted while its last complete block is still to land, a
+    request that EOS ends inside a block, one that its budget ends: the
+    tokens (and reveal steps) of an undisturbed run, and no pending state
+    left in any slot."""
+    toks, steps, _ = reference[2]
+    toks, steps = list(toks), list(steps)
+    eos = None
+    if how == "eos":
+        eos = int(toks[9])  # inside the third generated block
+        cut = toks.index(eos) + 1
+        toks, steps = toks[:cut], steps[:cut]
+    eng = engine(model, slots=2, window=1 if how == "evicted" else 5)
+    rid = eng.submit(prompts[2], NEW, eos_id=eos)
+    other = eng.submit(prompts[0], NEW)
+    evicted = 0
+    while eng.has_work:
+        eng.step()
+        s = next((s for s in range(eng.slots) if eng.slot_req[s] is not None
+                  and eng.slot_req[s].rid == rid), None)
+        if how == "evicted" and s is not None and eng.blk_pend[s] and (
+                evicted < 2):
+            eng._evict(s)  # the pending block's tokens are the request's
+            evicted += 1
+            assert not eng.blk_pend[s]
+    assert evicted == (2 if how == "evicted" else 0)
+    assert eng.finished[rid].tokens == toks
+    assert eng.finished[rid].reveal_steps == steps
+    assert eng.finished[other].tokens == list(reference[0][0])
+    assert not eng.blk_pend.any()
+    st = eng.stats()
+    assert st["commit_forwards"] == st["blocks_committed"]
+    assert st["expert_rows_dropped"] == 0
 
 
 def test_prefix_cache_reuses_whole_pages_only(model, prompts, reference):

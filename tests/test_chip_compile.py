@@ -153,11 +153,14 @@ def test_paged_decode_kernel_compiles_100k_token_table(one_chip):
 
 
 # the block-diffusion cell (serve-sdar-block4): 32 slots of 48 pages, 32
-# query heads over 4 KV heads of 128, blocks of 4
-BLOCK_CELL = dict(slots=32, hkv=4, g=8, t=4, c=128, pmax=48)
+# query heads over 4 KV heads of 128, blocks of 4; a forward carries two
+# blocks a slot (the block that is committed and the one being denoised)
+BLOCK_CELL = dict(slots=32, hkv=4, g=8, t=8, c=128, pmax=48, block=4)
 
 
-def _block_kernel_compiles(one_chip, pool_dt, *, slots, hkv, g, t, c, pmax):
+def _block_kernel_compiles(
+    one_chip, pool_dt, *, slots, hkv, g, t, c, pmax, block=None
+):
     from midgpt_tpu.ops.paged_attn import paged_verify_attention
 
     _, pool, rows, scales = _paged_shapes(
@@ -169,22 +172,24 @@ def _block_kernel_compiles(one_chip, pool_dt, *, slots, hkv, g, t, c, pmax):
 
     def fn(q, kc, vc, pk, pv, bt, start, *scales):
         return paged_verify_attention(
-            q, kc, vc, pk, pv, bt, start, 1, *scales, block=t
+            q, kc, vc, pk, pv, bt, start, 1, *scales, block=block or t
         )
 
     return _compiles_to_kernel(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("t", [4, 8])
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
-def test_paged_block_kernel_compiles(one_chip, pool):
+def test_paged_block_kernel_compiles(one_chip, pool, t):
     """The verify kernel under the block mask — both products on the
     matrix unit, the f32 probabilities at full precision — at the
-    benchmark cell's geometry: all four KV heads a grid step."""
+    benchmark cell's geometry: all four KV heads a grid step, at the
+    window's two blocks of rows a slot and at one."""
     from midgpt_tpu.ops.paged_attn import head_block, supported
 
     pool_dt = jnp.int8 if pool == "int8" else jnp.bfloat16
-    geo = BLOCK_CELL
-    gate = dict(groups=geo["g"], spec_t=geo["t"], block=geo["t"])
+    geo = dict(BLOCK_CELL, t=t)
+    gate = dict(groups=geo["g"], spec_t=t, block=geo["block"])
     itemsize = jnp.dtype(pool_dt).itemsize
     assert supported(geo["pmax"], PS, geo["c"], itemsize, heads=geo["hkv"],
                      **gate)
@@ -312,7 +317,8 @@ def test_block_window_compiles_without_a_pool_or_an_expert_copy(
     """The block-diffusion window at the benchmark cell's widths (32 query
     heads over 4 KV heads of 128, 128 experts of 768, two layers of it, 32
     slots of 48 pages), compiled: the verify kernel takes the block mask at
-    T = 4 and nothing re-lays its output inside the call; the pool, a carry
+    T = 8 (two blocks a slot: the one that lands and the one being
+    denoised) and nothing re-lays its output inside the call; the pool, a carry
     of the window's scan that every forward reads
     and every commit writes, is never copied; and no temporary is the size
     of a layer's expert tensors — sliced out of the layer stack in front of
@@ -363,6 +369,7 @@ def test_block_window_compiles_without_a_pool_or_an_expert_copy(
         arr((slots,), jnp.bool_), arr((slots,), jnp.int32),
         arr((slots,), jnp.int32), arr((slots,), jnp.int32),
         arr((slots, blk), jnp.int32), arr((slots, blk), jnp.bool_),
+        arr((slots, blk), jnp.int32), arr((slots,), jnp.bool_),
         arr((slots, blk), jnp.int32),
     ).compile()
     text = compiled.as_text()
@@ -375,7 +382,7 @@ def test_block_window_compiles_without_a_pool_or_an_expert_copy(
         line.strip()[:160] for line in text.splitlines()
         if re.search(r"= \S+ copy\(", line) and "paged_verify" in line
     ]
-    assert f"bf16[{slots},4,8,{blk},1,128]" not in text
+    assert f"bf16[{slots},4,8,{2 * blk},1,128]" not in text
     # two grouped matmuls a layer, the Pallas kernel (ops/grouped.py)
     assert len(re.findall(r"%gmm\S* = \S+ custom-call\(", text)) == 2 * L
     pool_shape = f"bf16[{L},{slots * pmax},{PS},512]"
