@@ -2,7 +2,7 @@
 """Does the system still start on the chip? One process, the normal entry
 points, random weights from ``--seed``, nothing measured.
 
-    python chip_smoke.py             # one TPU chip: phases 1-6
+    python chip_smoke.py             # one TPU chip: phases 1-7
     python chip_smoke.py --only 6    # phase 1 and the phases named
     python chip_smoke.py --chips 4   # four chips: the two sharded paths only
 
@@ -31,6 +31,12 @@ One chip, in order — any failed assertion ends the run non-zero:
    block mask (both of its products on the matrix unit: ops/paged_attn.py) against
    the XLA gather path (``KERNEL_TOL``), and under the
    causal mask it must NOT agree (the mask is really another).
+
+7. gated delta rule — the decode step's Pallas kernel (``ops/gated_delta``)
+   at 32 slots of 30 heads with a state of 96 x 192 (the benchmark's hybrid
+   cell), compiled, against its ``jax.numpy`` body, in place in a stack of
+   two layers; and a 256-token chunk of the chunked form against the rule
+   a token at a time.
 
 Four chips: ``openwebtext`` on an fsdp=2 x tensor=2 mesh against a
 one-device mesh (same seed, data, global batch), and a tp=2 x 2-replica
@@ -502,6 +508,58 @@ def block_forward_vs_gather(seed: int) -> None:
           f"({other}): the mask changed nothing")
     say(f"  {label}: logits agree with the XLA gather path (max rel. error "
         f"{err:.2e}; the causal kernel is {other:.2e} away)")
+
+
+def gated_delta_vs_numpy(seed: int) -> None:
+    """Phase 7: the gated delta rule's step kernel and its chunked form,
+    at the published widths, against the ``jax.numpy`` bodies."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from midgpt_tpu.ops import gated_delta as gd
+
+    s, h, dk, dv, t = 32, 30, 96, 192, 256
+    ks = jax.random.split(jax.random.PRNGKey(seed + 23), 6)
+    bf = jnp.bfloat16
+
+    def unit(a):
+        return a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+
+    q = (unit(jax.random.normal(ks[0], (s, t, h, dk))) / dk ** 0.5).astype(bf)
+    k = unit(jax.random.normal(ks[1], (s, t, h, dk))).astype(bf)
+    v = jax.random.normal(ks[2], (s, t, h, dv)).astype(bf)
+    g = -2.0 * jax.random.uniform(ks[3], (s, t, h)) ** 4  # alpha 0.14 .. 1
+    beta = 2.0 * jax.random.uniform(ks[4], (s, t, h))
+    stack = jax.random.normal(ks[5], (2, s, h, dk, dv), jnp.float32)
+
+    label = "gdn_step[32 slots, 30 heads of 96 x 192]"
+    run = jax.jit(lambda *a: gd.step(*a, 1), donate_argnums=(5,))
+    one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    check_compiled_kernels(label, run, *one, stack)
+    o_want, s_want = jax.jit(gd.step_reference)(*one, stack[1])
+    other = np.asarray(stack[0])
+    o_got, got = run(*one, stack)
+    err = max(rel_err(o_got, o_want), rel_err(got[1], s_want))
+    check(err <= 1e-5, f"{label}: rel. error vs the jax.numpy body {err}")
+    check(np.array_equal(np.asarray(got[0]), other),
+          f"{label}: the other layer's states moved")
+    say(f"  {label}: output and state agree with the jax.numpy body "
+        f"(max rel. error {err:.2e}), the other layer's rows untouched")
+
+    label = "gdn chunked[256 tokens, chunks of 64]"
+    s0 = got[1][:4]
+    args = tuple(a[:4] for a in (q, k, v, g, beta)) + (s0,)
+    o_c, s_c = jax.jit(gd.chunked)(*args)
+    with jax.default_matmul_precision("highest"):
+        o_r, s_r = jax.jit(gd.recurrent)(*args)
+    err = max(rel_err(o_c, o_r), rel_err(s_c, s_r))
+    check(np.isfinite(np.asarray(o_c)).all(), f"{label}: non-finite output")
+    check(err <= KERNEL_TOL,
+          f"{label}: rel. error vs the token-by-token rule {err} > "
+          f"{KERNEL_TOL}")
+    say(f"  {label}: output and state agree with the rule taken a token at "
+        f"a time (max rel. error {err:.2e})")
 
 
 # ---------------------------------------------------------------------------
@@ -1005,7 +1063,7 @@ def main() -> int:
     ap.add_argument(
         "--only", default="",
         help="one chip: after phase 1, only the phases numbered here "
-             "(2 and 6 stand alone; 4 needs 3, 5 needs 4), e.g. 2,6",
+             "(2, 6 and 7 stand alone; 4 needs 3, 5 needs 4), e.g. 2,6",
     )
     args = ap.parse_args()
     only = {int(n) for n in args.only.split(",") if n}
@@ -1036,6 +1094,9 @@ def main() -> int:
             if want(6):
                 with phase("6 block-diffusion forward"):
                     block_forward_vs_gather(args.seed)
+            if want(7):
+                with phase("7 gated delta rule"):
+                    gated_delta_vs_numpy(args.seed)
         else:
             cfg = smoke_config(args.workdir, args.seed, SHARDED_SET)
             with phase("sharded training: fsdp=2 x tensor=2 vs one device"):

@@ -75,9 +75,11 @@ class ModelConfig:
     # a head width that is its own number (None = n_embd // n_head): the
     # projections are then D -> H*C and H*C -> D with H*C != D
     head_width: tp.Optional[int] = None
-    qk_norm_kind: str = "layer"  # "layer" (LayerNorm) | "rms" (RMSNorm, eps 1e-6)
+    # "layer" (LayerNorm) | "rms" (RMSNorm, eps 1e-6), both per head;
+    # "rms_full": RMSNorm over the whole H*C projection, learned scale (OLMo 2)
+    qk_norm_kind: str = "layer"
     # "interleaved": pairs (2i, 2i+1) rotate (GPT-J); "half": rotate_half,
-    # the pairs are (i, i + C/2)
+    # the pairs are (i, i + C/2); "none": no rotary embedding
     rope_style: str = "interleaved"
     # the block norms and the final norm: learned scale or none; one eps for
     # all three (None = 1e-6 in the blocks, 1e-5 at the end, as midGPT)
@@ -98,6 +100,61 @@ class ModelConfig:
     block_len: int = 0
     block_steps: int = 0
     mask_token: int = -1
+    # layers of two kinds in one model: per layer "full_attention" or
+    # "linear_attention" (None = every layer full attention). A linear layer
+    # is a gated-delta-rule mixer (models/gpt.GatedDeltaNet, ops/gated_delta):
+    # ``linear_key_heads`` heads of ``linear_key_dim`` for q and k,
+    # ``linear_value_heads`` of ``linear_value_dim`` for v and the state, a
+    # causal depthwise convolution of ``linear_conv`` taps in front, beta in
+    # (0, 2) with ``linear_neg_eigval``
+    layer_types: tp.Optional[tp.Tuple[str, ...]] = None
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
+    linear_neg_eigval: bool = False
+    # "pre": x + f(norm(x)) (midGPT); "post": x + norm(f(x)), the norm on a
+    # sub-layer's output before the residual add (OLMo 2's reordered norm)
+    norm_order: str = "pre"
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)  # a JSON list: make it hashable
+            assert len(kinds) == self.n_layer, (len(kinds), self.n_layer)
+            assert set(kinds) <= {"full_attention", "linear_attention"}, kinds
+            object.__setattr__(self, "layer_types", kinds)
+        assert self.norm_order in ("pre", "post"), self.norm_order
+
+    @property
+    def layer_plan(self) -> tp.Tuple[tp.Tuple[str, int], ...]:
+        """Per layer: its kind ("full" | "linear") and its index within
+        the stack of its kind — which is also its row of the cache of that
+        kind (the KV pool's layer axis counts full layers only, the
+        recurrent state's linear ones)."""
+        kinds = self.layer_types or ("full_attention",) * self.n_layer
+        seen = {"full": 0, "linear": 0}
+        plan = []
+        for k in kinds:
+            kind = "linear" if k == "linear_attention" else "full"
+            plan.append((kind, seen[kind]))
+            seen[kind] += 1
+        return tuple(plan)
+
+    @property
+    def kv_layers(self) -> int:
+        return sum(1 for kind, _ in self.layer_plan if kind == "full")
+
+    @property
+    def linear_layers(self) -> int:
+        return self.n_layer - self.kv_layers
+
+    @property
+    def linear_channels(self) -> int:
+        """Width of a linear layer's q | k | v projection, which is what its
+        convolution runs over."""
+        return (2 * self.linear_key_heads * self.linear_key_dim
+                + self.linear_value_heads * self.linear_value_dim)
 
     @property
     def kv_heads(self) -> int:

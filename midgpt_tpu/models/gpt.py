@@ -330,6 +330,8 @@ class Attention:
     dropout_rate: float = static(default=0.0)
     ring_schedule: str = static(default="zigzag")
     rope_style: str = static(default="interleaved")
+    # the QK norm runs over the whole H*C projection, not per head
+    qk_norm_full: bool = static(default=False)
 
     @staticmethod
     def init(key: KeyArray, cfg: ModelConfig) -> "Attention":
@@ -337,12 +339,22 @@ class Attention:
         c = cfg.head_dim
         hkv = cfg.kv_heads
         qkv_out = (cfg.n_head + 2 * hkv) * c
-        assert cfg.qk_norm_kind in ("layer", "rms"), cfg.qk_norm_kind
-        assert cfg.rope_style in ("interleaved", "half"), cfg.rope_style
+        assert cfg.qk_norm_kind in ("layer", "rms", "rms_full"), (
+            cfg.qk_norm_kind
+        )
+        assert cfg.rope_style in ("interleaved", "half", "none"), (
+            cfg.rope_style
+        )
+        full = cfg.qk_norm and cfg.qk_norm_kind == "rms_full"
 
-        def head_norm():
+        def head_norm(heads):
             if not cfg.qk_norm:
                 return None
+            if full:
+                return RMSNorm.init(
+                    heads * c, use_weight=True, eps=cfg.norm_eps or 1e-6,
+                    impl="jnp",
+                )
             if cfg.qk_norm_kind == "rms":
                 return RMSNorm.init(c, use_weight=True, eps=1e-6, impl="jnp")
             return LayerNorm.init(c, eps=1e-6)
@@ -350,14 +362,32 @@ class Attention:
         return Attention(
             wqkv=Linear.init(k1, cfg.n_embd, qkv_out),
             wo=Linear.init(k2, cfg.n_head * c, cfg.n_embd),
-            q_norm=head_norm(),
-            k_norm=head_norm(),
+            q_norm=head_norm(cfg.n_head),
+            k_norm=head_norm(hkv),
             n_head=cfg.n_head,
             n_kv_head=hkv,
             dropout_rate=cfg.dropout,
             ring_schedule=cfg.ring_schedule,
             rope_style=cfg.rope_style,
+            qk_norm_full=full,
         )
+
+    def _split_qkv(self, qkv: Array) -> tp.Tuple[Array, Array, Array]:
+        """The fused projection ``[B, T, (H + 2 Hkv) C]`` as per-head q
+        ``[B, T, H, C]`` and k, v ``[B, T, Hkv, C]``, QK-normed."""
+        b, t, _ = qkv.shape
+        h, hkv = self.n_head, self.n_kv_head
+        c = self.head_dim()
+        full = self.qk_norm_full
+        q = qkv[..., : h * c]
+        q = (self.q_norm(q) if full else q).reshape(b, t, h, c)
+        k = qkv[..., h * c : (h + hkv) * c]
+        k = (self.k_norm(k) if full else k).reshape(b, t, hkv, c)
+        v = qkv[..., (h + hkv) * c :].reshape(b, t, hkv, c)
+        if self.q_norm is not None and not full:
+            q = self.q_norm(q)
+            k = self.k_norm(k)
+        return q, k, v
 
     def __call__(
         self,
@@ -386,13 +416,7 @@ class Attention:
         if self._use_fused(impl, t, deterministic) and not return_kv:
             return self._fused_call(x, sin, cos, pdrop_key, deterministic)
         with jax.named_scope("attention"):
-            qkv = self.wqkv(x)  # [B, T, (H + 2Hkv) C]
-            q = qkv[..., : h * c].reshape(b, t, h, c)
-            k = qkv[..., h * c : (h + hkv) * c].reshape(b, t, hkv, c)
-            v = qkv[..., (h + hkv) * c :].reshape(b, t, hkv, c)
-            if self.q_norm is not None:
-                q = self.q_norm(q)
-                k = self.k_norm(k)
+            q, k, v = self._split_qkv(self.wqkv(x))
             # [B, H, T, C]
             q = jnp.transpose(q, (0, 2, 1, 3))
             k = jnp.transpose(k, (0, 2, 1, 3))
@@ -571,13 +595,7 @@ class Attention:
         b, t, d = x.shape
         h, hkv = self.n_head, self.n_kv_head
         c = self.head_dim()
-        qkv = self.wqkv(x)  # [S, T, (H+2Hkv)C]
-        q = qkv[..., : h * c].reshape(b, t, h, c)
-        k = qkv[..., h * c : (h + hkv) * c].reshape(b, t, hkv, c)
-        v = qkv[..., (h + hkv) * c :].reshape(b, t, hkv, c)
-        if self.q_norm is not None:
-            q = self.q_norm(q)
-            k = self.k_norm(k)
+        q, k, v = self._split_qkv(self.wqkv(x))  # [S, T, heads, C]
         q = jnp.transpose(q, (0, 2, 1, 3))  # [S, H, T, C]
         k = jnp.transpose(k, (0, 2, 1, 3))  # [S, Hkv, T, C]
         v = jnp.transpose(v, (0, 2, 1, 3))
@@ -954,6 +972,190 @@ class Attention:
                 pool_k, pool_v, pool_sk, pool_sv, bt, layer,
             ).astype(x.dtype)  # [S, Hkv, G, T, C]
         return self._merge_heads_out(out.reshape(b, h, t, c)), k, v
+
+
+@module
+class GatedDeltaNet:
+    """The linear-attention mixer: a gated delta rule (ops/gated_delta) over
+    q, k, v that come through a short causal depthwise convolution and SiLU;
+    q and k L2-normalised per head, the rule's output RMS-normed per head
+    and gated. What a layer carries from token to token is per head a
+    ``[dk, dv]`` float32 state and the convolution's last ``taps - 1``
+    inputs (the tail), nothing that grows with the context.
+
+    Three bodies over one set of steps: :meth:`__call__` (a whole sequence
+    from an empty state), :meth:`prefill_at` (a chunk of one slot that takes
+    and returns state and tail; right-padding does not advance either) and
+    :meth:`decode_at` (one token for S slots against the engine's stacks)."""
+
+    wqkv: Linear  # [D, 2 Hk dk + Hv dv]: q | k | v, heads major
+    conv: Array  # [taps, 2 Hk dk + Hv dv]; row taps-1 meets the newest token
+    wg: Linear  # [D, Hv dv] the output gate
+    wba: Linear  # [D, 2 Hv]: beta | a
+    a_log: Array  # [Hv]
+    dt_bias: Array  # [Hv]
+    o_norm: RMSNorm  # over dv, one scale shared by the heads
+    wo: Linear  # [Hv dv, D]
+    key_heads: int = static()
+    value_heads: int = static()
+    key_dim: int = static()
+    neg_eigval: bool = static(default=False)
+
+    @staticmethod
+    def init(key: KeyArray, cfg: ModelConfig) -> "GatedDeltaNet":
+        hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+        dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+        assert hk >= 1 and hv % hk == 0 and dk >= 1 and dv >= 1, (
+            hk, hv, dk, dv
+        )
+        k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
+        ch, taps = cfg.linear_channels, cfg.linear_conv
+        # decays spread over time scales of a token to a few thousand
+        tau = jnp.exp(jax.random.uniform(
+            k6, (hv,), jnp.float32, math.log(1.5), math.log(4096.0)
+        ))
+        return GatedDeltaNet(
+            wqkv=Linear.init(k1, cfg.n_embd, ch),
+            conv=jax.random.normal(k2, (taps, ch), jnp.float32)
+            / math.sqrt(taps),
+            wg=Linear.init(k3, cfg.n_embd, hv * dv),
+            wba=Linear.init(k4, cfg.n_embd, 2 * hv),
+            a_log=jnp.zeros((hv,), jnp.float32),
+            dt_bias=jnp.log(jnp.expm1(1.0 / tau)),  # softplus^-1(1 / tau)
+            o_norm=RMSNorm.init(
+                dv, use_weight=True, eps=cfg.norm_eps or 1e-6, impl="jnp"
+            ),
+            wo=Linear.init(k5, hv * dv, cfg.n_embd),
+            key_heads=hk, value_heads=hv, key_dim=dk,
+            neg_eigval=cfg.linear_neg_eigval,
+        )
+
+    @property
+    def taps(self) -> int:
+        return self.conv.shape[0]
+
+    def _project(self, x: Array):
+        """``x`` ``[B, T, D]`` -> the convolution's input ``[B, T, Ch]``, the
+        gate ``[B, T, Hv dv]`` and, float32, ``beta`` and the log decay
+        ``g`` ``[B, T, Hv]``."""
+        f32 = jnp.float32
+        hv = self.value_heads
+        with jax.named_scope("gdn_proj"):
+            raw = self.wqkv(x)
+            gate = self.wg(x)
+            ba = self.wba(x).astype(f32)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            if self.neg_eigval:
+                beta = 2.0 * beta
+            g = -jnp.exp(self.a_log.astype(f32)) * jax.nn.softplus(
+                ba[..., hv:] + self.dt_bias.astype(f32)
+            )
+        return raw, gate, beta, g
+
+    def _conv_heads(self, window: Array):
+        """``window`` ``[B, taps - 1 + T, Ch]``: the tail, then T new inputs.
+        Convolution and SiLU in float32, then per head q (L2-normalised,
+        over sqrt(dk)), k (L2-normalised) ``[B, T, Hv, dk]`` and v
+        ``[B, T, Hv, dv]`` in the inputs' type."""
+        f32 = jnp.float32
+        hk, hv, dk = self.key_heads, self.value_heads, self.key_dim
+        b, n, ch = window.shape
+        t = n - (self.taps - 1)
+        with jax.named_scope("gdn_conv"):
+            w = self.conv.astype(f32)
+            y = sum(
+                w[j] * jax.lax.slice_in_dim(window, j, j + t, axis=1).astype(f32)
+                for j in range(self.taps)
+            )
+            y = jax.nn.silu(y)
+            q = y[..., : hk * dk].reshape(b, t, hk, dk)
+            k = y[..., hk * dk : 2 * hk * dk].reshape(b, t, hk, dk)
+            v = y[..., 2 * hk * dk :].reshape(b, t, hv, -1)
+
+            def unit(a):
+                return a * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6
+                )
+
+            q, k = unit(q) * dk ** -0.5, unit(k)
+            if hv != hk:  # value heads share key heads in groups
+                q = jnp.repeat(q, hv // hk, axis=2)
+                k = jnp.repeat(k, hv // hk, axis=2)
+        dt = window.dtype
+        return q.astype(dt), k.astype(dt), v.astype(dt)
+
+    def _out(self, o: Array, gate: Array) -> Array:
+        """The rule's output ``[B, T, Hv, dv]`` float32, normed per head and
+        gated, through ``wo``."""
+        b, t, hv, dv = o.shape
+        with jax.named_scope("gdn_out"):
+            z = jax.nn.silu(gate.astype(jnp.float32)).reshape(b, t, hv, dv)
+            y = (self.o_norm(o) * z).astype(gate.dtype)
+            return self.wo(y.reshape(b, t, hv * dv))
+
+    def prefill_at(
+        self,
+        x: Array,  # [B, T, D]
+        state: Array,  # [B, Hv, dk, dv] float32
+        tail: Array,  # [B, taps - 1, Ch]
+        real_n: tp.Optional[Array] = None,  # [] int32: rows past it are pad
+    ) -> tp.Tuple[Array, Array, Array]:
+        from midgpt_tpu.ops.gated_delta import chunked
+
+        t = x.shape[1]
+        raw, gate, beta, g = self._project(x)
+        window = jnp.concatenate([tail.astype(raw.dtype), raw], axis=1)
+        q, k, v = self._conv_heads(window)
+        keep = self.taps - 1
+        if real_n is None:
+            tail = window[:, t:]
+        else:
+            # padding advances nothing: no decay, no write, and the tail
+            # is the last inputs of the real rows
+            live = (jnp.arange(t) < real_n)[None, :, None]
+            beta, g = jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
+            tail = jax.lax.dynamic_slice_in_dim(window, real_n, keep, axis=1)
+        o, state = chunked(q, k, v, g, beta, state)
+        return self._out(o, gate), state, tail.astype(x.dtype)
+
+    def __call__(self, x: Array) -> Array:
+        b = x.shape[0]
+        ch = self.conv.shape[1]
+        dv = self.wo.weight.shape[0] // self.value_heads
+        out, _, _ = self.prefill_at(
+            x,
+            jnp.zeros((b, self.value_heads, self.key_dim, dv), jnp.float32),
+            jnp.zeros((b, self.taps - 1, ch), x.dtype),
+        )
+        return out
+
+    def decode_at(
+        self,
+        x: Array,  # [S, 1, D] — one new token per decode slot
+        states: Array,  # [Ll, S, Hv, dk, dv] float32, every linear layer's
+        tails: Array,  # [Ll, S, taps - 1, Ch]
+        layer: int,  # STATIC: this layer's row of both
+        valid: Array,  # [S] bool — a slot whose token is none leaves its
+        # state and tail as they were
+    ) -> tp.Tuple[Array, Array, Array]:
+        from midgpt_tpu.ops.gated_delta import step
+
+        raw, gate, beta, g = self._project(x)
+        tail = tails[layer]
+        window = jnp.concatenate([tail.astype(raw.dtype), raw], axis=1)
+        q, k, v = self._conv_heads(window)
+        beta = jnp.where(valid[:, None], beta[:, 0], 0.0)
+        g = jnp.where(valid[:, None], g[:, 0], 0.0)
+        o, states = step(q[:, 0], k[:, 0], v[:, 0], g, beta, states, layer)
+        new_tail = jnp.where(
+            valid[:, None, None], window[:, 1:].astype(tails.dtype), tail
+        )
+        zero = jnp.zeros((), jnp.int32)
+        tails = jax.lax.dynamic_update_slice(
+            tails, new_tail[None],
+            (jnp.asarray(layer, jnp.int32), zero, zero, zero),
+        )
+        return self._out(o[:, None], gate), states, tails
 
 
 def mlp_hidden_dim(cfg: ModelConfig) -> int:
@@ -1412,18 +1614,30 @@ def mlp_call(mlp, x, *, key=None, deterministic=True, with_stats=False,
 
 @module
 class Block:
-    """Pre-norm residual block (parity: model.py:84-105)."""
+    """Pre-norm residual block (parity: model.py:84-105). ``attn`` is the
+    block's mixer: full attention, or (``kind="linear"``) a gated delta
+    rule. ``norm_order="post"`` puts each norm on its sub-layer's OUTPUT,
+    before the residual add."""
 
-    attn: Attention
+    attn: tp.Union[Attention, GatedDeltaNet]
     mlp: tp.Union[MLP, "MoEMLP", "ExpertMLP"]
     ln1: RMSNorm
     ln2: RMSNorm
+    norm_order: str = static(default="pre")
+
+    def _pre(self, norm: RMSNorm, x: Array) -> Array:
+        return norm(x) if self.norm_order == "pre" else x
+
+    def _post(self, norm: RMSNorm, y: Array) -> Array:
+        return norm(y) if self.norm_order == "post" else y
 
     @staticmethod
-    def init(key: KeyArray, cfg: ModelConfig) -> "Block":
+    def init(key: KeyArray, cfg: ModelConfig, kind: str = "full") -> "Block":
         k1, k2 = jax.random.split(key)
+        mixer = GatedDeltaNet if kind == "linear" else Attention
         return Block(
-            attn=Attention.init(k1, cfg),
+            norm_order=cfg.norm_order,
+            attn=mixer.init(k1, cfg),
             mlp=make_mlp(k2, cfg),
             # weightless block norms (model.py:94-95, layers.py:64-68)
             # unless the config asks for the learned scale
@@ -1452,29 +1666,33 @@ class Block:
         attn_key, mlp_key = (
             jax.random.split(key) if key is not None else (None, None)
         )
-        attn_out = self.attn(
-            self.ln1(x), sin, cos, impl=impl, key=attn_key,
-            deterministic=deterministic, return_kv=return_kv,
-        )
         kv = None
-        if return_kv:
-            attn_out, kv = attn_out
-        x = x + attn_out
+        if isinstance(self.attn, GatedDeltaNet):
+            assert not return_kv, "a linear-attention layer has no K/V"
+            attn_out = self.attn(self._pre(self.ln1, x))
+        else:
+            attn_out = self.attn(
+                self._pre(self.ln1, x), sin, cos, impl=impl, key=attn_key,
+                deterministic=deterministic, return_kv=return_kv,
+            )
+            if return_kv:
+                attn_out, kv = attn_out
+        x = x + self._post(self.ln1, attn_out)
         y, aux = mlp_call(
-            self.mlp, self.ln2(x), key=mlp_key, deterministic=deterministic
+            self.mlp, self._pre(self.ln2, x), key=mlp_key,
+            deterministic=deterministic,
         )
-        x = x + y
+        x = x + self._post(self.ln2, y)
         if return_aux:
             return ((x, aux), kv) if return_kv else (x, aux)
         return (x, kv) if return_kv else x
 
     def decode_at(self, x, cache_k, cache_v, layer, slot, mask, sin_row, cos_row):
         attn_out, cache_k, cache_v = self.attn.decode_at(
-            self.ln1(x), cache_k, cache_v, layer, slot, mask, sin_row, cos_row
+            self._pre(self.ln1, x), cache_k, cache_v, layer, slot, mask,
+            sin_row, cos_row,
         )
-        x = x + attn_out
-        x = x + mlp_call(self.mlp, self.ln2(x))[0]
-        return x, cache_k, cache_v
+        return self._mlp_residual(x, attn_out), cache_k, cache_v
 
     def decode_paged_at(
         self, x, pool_k, pool_v, bt, rk, rv, layer, r, mask_pool, mask_rec,
@@ -1483,14 +1701,33 @@ class Block:
     ):
         with jax.named_scope("attention"):
             attn_out, rk, rv = self.attn.decode_paged_at(
-                self.ln1(x), pool_k, pool_v, bt, rk, rv, layer, r,
-                mask_pool, mask_rec, sin_rows, cos_rows,
+                self._pre(self.ln1, x), pool_k, pool_v, bt, rk, rv, layer,
+                r, mask_pool, mask_rec, sin_rows, cos_rows,
                 pooled_len=pooled_len, pool_sk=pool_sk, pool_sv=pool_sv,
                 paged_kernel=paged_kernel,
             )
-        x = x + attn_out
-        x = x + mlp_call(self.mlp, self.ln2(x))[0]
-        return x, rk, rv
+        return self._mlp_residual(x, attn_out), rk, rv
+
+    def _mlp_residual(self, x, mixer_out, **kw):
+        """Both residual adds behind a mixer's output."""
+        x = x + self._post(self.ln1, mixer_out)
+        y = mlp_call(self.mlp, self._pre(self.ln2, x), **kw)[0]
+        return x + self._post(self.ln2, y)
+
+    def decode_state_at(self, x, states, tails, layer, valid):
+        """A linear-attention block's decode step (GatedDeltaNet.decode_at)."""
+        out, states, tails = self.attn.decode_at(
+            self._pre(self.ln1, x), states, tails, layer, valid
+        )
+        return self._mlp_residual(x, out), states, tails
+
+    def prefill_state_at(self, x, state, tail, real_n):
+        """A linear-attention block over one slot's prefill chunk
+        (GatedDeltaNet.prefill_at)."""
+        out, state, tail = self.attn.prefill_at(
+            self._pre(self.ln1, x), state, tail, real_n
+        )
+        return self._mlp_residual(x, out), state, tail
 
     def prefill_paged_at(
         self, x, pool_k, pool_v, bt, layer, mask_pool, mask_self,
@@ -1500,13 +1737,11 @@ class Block:
         if not sp:
             with jax.named_scope("attention"):
                 attn_out, k, v = self.attn.prefill_paged_at(
-                    self.ln1(x), pool_k, pool_v, bt, layer, mask_pool,
-                    mask_self, sin_rows, cos_rows, start=start,
+                    self._pre(self.ln1, x), pool_k, pool_v, bt, layer,
+                    mask_pool, mask_self, sin_rows, cos_rows, start=start,
                     pool_sk=pool_sk, pool_sv=pool_sv,
                 )
-            x = x + attn_out
-            x = x + mlp_call(self.mlp, self.ln2(x), stacked=experts)[0]
-            return x, k, v
+            return self._mlp_residual(x, attn_out, stacked=experts), k, v
         # Sequence-parallel prefill (Megatron-SP style): the per-token
         # segments that tensor parallelism leaves REPLICATED — ln1/ln2,
         # both residual adds — run with the chunk's T rows sharded over
@@ -1523,6 +1758,7 @@ class Block:
         # is not contractually the all-reduce's (the PR 9 lse-merge
         # lesson, one level down). That is what makes sp=True bitwise
         # against sp=False by construction rather than by tolerance.
+        assert self.norm_order == "pre", self.norm_order
         x = shard_act(x, None, "sp", None)
         h1 = shard_act(self.ln1(x), None, None, None)  # gather rows
         with jax.named_scope("attention"):
@@ -1550,6 +1786,7 @@ class Block:
         ``expert_rows`` (an ExpertMLP model): also the rows routed to each
         expert here, ``[E]``. ``experts``, ``live``: ExpertMLP's
         ``stacked`` and ``live``."""
+        assert self.norm_order == "pre", self.norm_order
         attn_out, k, v = self.attn.verify_paged_at(
             self.ln1(x), pool_k, pool_v, bt, layer, mask_pool, mask_self,
             sin_rows, cos_rows, start=start, pool_sk=pool_sk,
@@ -1593,15 +1830,36 @@ class GPT:
     """The full model. ``blocks`` leaves carry a leading n_layer axis."""
 
     wte: Embedding  # [V, D]
-    blocks: Block  # stacked: every leaf [L, ...]
+    blocks: Block  # stacked: every leaf [L, ...] (the full-attention layers)
     ln_f: RMSNorm
     lm_head: tp.Optional[Linear]  # [D, V]; None when tie_embeddings
     config: ModelConfig = static()
+    # a model with ``config.layer_types``: its linear-attention layers, a
+    # stack of their own; ``config.layer_plan`` says which layer is which
+    lin_blocks: tp.Optional[Block] = None
+
+    def layer(self, kind: str, i: int) -> Block:
+        """Layer ``i`` of the stack of ``kind`` (static slices)."""
+        stack = self.lin_blocks if kind == "linear" else self.blocks
+        return jax.tree.map(lambda a: a[i], stack)
 
     @staticmethod
     def init(key: KeyArray, cfg: ModelConfig) -> "GPT":
         block_key, head_key = jax.random.split(key)
         block_keys = jax.random.split(block_key, cfg.n_layer)
+        lin_blocks = None
+        if cfg.linear_layers:
+            assert cfg.kv_layers, "a model needs a full-attention layer"
+            where = {
+                kind: jnp.asarray(
+                    [i for i, (k, _) in enumerate(cfg.layer_plan) if k == kind]
+                )
+                for kind in ("full", "linear")
+            }
+            lin_blocks = jax.vmap(lambda k: Block.init(k, cfg, "linear"))(
+                block_keys[where["linear"]]
+            )
+            block_keys = block_keys[where["full"]]
         blocks = jax.vmap(lambda k: Block.init(k, cfg))(block_keys)
         embed_std = 1 / math.sqrt(cfg.n_embd)
         wte_wt = embed_std * jax.random.normal(
@@ -1622,6 +1880,7 @@ class GPT:
             ),
             lm_head=lm_head,
             config=cfg,
+            lin_blocks=lin_blocks,
         )
 
     def hidden(
@@ -1698,9 +1957,18 @@ class GPT:
 
             unroll = cfg.scan_unroll if cfg.scan_unroll else cfg.n_layer
             carry0 = (h, jnp.zeros((), jnp.float32)) if return_aux else h
-            carry, kvs = jax.lax.scan(
-                body, carry0, (self.blocks, scan_keys), unroll=unroll
-            )
+            if self.lin_blocks is not None:
+                # layers of two kinds: no one scan body fits them, so the
+                # plan is walked layer by layer
+                assert not return_kv, "return_kv needs full attention only"
+                carry, kvs = carry0, None
+                for i, (kind, j) in enumerate(cfg.layer_plan):
+                    k = None if scan_keys is None else scan_keys[i]
+                    carry, _ = body(carry, (self.layer(kind, j), k))
+            else:
+                carry, kvs = jax.lax.scan(
+                    body, carry0, (self.blocks, scan_keys), unroll=unroll
+                )
             if return_aux:
                 # SUM over layers (Switch eq. 4 applies alpha per layer
                 # and sums) — a mean would weaken balancing pressure by
@@ -1840,6 +2108,7 @@ def decode_step(
     the block weights stream exactly once per token, and XLA fuses the
     whole layer into a handful of kernels."""
     cfg = model.config
+    assert model.lin_blocks is None, "decode_step needs full attention only"
     w = cache.k.shape[-1]
     sin_np, cos_np = rope_tables(cfg.head_dim, rope_len or w, cfg.rope_base)
     sin_t, cos_t = jnp.asarray(sin_np), jnp.asarray(cos_np)
@@ -1876,7 +2145,9 @@ def decode_step_paged(
     pool_sv: tp.Optional[Array] = None,
     paged_kernel: str = "xla",
     layer_scan: str = "off",
-) -> tp.Tuple[Array, Array, Array]:
+    state: tp.Optional[tp.Tuple[Array, Array]] = None,
+    valid: tp.Optional[Array] = None,
+) -> tp.Tuple[Array, ...]:
     """One decode step of the continuous-batching engine: every slot
     attends over its OWN block-table pages (positions < pooled_len[s])
     plus the shared recent buffer (window positions pooled_len[s]..r),
@@ -1901,7 +2172,14 @@ def decode_step_paged(
     op-for-op the per-layer trace — which the scan-equivalence prover
     (midgpt_tpu.analysis.fusion, CI serving-choreo job) proves
     statically and the bitwise on-vs-off token-identity matrix pins at
-    runtime."""
+    runtime.
+
+    A model with linear-attention layers (``config.layer_plan``) also takes
+    ``state`` — every linear layer's per-slot states ``[Ll, S, Hv, dk, dv]``
+    and convolution tails ``[Ll, S, taps - 1, Ch]`` — and ``valid`` ``[S]``
+    (a slot whose token is none leaves both as they were), and returns the
+    new ``state`` after ``rk, rv``. The pool's and the recent buffers' layer
+    axis counts the full-attention layers only."""
     cfg = model.config
     s = tokens.shape[0]
     pmax = bt.shape[1]
@@ -1936,7 +2214,9 @@ def decode_step_paged(
     h = embed_tokens(model.wte, tokens[:, None])  # [S, 1, D]
     sin_h, cos_h = sin_rows.astype(h.dtype), cos_rows.astype(h.dtype)
     assert layer_scan in ("on", "off"), layer_scan
+    assert (state is None) == (model.lin_blocks is None)
     if layer_scan == "on":
+        assert state is None, "layer_scan='on' needs identical layers"
         quant = pool_sk is not None
 
         def body(hc, xs):
@@ -1956,8 +2236,11 @@ def decode_step_paged(
             xs = xs + (pool_sk, pool_sv)
         h, (rk, rv) = jax.lax.scan(body, h, xs)
     else:
-        for i in range(cfg.n_layer):
-            block = jax.tree.map(lambda a: a[i], model.blocks)
+        for kind, i in cfg.layer_plan:
+            block = model.layer(kind, i)
+            if kind == "linear":
+                h, *state = block.decode_state_at(h, *state, i, valid)
+                continue
             h, rk, rv = block.decode_paged_at(
                 h, pool_k, pool_v, bt, rk, rv, i, r, mask_pool, mask_rec,
                 sin_h, cos_h, pooled_len=pooled_len, pool_sk=pool_sk,
@@ -1967,6 +2250,8 @@ def decode_step_paged(
     # vocab-sharded logits (TP lm head is column-parallel): nothing here
     # gathers the [S, V] row — greedy argmax partitions over 'tensor'
     logits = shard_act(model.project(h)[:, 0, :], None, "vocab")  # [S, V]
+    if state is not None:
+        return logits, rk, rv, tuple(state)
     return logits, rk, rv
 
 
@@ -1983,7 +2268,9 @@ def prefill_chunk_paged(
     layer_scan: str = "off",
     sp: bool = False,
     block_len: int = 0,
-) -> tp.Tuple[Array, Array, Array]:
+    state: tp.Optional[tp.Tuple[Array, Array]] = None,
+    real_n: tp.Optional[Array] = None,
+) -> tp.Tuple[Array, ...]:
     """Suffix-only prefill of one chunk against a pre-populated block
     table: the chunk's tokens (context positions ``start .. start+T-1``)
     attend to everything already resident in the slot's pages (positions
@@ -2012,7 +2299,13 @@ def prefill_chunk_paged(
     raw V [L, 1, Hkv, T, C] for the page write
     (serving.paged.write_token_rows). Pad rows beyond the chunk's real
     length are harmless: causally invisible to real rows (they sit at
-    LATER positions) and their K/V rows are masked out of the write."""
+    LATER positions) and their K/V rows are masked out of the write.
+
+    A model with linear-attention layers takes the slot's ``state`` as the
+    chunk finds it — states ``[Ll, 1, Hv, dk, dv]`` and convolution tails
+    ``[Ll, 1, taps - 1, Ch]`` — and ``real_n`` (pad rows advance neither),
+    and returns the state behind the chunk's last real row after ``ks,
+    vs``, which then count the full-attention layers only."""
     cfg = model.config
     b, t = tokens.shape
     assert b == 1, f"chunk prefill is per-slot, got batch {b}"
@@ -2050,7 +2343,9 @@ def prefill_chunk_paged(
         h = shard_act(h, None, "sp", None)
     sin_h, cos_h = sin_rows.astype(h.dtype), cos_rows.astype(h.dtype)
     assert layer_scan in ("on", "off"), layer_scan
+    assert (state is None) == (model.lin_blocks is None)
     if layer_scan == "on":
+        assert state is None, "layer_scan='on' needs identical layers"
         # layer loop folded into one lax.scan (see decode_step_paged):
         # read-only pool/scale planes ride as xs, the chunk's per-layer
         # K/V land as scan ys — exactly the jnp.stack of the unrolled
@@ -2075,9 +2370,16 @@ def prefill_chunk_paged(
             xs = xs + (jnp.arange(cfg.n_layer, dtype=jnp.int32),)
         h, (ks, vs) = jax.lax.scan(body, h, xs)
     else:
-        ks, vs = [], []
-        for i in range(cfg.n_layer):
-            block = jax.tree.map(lambda a: a[i], model.blocks)  # static
+        ks, vs, states, tails = [], [], [], []
+        for kind, i in cfg.layer_plan:
+            block = model.layer(kind, i)  # static slices
+            if kind == "linear":
+                h, s_i, t_i = block.prefill_state_at(
+                    h, state[0][i], state[1][i], real_n
+                )
+                states.append(s_i)
+                tails.append(t_i)
+                continue
             h, k, v = block.prefill_paged_at(
                 h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
                 cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
@@ -2094,6 +2396,8 @@ def prefill_chunk_paged(
         h = shard_act(h, None, None, None)
     ks = shard_act(ks, None, None, "kv_heads", None, None)
     vs = shard_act(vs, None, None, "kv_heads", None, None)
+    if state is not None:
+        return h, ks, vs, (jnp.stack(states), jnp.stack(tails))
     return h, ks, vs  # ks/vs: [L, 1, Hkv, T, C]
 
 
@@ -2191,6 +2495,7 @@ def verify_tokens_paged(
     h = embed_tokens(model.wte, tokens)  # [S, T, D]
     sin_h, cos_h = sin_rows.astype(h.dtype), cos_rows.astype(h.dtype)
     assert layer_scan in ("on", "off"), layer_scan
+    assert model.lin_blocks is None, "verify needs full attention only"
     if layer_scan == "on":
         # layer loop folded into one lax.scan (see decode_step_paged):
         # the candidate rows' per-layer K/V land as scan ys

@@ -215,7 +215,10 @@ def apply_rotary(
 ) -> Array:
     """Apply RoPE. ``x``: [..., T, C]; sin/cos: [T, C//2] (parity:
     layers.py:92-99). ``style``: which lanes pair up — "interleaved"
-    (2i, 2i+1), or "half" (i, i + C/2: ``rotate_half``)."""
+    (2i, 2i+1), or "half" (i, i + C/2: ``rotate_half``); "none" is no
+    rotation at all."""
+    if style == "none":  # a model without a rotary embedding
+        return x
     with jax.named_scope("rope"):
         sin = jnp.asarray(sin, dtype=x.dtype)
         cos = jnp.asarray(cos, dtype=x.dtype)

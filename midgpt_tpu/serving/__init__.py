@@ -129,6 +129,7 @@ from midgpt_tpu.serving.paged import (
     PageAllocator,
     PagedKVPool,
     PrefixIndex,
+    RecurrentState,
     copy_page,
     export_pages,
     flush_recent,
@@ -155,6 +156,7 @@ __all__ = [
     "NgramProposer",
     "PageAllocator",
     "PagedKVPool",
+    "RecurrentState",
     "PoolOverloaded",
     "PrefixIndex",
     "Proposer",

@@ -117,6 +117,7 @@ from midgpt_tpu.serving.paged import (
     PageAllocator,
     PagedKVPool,
     PrefixIndex,
+    RecurrentState,
     copy_page,
     export_pages,
     flush_recent,
@@ -271,7 +272,8 @@ def _build_decode_window(
     from midgpt_tpu.parallel.sharding import axis_rules, shard_act
     from midgpt_tpu.sampling import derive_request_key, sample_token
 
-    rshape = (cfg.n_layer, slots, cfg.kv_heads, window, cfg.head_dim)
+    rshape = (cfg.kv_layers, slots, cfg.kv_heads, window, cfg.head_dim)
+    hybrid = cfg.linear_layers > 0
 
     def window_fn(
         model: GPT,  # ENTRY PARAMETER, not a closure constant: closed
@@ -290,10 +292,14 @@ def _build_decode_window(
         eos: Array,  # [S] int32 — per-request EOS id (-1 = none)
         seeds: Array,  # [S] int32 — per-request sampling seed
         key: Array,  # base PRNG key (engine-constant)
+        state: tp.Optional[RecurrentState] = None,  # DONATED: a model
+        # with linear-attention layers carries it through the K steps as
+        # it carries the recent buffers, and returns it last
     ):
         assert bt.shape == (slots, pmax), (
             f"block table {bt.shape} != declared geometry ({slots}, {pmax})"
         )
+        assert (state is not None) == hybrid
         with axis_rules(mesh, serving_logical_rules()):
             # recent rows travel in the pool's ROW dtype: the pool dtype
             # for float pools, bf16 grid-rounded values for int8 pools
@@ -316,7 +322,7 @@ def _build_decode_window(
                 )(lg, ks)
 
             def body(carry, r):
-                logits, rk, rv, done, emitted = carry
+                logits, rk, rv, done, emitted, *st = carry
                 pre_done = done
                 tok = sample(logits, emitted)
                 tok = jnp.where(pre_done, jnp.int32(pad_id), tok)
@@ -328,24 +334,29 @@ def _build_decode_window(
                 # K/V row is only needed if a real token can follow it
                 write_valid = ~done
                 pos = pooled_len + r  # per-slot absolute position
-                new_logits, rk, rv = decode_step_paged(
+                # (linear-attention layers: a slot's state and tail move
+                # only where a real token can follow this one, so finished,
+                # empty and still-prefilling slots ride through untouched)
+                new_logits, rk, rv, *st = decode_step_paged(
                     model, tok, pos, pool.k, pool.v, bt, rk, rv, r,
                     pooled_len, rope_len, pool_sk=pool.scale_k,
                     pool_sv=pool.scale_v, paged_kernel=paged_kernel,
                     layer_scan=layer_scan,
+                    **({"state": st[0], "valid": write_valid} if st else {}),
                 )
                 # the carry is f32 regardless of compute dtype (an exact
                 # widening — sampling sees the same values either way)
                 new_logits = new_logits.astype(logits.dtype)
                 return (
-                    (new_logits, rk, rv, done, emitted),
+                    (new_logits, rk, rv, done, emitted, *st),
                     (tok, ~pre_done, write_valid),
                 )
 
-            (logits, rk, rv, done, emitted), (toks, emit, wvalid) = (
+            st = ((state.s, state.conv),) if hybrid else ()
+            (logits, rk, rv, done, emitted, *st), (toks, emit, wvalid) = (
                 jax.lax.scan(
                     body,
-                    (logits, rk, rv, done, emitted),
+                    (logits, rk, rv, done, emitted, *st),
                     jnp.arange(window, dtype=jnp.int32),
                 )
             )
@@ -357,9 +368,14 @@ def _build_decode_window(
             # (same spec the engine committed the input with — donation
             # silently drops if the output resharded)
             logits = shard_act(logits, None, "vocab")
+        if hybrid:
+            return (pool, logits, toks, emit, done, new_len, emitted,
+                    RecurrentState(*st[0]))
         return pool, logits, toks, emit, done, new_len, emitted
 
-    return jax.jit(window_fn, donate_argnums=(1, 2))
+    return jax.jit(
+        window_fn, donate_argnums=(1, 2, 11) if hybrid else (1, 2)
+    )
 
 
 def make_prefill_chunk_program(
@@ -409,13 +425,20 @@ def _build_prefill_chunk_program(
         start: Array,  # [] int32 — absolute position of chunk token 0
         real_n: Array,  # [] int32 — real tokens in this chunk
         bt_row: Array,  # [pmax] int32 — the slot's block table
+        state: tp.Optional[RecurrentState] = None,  # DONATED; a model with
+        # linear-attention layers: returned last, the slot's rows advanced
+        fresh: tp.Optional[Array] = None,  # [] bool — the request's first
+        # chunk: the slot's state and tail start from zeros
     ):
         with axis_rules(mesh, serving_logical_rules(prefill_sp)):
-            h, ks, vs = prefill_chunk_paged(
+            h, ks, vs, *new = prefill_chunk_paged(
                 model, tokens, start, pool.k, pool.v, bt_row[None, :],
                 rope_len, pool_sk=pool.scale_k, pool_sv=pool.scale_v,
                 layer_scan=layer_scan, sp=(prefill_sp == "on"),
                 block_len=cfg.block_len,
+                **({} if state is None else {
+                    "state": state.of_slot(slot, fresh), "real_n": real_n,
+                }),
             )  # h: [1, T, D]; ks/vs: [L, 1, Hkv, T, C]
             if cfg.block_len:
                 # a block-diffusion prompt's whole blocks leave K/V and
@@ -446,9 +469,13 @@ def _build_prefill_chunk_program(
             pool = write_token_rows(
                 pool, ks[:, 0], vs[:, 0], bt_row, start, real_n
             )
+        if new:
+            return pool, logits, state.with_slot(slot, *new[0])
         return pool, logits
 
-    return jax.jit(chunk_fn, donate_argnums=(1, 2))
+    return jax.jit(
+        chunk_fn, donate_argnums=(1, 2, 8) if cfg.linear_layers else (1, 2)
+    )
 
 
 def make_verify_program(
@@ -1265,6 +1292,15 @@ _ENGINE_COUNTERS = (
     "expert_rows_max",
     "experts_touched",
     "expert_layer_forwards",
+    # a model with linear-attention layers (all zero otherwise): admissions
+    # (each starts its slot's recurrent state from zeros), tokens prefilled
+    # again after an eviction because no snapshot of the state exists,
+    # admissions whose first page the prefix cache had seen (and could not
+    # serve), and slot x linear layer x decode step
+    "state_resets",
+    "state_reprefill_tokens",
+    "prefix_hits_refused",
+    "recurrent_slot_steps",
 )
 
 
@@ -1487,6 +1523,32 @@ class ServingEngine:
         # equally valid — the programs accept either form through one
         # code path (GPT.project + the block projections).
         assert quant in (None, "int8"), f"unknown quant mode {quant!r}"
+        # layers of two kinds (cfg.layer_types): the linear-attention
+        # layers' cache is a fixed-size state a slot (serving.paged
+        # .RecurrentState), beside the page pool, which then holds the
+        # full-attention layers only. Whatever would need a snapshot of that
+        # state at some earlier position, or the state sharded, is refused
+        # here, by name (ROADMAP Reach)
+        self.hybrid = model.config.linear_layers > 0
+        if self.hybrid:
+            unsupported = {
+                "temperature > 0": temperature > 0.0,
+                "speculate": bool(speculate),
+                "quant": quant is not None,
+                "kv_quant": kv_quant is not None,
+                "a mesh": mesh is not None,
+                "role != 'both'": role != "both",
+                "spill": spill == "on",
+                "prefill_sp": prefill_sp == "on",
+                "layer_scan='on'": layer_scan == "on",
+                "block_len": bool(model.config.block_len),
+            }
+            bad = [k for k, v in unsupported.items() if v]
+            if bad:
+                raise ValueError(
+                    "a model with linear-attention layers does not support "
+                    + ", ".join(bad)
+                )
         if quant is not None:
             from midgpt_tpu.quant import is_quantized, quantize_model
 
@@ -1714,6 +1776,20 @@ class ServingEngine:
             # one takes block_steps forwards, so a window commits at most
             # this many rows a slot
             self._grow = b * -(-window // cfg.block_steps)
+        if self.hybrid:
+            # prefix_cache=True is accepted and does nothing: a page hit
+            # without the recurrent state at that boundary is wrong, so the
+            # index is neither consulted nor fed. What it would have served
+            # is counted (prefix_hits_refused) from the first pages seen
+            self.index = None
+            self._first_pages: tp.Set[bytes] = set()
+        self.state = (
+            RecurrentState.init(cfg, slots, cache_dtype)
+            if self.hybrid else None
+        )
+        # a slot whose request has not prefilled a chunk yet: its next chunk
+        # starts the recurrent state from zeros
+        self.fresh = np.zeros((slots,), bool)
         self.pool = PagedKVPool.init(
             cfg, num_pages, page_size, cache_dtype, mesh=mesh,
             kv_quant=kv_quant,
@@ -2110,6 +2186,12 @@ class ServingEngine:
         still intact here and the cluster can abandon this copy and
         re-serve cold from its submission record (streams bit-identical
         by the determinism contract)."""
+        if self.hybrid:
+            raise ValueError(
+                "a model with linear-attention layers does not support "
+                "export_request: the pages would leave without the "
+                "recurrent state"
+            )
         req = self.slot_req[s]
         assert req is not None and bool(self.handoff_ready[s]), (s, req)
         if self._handoff_poison:
@@ -2158,6 +2240,11 @@ class ServingEngine:
         seed), so the stream is bit-identical to the monolithic engine —
         and a later eviction under pressure re-prefills locally through
         the ordinary (also bit-identical) eviction path."""
+        if self.hybrid:
+            raise ValueError(
+                "a model with linear-attention layers does not support "
+                "import_request: a record carries no recurrent state"
+            )
         free = [s for s in range(self.slots) if self.slot_req[s] is None]
         if not free:
             return None
@@ -2689,6 +2776,8 @@ class ServingEngine:
             self.prompt_tokens_total += p
             self.prompt_tokens_cached += matched
             req.cached_tokens += matched
+            if self.hybrid:
+                self._admit_state(s, req)
             req.admit_tokens = len(req.tokens)  # livelock-guard baseline
             now = self.clock()
             if not req.tokens and req.evictions == 0:
@@ -2707,6 +2796,31 @@ class ServingEngine:
                 # prefill (shorter than a block, or all of it cached)
                 self._open_first_block(s)
             admitted += 1
+
+    def _admit_state(self, s: int, req: Request) -> None:
+        """A model with linear-attention layers: slot ``s``'s recurrent
+        state starts over with ``req`` — at its first chunk, from zeros, at
+        no dispatch of its own — and everything before the first token is
+        prefilled again, whatever the pages may hold."""
+        self.fresh[s] = True
+        self.state_resets += 1
+        if req.evictions:
+            self.state_reprefill_tokens += int(req.prompt.size)
+        if self.prefix_cache and req.prompt.size >= self.page_size:
+            first = req.prompt[: self.page_size].tobytes()
+            if first in self._first_pages:
+                self.prefix_hits_refused += 1
+            elif len(self._first_pages) < 65536:
+                self._first_pages.add(first)
+
+    def _chunk_state(self, s: int) -> tp.Tuple:
+        """The recurrent state's two arguments of a chunk program (none
+        for a model without linear-attention layers); slot ``s`` is no
+        longer fresh behind it."""
+        if not self.hybrid:
+            return ()
+        fresh, self.fresh[s] = bool(self.fresh[s]), False
+        return self.state, jnp.asarray(fresh)
 
     # -- chunked prefill ----------------------------------------------------
 
@@ -2764,7 +2878,7 @@ class ServingEngine:
                     layer_scan=self.layer_scan,
                     prefill_sp=self.prefill_sp,
                 )
-            self.pool, self.logits = self._chunk_fns[bucket](
+            self.pool, self.logits, *st = self._chunk_fns[bucket](
                 self.model,
                 self.pool,
                 self.logits,
@@ -2779,7 +2893,10 @@ class ServingEngine:
                 # chunk's rows were then dropped and its pages, already
                 # registered as a cached prefix, never written
                 jnp.asarray(self.bt[s].copy()),
+                *self._chunk_state(s),
             )
+            if st:
+                self.state = st[0]
         self.prefill_dispatches += 1
         self.prefill_tokens_computed += clen
         if tele is not None:
@@ -3166,7 +3283,7 @@ class ServingEngine:
         ) as disp:
             (
                 self.pool, self.logits, toks, emit, done_d, new_len,
-                emitted_d,
+                emitted_d, *st,
             ) = self._window_fn(
                 self.model,
                 self.pool,
@@ -3179,7 +3296,14 @@ class ServingEngine:
                 jnp.asarray(self.eos),
                 jnp.asarray(self.seeds),
                 self._key,
+                *((self.state,) if self.hybrid else ()),
             )
+            if st:
+                self.state = st[0]
+                self.recurrent_slot_steps += (
+                    self.window * len(decoding)
+                    * self.model.config.linear_layers
+                )
 
         # ONE device->host sync per window: the stacked [K, S] outputs
         with self._harvest_span(
@@ -3470,7 +3594,7 @@ class ServingEngine:
                     layer_scan=self.layer_scan,
                     prefill_sp=self.prefill_sp,
                 )
-            self.pool, self.logits = self._chunk_fns[b](
+            self.pool, self.logits, *st = self._chunk_fns[b](
                 self.model,
                 self.pool,
                 self.logits,
@@ -3479,7 +3603,10 @@ class ServingEngine:
                 jnp.asarray(0, jnp.int32),
                 jnp.asarray(b, jnp.int32),
                 sentinel_row,
+                *self._chunk_state(0),
             )
+            if st:  # (slot 0's state is scratch too: admission resets it)
+                self.state = st[0]
         return buckets
 
     def clear_prefix_cache(self) -> int:
@@ -3489,6 +3616,8 @@ class ServingEngine:
         warmup so measured hit rates (and spill counts) come from the
         measured trace alone."""
         n = 0
+        if self.hybrid:
+            self._first_pages.clear()
         if self.index is None:
             return n
         # spilled nodes first: they hang below cold resident pages, and
@@ -3638,6 +3767,19 @@ class ServingEngine:
             # and the block-table pages of the same steps
             "kv_pages_walked": self.kv_pages_walked,
             "kv_pages_table": self.kv_pages_table,
+            # linear-attention layers (RecurrentState; zero otherwise), and
+            # what the two kinds of cache hold right now
+            "state_resets": self.state_resets,
+            "state_reprefill_tokens": self.state_reprefill_tokens,
+            "prefix_hits_refused": self.prefix_hits_refused,
+            "recurrent_slot_steps": self.recurrent_slot_steps,
+            "recurrent_state_bytes": (
+                self.state.nbytes if self.hybrid else 0
+            ),
+            "kv_bytes_live": int(self.pooled_len.sum()) * 2 * (
+                self.pool.k.shape[0] * self.pool.k.shape[-1]
+                * self.pool.k.dtype.itemsize
+            ),
         }
 
 

@@ -98,8 +98,10 @@ class PagedKVPool:
         planes (sharded with their heads)."""
         assert num_pages >= 1 and page_size >= 1, (num_pages, page_size)
         assert kv_quant in (None, "int8"), f"unknown kv_quant {kv_quant!r}"
+        # (the layer axis counts the layers that HAVE keys and values: a
+        # linear-attention layer's cache is RecurrentState's)
         shape = (
-            cfg.n_layer, num_pages, page_size, cfg.kv_heads * cfg.head_dim
+            cfg.kv_layers, num_pages, page_size, cfg.kv_heads * cfg.head_dim
         )
         if kv_quant == "int8":
             dtype = jnp.int8
@@ -110,7 +112,7 @@ class PagedKVPool:
             # scale 1.0 on unwritten pages is inert: a page's scale is
             # overwritten by its birth write before pooled_len ever
             # exposes the page to a read
-            planes = (cfg.n_layer, num_pages, cfg.kv_heads)
+            planes = (cfg.kv_layers, num_pages, cfg.kv_heads)
             scale_k = jnp.ones(planes, jnp.float32)
             scale_v = jnp.ones(planes, jnp.float32)
         if mesh is not None:
@@ -153,6 +155,62 @@ class PagedKVPool:
         values (|code| <= 127 times a po2 scale) are exact in bf16, so
         nothing is lost between the rounding and the page write."""
         return jnp.bfloat16 if self.quantized else self.k.dtype
+
+
+@module
+class RecurrentState:
+    """The cache of a model's linear-attention layers (models.gpt
+    .GatedDeltaNet), beside the page pool in the same engine: per linear
+    layer and SLOT — owned by the slot, not paged, of a fixed size whatever
+    the context — a float32 state a head and the convolution's tail. A slot's
+    rows mean something from its request's first prefill chunk on (which
+    starts from zeros: the chunk program's ``fresh`` flag) and are garbage
+    otherwise. No snapshot of it exists at any position but the newest,
+    which is what the engine refuses for such a model: a prefix-cache hit,
+    a handoff, a rollback."""
+
+    s: Array  # [Ll, S, Hv, dk, dv] float32
+    conv: Array  # [Ll, S, taps - 1, 2 Hk dk + Hv dv] the cache dtype
+
+    @staticmethod
+    def init(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16):
+        ll, hv = cfg.linear_layers, cfg.linear_value_heads
+        assert ll >= 1 and slots >= 1, (ll, slots)
+        return RecurrentState(
+            s=jnp.zeros(
+                (ll, slots, hv, cfg.linear_key_dim, cfg.linear_value_dim),
+                jnp.float32,
+            ),
+            conv=jnp.zeros(
+                (ll, slots, cfg.linear_conv - 1, cfg.linear_channels), dtype
+            ),
+        )
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.s.nbytes + self.conv.nbytes)
+
+    def of_slot(self, slot: Array, fresh: Array):
+        """Slot ``slot``'s rows ``([Ll, 1, Hv, dk, dv], [Ll, 1, taps - 1,
+        Ch])`` as a prefill chunk finds them: zeros where ``fresh`` (the
+        request's first chunk — admission costs no dispatch of its own)."""
+        s = jax.lax.dynamic_slice_in_dim(self.s, slot, 1, axis=1)
+        conv = jax.lax.dynamic_slice_in_dim(self.conv, slot, 1, axis=1)
+        return (jnp.where(fresh, jnp.zeros_like(s), s),
+                jnp.where(fresh, jnp.zeros_like(conv), conv))
+
+    def with_slot(self, slot: Array, s: Array, conv: Array):
+        """Slot ``slot``'s rows replaced (in place, where donated)."""
+        zero = jnp.zeros((), slot.dtype)
+        return RecurrentState(
+            s=jax.lax.dynamic_update_slice(
+                self.s, s.astype(self.s.dtype), (zero, slot, zero, zero, zero)
+            ),
+            conv=jax.lax.dynamic_update_slice(
+                self.conv, conv.astype(self.conv.dtype),
+                (zero, slot, zero, zero),
+            ),
+        )
 
 
 class PageAllocator:

@@ -148,6 +148,12 @@ ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "expert_layer_forwards",
     "kv_pages_walked",
     "kv_pages_table",
+    "state_resets",
+    "state_reprefill_tokens",
+    "prefix_hits_refused",
+    "recurrent_slot_steps",
+    "recurrent_state_bytes",
+    "kv_bytes_live",
 )
 
 #: ``ServingCluster.stats()`` = the summed engine inventory plus these

@@ -2,9 +2,11 @@
 
     JAX_PLATFORMS=cpu python scripts/serving_jaxprs.py <checkout> <out_dir>
 
-``str(jax.make_jaxpr(...))`` of every program of ``serve-xl-decode`` and
-``serve-sdar-block4`` (each prefill-chunk bucket, the decode or the block
-window) and of the verify program at ``speculate=4`` on ``midgpt-xl``,
+``str(jax.make_jaxpr(...))`` of every program of ``serve-xl-decode``,
+``serve-sdar-block4`` and ``serve-olmo-hybrid-decode`` (each prefill-chunk
+bucket, the decode or the block window; a cell whose configuration the
+checkout's ``ModelConfig`` cannot hold is passed over) and of the verify
+program at ``speculate=4`` on ``midgpt-xl``,
 traced from ``<checkout>`` through the engine's own ``make_*`` factories
 with the cell's configuration and engine settings, ``paged_kernel="pallas"``
 (what ``auto`` resolves to on a TPU), published widths, full depth (shapes
@@ -30,6 +32,7 @@ import jax.numpy as jnp  # noqa: E402
 from benchmark import program  # noqa: E402
 from midgpt_tpu.models import GPT  # noqa: E402
 from midgpt_tpu.serving import engine as eng  # noqa: E402
+from midgpt_tpu.serving import paged  # noqa: E402
 from midgpt_tpu.serving.paged import PagedKVPool, pages_needed  # noqa: E402
 
 PAGE = 16  # ServingEngine's page_size default; no cell sets another
@@ -46,10 +49,18 @@ def emit(name, fn, *args):
           flush=True)
 
 
-for cell in ("serve-xl-decode", "serve-sdar-block4"):
+for cell in ("serve-xl-decode", "serve-sdar-block4",
+             "serve-olmo-hybrid-decode"):
+    if not os.path.exists(f"benchmark/workloads/{cell}.json"):
+        continue
     spec = json.load(open(f"benchmark/workloads/{cell}.json"))
     sizes = json.load(open(f"benchmark/configs/{spec['config']}.json"))
-    cfg = program.model_config(sizes, spec.get("program"))
+    try:
+        cfg = program.model_config(sizes, spec.get("program"))
+    except (AssertionError, TypeError, ValueError) as e:
+        print(cell, "passed over:", repr(e)[:120], flush=True)
+        continue
+    hybrid = bool(getattr(cfg, "linear_layers", 0))
     kw = spec["engine"]
     s, window = kw["slots"], kw.get("window", 4)
     pmax = pages_needed(cfg.block_size, PAGE)
@@ -59,6 +70,9 @@ for cell in ("serve-xl-decode", "serve-sdar-block4"):
     )
     pool = jax.eval_shape(lambda: PagedKVPool.init(cfg, kw["num_pages"], PAGE))
     logits = sds((s, cfg.vocab_size), jnp.float32)
+    # a model with linear-attention layers: the recurrent state, last
+    state = ((jax.eval_shape(lambda: paged.RecurrentState.init(cfg, s)),)
+             if hybrid else ())
     geom = dict(pmax=pmax, rope_len=cfg.block_size, paged_kernel="pallas")
     # ServingEngine._prefill_bucket: pages rounded up to a power of two
     for pages in sorted({1 << (pages_needed(n, PAGE) - 1).bit_length()
@@ -67,7 +81,8 @@ for cell in ("serve-xl-decode", "serve-sdar-block4"):
         emit(f"{cell}.prefill_chunk_{t}",
              eng.make_prefill_chunk_program(
                  model, chunk_len=t, pmax=pmax, rope_len=cfg.block_size),
-             model, pool, logits, i32(), i32(1, t), i32(), i32(), i32(pmax))
+             model, pool, logits, i32(), i32(1, t), i32(), i32(), i32(pmax),
+             *state, *((flag(),) if hybrid else ()))
     if cfg.block_len:
         b = cfg.block_len
         emit(f"{cell}.block_window",
@@ -79,7 +94,9 @@ for cell in ("serve-xl-decode", "serve-sdar-block4"):
     emit(f"{cell}.decode_window",
          eng.make_decode_window(model, slots=s, window=window, **geom),
          model, pool, logits, i32(s, pmax), i32(s), flag(s), i32(s), i32(s),
-         i32(s), i32(s), sds((2,), jnp.uint32))
+         i32(s), i32(s), sds((2,), jnp.uint32), *state)
+    if hybrid:
+        continue  # no speculation without a rollback of the state
     emit(f"{cell}.verify_spec4",
          eng.make_verify_program(model, slots=s, spec_len=4, **geom),
          model, pool, logits, i32(s, pmax), i32(s), flag(s), i32(s), i32(s),

@@ -398,6 +398,144 @@ def test_block_window_compiles_without_a_pool_or_an_expert_copy(
     )
 
 
+def test_gdn_step_kernel_compiles_in_place(one_chip):
+    """The gated delta rule's decode kernel at the benchmark cell's widths
+    (32 slots, 30 heads, a state of 96 x 192 a head, twelve layers' stack):
+    Mosaic takes it, its events carry the name the benchmark's reduction
+    finds them by (benchmark/metrics/gdn_step_roofline.serve), and the
+    stack is aliased input to output, nothing of its size copied."""
+    import re
+
+    from midgpt_tpu.ops import gated_delta as gd
+
+    s, h, dk, dv, ll = 32, 30, 96, 192, 12
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def fn(q, k, v, g, beta, stack):
+        return gd._STEP_CALL(q, k, v, g, beta, stack, layer=3, interpret=False)
+
+    args = [
+        jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+        for shape, dt in (
+            ((s, h, dk), bf), ((s, h, dk), bf), ((s, h, dv), bf),
+            ((s, h), f32), ((s, h), f32), ((ll, s, h, dk, dv), f32),
+        )
+    ]
+    compiled = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(r"%gdn_step(\.\d+)? = .*custom-call\(", text)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= ll * s * h * dk * dv * 4
+    assert stats.temp_size_in_bytes < s * h * dk * dv * 4
+    assert gd.kernel_shapes_ok(h, dk, dv)
+
+
+def _hybrid_cell(one_chip, monkeypatch):
+    """A model of three gated-delta-rule layers and one full-attention
+    layer at the benchmark cell's widths (D 3840, 30 heads, V 100352; 32
+    slots of 128 pages), as described arrays: the program's arguments
+    ``(model, pool, logits, state)``, ``arr``, and ``cache_copies(text)``,
+    the compiled program's copies of the pool's or the state's shape."""
+    import re
+
+    from midgpt_tpu.config import ModelConfig
+    from midgpt_tpu.models import GPT
+    from midgpt_tpu.ops import gated_delta as gd
+    from midgpt_tpu.serving.paged import PagedKVPool, RecurrentState
+
+    # compiled for a TPU from a process whose backend is the CPU: the step
+    # takes the kernel it takes on the chip
+    monkeypatch.setattr(gd, "is_tpu_backend", lambda: True)
+    slots, pmax, pages = 32, 128, 4096
+    cfg = ModelConfig(
+        block_size=pmax * PS, vocab_size=100352, n_layer=4, n_head=30,
+        n_kv_head=30, head_width=128, n_embd=3840, mlp="swiglu",
+        mlp_hidden=11008, qk_norm_kind="rms_full", rope_style="none",
+        norm_scale=True, norm_eps=1e-6, norm_order="post",
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        linear_key_heads=30, linear_value_heads=30, linear_key_dim=96,
+        linear_value_dim=192, linear_neg_eigval=True,
+    )
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree.map(lambda a: arr(a.shape, a.dtype), tree)
+
+    model = described(jax.eval_shape(
+        lambda k: jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16), GPT.init(k, cfg)
+        ), jax.random.PRNGKey(0),
+    ))
+    pool = described(jax.eval_shape(
+        lambda: PagedKVPool.init(cfg, pages, PS, jnp.bfloat16)
+    ))
+    state = described(jax.eval_shape(
+        lambda: RecurrentState.init(cfg, slots, jnp.bfloat16)
+    ))
+    logits = arr((slots, cfg.vocab_size), jnp.float32)
+
+    def cache_copies(text):
+        shapes = (f"bf16[1,{pages},{PS},3840]", "f32[3,32,30,96,192]")
+        return [
+            line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= \S+ copy\(", line)
+            and line.split("= ", 1)[1].startswith(shapes)
+        ]
+
+    return cfg, (model, pool, logits, state), arr, cache_copies
+
+
+def test_hybrid_window_compiles_without_a_cache_copy(one_chip, monkeypatch):
+    """The decode window of :func:`_hybrid_cell`: one step-kernel call a
+    linear layer and one paged-attention call a full one in the window's
+    step, and neither the page pool nor the recurrent state — both
+    donated, both carried through the window's scan — is copied."""
+    import re
+
+    from midgpt_tpu.serving.engine import make_decode_window
+
+    cfg, (model, pool, logits, state), arr, cache_copies = _hybrid_cell(
+        one_chip, monkeypatch
+    )
+    slots, pmax = logits.shape[0], cfg.block_size // PS
+    i32 = lambda *shape: arr(shape, jnp.int32)  # noqa: E731
+    window = make_decode_window(
+        model, slots=slots, window=16, pmax=pmax, rope_len=cfg.block_size,
+        paged_kernel="pallas",
+    )
+    text = window.lower(
+        model, pool, logits, i32(slots, pmax), i32(slots),
+        arr((slots,), jnp.bool_), i32(slots), i32(slots), i32(slots),
+        i32(slots), arr((2,), jnp.uint32), state,
+    ).compile().as_text()
+    assert len(re.findall(r"%gdn_step(?:\.\d+)? = .*? custom-call\(", text)) == 3
+    assert len(re.findall(r"%closed_call\.\d+ = \S+ custom-call\(", text)) == 1
+    assert not cache_copies(text), cache_copies(text)
+
+
+def test_hybrid_chunk_compiles_without_a_cache_copy(one_chip, monkeypatch):
+    """A 256-token prefill chunk of :func:`_hybrid_cell` (the chunked rule,
+    a slot's state taken, advanced and put back): no copy of the pool or of
+    the state either."""
+    from midgpt_tpu.serving.engine import make_prefill_chunk_program
+
+    cfg, (model, pool, logits, state), arr, cache_copies = _hybrid_cell(
+        one_chip, monkeypatch
+    )
+    pmax = cfg.block_size // PS
+    i32 = lambda *shape: arr(shape, jnp.int32)  # noqa: E731
+    chunk = make_prefill_chunk_program(
+        model, chunk_len=256, pmax=pmax, rope_len=cfg.block_size,
+    )
+    text = chunk.lower(
+        model, pool, logits, i32(), i32(1, 256), i32(), i32(), i32(pmax),
+        state, arr((), jnp.bool_),
+    ).compile().as_text()
+    assert not cache_copies(text), cache_copies(text)
+
+
 def test_flash_attention_compiles_fwd_bwd(one_chip):
     from midgpt_tpu.ops.flash import flash_attention
 

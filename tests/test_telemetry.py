@@ -137,10 +137,16 @@ def test_engine_stats_key_contract(model):
         assert st[k] == snap["counters"][k]
     assert st["reject_reasons"] == snap["labeled"]["reject_reasons"]
     json.dumps(snap)
-    # the paged kernel's walk (PR 26): the last two keys, counted per
-    # decode step from the scheduler's own lengths — pages the occupied
-    # slots hold, against the pages their block tables could
-    assert ENGINE_STATS_KEYS[-2:] == ("kv_pages_walked", "kv_pages_table")
+    # the paged kernel's walk (PR 26), counted per decode step from the
+    # scheduler's own lengths — pages the occupied slots hold, against the
+    # pages their block tables could; behind it the recurrent state's keys
+    # (PR 32), zero for a model without linear-attention layers
+    assert ENGINE_STATS_KEYS[-8:-6] == ("kv_pages_walked", "kv_pages_table")
+    assert ENGINE_STATS_KEYS[-6:] == (
+        "state_resets", "state_reprefill_tokens", "prefix_hits_refused",
+        "recurrent_slot_steps", "recurrent_state_bytes", "kv_bytes_live",
+    )
+    assert not any(st[k] for k in ENGINE_STATS_KEYS[-6:-1])
     assert 0 < st["kv_pages_walked"] <= st["kv_pages_table"]
     assert st["kv_pages_table"] == (
         st["windows"] * eng.window * eng.slots * eng.bt.shape[1]
