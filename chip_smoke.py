@@ -28,7 +28,7 @@ One chip, in order — any failed assertion ends the run non-zero:
    heads over 4 KV heads of 128, half-split RoPE and QK-RMSNorm, the
    expert layer behind it (every expert chosen, so that no near-tie of the
    router widens the comparison), bf16, ragged lengths: the Pallas kernel under the
-   block mask (both of its products on the matrix unit: ops/paged_attn.py) against
+   block mask (one body with decode's: ops/paged_attn.py) against
    the XLA gather path (``KERNEL_TOL``), and under the
    causal mask it must NOT agree (the mask is really another).
 
@@ -444,6 +444,29 @@ def paged_kernels_vs_gather(seed: int) -> None:
                   f"{label}: rel. error vs the XLA gather {err} > {KERNEL_TOL}")
             say(f"  {label}: logits agree with the XLA gather path "
                 f"(max rel. error {err:.2e}, shape {tuple(got.shape)})")
+        # the twin (ops/paged_attn.py, THE CONTRACT, clause 1): a verify
+        # dispatch of ONE candidate row is a one-step decode window, to
+        # the bit, compiled, on this chip
+        rk1 = jnp.zeros((cfg.n_layer, s, cfg.kv_heads, 1, cfg.head_dim),
+                        pool.row_dtype)
+        as_decode = jax.jit(
+            lambda mod, tk, pk, pv, b_, rk_, pl_, sk, sv: decode_step_paged(
+                mod, tk, pl_, pk, pv, b_, rk_, rk_,
+                jnp.asarray(0, jnp.int32), pl_, cfg.block_size, pool_sk=sk,
+                pool_sv=sv, paged_kernel="pallas",
+            )[0]
+        )(model, tokens, pool.k, pool.v, bt, rk1, pooled_len,
+          pool.scale_k, pool.scale_v)
+        as_verify = jax.jit(verify("pallas"))(
+            model, tokens[:, None], pool.k, pool.v, bt, pooled_len,
+            pool.scale_k, pool.scale_v)[:, 0]
+        twin = np.array_equal(np.asarray(as_decode, np.float32),
+                              np.asarray(as_verify, np.float32))
+        check(twin, f"paged twin[{pool_name}]: a one-row verify is not the "
+                    f"decode step to the bit "
+                    f"(rel. error {rel_err(as_verify, as_decode)})")
+        say(f"  paged twin[{pool_name}]: one verify row == the decode step, "
+            f"to the bit")
 
 
 def block_forward_vs_gather(seed: int) -> None:

@@ -91,6 +91,15 @@ _ALIGNED_CALLS = frozenset({
     "while",
 })
 
+# jitted helpers the flattener keeps as ONE contract node, like a
+# kernel call, instead of inlining: ``ops.paged_attn.pv_limbs`` takes
+# f32 probabilities against bf16 values and returns their f32 product
+# sums — inside, the probabilities travel as three bf16 limbs whose sum
+# is the f32 probability to the bit (tests/test_paged_attn.py proves
+# it), so the bf16 operands of its one pass are NOT a rounding of the
+# probabilities, and a prover that inlined it would read them as one
+_CONTRACT_CALLS = frozenset({"pv_limbs"})
+
 _FLOAT_DTYPES = frozenset({"bfloat16", "float16", "float32", "float64"})
 
 # the arithmetic alphabet a normalized trace keeps; everything else
@@ -178,6 +187,7 @@ def flatten_jaxpr(closed) -> FlatGraph:
 
     def walk(jpr, env_) -> None:
         for eqn in jpr.eqns:
+            contract = None
             if eqn.primitive.name == "pallas_call":
                 # a Pallas kernel is ONE contract node in the outer
                 # trace: (operand dtypes, output dtypes). Its body is
@@ -187,14 +197,15 @@ def flatten_jaxpr(closed) -> FlatGraph:
                 # comparison needs is "same operands in, same dtype
                 # arithmetic inside, same dtype out".
                 kernels.append(eqn.params.get("jaxpr"))
+                contract = "paged_kernel"
+            elif (eqn.primitive.name == "jit"
+                    and eqn.params.get("name") in _CONTRACT_CALLS):
+                # a contraction helper whose exactness its own test
+                # proves (see ``_CONTRACT_CALLS``): ONE node, read by
+                # the dtypes that cross it
+                contract = eqn.params["name"]
+            if contract is not None:
                 ins = [read(env_, a) for a in eqn.invars]
-                in_d = tuple(
-                    str(getattr(a.aval, "dtype", "?")) for a in eqn.invars
-                )
-                out_d = tuple(
-                    str(getattr(v.aval, "dtype", "?"))
-                    for v in eqn.outvars
-                )
                 rec_outs = []
                 for ov in eqn.outvars:
                     vid, _ = fresh("var")
@@ -202,9 +213,15 @@ def flatten_jaxpr(closed) -> FlatGraph:
                     rec_outs.append(vid)
                 ops.append(Op(
                     idx=len(ops),
-                    prim="paged_kernel",
-                    in_dtypes=in_d,
-                    out_dtypes=out_d,
+                    prim=contract,
+                    in_dtypes=tuple(
+                        str(getattr(a.aval, "dtype", "?"))
+                        for a in eqn.invars
+                    ),
+                    out_dtypes=tuple(
+                        str(getattr(v.aval, "dtype", "?"))
+                        for v in eqn.outvars
+                    ),
                     in_ids=tuple(vid for vid, _ in ins),
                     out_ids=tuple(rec_outs),
                     in_origins=tuple(origin for _, origin in ins),
@@ -430,6 +447,21 @@ class SoftmaxSignature:
     probs_dtype: tp.FrozenSet[str]
     pv_contracts: tp.FrozenSet[tp.Tuple[str, tp.Tuple[str, ...], str]]
 
+    def arithmetic(self) -> tp.Tuple[tp.Any, ...]:
+        """The signature without WHICH UNIT forms the products: the
+        paged kernel contracts on the matrix unit (``dot``: bf16
+        operands, exact in the f32 they accumulate in), the gather path
+        it is held to as f32 multiply-sums (``mulsum``). What both must
+        share — the accumulation dtypes of the scores and of PV, the
+        mask add, the scale and its place, the softmax dtype, the
+        probabilities' dtype into PV — is this tuple."""
+        return (
+            frozenset(acc for _, _, acc in self.qk_contracts),
+            self.mask_add_dtypes, self.scale_op, self.scale_before_mask,
+            self.softmax_dtype, self.probs_dtype,
+            frozenset(acc for _, _, acc in self.pv_contracts),
+        )
+
     def describe(self) -> str:
         return (
             f"qk={sorted(self.qk_contracts)} "
@@ -451,7 +483,7 @@ def _canonical_contract(
     form) reports the MULTIPLY's operand dtypes with the reduce's output
     as the accumulation dtype. Numerically these are the same object —
     'what dtypes are the products formed at, what dtype do they sum in'."""
-    if op.prim == "dot_general":
+    if op.prim == "dot_general" or op.prim in _CONTRACT_CALLS:
         return ("dot", op.in_dtypes, op.out_dtypes[0])
     assert op.prim == "reduce_sum", op.prim
     src = graph.producer.get(op.in_ids[0])
@@ -604,7 +636,7 @@ def softmax_signature(
             hops += 1
             vid = frontier.pop()
             for c in graph.consumers.get(vid, []):
-                if c.prim == "dot_general":
+                if c.prim == "dot_general" or c.prim in _CONTRACT_CALLS:
                     pv.add(_canonical_contract(graph, c))
                     probs_dtype.add(c.in_dtypes[0])
                 elif c.prim == "mul":
@@ -633,7 +665,7 @@ def softmax_signature(
 
 
 def band_accumulation_order(
-    graph: FlatGraph, exp_op: Op
+    graph: FlatGraph, exp_op: Op, *, kernel_body: bool = False
 ) -> tp.Optional[tp.Tuple[int, ...]]:
     """The PV accumulation ORDER around one attention softmax: the
     tuple of last-dim probability-row offsets of the fold's add-tree
@@ -649,7 +681,9 @@ def band_accumulation_order(
     it straight off the jaxpr: walk forward from the normalized probs
     (the softmax's denominator ``div``) carrying the cumulative
     last-dim slice offset, mark every ``mul`` -> ``reduce_sum``
-    consumer as one PV partial at its offset, then linearize the add
+    consumer (the gather path) or matrix-unit product (the kernel: a
+    ``dot_general``, or the limb helper's contract node) as one PV
+    partial at its offset, then linearize the add
     tree that folds the partials — the left-to-right leaf sequence IS
     the summation order. The recent/self partial appears as the final
     leaf at offset W (its probability slice starts past the pool
@@ -687,23 +721,32 @@ def band_accumulation_order(
                 frontier.extend((o, noff) for o in c.out_ids)
             elif c.prim in _PASSTHRU:
                 frontier.extend((o, off) for o in c.out_ids)
-            elif c.prim == "mul":
-                for c2 in graph.consumers.get(c.out_ids[0], []):
-                    if c2.prim == "reduce_sum":
-                        # the partial, and any re-view of it on the way
-                        # to the fold (a keepdims reduce trails a
-                        # reshape): the last view is what the adds see
-                        vid = c2.out_ids[0]
-                        while vid is not None:
-                            partials[vid] = off
-                            views = [
-                                v for v in graph.consumers.get(vid, [])
-                                if v.prim in _PASSTHRU
-                            ]
-                            vid = (
-                                views[0].out_ids[0]
-                                if len(views) == 1 else None
-                            )
+            elif c.prim == "mul" or (kernel_body and (
+                    c.prim == "dot_general" or c.prim in _CONTRACT_CALLS)):
+                # one PV partial: the gather path's ``mul`` ->
+                # ``reduce_sum``, or — in a KERNEL BODY — a product on
+                # the matrix unit (a ``dot_general``, or the limb
+                # helper's node). An XLA program's einsum PV (the
+                # prefill chunk, the naive reference) is no fold
+                ends = [c] if c.prim != "mul" else [
+                    c2 for c2 in graph.consumers.get(c.out_ids[0], [])
+                    if c2.prim == "reduce_sum"
+                ]
+                for end in ends:
+                    # the partial, and any re-view of it on the way
+                    # to the fold (a keepdims reduce trails a
+                    # reshape): the last view is what the adds see
+                    vid = end.out_ids[0]
+                    while vid is not None:
+                        partials[vid] = off
+                        views = [
+                            v for v in graph.consumers.get(vid, [])
+                            if v.prim in _PASSTHRU
+                        ]
+                        vid = (
+                            views[0].out_ids[0]
+                            if len(views) == 1 else None
+                        )
     if len(set(partials.values())) < 2:
         return None
     # find the fold's root by climbing add-consumers from one partial
@@ -779,9 +822,12 @@ def kernel_choreography(name: str, kernel_jaxpr) -> SoftmaxSignature:
     """The softmax-core signature of a Pallas kernel BODY: the body is
     ordinary jnp arithmetic over refs, so the very same extractor that
     reads the XLA programs reads it — which is the point: the kernel's
-    contract (f32 score accumulation, mask before scale, f32 softmax,
-    f32 probs through PV) is proven by the same machinery that proved
-    the program it replaces, not by a parallel hand-written checklist."""
+    contract (f32 score accumulation — the products' ``dot_general``
+    names it as ``preferred_element_type`` —, mask before scale, one
+    joint f32 softmax, f32 probs INTO the PV product: the limb helper
+    is a contract node, ``_CONTRACT_CALLS``) is proven by the same
+    machinery that proved the program it replaces, not by a parallel
+    hand-written checklist."""
     graph = flatten_jaxpr(kernel_jaxpr)
     exps = [
         op for op in graph.ops
@@ -864,7 +910,8 @@ def extract_choreography(name: str, closed_jaxpr) -> ProgramChoreography:
             if op.prim == "exp" and op.out_dtypes[0] in _FLOAT_DTYPES
         ]
         band_order = (
-            band_accumulation_order(kgraph, kexps[0]) if kexps else None
+            band_accumulation_order(kgraph, kexps[0], kernel_body=True)
+            if kexps else None
         )
     else:
         exps = [

@@ -177,12 +177,15 @@ def _gather_attend(
     shared by the decode window and the verify program: the slots' pages
     gathered through ``bt`` (:func:`_gathered_pool_view`), scores as f32
     broadcast-multiply + reduce (q upcast first, cache upcast first, sum
-    over C — a ``[1, C] x [C, W]`` matvec uses one MXU row per pass, the
-    VPU form streams the cache at full rate), mask added before the
-    in-softmax ``/ sqrt(c)``, ONE joint f32 softmax over [pool | own rows]
-    (exact, not an approximation), f32 probs through both P·V sums. The
-    Pallas kernel (ops.paged_attn) mirrors this op sequence and is held to
-    it bitwise. Returns f32 ``[S, Hkv, G, T, C]``."""
+    over C: what XLA fuses over the gathered view, and what CPUs and the
+    geometries the kernel refuses run), mask added before the in-softmax
+    ``/ sqrt(c)``, ONE joint f32 softmax over [pool | own rows] (exact,
+    not an approximation), f32 probs through both P·V sums. The Pallas
+    kernel (ops.paged_attn) forms the same sums on the matrix unit — on
+    the chip the multiply-sum form, in a kernel, streamed the cache at a
+    quarter of the rate (PERF.md section 6, PR 33) — and is held to this
+    core at a tolerance (its module docstring, THE CONTRACT). Returns f32
+    ``[S, Hkv, G, T, C]``."""
     from midgpt_tpu.ops.paged_attn import banded_fold, resolved_band_pages
 
     hkv, c = qg.shape[1], qg.shape[-1]
@@ -214,8 +217,9 @@ def _gather_attend(
     # P·V over the pool in the banded kernel's pinned ascending-band
     # order (ops.paged_attn.banded_fold, same band plan): f32 addition is
     # not associative, so matching the kernel's chunked reduction order
-    # IS what keeps kernel == XLA bitwise at long contexts. One band
-    # (every small geometry) is the single unsliced reduce.
+    # is what keeps kernel and XLA within their tolerance at long
+    # contexts. One band (every small geometry) is the single unsliced
+    # reduce.
     bw = resolved_band_pages(
         bt.shape[1], ps, c, jnp.dtype(pool_k.dtype).itemsize
     ) * ps
@@ -722,8 +726,8 @@ class Attention:
         with the ragged Pallas kernel (ops.paged_attn): the block table
         is walked IN-KERNEL over each slot's ``pooled_len``, LIVE pages
         stream from HBM exactly once and dead ones not at all, and no
-        ``[S, Pmax*PS, ...]`` gathered intermediate exists — BITWISE the
-        same result (the kernel mirrors the core's op sequence; tested). An int8 pool
+        ``[S, Pmax*PS, ...]`` gathered intermediate exists — the same
+        sums on the matrix unit (held to the core at a tolerance; tested). An int8 pool
         (``pool_sk``/``pool_sv`` given) dequantizes per (page, KV-head)
         po2 scale — in-kernel on the kernel path, at the gathered view
         here — and this step's K/V row is rounded through its target
@@ -778,7 +782,7 @@ class Attention:
         rkl, rvl = rk[layer], rv[layer]  # [S, Hkv, R, C]
         if paged_kernel == "pallas":
             # the ragged in-kernel block-table walk (ops.paged_attn):
-            # bitwise the core's arithmetic, none of its HBM gather
+            # the core's sums on the matrix unit, none of its HBM gather
             qs = shard_act(
                 q.reshape(b, hkv, h // hkv, c), None, "kv_heads", None, None
             )
@@ -953,10 +957,8 @@ class Attention:
         vc = v.astype(row_dt)
         if paged_kernel == "pallas":
             # the ragged in-kernel block-table walk (ops.paged_attn):
-            # bitwise the core's arithmetic, none of its HBM gather
-            # (under the block mask, ``block`` > 1: the core's sums in
-            # another order, on the matrix unit — no decode window exists
-            # for a block-diffusion model's forward to be bitwise with)
+            # the core's sums on the matrix unit, none of its HBM gather;
+            # one body with the decode window's, whatever ``block``
             qg = shard_act(qg, None, "kv_heads", None, None, None)
             out = _paged_kernel_dispatch(
                 "verify", layer,

@@ -29,9 +29,9 @@ how many heads a grid step serves, ``band_pages`` the band; both come
 from shapes alone, and ``supported`` says whether the estimate of what
 a step then holds (``vmem_bytes``: O(band) + the O(W) f32 score rows)
 fits. ``tests/test_chip_compile.py`` compiles both kernels for a
-described v5e at two geometries, both pool precisions and a 100k-token
-table, and the block forward's contraction (below) at the
-block-diffusion cell's.
+described v5e at the serving cells' geometries, both pool precisions
+and a 100k-token table, and the block forward at the block-diffusion
+cell's.
 
 BANDS: the page walk is fetched AND computed in ascending PAGE BANDS of
 ``band_pages`` pages — the narrowest divisor of Pmax of at least
@@ -44,87 +44,78 @@ zero, so the skip moves no bit). Within the last live band the pages
 past the length are ZEROED in VMEM, never left as found: VMEM nobody
 wrote may hold NaN, and NaN passes the additive -inf mask and
 ``0 x NaN`` passes the PV sum. Because f32 addition is not associative
-the plan is part of the numerical contract below: the XLA reference
-folds by the same plan (``resolved_band_pages`` is shared).
+the plan is part of the numerical contract below (clause 4).
 
-EXACTNESS CONTRACT (the reason this kernel looks the way it does). WHOM
-IT BINDS: the decode program and the causal verify program
-(``block`` = 1) — bitwise with the XLA gather path and, op for op, with
-each other, because speculation's acceptance compares the verify
-logits' argmax with what the decode window would have sampled. WHOM IT
-DOES NOT: the block-diffusion forward (``block`` > 1), see THE BLOCK
-FORWARD'S CONTRACTION below. The
-serving suite's landing gate is greedy token-identity against the XLA
-path, and the repo has twice shipped attention variants that drifted by
-~2 bf16 ulps and flipped near-tied greedy argmaxes on real checkpoints
-(PR 4/PR 5, see analysis.choreo). A classic flash-style online-softmax
+ONE CONTRACTION (PR 33). Whatever the mask kind — a decode step
+(``decode=True``), causal speculative verify (``block`` = 1), a
+block-diffusion forward (``block`` > 1) — both products of a KV head's
+``G*T`` query rows run on the MATRIX UNIT, over one page walk:
+
+  pass 1 (K): the rows ``[G*T, C]`` against a K band ``[BW, C]`` AS IT
+    LIES in the fetch buffer, contracted over C in NT form: nothing is
+    turned over in VMEM, and the score row comes out with time on the
+    lane axis — dense ``[rows, W + R]`` f32 tiles. The bands' masked
+    scores and the rows' own concatenate into the one full-context row;
+  ONE flat joint f32 softmax over ``[pool | own rows]``, the additive
+    mask before the in-softmax ``/ sqrt(c)``. The mask kind selects
+    WHAT a row sees (``cols // block <= (r | row) // block``), never
+    how it is summed;
+  pass 2 (V): the f32 probabilities against each V band (``_pv``: never
+    rounded to bf16 — over a bf16 V they go in as three exact bf16
+    limbs, one pass), the band partials folded in PINNED ASCENDING-BAND
+    ORDER (``banded_fold``), the own rows' partial added after, one
+    rounding at the end.
+
+An MHA decode head has ONE query row: the row block is padded to a
+sublane tile (8 rows) in the kernel and the padding never written out;
+a zero row scores 0 everywhere — a uniform softmax, never a NaN. (Up to
+PR 32 decode and causal verify ran a VPU body: per head, band and pass
+a ``[128, 128]`` transpose of the K band or a lane-to-sublane turn of
+the probability slice, an upcast of the band to 16 f32 vregs and 16
+multiply-adds, for one query row: ~160 ns a band where HBM delivers
+the band in 40 ns, 19-24 % of a roofline in both decode cells. PERF.md
+section 6, PR 33, has the table that separated the band body from the
+grid step.)
+
+THE CONTRACT (what holds this kernel, in place of "bitwise with the
+gather path", an equality of the INTERPRETED kernel on a CPU that the
+chip never had — there the compiled kernel and the XLA program order
+their reductions as their compilers choose):
+
+  1. TWIN, TO THE BIT: a causal-verify row and the decode row of the
+     same token are one body and are bit-identical, in interpret mode
+     and on the chip — rows of one product do not see each other, and
+     the 8-row padding makes both the same shape class. Speculation's
+     acceptance compares the verify logits' argmax with what the decode
+     window would have sampled, and rests on this
+     (tests/test_paged_attn.py ``test_verify_one_row_is_decode``,
+     ``test_no_mask_kind_selects_arithmetic``).
+  2. GATHER PATH, AT A TOLERANCE: ``rtol=1e-5`` over an f32 pool,
+     within one bf16 ulp of the output over a bf16 or int8 pool — for
+     decode, causal verify and the block forward alike. Products of
+     bf16 values are exact in f32 and accumulate in f32 on either unit:
+     this is the gather path's sum in another order, not a lower
+     precision, and the tolerance still catches the bug class it guards
+     against (a bf16 accumulation moves a logit by 1e-3; the repo has
+     twice shipped attention variants that drifted by ~2 bf16 ulps and
+     flipped near-tied greedy argmaxes on real checkpoints — PR 4/PR 5,
+     see analysis.choreo). On the chip chip_smoke.py holds the two to a
+     stated tolerance, and ``benchmark/reference*.py`` the cells.
+  3. STREAMS: the engine-level greedy token-identity matrices (kernel
+     against gather path, int8 stream invariance, eviction, prefix hit,
+     tp2 / tp4) stay token-for-token.
+  4. FOLD ORDER: ascending bands through ``banded_fold`` on both the
+     kernel and the XLA reference (``resolved_band_pages`` is shared),
+     machine-checked: analysis.choreo's banded-accumulation-order clause
+     extracts the fold's add-tree leaf order from the jaxpr and fails if
+     any band lands out of ascending order.
+
+WHY THE SOFTMAX IS FLAT: a classic flash-style online-softmax
 accumulator — running max with ``exp(m_old - m_new)`` rescales folded
-into the accumulator — can NEVER be bitwise against the XLA joint
-softmax: the rescale multiplies are extra roundings. So the f32 score
-row for the FULL context stays resident and normalization is ONE flat
-f32 softmax. Concretely the kernel makes two passes over the bands:
-
-  pass 1 (K): each band's scores are per-position sums over C —
-    banding is invisible to them bitwise — concatenated with the
-    recent/self scores into the one full score row, then the single
-    joint softmax. The row keeps time on the lane axis (W/128 vregs,
-    not the W/8 of a column), so each live K band is turned over once
-    in VMEM, [BW, C] -> [C, BW]: a transpose rounds nothing;
-  pass 2 (V): each band's PV partial summed over its band width,
-    folded in PINNED ASCENDING-BAND ORDER (``banded_fold``). The fold
-    order is the ONE place banding touches f32 summation order, so
-    the XLA reference path runs the IDENTICAL chunked reduction
-    (models.gpt banded PV fold, same ``banded_fold``, same band plan)
-    and the interpreted kernel is BITWISE equal to the XLA path on the
-    CPU (asserted by tests/test_paged_attn.py down to the f32 pattern)
-    across decode + verify, MHA + GQA, ragged lengths, both pool
-    precisions, and the greedy/sampled token-identity matrix. The
-    accumulation order is machine-checked: analysis.choreo's
-    banded-accumulation-order clause extracts the fold's add-tree leaf
-    order from the jaxpr and fails if any band lands out of ascending
-    order. On the chip the compiled kernel and the XLA program order
-    their reductions as their compilers choose; chip_smoke.py holds
-    them to a stated tolerance there.
-
-THE BLOCK FORWARD'S CONTRACTION (``block`` > 1, PR 29). A
-block-diffusion model's forward is the verify program under another
-mask: T = ``block_len`` rows a slot that all see each other, times G
-query heads a KV head — 32 rows a KV head at the benchmark's cell. It
-has NO DECODE TWIN: such a model has no token-at-a-time window
-(``ServingEngine`` refuses ``speculate`` with ``block_len``), so there
-is nothing for its attention to be bitwise with, and the VPU form —
-``[G, T, C, 1] * [C, BW]`` f32 multiply-sums, 32 x 128 x 128 of them a
-band a head, twice — spent 47 % of that cell's device time at 1.3 % of
-a roofline. Under the block mask both products run on the MATRIX UNIT
-instead: the ``hb`` heads' queries as ``[G*T, C]`` rows against a K band
-``[BW, C]`` (contracted over C in NT form: no band turned over), and
-the f32 probability rows ``[G*T, BW]`` against the V band. The rest is
-the contract above, unchanged in kind: bf16 operands multiply exactly
-in f32 (this is the VPU form's sum in another order, not a lower
-precision; an int8 pool's dequantized band is exact in bf16), scores
-accumulate in f32, the additive mask comes before the in-softmax scale,
-ONE flat f32 softmax over the resident ``[G*T, W + T]`` row (dense
-tiles now, where ``[G, T, 1, W]`` padded its unit sublane 8x), f32
-probabilities INTO the PV product (``Precision.HIGHEST``; rounding them
-to bf16 would be another result), band partials folded in ascending
-order and the rows' own partial added after, one rounding at the end.
-The output leaves as ``[hb, G*T, C]``, whole tiles, so the caller's
-reshape is free. What holds this path is a tolerance, not the bit: the
-gather path at ``rtol=1e-5`` over an f32 pool and within one bf16 ulp
-of the output over a bf16 or int8 pool (tests/test_paged_attn.py,
-tests/test_block_diffusion.py), and ``benchmark/reference_block.py`` at
-the cell's limits on the chip.
-
-WHY THE SWITCH IS THE MASK AND NOT THE ROW COUNT: speculative verify on
-a grouped-query model reaches the same 32 rows (G 8 x ``speculate + 1``
-= 4) and DOES have a decode twin, whose token identity with speculation
-off is a tested contract. Bit-identity with a VPU program and
-throughput at 32 rows cannot both be had from one contraction, so there
-are two behind one walk, and the kernel picks by the one thing in its
-input that says which need it serves: the mask kind (``block``, a
-static argument; ``verify_contraction``). ``decode=True`` and
-``block == 1`` keep the VPU body op for op. No knob, no environment
-variable.
+into the accumulator — adds roundings the gather path's joint softmax
+does not have, and two rows of one token under two column counts would
+no longer be twins. So the f32 score row for the FULL context stays
+resident and normalization is ONE flat f32 softmax.
 
 INT8 KV (``scale_k``/``scale_v`` given): the pool payload is int8 with
 one f32 power-of-two scale per (page, KV-head) plane
@@ -137,14 +128,15 @@ int8 pool behaves like a bf16 pool whose values happen to lie on the
 page grid, and the greedy token streams stay invariant across every
 engine feature combination (unit-tested at the page level).
 
-Dtype choreography of the decode and causal-verify body
-(machine-checked: analysis.choreo extracts the
-kernel body's softmax signature and proves it equal to the decode
-window's — a bf16-accumulating edit here turns the serving-choreo CI
-gate red): bf16 Q/K products formed as f32 upcast-multiplies, f32 score
-accumulation, additive mask before the in-softmax scale, one joint f32
-exp per layer, f32 probs through the PV sums, output rounded to the
-compute dtype once at the end.
+Dtype choreography of the body (machine-checked: analysis.choreo
+extracts the kernel body's softmax signature and proves its arithmetic
+equal to the decode window's — a bf16-accumulating edit here turns the
+serving-choreo CI gate red): bf16 Q/K products on the matrix unit with
+f32 accumulation (the scores' ``preferred_element_type`` is
+``SCORE_ACC_DTYPE``),
+additive mask before the in-softmax scale, one joint f32 exp per
+layer, f32 probs into the PV product, output rounded to the compute
+dtype once at the end.
 
 Interpret mode is the caller's choice (``interpret=True``, or the
 tests' ``pallas_interpret`` fixture): the program never picks it from
@@ -167,11 +159,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-# The score-accumulation dtype of both kernels. Module-level so the
-# choreography fault-injection test (tests/test_choreo.py) can
-# monkeypatch a bf16-accumulating kernel variant and prove the prover
-# catches it; the shipped value is load-bearing — f32 accumulation IS
-# the decode choreography contract.
+# The accumulation dtype of the kernel's score products (their
+# ``preferred_element_type``). Module-level so the choreography
+# fault-injection test (tests/test_choreo.py) can monkeypatch a
+# bf16-accumulating kernel variant and prove the prover catches it; the
+# shipped value is load-bearing — f32 accumulation IS the decode
+# choreography contract.
 SCORE_ACC_DTYPE = jnp.float32
 
 # What the kernels ask the compiler for, and what ``supported`` lets
@@ -196,9 +189,15 @@ _PLAN_DEPTH = 2
 # band count: a geometry that would need more bands than this is
 # rejected by the gate rather than traced into an enormous program.
 # ``MAX_UNROLL`` caps heads x bands, the bodies one pass of one grid
-# step unrolls, and so how many KV heads a grid step serves.
+# step unrolls, and so how many KV heads a grid step serves — which is
+# how many heads' lanes of a page one DMA moves, and the walk runs at
+# the rate its DMAs' size allows: at 32 slots x 30 heads of 128 x 16
+# bands, 6 heads a step (1.5 KB runs) walk at 48 % of the HBM rate, 15
+# (3.8 KB) at over 70 % — a call of 1.72 ms against 0.98 (PERF.md
+# section 6, PR 33). 256 since the body is one product a band: at that
+# geometry 240 of them compile faster than 96 of the VPU body did.
 MAX_BANDS = 64
-MAX_UNROLL = 128
+MAX_UNROLL = 256
 
 # The narrowest band the plan makes: one [128, C] f32 block of rows. A
 # band is the kernel's unit of skipped work; below this the reductions'
@@ -214,7 +213,7 @@ _FORCE_BAND_PAGES: tp.Optional[int] = None
 # accumulation-order clause: "ascending" is the pinned contract; the
 # choreo fault test flips this to "descending" and the prover must
 # fail EXACTLY the band-order clause (both the kernel and the XLA
-# reference fold through banded_fold, so bitwise kernel==XLA survives
+# reference fold through banded_fold, so kernel against XLA survives
 # the flip and no other clause goes red).
 _BAND_FOLD_ORDER = "ascending"
 
@@ -222,8 +221,8 @@ _BAND_FOLD_ORDER = "ascending"
 def banded_fold(parts: tp.Sequence[Array]) -> Array:
     """Fold the per-band PV partials in the PINNED ascending-band
     order (a left fold: ((o_0 + o_1) + o_2) + ...). f32 addition is
-    not associative, so this order IS the bitwise contract between the
-    banded kernel and the banded XLA reference — both call exactly
+    not associative, so this order is part of the contract between
+    the banded kernel and the banded XLA reference — both call exactly
     this function. The recent/self partial is added AFTER the fold,
     outside it (it is not a page band)."""
     seq = list(parts)
@@ -233,14 +232,6 @@ def banded_fold(parts: tp.Sequence[Array]) -> Array:
     for p in seq[1:]:
         out = out + p
     return out
-
-
-def verify_contraction(block: int) -> str:
-    """Which unit the verify kernel's two products run on, by the mask
-    kind alone (``block`` as ``paged_verify_attention`` takes it):
-    ``"mxu"`` under the block-diffusion mask, ``"vpu"`` — the decode
-    window's arithmetic, bit for bit — under the causal one."""
-    return "mxu" if block > 1 else "vpu"
 
 
 def _band_bytes(band_pages_: int, page_size: int, c: int,
@@ -317,8 +308,7 @@ def _page_tile_bytes(page_size: int, width: int, itemsize: int) -> int:
 
 
 def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
-               groups: int = 8, spec_t: int = 1, heads: int = 1,
-               block: int = 1) -> int:
+               groups: int = 8, spec_t: int = 1, heads: int = 1) -> int:
     """Estimated VMEM demand of one grid step (one slot, ``heads`` KV
     heads), in bytes — O(band) in the table length but for the f32
     score rows:
@@ -327,18 +317,13 @@ def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
       pages at pool dtype (K bands, then V bands, take turns in them:
       one computing, one on its way), plus an int8 pool's f32
       dequantized band;
-    - one head's band compute: the band's f32 view and the
-      ``[G, T, BW, C]`` f32 product the reductions consume;
-    - per head, the full-context f32 score and prob rows
-      ``[G, T, 1, W]`` — the flat-softmax residency — whose unit
-      sublane dim pads 8x.
-
-    The block forward's contraction (``block`` > 1) forms no such
-    product and pads nothing: its band compute is the band's view and
-    one ``[G*T, BW]`` f32 tile set, and its score rows are dense
-    ``[G*T, W + T]`` f32 — priced three times over, for the masked
-    parts, the joint row they concatenate into and the probabilities
-    are alive together round the softmax."""
+    - one head's band compute: the band's f32 view and one
+      ``[rows, BW]`` f32 tile set (``rows`` = G*T, padded to whole
+      sublane tiles);
+    - per head, the full-context f32 score rows ``[rows, W + T]``,
+      dense — the flat-softmax residency — priced three times over,
+      for the masked parts, the joint row they concatenate into and
+      the probabilities are alive together round the softmax."""
     g, t = max(1, groups), max(1, spec_t)
     bp = resolved_band_pages(pmax, page_size, c, itemsize)
     bw, w = bp * page_size, pmax * page_size
@@ -346,19 +331,14 @@ def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
     fetch = 2 * bp * _page_tile_bytes(page_size, heads * c, itemsize)
     if itemsize == 1:
         fetch += bp * _page_tile_bytes(page_size, heads * c, 4)
-    if verify_contraction(block) == "mxu":
-        rows = _ceil_to(g * t, 8)
-        band = bw * lanes * 4 + rows * _ceil_to(bw, 128) * 4
-        scores = heads * 3 * rows * (w + _ceil_to(t, 128)) * 4
-    else:
-        band = bw * lanes * 4 + g * t * bw * lanes * 4
-        scores = heads * 2 * g * t * 8 * w * 4
+    rows = _ceil_to(g * t, 8)
+    band = bw * lanes * 4 + rows * _ceil_to(bw, 128) * 4
+    scores = heads * 3 * rows * (w + _ceil_to(t, 128)) * 4
     return fetch + band + scores
 
 
 def head_block(hkv: int, pmax: int, page_size: int, c: int, itemsize: int,
-               groups: int = 8, spec_t: int = 1,
-               block: int = 1) -> tp.Optional[int]:
+               groups: int = 8, spec_t: int = 1) -> tp.Optional[int]:
     """KV heads one grid step serves — which is also how many heads'
     lanes of a page one DMA moves (a page row carries all heads side by
     side). The LARGEST divisor of ``hkv`` whose lane run is whole
@@ -377,21 +357,20 @@ def head_block(hkv: int, pmax: int, page_size: int, c: int, itemsize: int,
         if hb < hkv and (hb * c) % 128:
             continue  # Mosaic DMAs whole lane tiles or the whole row
         if vmem_bytes(pmax, page_size, c, itemsize, groups=groups,
-                      spec_t=spec_t, heads=hb, block=block) <= VMEM_BUDGET:
+                      spec_t=spec_t, heads=hb) <= VMEM_BUDGET:
             best = hb
     return best
 
 
 def supported(pmax: int, page_size: int, c: int, itemsize: int,
               groups: int = 8, spec_t: int = 1,
-              heads: tp.Optional[int] = None, block: int = 1) -> bool:
+              heads: tp.Optional[int] = None) -> bool:
     """Will the chip's compiler take the kernels at this geometry?
     (``groups`` = query heads per KV head; ``spec_t`` = candidate rows
     per slot in the verify kernel — pass ``speculate + 1`` when
-    speculation is on; ``heads`` = the KV heads one device holds, so
-    ``kv_heads / tp``; ``block`` = a block-diffusion model's
-    ``block_len``, whose forward takes the other contraction and is
-    priced as that.) Two conditions, both learned from compiling for
+    speculation is on, a block-diffusion window's rows a slot for its
+    forward; ``heads`` = the KV heads one device holds, so
+    ``kv_heads / tp``.) Two conditions, both learned from compiling for
     a described v5e: a band plan exists (else the unrolled trace is
     unbounded), and some ``head_block`` of ``heads`` fits
     ``VMEM_BUDGET`` and ``MAX_UNROLL`` — the very block ``_paged_call``
@@ -404,27 +383,70 @@ def supported(pmax: int, page_size: int, c: int, itemsize: int,
         heads = 128 // c if c < 128 and 128 % c == 0 else 1
     return head_block(
         heads, pmax, page_size, c, itemsize, groups=groups, spec_t=spec_t,
-        block=block,
     ) is not None
 
 
-def _mxu(a: Array, b: Array, dims) -> Array:
-    """One product on the matrix unit, accumulated in f32: the block
-    forward's contraction. Operands narrower than f32 multiply exactly
-    there (a bf16 x bf16 product fits f32), so this is the VPU form's
-    sum in another order and not a lower precision; f32 operands — a
-    test's f32 pool, and always the probabilities — take the
-    full-precision passes."""
+def _mxu(a: Array, b: Array, dims, acc=jnp.float32) -> Array:
+    """One product on the matrix unit, accumulated in ``acc`` — f32;
+    the score products name ``SCORE_ACC_DTYPE``, the contract point.
+    Operands narrower than f32 multiply exactly there (a bf16 x bf16
+    product fits f32), so this is the gather path's sum in another
+    order and not a lower precision; f32 operands (a test's f32 pool)
+    take the full-precision passes."""
     dt = jnp.promote_types(a.dtype, b.dtype)
     return jax.lax.dot_general(
         a.astype(dt), b.astype(dt), (dims, ((), ())),
         precision=jax.lax.Precision.HIGHEST if dt == jnp.float32 else None,
-        preferred_element_type=jnp.float32,
+        preferred_element_type=acc,
     )
 
 
 _NT = ((1,), (1,))  # [M, K] x [N, K]: no operand turned over in VMEM
 _NN = ((1,), (0,))  # [M, K] x [K, N]
+
+
+def prob_limbs(p: Array) -> tp.Tuple[Array, Array, Array]:
+    """An f32 probability as three bf16 limbs, ``hi + mid + lo == p``
+    EXACTLY for every p in [0, 1] that is not subnormal: f32's 24
+    significant bits are three runs of 8, each residual is exact in
+    f32 and the last is exact in bf16 (tests/test_paged_attn.py, to
+    the bit)."""
+    hi = p.astype(jnp.bfloat16)
+    r1 = p - hi.astype(jnp.float32)
+    mid = r1.astype(jnp.bfloat16)
+    lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+@jax.jit
+def pv_limbs(p: Array, v: Array) -> Array:
+    """f32 probabilities ``[rows, n]`` against bf16 V rows ``[n, C]``
+    at full precision in ONE bf16 pass: ``Precision.HIGHEST`` splits
+    both operands of an f32 x f32 product in three and runs six passes,
+    three of which multiply the zeros under a bf16 V — and at a few
+    query rows a pass IS its weight load of the V band. Here the
+    probabilities go in as their three exact limbs, ``3 x rows`` rows
+    of one pass, and the row groups add in f32 in a fixed order (lo,
+    mid, hi): every product is exact in f32, so this is the same sum in
+    another order, not a rounding. Jitted under its own name: the
+    choreography prover reads the call as ONE contract node — f32
+    probabilities in, f32 accumulation out — whose exactness its own
+    test proves (analysis.choreo)."""
+    rows = p.shape[0]
+    hi, mid, lo = prob_limbs(p)
+    o = _mxu(jnp.concatenate([lo, mid, hi], axis=0), v, _NN)
+    return (o[:rows] + o[rows:2 * rows]) + o[2 * rows:]
+
+
+def _pv(p: Array, v: Array) -> Array:
+    """PASS 2's product, f32 out. The probabilities are NEVER rounded
+    to bf16 (that is another result): over a bf16 V (the pool's, or an
+    int8 pool's dequantized band, exact in bf16) they go in as limbs
+    (``pv_limbs``), over an f32 V (a test's pool) the product takes the
+    matrix unit's full-precision passes."""
+    if v.dtype == jnp.float32:
+        return _mxu(p, v, _NN)
+    return pv_limbs(p, v)
 
 
 def _attend_kernel(
@@ -435,13 +457,11 @@ def _attend_kernel(
     *refs,
     # then ``r`` [1] int32 (decode: step index within the window), an
     # int8 pool's gathered page scales flat [S*Pmax*Hkv] f32 (K, V);
-    # inputs q as columns [HB, G, T, C, 1], the K and V rows
-    # [HB, R, C] (decode: the window's recent rows; verify: the T
-    # candidates' own), the K and V pools whole in HBM; the output
-    # [HB, G, T, 1, C] (the block forward, ``block`` > 1: q and the
-    # output as rows [HB, G*T, C]); scratch:
-    # the fetch buffer [2, BP, PS, HB*C], an int8 pool's dequantized
-    # band [BP, PS, HB*C] f32, 2 DMA semaphores.
+    # inputs q as rows [HB, G*T, C], the K and V rows [HB, R, C]
+    # (decode: the window's recent rows; verify: the T candidates'
+    # own), the K and V pools whole in HBM; the output [HB, G*T, C];
+    # scratch: the fetch buffer [2, BP, PS, HB*C], an int8 pool's
+    # dequantized band [BP, PS, HB*C] f32, 2 DMA semaphores.
     nb: int,
     hkv: int,
     quant: bool,
@@ -459,15 +479,7 @@ def _attend_kernel(
         deq = refs[7]
     sem = refs[-1]
     _, bp, ps, _ = buf.shape
-    # THE CONTRACTION follows the mask kind, the one thing in the
-    # operands that says whether the rows have a decode twin (module
-    # docstring): under the block-diffusion mask both products run on
-    # the matrix unit, over all G*T rows of a KV head at once
-    mxu = verify_contraction(block) == "mxu"
-    if mxu:
-        hb, gt, c = q_ref.shape
-    else:
-        hb, g, t, c, _ = q_ref.shape
+    hb, gt, c = q_ref.shape
     rr = rv_ref.shape[1]
     pmax = nb * bp
     bw, w = bp * ps, pmax * ps
@@ -524,37 +536,46 @@ def _attend_kernel(
 
         jax.lax.fori_loop(0, bp, page_scale, 0)
 
-    def band(u: int, hh: int, turned: bool = False, dtype=jnp.float32):
-        """Unit ``u``'s band for head ``hh`` as the logical f32 stream
-        (what the XLA path's gathered view holds in columns
-        [b*BW, (b+1)*BW)): the fetched rows [BW, C], or turned over,
-        [C, BW] — in the pool's own dtype, before the upcast, so that a
-        bf16 band is half the vregs to turn. A transpose moves values
-        and rounds nothing. (The matrix unit takes the band in the
-        ``dtype`` of the rows' own K/V: the pool's, or bf16 over an
-        int8 pool, whose dequantized values — ``|q| <= 127`` times a
-        power of two — bf16 holds exactly.)"""
+    def band(u: int, hh: int, dtype):
+        """Unit ``u``'s band for head ``hh``, the fetched rows [BW, C]
+        as they lie (what the XLA path's gathered view holds in columns
+        [b*BW, (b+1)*BW)), in the ``dtype`` of the rows' own K/V: the
+        pool's, or bf16 over an int8 pool, whose dequantized values —
+        ``|q| <= 127`` times a power of two — bf16 holds exactly."""
         lanes = slice(hh * c, (hh + 1) * c)
         x = deq[:, :, lanes] if quant else buf[u % 2, :, :, lanes]
-        x = x.reshape(bw, c)
-        return (x.T if turned else x).astype(dtype)
+        return x.reshape(bw, c).astype(dtype)
 
-    # The score row keeps TIME ON THE LANE AXIS — a full-context row is
-    # W/128 vregs a (g, t), where a column (what a page's own rows-by-C
-    # orientation would reduce to) is W/8 and makes the flat softmax
-    # the costliest thing in the kernel (PERF.md section 6, PR 26). So
-    # pass 1 turns each live K band over once, [BW, C] -> [C, BW], and
-    # pass 2 turns the band's slice of the probability row into the
-    # column that scales V's rows where they lie (turning V over as
-    # well, and summing over lanes as the XLA path does, measured half
-    # again as slow a kernel).
-    qs = [q_ref[hh] for hh in range(hb)]  # [G, T, C, 1]
+    # A head's G*T query rows, padded with zero rows to whole sublane
+    # tiles: an MHA decode head has ONE row. A zero row scores 0 on
+    # every column — a uniform softmax, never a NaN — and is never
+    # written out; rows of one product do not see each other, so the
+    # padding moves no bit of a real row and makes a decode row and a
+    # causal-verify row of the same token one shape class. The rows' own
+    # K/V are padded likewise (a one-step window has ONE: Mosaic refuses
+    # a product of one column): no row's mask admits a column past R,
+    # so a padded column's probability is an exact zero against a zero
+    # V row.
+    assert rr % block == 0, (rr, block)  # else a padded column is seen
+    rows_p, rr_p = _ceil_to(gt, 8), _ceil_to(rr, 8)
 
-    def scores_mxu(u: int):
-        """PASS 1 on the matrix unit: a head's G*T query rows against
-        the band's K rows, contracted over C — [G*T, BW] f32, a dense
-        tile set where the VPU form's [G, T, 1, BW] rows pad their unit
-        sublane 8x."""
+    def padded(x):
+        pad = -x.shape[0] % 8
+        if not pad:
+            return x
+        zeros = jnp.zeros((pad,) + x.shape[1:], x.dtype)
+        return jnp.concatenate([x, zeros], axis=0)
+
+    qs = [padded(q_ref[hh]) for hh in range(hb)]  # [rows, C]
+
+    def scores(u: int):
+        """PASS 1: a head's query rows against the band's K rows as
+        they lie in the fetch buffer, contracted over C in NT form —
+        nothing is turned over in VMEM, and the score row comes out
+        with TIME ON THE LANE AXIS: dense [rows, BW] f32 tiles (a
+        full-context row is W/128 vregs, where a column is W/8 and
+        makes the flat softmax the costliest thing in the kernel —
+        PERF.md section 6, PR 26)."""
         if quant:
             dequant(u)
         idx = jax.lax.broadcasted_iota(jnp.int32, (1, bw), 1) + u * bw
@@ -562,73 +583,29 @@ def _attend_kernel(
             jnp.float32
         )
         return [
-            _mxu(qs[hh], band(u, hh, dtype=rk_ref.dtype), _NT) + mask_b
+            _mxu(qs[hh], band(u, hh, rk_ref.dtype), _NT, SCORE_ACC_DTYPE)
+            + mask_b
             for hh in range(hb)
         ]
-
-    def pv_mxu(u: int):
-        """PASS 2 on the matrix unit: the band's slice of the f32
-        probability rows against its V rows, [G*T, C] f32. The
-        probabilities go in as f32 — rounding them to bf16 would be
-        another result — and V as the pool has it."""
-        if quant:
-            dequant(u)
-        lo = (u - nb) * bw
-        return [
-            _mxu(probs[hh][:, lo:lo + bw], band(u, hh, dtype=rv_ref.dtype),
-                 _NN)
-            for hh in range(hb)
-        ]
-
-    def scores(u: int):
-        """PASS 1 (K): a band's scores are per-position sums over C, so
-        banding is bitwise-invisible to them. The decode choreography,
-        op for op (decode_paged_at): f32 upcast-multiplies, f32
-        accumulation, mask BEFORE the in-softmax scale."""
-        if quant:
-            dequant(u)
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, bw), 3) + u * bw
-        mask_b = jnp.where(idx < len_ref[i], 0.0, -jnp.inf).astype(
-            jnp.float32
-        )
-        return [
-            jnp.sum(
-                qs[hh].astype(SCORE_ACC_DTYPE)
-                * band(u, hh, turned=True)[None, None].astype(
-                    SCORE_ACC_DTYPE
-                ),
-                axis=-2, keepdims=True, dtype=SCORE_ACC_DTYPE,
-            ) + mask_b
-            for hh in range(hb)
-        ]  # [G, T, 1, BW] each
 
     def pv(u: int):
-        """PASS 2 (V): a band's PV partial summed over its band
-        width."""
+        """PASS 2: the band's slice of the f32 probability rows against
+        its V rows, [rows, C] f32 (``_pv``)."""
         if quant:
             dequant(u)
         lo = (u - nb) * bw
         return [
-            jnp.sum(
-                jnp.swapaxes(probs[hh][..., lo:lo + bw], -1, -2)
-                * band(u, hh)[None, None],
-                axis=-2, keepdims=True,
-            )
+            _pv(probs[hh][:, lo:lo + bw], band(u, hh, rv_ref.dtype))
             for hh in range(hb)
-        ]  # [G, T, 1, C] each
+        ]
 
     # A band past the slot's length is not computed at all: its scores
     # are the mask's -inf and its PV partial the zero that a zero
     # probability leaves — exactly what the XLA path computes there, so
     # the skip moves no bit (the fold below still adds the zero).
-    if mxu:
-        scores, pv = scores_mxu, pv_mxu
-        s_dead, o_dead = (gt, bw), (gt, c)
-    else:
-        s_dead, o_dead = (g, t, 1, bw), (g, t, 1, c)
     dead = (
-        lambda: hb * [jnp.full(s_dead, -jnp.inf, jnp.float32)],
-        lambda: hb * [jnp.zeros(o_dead, jnp.float32)],
+        lambda: hb * [jnp.full((rows_p, bw), -jnp.inf, jnp.float32)],
+        lambda: hb * [jnp.zeros((rows_p, c), jnp.float32)],
     )
     parts = [[] for _ in range(hb)]
     opars = [[] for _ in range(hb)]
@@ -644,36 +621,24 @@ def _attend_kernel(
         )
         for hh in range(hb):
             (parts if u < nb else opars)[hh].append(out[hh])
-        if u == nb - 1 and mxu:
-            # the same ONE flat f32 softmax, over [G*T, W + T] rows:
-            # row i of a head's block is candidate i mod T of its slot
-            rows = jax.lax.rem(
-                jax.lax.broadcasted_iota(jnp.int32, (gt, rr), 0), rr
-            )
-            cols = jax.lax.broadcasted_iota(jnp.int32, (gt, rr), 1)
-            mask_rows = jnp.where(
-                cols // block <= rows // block, 0.0, -jnp.inf
-            ).astype(jnp.float32)
-            for hh in range(hb):
-                s_rows = _mxu(qs[hh], rk_ref[hh], _NT)  # [G*T, R]
-                s_all = jnp.concatenate(
-                    parts[hh] + [s_rows + mask_rows], axis=-1
-                )
-                probs.append(jax.nn.softmax(s_all / math.sqrt(c), axis=-1))
-        elif u == nb - 1:
+        if u == nb - 1:
             # the masked parts concatenate into the ONE full-context
             # f32 score row (the flat-softmax contract — no online
-            # rescaling); the rows' own scores close it
-            rows = jax.lax.broadcasted_iota(jnp.int32, (1, t, 1, rr), 1)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (1, t, 1, rr), 3)
-            seen = cols <= (r_ref[0] if decode else rows)
-            mask_rows = jnp.where(seen, 0.0, -jnp.inf).astype(jnp.float32)
+            # rescaling); the rows' own scores close it. Row i of a
+            # head's block is candidate i mod T of its slot; the mask
+            # kind selects WHAT a row sees and never how it is summed
+            rows = jax.lax.rem(
+                jax.lax.broadcasted_iota(jnp.int32, (rows_p, rr_p), 0), rr
+            )
+            cols = jax.lax.broadcasted_iota(jnp.int32, (rows_p, rr_p), 1)
+            mask_rows = jnp.where(
+                cols // block <= (r_ref[0] if decode else rows) // block,
+                0.0, -jnp.inf,
+            ).astype(jnp.float32)
             for hh in range(hb):
-                s_rows = jnp.sum(
-                    qs[hh].astype(SCORE_ACC_DTYPE)
-                    * rk_ref[hh].T[None, None].astype(SCORE_ACC_DTYPE),
-                    axis=-2, keepdims=True, dtype=SCORE_ACC_DTYPE,
-                )  # [G, T, 1, R]
+                s_rows = _mxu(
+                    qs[hh], padded(rk_ref[hh]), _NT, SCORE_ACC_DTYPE
+                )  # [rows, R]
                 s_all = jnp.concatenate(
                     parts[hh] + [s_rows + mask_rows], axis=-1
                 )
@@ -681,18 +646,12 @@ def _attend_kernel(
                     jax.nn.softmax(s_all / math.sqrt(c), axis=-1)
                 )  # f32, joint
     for hh in range(hb):
-        # the one place banding touches f32 summation order, matched
-        # bitwise by the XLA reference's banded_fold
-        o_pool = banded_fold(opars[hh])
-        if mxu:
-            o_rows = _mxu(probs[hh][:, w:], rv_ref[hh], _NN)
-        else:
-            p_rows = jnp.swapaxes(probs[hh][..., w:], -1, -2)  # [G,T,R,1]
-            o_rows = jnp.sum(
-                p_rows * rv_ref[hh][None, None].astype(jnp.float32),
-                axis=-2, keepdims=True,
-            )
-        out_ref[hh] = (o_pool + o_rows).astype(out_ref.dtype)
+        # the one place banding touches f32 summation order: the fold
+        # the XLA reference's banded_fold makes too
+        o = banded_fold(opars[hh]) + _pv(
+            probs[hh][:, w:], padded(rv_ref[hh])
+        )
+        out_ref[hh] = o[:gt].astype(out_ref.dtype)
 
 
 def _paged_call(
@@ -704,9 +663,11 @@ def _paged_call(
     """The call both kernels share. Grid over (slot, KV-head block);
     operands are the scalar-prefetched ``scalars`` (block table first,
     an int8 pool's gathered page scales last, flat), ``q``
-    ``[S, Hkv, G, T, C]`` as columns, the row buffers ``[S, Hkv, R, C]``
-    blocked per (slot, head block), and the two pools left whole in HBM
-    for the kernel's own page walk. Returns ``q``-shaped output."""
+    ``[S, Hkv, G, T, C]`` handed over as a KV head's ``G*T`` rows, the
+    row buffers ``[S, Hkv, R, C]`` blocked per (slot, head block), and
+    the two pools left whole in HBM for the kernel's own page walk.
+    Returns ``q``-shaped output: both reshapes are free, and nothing
+    re-lays the output behind the call."""
     s, hkv, g, t, c = q.shape
     ps = pool_k.shape[2]
     pmax = scalars[0].shape[1]
@@ -715,9 +676,7 @@ def _paged_call(
     bp = resolved_band_pages(pmax, ps, c, itemsize)
     # where ``supported(..., heads=hkv)`` says no (a test's geometry,
     # interpreted) a step moves whole rows
-    hb = head_block(
-        hkv, pmax, ps, c, itemsize, groups=g, spec_t=t, block=block
-    ) or hkv
+    hb = head_block(hkv, pmax, ps, c, itemsize, groups=g, spec_t=t) or hkv
     pallas_call = pl.pallas_call
     if interpret:
         pallas_call = functools.partial(pallas_call, interpret=True)
@@ -731,17 +690,7 @@ def _paged_call(
             (None, hb) + rest, lambda i, j, *_: (i, j) + (0,) * len(rest)
         )
 
-    # q as columns: pass 1 reduces over C on the sublane axis. (The
-    # rows' K is turned over in the kernel: turned in XLA, the window's
-    # recent buffer takes the turned layout and every step's row write
-    # into it pays for that.)
-    # The block forward (``block`` > 1) hands the matrix unit a KV
-    # head's G*T rows as they lie, and takes its output the same way —
-    # whole (sublane, 128) tiles, so both reshapes are free and nothing
-    # re-lays the output behind the call.
-    mxu = verify_contraction(block) == "mxu"
-    q_in = q.reshape(s, hkv, g * t, c) if mxu else q[..., None]
-    out_shape = q_in.shape if mxu else (s, hkv, g, t, 1, c)
+    q_in = q.reshape(s, hkv, g * t, c)
     scratch = [pltpu.VMEM((2, bp, ps, hb * c), pool_k.dtype)]
     if quant:
         scratch.append(pltpu.VMEM((bp, ps, hb * c), jnp.float32))
@@ -759,16 +708,16 @@ def _paged_call(
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=head_spec(out_shape),
+            out_specs=head_spec(q_in.shape),
             scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
         ),
-        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT,
         ),
     )(*scalars, q_in, rows_k, rows_v, pool_k, pool_v)
-    return out.reshape(q.shape) if mxu else out[..., 0, :]
+    return out.reshape(q.shape)
 
 
 def _jitted(name: str):
@@ -806,6 +755,7 @@ def clear_traces() -> None:
     """Forget the jitted calls' traces (see ``_jitted``)."""
     _DECODE_CALL.clear_cache()
     _VERIFY_CALL.clear_cache()
+    pv_limbs.clear_cache()
 
 
 def paged_decode_attention(
@@ -824,8 +774,9 @@ def paged_decode_attention(
 ) -> Array:  # [S, Hkv, G, C] compute dtype
     """One decode step's paged attention for all slots: pool part read
     live page by live page through the block table, recent part from the
-    window's write buffer, one joint softmax — bitwise the (banded-fold)
-    XLA gather path's result without the gathered HBM intermediate."""
+    window's write buffer, one joint softmax — the (banded-fold) XLA
+    gather path's result (module docstring, THE CONTRACT) without the
+    gathered HBM intermediate."""
     return _DECODE_CALL(
         _scalars(bt, pooled_len, layer, jnp.reshape(r, (1,))),
         q[:, :, :, None, :], rk_l, rv_l, pool_k, pool_v, scale_k, scale_v,
@@ -852,11 +803,9 @@ def paged_verify_attention(
     ``block`` > 1, causal across blocks of that many rows and
     bidirectional inside one: the block-diffusion forward), one joint
     softmax, decode choreography — the kernel twin of
-    ``Attention.verify_paged_at``, the same kernel body and page walk as
-    :func:`paged_decode_attention`. Under the block mask the two
-    products run on the matrix unit (``verify_contraction``): the same
-    sums in another order, held to the gather path at a tolerance and
-    not to the bit — the module docstring says why."""
+    ``Attention.verify_paged_at``, the same kernel body, contraction and
+    page walk as :func:`paged_decode_attention`: the mask kind selects
+    what a row sees, never how it is summed."""
     return _VERIFY_CALL(
         _scalars(bt, start, layer), q, kc, vc, pool_k, pool_v, scale_k,
         scale_v, decode=False, interpret=interpret, block=block,

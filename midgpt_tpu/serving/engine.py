@@ -1586,7 +1586,6 @@ class ServingEngine:
                 # (a block model's forward carries two blocks a slot)
                 spec_t=2 * cfg.block_len or speculate + 1,
                 heads=max(1, cfg.kv_heads // tp_sz),
-                block=max(1, cfg.block_len),
             )
             kernel_ok = pk_supported(**geometry)
             if paged_kernel == "pallas" and not kernel_ok:
@@ -1600,15 +1599,6 @@ class ServingEngine:
                     "pallas" if is_tpu_backend() and kernel_ok else "xla"
                 )
         self.paged_kernel = paged_kernel
-        # which contraction the kernel's many-row program (speculative
-        # verify, or a block-diffusion model's forward) is built with:
-        # "mxu" under the block mask, "vpu" where the rows have a decode
-        # twin to stay bitwise with; None on the gather path
-        self.verify_contraction = None
-        if paged_kernel == "pallas":
-            from midgpt_tpu.ops.paged_attn import verify_contraction
-
-            self.verify_contraction = verify_contraction(cfg.block_len)
         self.tp = 1
         if mesh is not None:
             from midgpt_tpu.models.gpt import (

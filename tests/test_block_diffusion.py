@@ -446,16 +446,50 @@ def test_pallas_block_forward_at_the_cells_head_geometry(
     assert err < 2e-2, err  # chip_smoke.py's KERNEL_TOL
 
 
-def test_the_engine_names_the_contraction_its_window_was_built_with(model):
-    """The choice is static — the mask kind — so what says that it engaged
-    is what the engine resolved beside ``paged_kernel``."""
-    assert engine(model).verify_contraction is None  # the gather path
-    assert engine(model, paged_kernel="pallas").verify_contraction == "mxu"
-    plain = GPT.init(jax.random.PRNGKey(0), dataclasses.replace(
-        CFG, block_len=0, block_steps=0, mask_token=-1))
-    spec = ServingEngine(plain, slots=2, page_size=16, speculate=3,
-                         cache_dtype=jnp.float32, paged_kernel="pallas")
-    assert spec.verify_contraction == "vpu"  # a decode twin to stay bitwise with
+def test_no_mask_kind_selects_the_kernels_arithmetic(model, pallas_interpret):
+    """There is ONE contraction: neither the engine nor the kernel module
+    names a choice any more, and the block model's window and a causal
+    speculative engine are built on the same body. One block of rows under
+    the block mask and the same rows under the causal mask differ in what a
+    row SEES; the block's last row sees all of it under both, and (one
+    layer: deeper, the rows it sees have themselves seen other things) its
+    logits agree to the bit."""
+    from midgpt_tpu.ops import paged_attn
+    from midgpt_tpu.serving import PagedKVPool
+
+    assert not hasattr(paged_attn, "verify_contraction")
+    for eng in (engine(model), engine(model, paged_kernel="pallas")):
+        assert not hasattr(eng, "verify_contraction")
+    cfg = dataclasses.replace(CFG, n_layer=1, mlp="gelu")
+    plain = dataclasses.replace(cfg, block_len=0, block_steps=0, mask_token=-1)
+    spec = ServingEngine(GPT.init(jax.random.PRNGKey(0), plain), slots=2,
+                         page_size=16, speculate=3, cache_dtype=jnp.float32,
+                         paged_kernel="pallas")
+    assert spec.paged_kernel == "pallas"
+    one = GPT.init(jax.random.PRNGKey(2), cfg)
+    pool = PagedKVPool.init(cfg, 8, 16, jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    pool = dataclasses.replace(
+        pool, k=jax.random.normal(ks[0], pool.k.shape),
+        v=jax.random.normal(ks[1], pool.v.shape))
+    tok = jax.random.randint(ks[2], (2, B), 0, 510).astype(jnp.int32)
+    bt = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
+    start = jnp.asarray([0, 21], jnp.int32)
+    last = {
+        blk: np.asarray(verify_tokens_paged(
+            one, tok, start, pool.k, pool.v, bt, cfg.block_size,
+            paged_kernel="pallas", block_len=blk)[0], np.float32)[:, B - 1]
+        for blk in (B, 0)
+    }
+    assert np.isfinite(last[B]).all()
+    np.testing.assert_array_equal(last[B], last[0])
+    first = np.asarray(verify_tokens_paged(
+        one, tok, start, pool.k, pool.v, bt, cfg.block_size,
+        paged_kernel="pallas", block_len=B)[0], np.float32)[:, 0]
+    causal_first = np.asarray(verify_tokens_paged(
+        one, tok, start, pool.k, pool.v, bt, cfg.block_size,
+        paged_kernel="pallas")[0], np.float32)[:, 0]
+    assert not np.allclose(first, causal_first, atol=1e-4)  # another mask
 
 
 # -- (d): the expert layer against the loop over experts --------------------

@@ -130,9 +130,58 @@ def test_paged_verify_kernel_compiles(one_chip, geometry, pool):
     _compiles_to_kernel(fn, one_chip, *shapes)
 
 
+# the two decode cells of the benchmark, and a one-step window: (slots,
+# KV heads, pages a slot, recent rows = decode steps a dispatch, KV heads
+# a grid step); heads of 128, MHA
+DECODE_CELLS = {
+    "serve-xl-decode": (8, 16, 48, 4, 16),
+    "serve-olmo-hybrid-decode": (32, 30, 128, 16, 15),
+    # a window of ONE step: one recent row (Mosaic refuses a product of
+    # one column; the kernel pads the rows' own K/V to a sublane tile)
+    "xl-one-step-window": (8, 16, 48, 1, 16),
+}
+
+
+@pytest.mark.parametrize("cell", list(DECODE_CELLS))
+def test_paged_decode_kernel_compiles_at_the_decode_cells(one_chip, cell):
+    """The decode kernel at the two cells that run it every decode step
+    (ONE query row a head, padded to a sublane tile in the kernel; 16
+    heads a grid step over 6 bands, and 15 of 30 heads over 16), two
+    layers of it in one program: one ``%closed_call.N`` custom call a
+    layer — the name and the count the benchmark's
+    ``paged_attn_roofline.serve`` finds the kernel's events by."""
+    import re
+
+    from midgpt_tpu.ops.paged_attn import (
+        head_block, paged_decode_attention, supported,
+    )
+
+    slots, heads, pmax, rr, hb = DECODE_CELLS[cell]
+    c = 128
+    assert supported(pmax, PS, c, 2, groups=1, heads=heads)
+    assert head_block(heads, pmax, PS, c, 2, groups=1) == hb
+    pool = ((L, slots * pmax, PS, heads * c), jnp.bfloat16)
+    rows = ((slots, heads, rr, c), jnp.bfloat16)
+    shapes = [
+        ((slots, heads, 1, c), jnp.bfloat16), pool, pool,
+        ((slots, pmax), jnp.int32), ((slots,), jnp.int32), rows, rows,
+        ((), jnp.int32),
+    ]
+
+    def fn(q, pk, pv, bt, ln, rk, rv, r):
+        for layer in range(L):
+            q = paged_decode_attention(q, pk, pv, bt, ln, rk, rv, r, layer)
+        return q
+
+    text = _compiles_to_kernel(fn, one_chip, *shapes)
+    assert len(re.findall(
+        r"%closed_call\.\d+ = \S+ custom-call\(", text
+    )) == L
+
+
 def test_paged_decode_kernel_compiles_100k_token_table(one_chip):
     """The gate accepts a 100k-token block table (6250 pages: 50 bands
-    of 125, two heads a grid step — tests/test_paged_attn.py pins the
+    of 125, four heads a grid step — tests/test_paged_attn.py pins the
     arithmetic); this is the compile that says the gate is right."""
     from midgpt_tpu.ops.paged_attn import paged_decode_attention, supported
 
@@ -181,15 +230,15 @@ def _block_kernel_compiles(
 @pytest.mark.parametrize("t", [4, 8])
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
 def test_paged_block_kernel_compiles(one_chip, pool, t):
-    """The verify kernel under the block mask — both products on the
-    matrix unit, the f32 probabilities at full precision — at the
+    """The verify kernel under the block mask — the one body's two
+    products on the matrix unit, the f32 probabilities as limbs — at the
     benchmark cell's geometry: all four KV heads a grid step, at the
     window's two blocks of rows a slot and at one."""
     from midgpt_tpu.ops.paged_attn import head_block, supported
 
     pool_dt = jnp.int8 if pool == "int8" else jnp.bfloat16
     geo = dict(BLOCK_CELL, t=t)
-    gate = dict(groups=geo["g"], spec_t=t, block=geo["block"])
+    gate = dict(groups=geo["g"], spec_t=t)
     itemsize = jnp.dtype(pool_dt).itemsize
     assert supported(geo["pmax"], PS, geo["c"], itemsize, heads=geo["hkv"],
                      **gate)
@@ -203,14 +252,13 @@ def test_paged_block_kernel_100k_token_table(one_chip):
     gate admits and Mosaic refuses. At the cell's heads of 128 no band
     plan fits (as for decode) and ``auto`` takes the gather path; at
     heads of 64 the gate admits the dense ``[G*T, W + T]`` score rows
-    (two heads a grid step, 50 bands) — and this is the compile that
+    (four heads a grid step, 50 bands) — and this is the compile that
     says it is right to."""
     from midgpt_tpu.ops.paged_attn import supported
 
     pmax = 6250
-    assert not supported(pmax, PS, 128, 2, groups=8, spec_t=4, heads=4,
-                         block=4)
-    assert supported(pmax, PS, C, 2, groups=1, spec_t=4, heads=H, block=4)
+    assert not supported(pmax, PS, 128, 2, groups=8, spec_t=4, heads=4)
+    assert supported(pmax, PS, C, 2, groups=1, spec_t=4, heads=H)
     _block_kernel_compiles(
         one_chip, jnp.bfloat16, slots=S, hkv=H, g=1, t=4, c=C, pmax=pmax
     )

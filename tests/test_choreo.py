@@ -418,17 +418,28 @@ def test_kernel_node_is_one_record_and_bodies_match_decode_contract(
 ):
     """The kernel appears as a single 'paged_kernel' contract node in
     the attention traces (not as inlined internals), decode == verify
-    op for op across it, and the KERNEL BODY's softmax signature equals
-    the XLA decode window's — same f32 accumulation, mask-before-scale,
-    f32 softmax, f32 probs through PV."""
+    op for op across it, and the KERNEL BODY's softmax signature — ONE
+    body, whatever the mask kind — shares the XLA decode window's
+    arithmetic: f32 accumulation (the products' ``preferred_element_type``),
+    mask-before-scale, one f32 softmax, f32 probs into PV. The unit that
+    forms the products differs by design: the kernel's are
+    ``dot_general``s of bf16 operands (exact in f32), and its PV over a
+    bf16 pool is the limb helper's contract node — f32 probabilities in,
+    never a bf16 operand."""
     progs = {p.name: p for p in kernel_report.programs}
     dec = progs["decode_window"]
     kinds = [rec[0] for rec in dec.attention]
     assert kinds.count("paged_kernel") == 1
     assert dec.attention == progs["verify"].attention
+    assert dec.softmax == progs["verify"].softmax
     xla = prove_serving_choreography("openwebtext")
     xla_dec = {p.name: p for p in xla.programs}["decode_window"]
-    assert dec.softmax == xla_dec.softmax
+    assert dec.softmax.arithmetic() == xla_dec.softmax.arithmetic()
+    assert {kind for kind, _, _ in dec.softmax.qk_contracts} == {"dot"}
+    assert dec.softmax.probs_dtype == {"float32"}
+    assert dec.softmax.pv_contracts == {
+        ("dot", ("float32", "bfloat16"), "float32")
+    }
 
 
 def test_prover_proves_kv_dequant_contract():
@@ -448,14 +459,12 @@ def test_prover_proves_kv_dequant_contract():
 
 
 def test_prover_catches_bf16_accumulating_kernel(paged_hook):
-    """Fault injection: a kernel variant that accumulates QK scores in
-    bf16 (SCORE_ACC_DTYPE is the kernels' contract point) must turn the
-    prover red. The failure lands on the extraction-degeneracy guard:
-    jnp silently RE-PROMOTES half-precision reductions, so the faulty
-    kernel's score chain grows convert hops that break the signature
-    walk — and a signature the prover can no longer read is a
-    violation, never a vacuous pass (this exact fault used to slip
-    through before the guard existed)."""
+    """Fault injection: a kernel variant whose products accumulate in
+    bf16 (SCORE_ACC_DTYPE, ``_mxu``'s ``preferred_element_type``, is the
+    kernels' contract point) must turn the prover red — EXACTLY the
+    score-accumulation clause: the products' own ``dot_general`` says
+    what they sum in, so the signature stays readable and no sibling
+    clause hides the fault or goes red with it."""
     engine_mod._PROGRAM_CACHE.clear()
     paged_hook("SCORE_ACC_DTYPE", jnp.bfloat16)
     try:
@@ -466,13 +475,9 @@ def test_prover_catches_bf16_accumulating_kernel(paged_hook):
         engine_mod._PROGRAM_CACHE.clear()
     assert not rep.ok
     checks = _checks(rep)
-    assert (
-        checks["shared: scores accumulate in f32 everywhere"] is False
-        or checks[
-            "shared: every program exposes its score contractions "
-            "to the prover"
-        ] is False
-    )
+    assert [name for name, ok in checks.items() if not ok] == [
+        "shared: scores accumulate in f32 everywhere"
+    ]
 
 
 _BAND_CLAUSE = "shared: banded PV accumulation runs in pinned ascending-band order"
