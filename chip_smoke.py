@@ -2,7 +2,7 @@
 """Does the system still start on the chip? One process, the normal entry
 points, random weights from ``--seed``, nothing measured.
 
-    python chip_smoke.py             # one TPU chip: phases 1-7
+    python chip_smoke.py             # one TPU chip: phases 1-8
     python chip_smoke.py --only 6    # phase 1 and the phases named
     python chip_smoke.py --chips 4   # four chips: the two sharded paths only
 
@@ -37,6 +37,14 @@ One chip, in order — any failed assertion ends the run non-zero:
    cell), compiled, against its ``jax.numpy`` body, in place in a stack of
    two layers; and a 256-token chunk of the chunked form against the rule
    a token at a time.
+
+8. latent attention — the paged kernel's latent mode (``ops/paged_attn``,
+   one page DMA for scores and values) at the published widths of the
+   benchmark's latent cell (32 heads, rows of 512 + 64 in 640 lanes) over a
+   33,792-position table at pages of 16, 32 and 64, compiled, against the
+   ``jax.numpy`` gather path; and one 256-row prefill chunk against 33 k
+   pooled rows (absorbed, heads in groups) against the published form with
+   every key up-projected.
 
 Four chips: ``openwebtext`` on an fsdp=2 x tensor=2 mesh against a
 one-device mesh (same seed, data, global batch), and a tp=2 x 2-replica
@@ -585,6 +593,119 @@ def gated_delta_vs_numpy(seed: int) -> None:
         f"a time (max rel. error {err:.2e})")
 
 
+def latent_attention_vs_numpy(seed: int) -> None:
+    """Phase 8: the latent decode kernel and one latent prefill chunk, at
+    the published widths over a 33 k table, against ``jax.numpy``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from midgpt_tpu.config import ModelConfig
+    from midgpt_tpu.models.gpt import LatentAttention, _gather_attend
+    from midgpt_tpu.models.layers import rope_tables
+    from midgpt_tpu.ops.paged_attn import paged_latent_attention, supported
+    from midgpt_tpu.pytree import cast_floating
+
+    block, bf = 33792, jnp.bfloat16
+    cfg = ModelConfig(
+        block_size=block, vocab_size=1024, n_layer=1, n_head=32, n_embd=2048,
+        attention="latent", latent_q=1536, latent_kv=512, latent_nope=128,
+        latent_rope=64, latent_v=128, rope_base=32e6, norm_scale=True,
+    )
+    attn = cast_floating(
+        LatentAttention.init(jax.random.PRNGKey(seed + 31), cfg), bf
+    )
+    row, dc, s = attn.row, attn.kv_rank, 4
+    ks = jax.random.split(jax.random.PRNGKey(seed + 37), 5)
+    # ragged: empty, inside a page, inside a band, the whole table
+    lens = jnp.asarray([0, 700, 20000, block - 16], jnp.int32)
+    q = jax.random.normal(ks[0], (s, 1, 32, row)).astype(bf)
+    rows = jax.random.normal(ks[1], (s, 1, 16, row)).astype(bf)
+    flat = jax.random.normal(ks[2], (2 * block, row)).astype(bf)
+    flat = flat.at[:, dc + 64:].set(0)
+    r = jnp.int32(5)
+    want = None
+    for ps in (16, 32, 64):
+        pmax = block // ps
+        label = f"paged_latent_attention[32 rows of 640 lanes, pages of {ps}]"
+        check(supported(pmax, ps, row, 2, groups=32, heads=1, latent=True),
+              f"{label}: the geometry is refused")
+        # the same positions' rows whatever the page: slot i's table is
+        # pages i * pmax / 2 on, so the slots share half their pages
+        pool = flat.reshape(1, 2 * pmax, ps, row)
+        bt = (jnp.arange(s)[:, None] * (pmax // 4)
+              + jnp.arange(pmax)[None]).astype(jnp.int32)
+        run = jax.jit(lambda q_, p_, b_, l_, r_: paged_latent_attention(
+            q_, p_, b_, l_, r_, r, 0, v_lanes=dc, scale_dim=192))
+        check_compiled_kernels(label, run, q, pool, bt, lens, rows)
+        got = run(q, pool, bt, lens, rows)
+        if want is None:
+            mask_pool = jnp.where(
+                jnp.arange(block)[None] < lens[:, None], 0.0, -jnp.inf
+            )[:, None, None, None, :]
+            mask_rec = jnp.where(jnp.arange(16) <= r, 0.0, -jnp.inf)
+            want = jax.jit(lambda q_, p_, b_, r_: _gather_attend(
+                q_[:, :, :, None], r_, r_[..., :dc], mask_pool, mask_rec,
+                p_, None, None, None, b_, 0, scale_dim=192,
+            )[:, :, :, 0])(q, pool, bt, rows)
+        check(np.isfinite(np.asarray(got, np.float32)).all(),
+              f"{label}: non-finite output")
+        err = rel_err(got, want)
+        check(err <= KERNEL_TOL,
+              f"{label}: rel. error vs the gather path {err} > {KERNEL_TOL}")
+        say(f"  {label}: agrees with the gather path (max rel. error "
+            f"{err:.2e})")
+
+    label = "latent prefill chunk[256 rows against 33 k pooled rows]"
+    t, start, ps = 256, block - 512, 64
+    x = jax.random.normal(ks[3], (1, start + t, 2048)).astype(bf)
+    sin, cos = rope_tables(64, block, 32e6)
+    sin, cos = jnp.asarray(sin, bf), jnp.asarray(cos, bf)
+
+    @jax.jit
+    def pooled_rows(xc, sin_c, cos_c):
+        return attn._project(xc, sin_c, cos_c)[2]
+
+    cuts = list(range(0, start, 4096)) + [start]
+    ctx = jnp.concatenate([
+        pooled_rows(x[:, i:j], sin[i:j], cos[i:j])
+        for i, j in zip(cuts, cuts[1:])], axis=1)[0]  # [start, row]
+    pool = jnp.zeros((1, block // ps, ps, row), bf).at[0].set(
+        jnp.pad(ctx, ((0, block - start), (0, 0))).reshape(-1, ps, row))
+    bt = jnp.arange(block // ps, dtype=jnp.int32)[None]
+    ii = jnp.arange(t)
+    chunk = jax.jit(lambda xc, p_: attn.prefill_paged_at(
+        xc, p_, bt, 0, jnp.where(jnp.arange(block) < start, 0.0, -jnp.inf),
+        jnp.where(ii[None, :] <= ii[:, None], 0.0, -jnp.inf),
+        sin[start:start + t], cos[start:start + t])[0])
+    got = chunk(x[:, start:], pool)
+
+    @jax.jit
+    def published(xc, ctx_rows):
+        # every key up-projected, nothing absorbed: the chunk's rows
+        # against [context | themselves]
+        q_nope, q_rope, own = attn._project(
+            xc, sin[start:start + t], cos[start:start + t])
+        allr = jnp.concatenate([ctx_rows, own[0]], axis=0)
+        kv = attn.wkv_b(allr[:, :dc]).reshape(-1, 32, 256)
+        sc = jnp.einsum("htn,shn->hts", q_nope[0], kv[..., :128],
+                        preferred_element_type=jnp.float32)
+        sc = sc + jnp.einsum("htr,sr->hts", q_rope[0], allr[:, dc:dc + 64],
+                             preferred_element_type=jnp.float32)
+        seen = jnp.arange(start + t)[None, :] <= (start + ii)[:, None]
+        p = jax.nn.softmax(jnp.where(seen, sc / 192 ** 0.5, -jnp.inf), -1)
+        o = jnp.einsum("hts,shv->thv", p.astype(bf), kv[..., 128:])
+        return attn.wo(o.reshape(1, t, 32 * 128))
+
+    err = rel_err(got, published(x[:, start:], ctx))
+    check(np.isfinite(np.asarray(got, np.float32)).all(),
+          f"{label}: non-finite output")
+    check(err <= KERNEL_TOL,
+          f"{label}: rel. error vs the published form {err} > {KERNEL_TOL}")
+    say(f"  {label}: the absorbed chunk agrees with the published form "
+        f"(max rel. error {err:.2e})")
+
+
 # ---------------------------------------------------------------------------
 # 3. train   4. resume
 # ---------------------------------------------------------------------------
@@ -1086,7 +1207,7 @@ def main() -> int:
     ap.add_argument(
         "--only", default="",
         help="one chip: after phase 1, only the phases numbered here "
-             "(2, 6 and 7 stand alone; 4 needs 3, 5 needs 4), e.g. 2,6",
+             "(2, 6, 7 and 8 stand alone; 4 needs 3, 5 needs 4), e.g. 2,6",
     )
     args = ap.parse_args()
     only = {int(n) for n in args.only.split(",") if n}
@@ -1120,6 +1241,9 @@ def main() -> int:
             if want(7):
                 with phase("7 gated delta rule"):
                     gated_delta_vs_numpy(args.seed)
+            if want(8):
+                with phase("8 latent attention"):
+                    latent_attention_vs_numpy(args.seed)
         else:
             cfg = smoke_config(args.workdir, args.seed, SHARDED_SET)
             with phase("sharded training: fsdp=2 x tensor=2 vs one device"):

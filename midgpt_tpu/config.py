@@ -117,6 +117,32 @@ class ModelConfig:
     # "pre": x + f(norm(x)) (midGPT); "post": x + norm(f(x)), the norm on a
     # sub-layer's output before the residual add (OLMo 2's reordered norm)
     norm_order: str = "pre"
+    # attention="latent": multi-head latent attention (models/gpt
+    # .LatentAttention) in every layer — queries through a rank-``latent_q``
+    # bottleneck with an RMSNorm, keys and values up-projected from one
+    # normed ``latent_kv``-wide latent a token, ``latent_rope`` decoupled
+    # rotary lanes a token shared by all heads beside ``latent_nope``
+    # unrotated ones a head, values of ``latent_v`` a head. What is cached a
+    # token a layer is the latent and the rotary key, nothing a head
+    attention: str = "heads"  # "heads" | "latent"
+    latent_q: int = 0
+    latent_kv: int = 0
+    latent_nope: int = 0
+    latent_rope: int = 0
+    latent_v: int = 0
+    # the first ``dense_layers`` layers' MLP is a dense SwiGLU of
+    # ``mlp_hidden`` whatever ``mlp`` says of the others (a stack of their
+    # own: models/gpt.GPT.dense_blocks)
+    dense_layers: int = 0
+    # mlp="experts": the router's scoring, "softmax" over all experts or
+    # "sigmoid" of each; ``expert_bias``: a learned per-expert bias added to
+    # the scores for the CHOICE only, never to a weight; the chosen weights
+    # times ``expert_scale``; ``shared_experts`` SwiGLU experts of
+    # ``expert_hidden`` that every row goes through, added once
+    expert_scoring: str = "softmax"
+    expert_bias: bool = False
+    expert_scale: float = 1.0
+    shared_experts: int = 0
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -125,25 +151,82 @@ class ModelConfig:
             assert set(kinds) <= {"full_attention", "linear_attention"}, kinds
             object.__setattr__(self, "layer_types", kinds)
         assert self.norm_order in ("pre", "post"), self.norm_order
+        assert self.attention in ("heads", "latent"), self.attention
+        assert self.expert_scoring in ("softmax", "sigmoid"), (
+            self.expert_scoring
+        )
+        assert 0 <= self.dense_layers < self.n_layer, self.dense_layers
+        if self.latent:
+            assert self.layer_types is None, (
+                "latent attention beside linear-attention layers has no form"
+            )
+            assert min(self.latent_q, self.latent_kv, self.latent_nope,
+                       self.latent_rope, self.latent_v) >= 1, self
+
+    @property
+    def latent(self) -> bool:
+        return self.attention == "latent"
 
     @property
     def layer_plan(self) -> tp.Tuple[tp.Tuple[str, int], ...]:
-        """Per layer: its kind ("full" | "linear") and its index within
-        the stack of its kind — which is also its row of the cache of that
-        kind (the KV pool's layer axis counts full layers only, the
-        recurrent state's linear ones)."""
+        """Per layer: its mixer's kind ("full" | "linear" | "latent") and its
+        row of the cache of that kind (the page pool's layer axis counts the
+        full or latent layers, the recurrent state's the linear ones)."""
         kinds = self.layer_types or ("full_attention",) * self.n_layer
-        seen = {"full": 0, "linear": 0}
+        seen = {"full": 0, "linear": 0, "latent": 0}
         plan = []
         for k in kinds:
-            kind = "linear" if k == "linear_attention" else "full"
+            kind = "linear" if k == "linear_attention" else (
+                "latent" if self.latent else "full"
+            )
             plan.append((kind, seen[kind]))
             seen[kind] += 1
         return tuple(plan)
 
     @property
+    def stack_plan(self) -> tp.Tuple[tp.Tuple[str, int], ...]:
+        """Per layer: the stack of ``models.gpt.GPT`` that holds it — one a
+        (mixer kind, MLP kind) present: ``dense_blocks`` (the leading
+        ``dense_layers``), ``lin_blocks`` (linear attention), ``blocks`` (the
+        others) — and its index there."""
+        seen = {"dense_blocks": 0, "lin_blocks": 0, "blocks": 0}
+        plan = []
+        for n, (kind, _) in enumerate(self.layer_plan):
+            stack = "dense_blocks" if n < self.dense_layers else (
+                "lin_blocks" if kind == "linear" else "blocks"
+            )
+            plan.append((stack, seen[stack]))
+            seen[stack] += 1
+        return tuple(plan)
+
+    @property
     def kv_layers(self) -> int:
-        return sum(1 for kind, _ in self.layer_plan if kind == "full")
+        """Layers whose cache is pages of the pool."""
+        return sum(1 for kind, _ in self.layer_plan if kind != "linear")
+
+    @property
+    def expert_layers(self) -> int:
+        return self.n_layer - self.dense_layers if self.mlp == "experts" else 0
+
+    @property
+    def latent_row(self) -> int:
+        """Lanes of a pooled latent row: the latent, then the rotary key,
+        zero-padded to whole 128-lane tiles (the page the chip DMAs whole)."""
+        return -(-(self.latent_kv + self.latent_rope) // 128) * 128
+
+    @property
+    def pool_heads(self) -> int:
+        """The pool row's head axis: KV heads, or one for a latent row."""
+        return 1 if self.latent else self.kv_heads
+
+    @property
+    def pool_width(self) -> int:
+        return self.latent_row if self.latent else self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """Lanes the rotary embedding turns."""
+        return self.latent_rope if self.latent else self.head_dim
 
     @property
     def linear_layers(self) -> int:

@@ -21,6 +21,7 @@ depth (parity: model.py:130-155).
 
 from __future__ import annotations
 
+import functools
 import math
 import typing as tp
 
@@ -172,6 +173,7 @@ def _gather_attend(
     mask_pool,  # additive f32, broadcastable to [S, Hkv, G, T, W]
     mask_own,  # additive f32, broadcastable to [S, Hkv, G, T, R]
     pool_k, pool_v, pool_sk, pool_sv, bt, layer,
+    scale_dim: tp.Optional[int] = None,
 ):
     """The XLA gather path's attention core in the DECODE choreography,
     shared by the decode window and the verify program: the slots' pages
@@ -185,7 +187,13 @@ def _gather_attend(
     the chip the multiply-sum form, in a kernel, streamed the cache at a
     quarter of the rate (PERF.md section 6, PR 33) — and is held to this
     core at a tolerance (its module docstring, THE CONTRACT). Returns f32
-    ``[S, Hkv, G, T, C]``."""
+    ``[S, Hkv, G, T, C]``.
+
+    A LATENT pool (``pool_v`` None; ``LatentAttention``): one "KV head"
+    whose key row is the pooled row as it lies and whose value is that row's
+    first ``own_v.shape[-1]`` lanes — one gather serves both sums — with the
+    softmax scaled by ``scale_dim`` (the published head's q·k width, not the
+    row's)."""
     from midgpt_tpu.ops.paged_attn import banded_fold, resolved_band_pages
 
     hkv, c = qg.shape[1], qg.shape[-1]
@@ -195,10 +203,14 @@ def _gather_attend(
     # is shard-local: each device gathers its own heads' pages. Pin the
     # view so the partitioner can never "help" by regathering heads (the
     # no-batch-allgather-in-page-gather audit rule gates that footgun).
+    latent = pool_v is None
     ck = _gathered_pool_view(pool_k, pool_sk, bt, layer, hkv)
-    cv = _gathered_pool_view(pool_v, pool_sv, bt, layer, hkv)
-    ck = shard_act(ck, None, "kv_heads", None, None)
-    cv = shard_act(cv, None, "kv_heads", None, None)
+    if latent:
+        cv = ck[:, :, : own_v.shape[-1]]
+    else:
+        cv = _gathered_pool_view(pool_v, pool_sv, bt, layer, hkv)
+        ck = shard_act(ck, None, "kv_heads", None, None)
+        cv = shard_act(cv, None, "kv_heads", None, None)
     s_pool = jnp.sum(
         qg[..., :, None].astype(jnp.float32)
         * ck[:, :, None, None].astype(jnp.float32),
@@ -210,7 +222,7 @@ def _gather_attend(
         axis=-1,
     )  # [S, Hkv, G, T, R]
     s_all = jnp.concatenate([s_pool + mask_pool, s_own + mask_own], axis=-1)
-    probs = jax.nn.softmax(s_all / math.sqrt(c), axis=-1)  # f32
+    probs = jax.nn.softmax(s_all / math.sqrt(scale_dim or c), axis=-1)  # f32
     w_pool = s_pool.shape[-1]
     p_pool = probs[..., :w_pool]
     p_own = probs[..., w_pool:]
@@ -221,7 +233,7 @@ def _gather_attend(
     # contexts. One band (every small geometry) is the single unsliced
     # reduce.
     bw = resolved_band_pages(
-        bt.shape[1], ps, c, jnp.dtype(pool_k.dtype).itemsize
+        bt.shape[1], ps, c, jnp.dtype(pool_k.dtype).itemsize, latent
     ) * ps
     if bw >= w_pool:
         o_pool = jnp.sum(
@@ -1160,6 +1172,267 @@ class GatedDeltaNet:
         return self._out(o[:, None], gate), states, tails
 
 
+# a prefill chunk's f32 score block ``[heads, T, W + T]`` is held for the
+# softmax: heads are taken in groups of at most this many bytes of it (256 rows
+# against 33k pooled positions are 35 MB a head)
+_LATENT_SCORE_BYTES = 512 * 1024 * 1024
+
+
+@module
+class LatentAttention:
+    """Multi-head latent attention (DeepSeek-V2/V3's): queries through a
+    low-rank bottleneck with an RMSNorm, keys and values up-projected from
+    ONE normed latent ``c`` a token (``kv_rank`` wide), and a decoupled rotary
+    key ``kr`` of ``rope`` lanes a token that all heads share beside each
+    head's ``nope`` unrotated lanes. A head's score is ``(q_nope . k_nope +
+    q_rope . kr) / sqrt(nope + rope)``, its value ``v_dim`` wide.
+
+    What a layer caches a token is the pooled ROW ``[c | kr | 0...]`` — after
+    the norm and after the rotation, zero-padded to whole lane tiles
+    (``ModelConfig.latent_row``) — and nothing a head. Two orders of one sum:
+
+    - PUBLISHED (:meth:`__call__`, a whole sequence from nothing): ``[k_nope
+      | v]_h = c Wkvb_h``, then ordinary attention a head;
+    - ABSORBED (the cached bodies): with ``Wkvb_h = [Wuk_h | Wuv_h]``,
+      ``q^_h = q_nope_h Wuk_h^T``, a score is ``(q^_h . c + q_rope_h . kr)``
+      — the head's query row ``[q^_h | q_rope_h]`` against the pooled row as
+      it lies — ``o^_h = sum_t p_t c_t`` and ``o_h = o^_h Wuv_h``. To the
+      attention core that is ONE KV head of ``n_head`` query rows whose key
+      is the row and whose value is the row's first ``kv_rank`` lanes, so the
+      decode step runs through :func:`_gather_attend` or the paged kernel's
+      latent mode (ops.paged_attn: one page DMA serves both products) and the
+      prefill chunk through the naive-attention choreography of
+      :meth:`Attention.prefill_paged_at`; the scale stays that of the
+      published head, never the row's width."""
+
+    wq_a: Linear  # [D, q_rank]
+    q_norm: RMSNorm  # over q_rank
+    wq_b: Linear  # [q_rank, H (nope + rope)]: a head's q_nope | q_rope
+    wkv_a: Linear  # [D, kv_rank + rope]: the latent | the rotary key
+    kv_norm: RMSNorm  # over kv_rank
+    wkv_b: Linear  # [kv_rank, H (nope + v_dim)]: a head's k_nope | v
+    wo: Linear  # [H v_dim, D]
+    n_head: int = static()
+    nope: int = static()
+    rope: int = static()
+    v_dim: int = static()
+    row: int = static()  # lanes of a pooled row
+    rope_style: str = static(default="interleaved")
+
+    @staticmethod
+    def init(key: KeyArray, cfg: ModelConfig) -> "LatentAttention":
+        k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+        h, dn, dr, dv = (cfg.n_head, cfg.latent_nope, cfg.latent_rope,
+                         cfg.latent_v)
+        eps = cfg.norm_eps or 1e-6
+        return LatentAttention(
+            wq_a=Linear.init(k1, cfg.n_embd, cfg.latent_q),
+            q_norm=RMSNorm.init(cfg.latent_q, True, eps, "jnp"),
+            wq_b=Linear.init(k2, cfg.latent_q, h * (dn + dr)),
+            wkv_a=Linear.init(k3, cfg.n_embd, cfg.latent_kv + dr),
+            kv_norm=RMSNorm.init(cfg.latent_kv, True, eps, "jnp"),
+            wkv_b=Linear.init(k4, cfg.latent_kv, h * (dn + dv)),
+            wo=Linear.init(k5, h * dv, cfg.n_embd),
+            n_head=h, nope=dn, rope=dr, v_dim=dv, row=cfg.latent_row,
+            rope_style=cfg.rope_style,
+        )
+
+    @property
+    def kv_rank(self) -> int:
+        return self.wkv_b.weight.shape[-2]
+
+    def _project(self, x: Array, sin_rows: Array, cos_rows: Array):
+        """The prologue: ``x`` [S, T, D] to a head's ``q_nope`` [S, H, T,
+        nope] and rotated ``q_rope`` [S, H, T, rope], and the tokens' pooled
+        rows [S, T, row] = normed latent | rotated key | zeros."""
+        s, t, _ = x.shape
+        h, dn, dr, dc = self.n_head, self.nope, self.rope, self.kv_rank
+        with jax.named_scope("mla_q"):
+            q = self.wq_b(self.q_norm(self.wq_a(x))).reshape(s, t, h, dn + dr)
+            q = jnp.transpose(q, (0, 2, 1, 3))  # [S, H, T, nope + rope]
+            q_nope = q[..., :dn]
+            q_rope = apply_rotary(
+                q[..., dn:], sin_rows, cos_rows, self.rope_style
+            )
+        with jax.named_scope("mla_kv_a"):
+            kv = self.wkv_a(x)  # [S, T, kv_rank + rope]
+            c = self.kv_norm(kv[..., :dc])
+            # one rotary key a token: a "head" axis of one, for the rows'
+            # broadcast
+            kr = apply_rotary(
+                kv[..., dc:][:, None], sin_rows, cos_rows, self.rope_style
+            )[:, 0]
+            pad = jnp.zeros((s, t, self.row - dc - dr), c.dtype)
+            rows = jnp.concatenate([c, kr, pad], axis=-1)
+        return q_nope, q_rope, rows
+
+    def _up(self):
+        """``Wkvb`` a head: ``Wuk`` [kv_rank, H, nope], ``Wuv`` [kv_rank, H,
+        v_dim]."""
+        w = self.wkv_b.weight.reshape(
+            self.kv_rank, self.n_head, self.nope + self.v_dim
+        )
+        return w[..., : self.nope], w[..., self.nope:]
+
+    def _absorb(self, q_nope: Array, q_rope: Array) -> Array:
+        """A head's query row against a pooled row: ``[q_nope Wuk^T | q_rope
+        | 0...]`` [S, H, T, row]."""
+        with jax.named_scope("mla_absorb"):
+            q_hat = jnp.einsum(
+                "shtn,chn->shtc", q_nope, self._up()[0].astype(q_nope.dtype)
+            )
+            pad = jnp.zeros(
+                q_hat.shape[:-1] + (self.row - self.kv_rank - self.rope,),
+                q_hat.dtype,
+            )
+            return jnp.concatenate([q_hat, q_rope, pad], axis=-1)
+
+    def _out(self, o_lat: Array) -> Array:
+        """The epilogue: a head's weighted latent ``o^`` [S, H, T, kv_rank]
+        through ``Wuv`` and ``wo`` to [S, T, D]."""
+        s, h, t, _ = o_lat.shape
+        with jax.named_scope("mla_out"):
+            o = jnp.einsum(
+                "shtc,chv->sthv", o_lat, self._up()[1].astype(o_lat.dtype)
+            )
+            return self.wo(o.reshape(s, t, h * self.v_dim))
+
+    def __call__(
+        self, x: Array, sin, cos, *, return_kv: bool = False, **_
+    ) -> Array:
+        """A whole sequence from nothing, in the published form."""
+        assert not return_kv, "a latent layer's cache is rows, not K/V"
+        b, t, _ = x.shape
+        h, dn, dc = self.n_head, self.nope, self.kv_rank
+        q_nope, q_rope, rows = self._project(x, sin, cos)
+        c, kr = rows[..., :dc], rows[..., dc : dc + self.rope]
+        kv = self.wkv_b(c).reshape(b, t, h, dn + self.v_dim)
+        scores = jnp.einsum(
+            "bhtn,bshn->bhts", q_nope, kv[..., :dn],
+            preferred_element_type=jnp.float32,
+        ) + jnp.einsum(
+            "bhtr,bsr->bhts", q_rope, kr, preferred_element_type=jnp.float32
+        )
+        ii = jnp.arange(t)
+        scores = jnp.where(ii[None, :] <= ii[:, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(
+            scores / math.sqrt(dn + self.rope), axis=-1
+        ).astype(x.dtype)
+        o = jnp.einsum("bhts,bshv->bthv", probs, kv[..., dn:])
+        return shard_act(
+            self.wo(o.reshape(b, t, h * self.v_dim)), "batch", "seq", "embed"
+        )
+
+    def decode_paged_at(
+        self,
+        x: Array,  # [S, 1, D] — one new token per decode slot
+        pool: Array,  # [L, NP, PS, row] the latent page pool, READ-ONLY here
+        bt: Array,  # [S, Pmax] int32 block tables
+        rk: Array,  # [L, S, 1, R, row] the window's recent rows
+        layer: int,  # STATIC: this layer's row of ``pool`` and ``rk``
+        r: Array,  # [] int32 — step index within the decode window
+        mask_pool: Array,  # [S, W = Pmax*PS] additive f32
+        mask_rec: Array,  # [R] additive f32
+        sin_rows: Array,  # [S, 1, 1, rope // 2]
+        cos_rows: Array,
+        pooled_len: Array,  # [S] int32
+        paged_kernel: str = "xla",
+    ) -> tp.Tuple[Array, Array]:
+        """One token a slot against the slot's pooled rows and the window's
+        recent ones, absorbed: ``q^``, ONE attention call, ``Wuv``, ``wo``."""
+        s = x.shape[0]
+        h, dc = self.n_head, self.kv_rank
+        q_nope, q_rope, rows = self._project(x, sin_rows, cos_rows)
+        q_hat = self._absorb(q_nope, q_rope)  # [S, H, 1, row]
+        zero = jnp.zeros((), r.dtype)
+        rk = jax.lax.dynamic_update_slice(
+            rk, rows.astype(rk.dtype)[None, :, None],
+            (jnp.asarray(layer, r.dtype), zero, zero, r, zero),
+        )
+        rkl = rk[layer]  # [S, 1, R, row]
+        with jax.named_scope("mla_decode"):
+            if paged_kernel == "pallas":
+                from midgpt_tpu.ops.paged_attn import paged_latent_attention
+
+                out = paged_latent_attention(
+                    q_hat[:, None, :, 0], pool, bt, pooled_len, rkl, r, layer,
+                    v_lanes=dc, scale_dim=self.nope + self.rope,
+                )  # [S, 1, H, kv_rank]
+            else:
+                out = _gather_attend(
+                    q_hat[:, None], rkl, rkl[..., :dc],
+                    mask_pool[:, None, None, None, :], mask_rec,
+                    pool, None, None, None, bt, layer,
+                    scale_dim=self.nope + self.rope,
+                ).astype(x.dtype)  # [S, 1, H, 1, kv_rank]
+        return self._out(out.reshape(s, h, 1, dc)), rk
+
+    def prefill_paged_at(
+        self,
+        x: Array,  # [1, T, D] — the prefill chunk's hidden states
+        pool: Array,  # [L, NP, PS, row], READ-ONLY here
+        bt: Array,  # [1, Pmax] int32 — the slot's block table
+        layer: int,  # STATIC
+        mask_pool: Array,  # [W = Pmax*PS] additive f32 (0 where pos < start)
+        mask_self: Array,  # [T, T] additive causal f32 within the chunk
+        sin_rows: Array,  # [T, rope // 2]
+        cos_rows: Array,
+    ) -> tp.Tuple[Array, Array]:
+        """A chunk's T rows against the slot's pooled rows and themselves,
+        absorbed, in :meth:`Attention.prefill_paged_at`'s choreography
+        (compute-dtype operands, f32 accumulation, mask before the scale,
+        probabilities rounded to the value dtype): the pooled rows are read
+        as they lie, nothing is up-projected a context token. Absorbed costs
+        2 x 1,088 FLOP a head a key against 2 x 320 and ``kv_rank x H (nope +
+        v_dim)`` a context token published: at 256 rows a chunk the same
+        arithmetic within a quarter, without a [W, H, nope + v_dim]
+        intermediate a layer (PERF.md section 6, PR 34). Returns the chunk's
+        pooled rows [1, 1, T, row] for the page write."""
+        _, t, _ = x.shape
+        h, dc = self.n_head, self.kv_rank
+        q_nope, q_rope, rows = self._project(x, sin_rows, cos_rows)
+        q_hat = self._absorb(q_nope, q_rope)  # [1, H, T, row]
+        own = rows[:, None]  # [1, 1, T, row]
+        with jax.named_scope("mla_prefill"):
+            ck = _gathered_pool_view(pool, None, bt, layer, 1)  # [1,1,row,W]
+            ck = ck.astype(q_hat.dtype)
+            w = ck.shape[-1]
+            scale = 1.0 / jnp.sqrt(self.nope + self.rope).astype(jnp.float32)
+
+            def attend(qg):  # [1, 1, G, T, row] -> [1, 1, G, T, kv_rank]
+                s_pool = jnp.einsum(
+                    "bhgtc,bhcw->bhgtw", qg, ck,
+                    preferred_element_type=jnp.float32,
+                )
+                s_self = jnp.einsum(
+                    "bhgtc,bhsc->bhgts", qg, own,
+                    preferred_element_type=jnp.float32,
+                )
+                s_all = jnp.concatenate(
+                    [s_pool + mask_pool, s_self + mask_self], axis=-1
+                )
+                probs = jax.nn.softmax(s_all * scale, axis=-1).astype(
+                    own.dtype
+                )
+                return jnp.einsum(
+                    "bhgtw,bhcw->bhgtc", probs[..., :w], ck[:, :, :dc]
+                ) + jnp.einsum(
+                    "bhgts,bhsc->bhgtc", probs[..., w:], own[..., :dc]
+                )
+
+            g = h
+            while g % 2 == 0 and g * t * (w + t) * 4 > _LATENT_SCORE_BYTES:
+                g //= 2
+            if g == h:
+                out = attend(q_hat[:, None])
+            else:
+                out = jax.lax.map(
+                    attend, q_hat.reshape(h // g, 1, 1, g, t, self.row)
+                )
+            out = out.astype(x.dtype).reshape(1, h, t, dc)
+        return self._out(out), own
+
+
 def mlp_hidden_dim(cfg: ModelConfig) -> int:
     """MLP hidden width. Fractional ratios (SwiGLU's 8/3) round UP to a
     multiple of 256 — int(8/3 * 4096) = 10922 is not even lane-aligned
@@ -1455,23 +1728,41 @@ class ExpertMLP:
     prefill chunk's hundreds of rows, a denoising forward's slots x block,
     a ``train()`` batch (``ragged_dot`` differentiates).
 
+    ``scoring="sigmoid"`` (DeepSeek-V3's router): ``p = sigmoid_f32(h
+    Wr)``, each expert's own; ``bias`` [E] is added to ``p`` for the CHOICE
+    of the k and never enters a weight; ``g = p / (sum(chosen p) + 1e-20)``
+    where ``renorm``, times ``scale``. ``shared``: a dense SwiGLU every live
+    row goes through, outside the sort, added once. With softmax scoring, no
+    bias, scale 1 and no shared expert the traced layer is what it was.
+
     Not here (ROADMAP Reach A1): an expert axis over a mesh (under a
-    tensor mesh the experts ride replicated), shared experts, int8 expert
-    tensors."""
+    tensor mesh the experts ride replicated), int8 expert tensors."""
 
     router: Linear  # [D, E]
     w_in: Array  # [E, D, 2F]: W1 (gate) | W3 (up)
     w_out: Array  # [E, F, D]: W2
     top_k: int = static()
     renorm: bool = static(default=True)
+    bias: tp.Optional[Array] = None  # [E] f32: moves the choice only
+    shared: tp.Optional[MLP] = None  # SwiGLU of shared_experts * F
+    scoring: str = static(default="softmax")
+    scale: float = static(default=1.0)
 
     @staticmethod
     def init(key: KeyArray, cfg: ModelConfig) -> "ExpertMLP":
-        kr, ki, ko = jax.random.split(key, 3)
+        kr, ki, ko, kb, ks = jax.random.split(key, 5)
         e, d, f = cfg.experts, cfg.n_embd, cfg.expert_hidden
         assert e >= 1 and f >= 1 and 1 <= cfg.experts_per_token <= e, (
             e, f, cfg.experts_per_token
         )
+        shared = None
+        if cfg.shared_experts:
+            k1, k2, k3 = jax.random.split(ks, 3)
+            fs = cfg.shared_experts * f
+            shared = MLP(
+                w_up=Linear.init(k1, d, fs), w_down=Linear.init(k2, fs, d),
+                w_gate=Linear.init(k3, d, fs),
+            )
         w_in = (1.0 / math.sqrt(d)) * jax.random.truncated_normal(
             ki, lower=-2, upper=2, shape=(e, d, 2 * f), dtype=jnp.float32
         )
@@ -1481,6 +1772,11 @@ class ExpertMLP:
         return ExpertMLP(
             router=Linear.init(kr, d, e), w_in=w_in, w_out=w_out,
             top_k=cfg.experts_per_token, renorm=cfg.expert_renorm,
+            # (a tenth of a sigmoid score's spread: it moves some choices)
+            bias=0.025 * jax.random.normal(kb, (e,), jnp.float32)
+            if cfg.expert_bias else None,
+            shared=shared, scoring=cfg.expert_scoring,
+            scale=float(cfg.expert_scale),
         )
 
     def __call__(
@@ -1522,19 +1818,30 @@ class ExpertMLP:
             # the router in f32 on every backend: a default-precision f32
             # product is bf16 passes on the TPU, and the 8th-against-9th
             # expert decision is the layer's near-tie surface
-            probs = jax.nn.softmax(
+            squash = (
+                jax.nn.sigmoid if self.scoring == "sigmoid"
+                else functools.partial(jax.nn.softmax, axis=-1)
+            )
+            probs = squash(
                 jnp.matmul(
                     xf.astype(jnp.float32),
                     self.router.weight.astype(jnp.float32),
                     precision=jax.lax.Precision.HIGHEST,
                 ),
-                axis=-1,
             )  # [N, E]
-            topv, topi = jax.lax.top_k(probs, k)  # [N, k]
-            gates = (
-                topv / jnp.sum(topv, axis=-1, keepdims=True)
-                if self.renorm else topv
-            )
+            if self.bias is None:
+                topv, topi = jax.lax.top_k(probs, k)  # [N, k]
+            else:
+                _, topi = jax.lax.top_k(
+                    probs + self.bias.astype(jnp.float32), k
+                )
+                topv = jnp.take_along_axis(probs, topi, axis=-1)
+            norm = jnp.sum(topv, axis=-1, keepdims=True)
+            if self.scoring == "sigmoid":
+                norm = norm + 1e-20  # (the published router's guard)
+            gates = topv / norm if self.renorm else topv
+            if self.scale != 1.0:
+                gates = gates * self.scale
             claims = topi.reshape(-1)  # [N k]: claim j is token j // k's
             if live is not None:
                 # expert id E: past every group, dropped by the count below
@@ -1548,6 +1855,10 @@ class ExpertMLP:
                 jnp.mean(first, axis=0) * jnp.mean(probs, axis=0)
             )
             xs = jnp.take(xf, order // k, axis=0)  # [N k, D]
+        y_shared = None
+        if self.shared is not None:
+            with jax.named_scope("expert_shared"):
+                y_shared = self.shared(xf[None])[0].astype(jnp.float32)
         with jax.named_scope("expert_matmul"):
             groups = rows
             if stacked is not None:
@@ -1563,9 +1874,10 @@ class ExpertMLP:
                 jnp.arange(order.shape[0], dtype=order.dtype)
             )
             yk = jnp.take(ys, back, axis=0).reshape(-1, k, d)
-            y = jnp.sum(
-                yk.astype(jnp.float32) * gates[..., None], axis=1
-            ).astype(x.dtype).reshape(x.shape)
+            y = jnp.sum(yk.astype(jnp.float32) * gates[..., None], axis=1)
+            if y_shared is not None:
+                y = y + y_shared
+            y = y.astype(x.dtype).reshape(x.shape)
             if live is not None:
                 # (what the kernel left in rows of no group is not a number)
                 y = jnp.where(live[..., None], y, 0)
@@ -1585,11 +1897,16 @@ def make_mlp(key: KeyArray, cfg: ModelConfig):
 
 def stacked_experts(model, layer) -> tp.Optional[tp.Tuple]:
     """``ExpertMLP``'s ``stacked`` argument for ``layer`` of ``model``
-    (None for every other kind of MLP)."""
+    (None for every other kind of MLP): the expert tensors of the stack
+    ``model.blocks`` and the layer's index there — behind
+    ``config.dense_layers`` leading dense layers, which have none."""
     mlp = model.blocks.mlp
-    if not isinstance(mlp, ExpertMLP):
+    dense = model.config.dense_layers
+    if not isinstance(mlp, ExpertMLP) or (
+        isinstance(layer, int) and layer < dense
+    ):
         return None
-    return mlp.w_in, mlp.w_out, layer
+    return mlp.w_in, mlp.w_out, layer - dense if dense else layer
 
 
 def mlp_call(mlp, x, *, key=None, deterministic=True, with_stats=False,
@@ -1617,11 +1934,13 @@ def mlp_call(mlp, x, *, key=None, deterministic=True, with_stats=False,
 @module
 class Block:
     """Pre-norm residual block (parity: model.py:84-105). ``attn`` is the
-    block's mixer: full attention, or (``kind="linear"``) a gated delta
-    rule. ``norm_order="post"`` puts each norm on its sub-layer's OUTPUT,
-    before the residual add."""
+    block's mixer: full attention, (``kind="linear"``) a gated delta rule,
+    or (``kind="latent"``) latent attention; ``mlp`` what ``cfg.mlp`` says,
+    or (``dense=True``: a leading dense layer) a SwiGLU of ``mlp_hidden``.
+    ``norm_order="post"`` puts each norm on its sub-layer's OUTPUT, before
+    the residual add."""
 
-    attn: tp.Union[Attention, GatedDeltaNet]
+    attn: tp.Union[Attention, GatedDeltaNet, LatentAttention]
     mlp: tp.Union[MLP, "MoEMLP", "ExpertMLP"]
     ln1: RMSNorm
     ln2: RMSNorm
@@ -1634,13 +1953,20 @@ class Block:
         return norm(y) if self.norm_order == "post" else y
 
     @staticmethod
-    def init(key: KeyArray, cfg: ModelConfig, kind: str = "full") -> "Block":
+    def init(key: KeyArray, cfg: ModelConfig, kind: str = "full",
+             dense: bool = False) -> "Block":
+        import dataclasses
+
         k1, k2 = jax.random.split(key)
-        mixer = GatedDeltaNet if kind == "linear" else Attention
+        mixer = {"linear": GatedDeltaNet, "latent": LatentAttention}.get(
+            kind, Attention
+        )
         return Block(
             norm_order=cfg.norm_order,
             attn=mixer.init(k1, cfg),
-            mlp=make_mlp(k2, cfg),
+            mlp=make_mlp(
+                k2, dataclasses.replace(cfg, mlp="swiglu") if dense else cfg
+            ),
             # weightless block norms (model.py:94-95, layers.py:64-68)
             # unless the config asks for the learned scale
             ln1=RMSNorm.init(
@@ -1715,6 +2041,41 @@ class Block:
         x = x + self._post(self.ln1, mixer_out)
         y = mlp_call(self.mlp, self._pre(self.ln2, x), **kw)[0]
         return x + self._post(self.ln2, y)
+
+    def decode_latent_at(
+        self, x, pool, bt, rk, layer, r, mask_pool, mask_rec, sin_rows,
+        cos_rows, pooled_len, paged_kernel="xla", experts=None, live=None,
+    ):
+        """A latent-attention block's decode step (LatentAttention
+        .decode_paged_at). ``experts``, ``live``: ExpertMLP's ``stacked`` and
+        ``live``; of such a block also the rows routed to each expert [E]."""
+        with jax.named_scope("attention"):
+            out, rk = self.attn.decode_paged_at(
+                self._pre(self.ln1, x), pool, bt, rk, layer, r, mask_pool,
+                mask_rec, sin_rows, cos_rows, pooled_len,
+                paged_kernel=paged_kernel,
+            )
+        if not isinstance(self.mlp, ExpertMLP):
+            return self._mlp_residual(x, out), rk
+        x = x + self._post(self.ln1, out)
+        y, _, rows = self.mlp(
+            self._pre(self.ln2, x), return_rows=True, stacked=experts,
+            live=live,
+        )
+        return x + self._post(self.ln2, y), rk, rows
+
+    def prefill_latent_at(
+        self, x, pool, bt, layer, mask_pool, mask_self, sin_rows, cos_rows,
+        experts=None,
+    ):
+        """A latent-attention block over one slot's prefill chunk
+        (LatentAttention.prefill_paged_at)."""
+        with jax.named_scope("attention"):
+            out, rows = self.attn.prefill_paged_at(
+                self._pre(self.ln1, x), pool, bt, layer, mask_pool,
+                mask_self, sin_rows, cos_rows,
+            )
+        return self._mlp_residual(x, out, stacked=experts), rows
 
     def decode_state_at(self, x, states, tails, layer, valid):
         """A linear-attention block's decode step (GatedDeltaNet.decode_at)."""
@@ -1839,17 +2200,29 @@ class GPT:
     # a model with ``config.layer_types``: its linear-attention layers, a
     # stack of their own; ``config.layer_plan`` says which layer is which
     lin_blocks: tp.Optional[Block] = None
+    # the leading ``config.dense_layers`` layers, whose MLP is a dense SwiGLU
+    # whatever the others' is: one stack a (mixer, MLP) kind present
+    # (``config.stack_plan``)
+    dense_blocks: tp.Optional[Block] = None
 
-    def layer(self, kind: str, i: int) -> Block:
-        """Layer ``i`` of the stack of ``kind`` (static slices)."""
-        stack = self.lin_blocks if kind == "linear" else self.blocks
-        return jax.tree.map(lambda a: a[i], stack)
+    def layer(self, n: int) -> Block:
+        """Layer ``n`` of the model, out of the stack that holds it (static
+        slices)."""
+        stack, i = self.config.stack_plan[n]
+        return jax.tree.map(lambda a: a[i], getattr(self, stack))
 
     @staticmethod
     def init(key: KeyArray, cfg: ModelConfig) -> "GPT":
         block_key, head_key = jax.random.split(key)
         block_keys = jax.random.split(block_key, cfg.n_layer)
-        lin_blocks = None
+        lin_blocks = dense_blocks = None
+        kind = "latent" if cfg.latent else "full"
+        if cfg.dense_layers:
+            assert not cfg.linear_layers, "dense_layers beside layer_types"
+            dense_blocks = jax.vmap(
+                lambda k: Block.init(k, cfg, kind, dense=True)
+            )(block_keys[: cfg.dense_layers])
+            block_keys = block_keys[cfg.dense_layers:]
         if cfg.linear_layers:
             assert cfg.kv_layers, "a model needs a full-attention layer"
             where = {
@@ -1862,7 +2235,7 @@ class GPT:
                 block_keys[where["linear"]]
             )
             block_keys = block_keys[where["full"]]
-        blocks = jax.vmap(lambda k: Block.init(k, cfg))(block_keys)
+        blocks = jax.vmap(lambda k: Block.init(k, cfg, kind))(block_keys)
         embed_std = 1 / math.sqrt(cfg.n_embd)
         wte_wt = embed_std * jax.random.normal(
             head_key, (cfg.vocab_size, cfg.n_embd), dtype=jnp.float32
@@ -1883,6 +2256,7 @@ class GPT:
             lm_head=lm_head,
             config=cfg,
             lin_blocks=lin_blocks,
+            dense_blocks=dense_blocks,
         )
 
     def hidden(
@@ -1908,7 +2282,7 @@ class GPT:
         impl = attn_impl if attn_impl is not None else cfg.attn_impl
         b, t = tokens.shape
         assert t <= cfg.block_size, f"sequence {t} > block_size {cfg.block_size}"
-        sin, cos = rope_tables(cfg.head_dim, t, cfg.rope_base)
+        sin, cos = rope_tables(cfg.rope_dim, t, cfg.rope_base)
 
         drop_key, scan_keys = (None, None)
         if key is not None:
@@ -1959,14 +2333,14 @@ class GPT:
 
             unroll = cfg.scan_unroll if cfg.scan_unroll else cfg.n_layer
             carry0 = (h, jnp.zeros((), jnp.float32)) if return_aux else h
-            if self.lin_blocks is not None:
+            if self.lin_blocks is not None or self.dense_blocks is not None:
                 # layers of two kinds: no one scan body fits them, so the
                 # plan is walked layer by layer
                 assert not return_kv, "return_kv needs full attention only"
                 carry, kvs = carry0, None
-                for i, (kind, j) in enumerate(cfg.layer_plan):
+                for i in range(cfg.n_layer):
                     k = None if scan_keys is None else scan_keys[i]
-                    carry, _ = body(carry, (self.layer(kind, j), k))
+                    carry, _ = body(carry, (self.layer(i), k))
             else:
                 carry, kvs = jax.lax.scan(
                     body, carry0, (self.blocks, scan_keys), unroll=unroll
@@ -2110,7 +2484,9 @@ def decode_step(
     the block weights stream exactly once per token, and XLA fuses the
     whole layer into a handful of kernels."""
     cfg = model.config
-    assert model.lin_blocks is None, "decode_step needs full attention only"
+    assert model.lin_blocks is None and not cfg.latent, (
+        "decode_step needs full attention only"
+    )
     w = cache.k.shape[-1]
     sin_np, cos_np = rope_tables(cfg.head_dim, rope_len or w, cfg.rope_base)
     sin_t, cos_t = jnp.asarray(sin_np), jnp.asarray(cos_np)
@@ -2181,7 +2557,14 @@ def decode_step_paged(
     and convolution tails ``[Ll, S, taps - 1, Ch]`` — and ``valid`` ``[S]``
     (a slot whose token is none leaves both as they were), and returns the
     new ``state`` after ``rk, rv``. The pool's and the recent buffers' layer
-    axis counts the full-attention layers only."""
+    axis counts the full-attention layers only.
+
+    A latent-attention model (``config.latent``): ``pool_k`` is the latent
+    pool and ``rk`` the window's recent rows ``[L, S, 1, R, row]``;
+    ``pool_v`` and ``rv`` are None, and come back None. ``valid`` ``[S]``: a
+    slot whose token is none claims no expert; of a model with expert layers
+    the rows routed to each expert of each expert layer ``[Le, E]`` int32
+    are returned last."""
     cfg = model.config
     s = tokens.shape[0]
     pmax = bt.shape[1]
@@ -2189,12 +2572,13 @@ def decode_step_paged(
     rr = rk.shape[3]
     # KV-head-sharded pool (TP serving): pages and the time dim stay
     # whole per shard, so every block-table gather below is shard-local
-    pool_k = shard_act(pool_k, None, None, None, "kv_heads")
-    pool_v = shard_act(pool_v, None, None, None, "kv_heads")
+    if not cfg.latent:
+        pool_k = shard_act(pool_k, None, None, None, "kv_heads")
+        pool_v = shard_act(pool_v, None, None, None, "kv_heads")
     if pool_sk is not None:
         pool_sk = shard_act(pool_sk, None, None, "kv_heads")
         pool_sv = shard_act(pool_sv, None, None, "kv_heads")
-    sin_np, cos_np = rope_tables(cfg.head_dim, rope_len, cfg.rope_base)
+    sin_np, cos_np = rope_tables(cfg.rope_dim, rope_len, cfg.rope_base)
     sin_t, cos_t = jnp.asarray(sin_np), jnp.asarray(cos_np)
 
     # paged slot j of the gathered [W = Pmax*PS] view holds logical
@@ -2217,8 +2601,11 @@ def decode_step_paged(
     sin_h, cos_h = sin_rows.astype(h.dtype), cos_rows.astype(h.dtype)
     assert layer_scan in ("on", "off"), layer_scan
     assert (state is None) == (model.lin_blocks is None)
+    expert_rows = []
     if layer_scan == "on":
-        assert state is None, "layer_scan='on' needs identical layers"
+        assert state is None and not cfg.latent, (
+            "layer_scan='on' needs identical full-attention layers"
+        )
         quant = pool_sk is not None
 
         def body(hc, xs):
@@ -2238,10 +2625,19 @@ def decode_step_paged(
             xs = xs + (pool_sk, pool_sv)
         h, (rk, rv) = jax.lax.scan(body, h, xs)
     else:
-        for kind, i in cfg.layer_plan:
-            block = model.layer(kind, i)
+        for n, (kind, i) in enumerate(cfg.layer_plan):
+            block = model.layer(n)
             if kind == "linear":
                 h, *state = block.decode_state_at(h, *state, i, valid)
+                continue
+            if kind == "latent":
+                h, rk, *rows = block.decode_latent_at(
+                    h, pool_k, bt, rk, i, r, mask_pool, mask_rec, sin_h,
+                    cos_h, pooled_len, paged_kernel=paged_kernel,
+                    experts=stacked_experts(model, n),
+                    live=None if valid is None else valid[:, None],
+                )
+                expert_rows += rows
                 continue
             h, rk, rv = block.decode_paged_at(
                 h, pool_k, pool_v, bt, rk, rv, i, r, mask_pool, mask_rec,
@@ -2254,6 +2650,8 @@ def decode_step_paged(
     logits = shard_act(model.project(h)[:, 0, :], None, "vocab")  # [S, V]
     if state is not None:
         return logits, rk, rv, tuple(state)
+    if expert_rows:
+        return logits, rk, rv, jnp.stack(expert_rows)
     return logits, rk, rv
 
 
@@ -2307,18 +2705,23 @@ def prefill_chunk_paged(
     chunk finds it — states ``[Ll, 1, Hv, dk, dv]`` and convolution tails
     ``[Ll, 1, taps - 1, Ch]`` — and ``real_n`` (pad rows advance neither),
     and returns the state behind the chunk's last real row after ``ks,
-    vs``, which then count the full-attention layers only."""
+    vs``, which then count the full-attention layers only.
+
+    A latent-attention model: ``pool_k`` is the latent pool, ``pool_v``
+    None; ``ks`` are the chunk's pooled rows ``[L, 1, 1, T, row]`` and
+    ``vs`` is None."""
     cfg = model.config
     b, t = tokens.shape
     assert b == 1, f"chunk prefill is per-slot, got batch {b}"
     pmax = bt.shape[1]
     ps = pool_k.shape[2]
-    pool_k = shard_act(pool_k, None, None, None, "kv_heads")
-    pool_v = shard_act(pool_v, None, None, None, "kv_heads")
+    if not cfg.latent:
+        pool_k = shard_act(pool_k, None, None, None, "kv_heads")
+        pool_v = shard_act(pool_v, None, None, None, "kv_heads")
     if pool_sk is not None:
         pool_sk = shard_act(pool_sk, None, None, "kv_heads")
         pool_sv = shard_act(pool_sv, None, None, "kv_heads")
-    sin_np, cos_np = rope_tables(cfg.head_dim, rope_len, cfg.rope_base)
+    sin_np, cos_np = rope_tables(cfg.rope_dim, rope_len, cfg.rope_base)
     sin_t, cos_t = jnp.asarray(sin_np), jnp.asarray(cos_np)
 
     # paged slot w of the gathered [W = Pmax*PS] view holds logical
@@ -2347,7 +2750,9 @@ def prefill_chunk_paged(
     assert layer_scan in ("on", "off"), layer_scan
     assert (state is None) == (model.lin_blocks is None)
     if layer_scan == "on":
-        assert state is None, "layer_scan='on' needs identical layers"
+        assert state is None and not cfg.latent, (
+            "layer_scan='on' needs identical full-attention layers"
+        )
         # layer loop folded into one lax.scan (see decode_step_paged):
         # read-only pool/scale planes ride as xs, the chunk's per-layer
         # K/V land as scan ys — exactly the jnp.stack of the unrolled
@@ -2373,14 +2778,21 @@ def prefill_chunk_paged(
         h, (ks, vs) = jax.lax.scan(body, h, xs)
     else:
         ks, vs, states, tails = [], [], [], []
-        for kind, i in cfg.layer_plan:
-            block = model.layer(kind, i)  # static slices
+        for n, (kind, i) in enumerate(cfg.layer_plan):
+            block = model.layer(n)  # static slices
             if kind == "linear":
                 h, s_i, t_i = block.prefill_state_at(
                     h, state[0][i], state[1][i], real_n
                 )
                 states.append(s_i)
                 tails.append(t_i)
+                continue
+            if kind == "latent":
+                h, k = block.prefill_latent_at(
+                    h, pool_k, bt, i, mask_pool, mask_self, sin_h, cos_h,
+                    experts=stacked_experts(model, n),
+                )
+                ks.append(k)
                 continue
             h, k, v = block.prefill_paged_at(
                 h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
@@ -2389,8 +2801,10 @@ def prefill_chunk_paged(
             )
             ks.append(k)
             vs.append(v)
-        ks, vs = jnp.stack(ks), jnp.stack(vs)
+        ks, vs = jnp.stack(ks), jnp.stack(vs) if vs else None
     h = model.ln_f(h)
+    if cfg.latent:
+        return h, ks, None  # ks: [L, 1, 1, T, row]
     if sp:
         # final ln_f ran row-sharded; gather the chunk back replicated so
         # the caller's last-real-row slice and lm-head projection are the
@@ -2475,6 +2889,7 @@ def verify_tokens_paged(
     if pool_sk is not None:
         pool_sk = shard_act(pool_sk, None, None, "kv_heads")
         pool_sv = shard_act(pool_sv, None, None, "kv_heads")
+    assert not cfg.latent, "the verify rows have no latent form yet"
     sin_np, cos_np = rope_tables(cfg.head_dim, rope_len, cfg.rope_base)
     sin_t, cos_t = jnp.asarray(sin_np), jnp.asarray(cos_np)
 

@@ -272,8 +272,12 @@ def _build_decode_window(
     from midgpt_tpu.parallel.sharding import axis_rules, shard_act
     from midgpt_tpu.sampling import derive_request_key, sample_token
 
-    rshape = (cfg.kv_layers, slots, cfg.kv_heads, window, cfg.head_dim)
+    rshape = (cfg.kv_layers, slots, cfg.pool_heads, window, cfg.pool_width)
     hybrid = cfg.linear_layers > 0
+    # a latent-attention model: one recent buffer (the pooled rows), a slot
+    # whose token is none claims no expert, and of its expert layers the
+    # window counts the rows routed, as the block window does
+    latent, counting = cfg.latent, cfg.latent and cfg.expert_layers > 0
 
     def window_fn(
         model: GPT,  # ENTRY PARAMETER, not a closure constant: closed
@@ -305,7 +309,7 @@ def _build_decode_window(
             # for float pools, bf16 grid-rounded values for int8 pools
             # (PagedKVPool.row_dtype)
             rk = jnp.zeros(rshape, pool.row_dtype)
-            rv = jnp.zeros(rshape, pool.row_dtype)
+            rv = None if latent else jnp.zeros(rshape, pool.row_dtype)
 
             def sample(lg, em):
                 if temperature == 0.0:
@@ -343,22 +347,24 @@ def _build_decode_window(
                     pool_sv=pool.scale_v, paged_kernel=paged_kernel,
                     layer_scan=layer_scan,
                     **({"state": st[0], "valid": write_valid} if st else {}),
+                    **({"valid": write_valid} if latent else {}),
                 )
+                rows = (st.pop(),) if counting else ()  # [Le, E]
                 # the carry is f32 regardless of compute dtype (an exact
                 # widening — sampling sees the same values either way)
                 new_logits = new_logits.astype(logits.dtype)
                 return (
                     (new_logits, rk, rv, done, emitted, *st),
-                    (tok, ~pre_done, write_valid),
+                    (tok, ~pre_done, write_valid, *rows),
                 )
 
             st = ((state.s, state.conv),) if hybrid else ()
-            (logits, rk, rv, done, emitted, *st), (toks, emit, wvalid) = (
-                jax.lax.scan(
-                    body,
-                    (logits, rk, rv, done, emitted, *st),
-                    jnp.arange(window, dtype=jnp.int32),
-                )
+            (logits, rk, rv, done, emitted, *st), (
+                toks, emit, wvalid, *rows
+            ) = jax.lax.scan(
+                body,
+                (logits, rk, rv, done, emitted, *st),
+                jnp.arange(window, dtype=jnp.int32),
             )
             pool = flush_recent(
                 pool, rk, rv, bt, pooled_len, jnp.transpose(wvalid)
@@ -371,6 +377,15 @@ def _build_decode_window(
         if hybrid:
             return (pool, logits, toks, emit, done, new_len, emitted,
                     RecurrentState(*st[0]))
+        if counting:
+            r_kle = rows[0]  # [K, Le, E]
+            return (pool, logits, toks, emit, done, new_len, emitted, {
+                "expert_rows": jnp.sum(r_kle),
+                "expert_claims": jnp.sum(wvalid.astype(jnp.int32))
+                * (cfg.experts_per_token * cfg.expert_layers),
+                "expert_rows_max": jnp.sum(jnp.max(r_kle, axis=-1)),
+                "experts_touched": jnp.sum((r_kle > 0).astype(jnp.int32)),
+            })
         return pool, logits, toks, emit, done, new_len, emitted
 
     return jax.jit(
@@ -467,7 +482,8 @@ def _build_prefill_chunk_program(
             # page-birth quantization arithmetic would otherwise land
             # inside the LAST layer's segment and break homogeneity)
             pool = write_token_rows(
-                pool, ks[:, 0], vs[:, 0], bt_row, start, real_n
+                pool, ks[:, 0], None if vs is None else vs[:, 0], bt_row,
+                start, real_n,
             )
         if new:
             return pool, logits, state.with_slot(slot, *new[0])
@@ -1253,6 +1269,7 @@ _ENGINE_COUNTERS = (
     "occupancy_sum",
     "kv_pages_walked",
     "kv_pages_table",
+    "kv_pages_distinct",
     "evictions",
     "prompt_tokens_total",
     "prompt_tokens_cached",
@@ -1549,6 +1566,29 @@ class ServingEngine:
                     "a model with linear-attention layers does not support "
                     + ", ".join(bad)
                 )
+        # latent attention (cfg.attention="latent"): the pool holds one
+        # payload a page, a token's pooled row, and the decode window runs
+        # the paged kernel's latent mode. What has no latent form yet is
+        # refused here, by name (ROADMAP Reach A3); prefix_cache=True works
+        self.latent = model.config.latent
+        if self.latent:
+            unsupported = {
+                "speculate": bool(speculate),
+                "quant": quant is not None,
+                "kv_quant": kv_quant is not None,
+                "a mesh": mesh is not None,
+                "role != 'both'": role != "both",
+                "spill": spill == "on",
+                "prefill_sp": prefill_sp == "on",
+                "layer_scan='on'": layer_scan == "on",
+                "block_len": bool(model.config.block_len),
+            }
+            bad = [k for k, v in unsupported.items() if v]
+            if bad:
+                raise ValueError(
+                    "a model with latent attention does not support "
+                    + ", ".join(bad)
+                )
         if quant is not None:
             from midgpt_tpu.quant import is_quantized, quantize_model
 
@@ -1587,6 +1627,12 @@ class ServingEngine:
                 spec_t=2 * cfg.block_len or speculate + 1,
                 heads=max(1, cfg.kv_heads // tp_sz),
             )
+            if self.latent:
+                # one "KV head" of every query head's rows over the pooled
+                # rows as they lie (ops.paged_attn, LATENT MODE)
+                geometry.update(
+                    c=cfg.latent_row, groups=cfg.n_head, heads=1, latent=True
+                )
             kernel_ok = pk_supported(**geometry)
             if paged_kernel == "pallas" and not kernel_ok:
                 raise ValueError(
@@ -3288,7 +3334,7 @@ class ServingEngine:
                 self._key,
                 *((self.state,) if self.hybrid else ()),
             )
-            if st:
+            if st and self.hybrid:
                 self.state = st[0]
                 self.recurrent_slot_steps += (
                     self.window * len(decoding)
@@ -3304,6 +3350,19 @@ class ServingEngine:
             self._harvest_state(done_d, new_len, emitted_d)
             if self.telemetry is not None:
                 hw.tokens = int(emit_h[:, np.asarray(decoding)].sum())
+            if st and not self.hybrid:
+                # a latent model's expert layers (the leading dense layers
+                # have no router): rows routed in the window's steps
+                routed = int(st[0]["expert_rows"])
+                self.expert_rows_routed += routed
+                self.expert_rows_dropped += (
+                    int(st[0]["expert_claims"]) - routed
+                )
+                self.expert_rows_max += int(st[0]["expert_rows_max"])
+                self.experts_touched += int(st[0]["experts_touched"])
+                self.expert_layer_forwards += (
+                    self.window * self.model.config.expert_layers
+                )
 
         def harvested(s: int, req: Request) -> tp.List[int]:
             return [
@@ -3411,11 +3470,17 @@ class ServingEngine:
         # block tables could (host state as it stood at the dispatch; no
         # device read)
         steps = stats.get("window", 1)
-        self.kv_pages_walked += steps * sum(
-            pages_needed(int(self.pooled_len[s]), self.page_size)
+        walks = [
+            self.bt[s, : pages_needed(int(self.pooled_len[s]), self.page_size)]
             for s in decoding
-        )
+        ]
+        self.kv_pages_walked += steps * sum(len(w) for w in walks)
         self.kv_pages_table += steps * self.slots * self.bt.shape[1]
+        # a page several slots share (a prefix hit) is walked by each of
+        # them and is one page
+        self.kv_pages_distinct += steps * (
+            len(np.unique(np.concatenate(walks))) if walks else 0
+        )
         tele = self.telemetry
         return span(
             "midgpt.engine.harvest_wait", tele, kind, clock=self.clock,
@@ -3688,6 +3753,7 @@ class ServingEngine:
         return rec
 
     def stats(self) -> tp.Dict[str, float]:
+        cfg = self.model.config
         occ = self.occupancy_sum / max(1, self.windows * self.slots)
         return {
             "tp": self.tp,
@@ -3766,11 +3832,37 @@ class ServingEngine:
             "recurrent_state_bytes": (
                 self.state.nbytes if self.hybrid else 0
             ),
-            "kv_bytes_live": int(self.pooled_len.sum()) * 2 * (
-                self.pool.k.shape[0] * self.pool.k.shape[-1]
-                * self.pool.k.dtype.itemsize
+            "kv_bytes_live": 0 if self.latent else (
+                int(self.pooled_len.sum()) * 2 * (
+                    self.pool.k.shape[0] * self.pool.k.shape[-1]
+                    * self.pool.k.dtype.itemsize
+                )
             ),
+            # the walk's pages counted once each (a shared page is walked
+            # by every slot that holds it), and a latent-attention model's
+            # cache: its layers, and the resident bytes of the tokens the
+            # block tables reference — latent and rotary key, a shared page
+            # counted once
+            "kv_pages_distinct": self.kv_pages_distinct,
+            "latent_layers": cfg.kv_layers if self.latent else 0,
+            "latent_bytes_live": self._latent_tokens_live() * (
+                cfg.kv_layers * (cfg.latent_kv + cfg.latent_rope)
+                * self.pool.k.dtype.itemsize
+            ) if self.latent else 0,
         }
+
+    def _latent_tokens_live(self) -> int:
+        """Tokens resident in the pages the slots' block tables reference,
+        a page that several tables share counted once."""
+        ps = self.page_size
+        held = np.zeros((self.alloc.num_pages,), np.int64)
+        for s in range(self.slots):
+            n = int(self.pooled_len[s])
+            first = ps * np.arange(pages_needed(n, ps))
+            np.maximum.at(
+                held, self.bt[s, : len(first)], np.minimum(ps, n - first)
+            )
+        return int(held.sum())
 
 
 # Attach the registry-backed counter properties (data descriptors, so

@@ -77,10 +77,16 @@ class PagedKVPool:
     (a stale scale on an aliased page is silent corruption; see
     :func:`copy_page`). Exactness contract in midgpt_tpu.quant (the KV
     grid section): dequantization is bitwise, so an int8 pool behaves
-    like a bf16 pool whose values lie on the grid."""
+    like a bf16 pool whose values lie on the grid.
+
+    A LATENT pool (a latent-attention model, ``cfg.latent``): ONE payload a
+    page — ``k`` holds a token's pooled row ``[latent | rotary key | 0...]``
+    (models.gpt.LatentAttention), ``[L, NP, PS, cfg.latent_row]`` with no
+    KV-head axis — and ``v`` is None. Allocator, block tables, prefix index
+    and every writer below are the same: none looks inside a page."""
 
     k: Array  # [L, NP, PS, Hkv*C] (pool dtype; int8 when quantized)
-    v: Array  # [L, NP, PS, Hkv*C]
+    v: tp.Optional[Array]  # [L, NP, PS, Hkv*C]; None: a latent pool
     page_size: int = static()
     scale_k: tp.Optional[Array] = None  # [L, NP, Hkv] f32 (int8 pools)
     scale_v: tp.Optional[Array] = None
@@ -101,12 +107,13 @@ class PagedKVPool:
         # (the layer axis counts the layers that HAVE keys and values: a
         # linear-attention layer's cache is RecurrentState's)
         shape = (
-            cfg.kv_layers, num_pages, page_size, cfg.kv_heads * cfg.head_dim
+            cfg.kv_layers, num_pages, page_size,
+            cfg.pool_heads * cfg.pool_width,
         )
         if kv_quant == "int8":
             dtype = jnp.int8
         k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
+        v = None if cfg.latent else jnp.zeros(shape, dtype)
         scale_k = scale_v = None
         if kv_quant == "int8":
             # scale 1.0 on unwritten pages is inert: a page's scale is
@@ -129,6 +136,7 @@ class PagedKVPool:
                 ])
                 return jax.device_put(a, NamedSharding(mesh, spec))
 
+            assert not cfg.latent, "a latent page has no head axis to shard"
             k = commit(k, POOL_SPEC_AXES)
             v = commit(v, POOL_SPEC_AXES)
             if scale_k is not None:
@@ -842,6 +850,17 @@ def _layer_index(n_layer: int) -> Array:
     return jnp.arange(n_layer, dtype=jnp.int32)[:, None]
 
 
+def _latent_rows_set(pool, vals, pg, of) -> PagedKVPool:
+    """A latent pool (one payload a page) with the rows ``vals`` ``[L, N,
+    row]`` set at (page, offset) ``pg``, ``of`` ``[1, N]``: what
+    :func:`flush_recent` and :func:`write_token_rows` do to ``k``."""
+    li = _layer_index(pool.k.shape[0])
+    return PagedKVPool(
+        k=pool.k.at[li, pg, of].set(vals.astype(pool.k.dtype), mode="drop"),
+        v=None, page_size=pool.page_size,
+    )
+
+
 def flush_recent(
     pool: PagedKVPool,
     rk: Array,  # [L, S, Hkv, K, C] — the window's recent rows (time-major)
@@ -886,6 +905,10 @@ def flush_recent(
     # the (page, row) indices are adjacent, so the [S*K] index dim stays
     # in place: vals arrive [L, S*K, Hkv*C], each a whole pool row
     vals_k = jnp.transpose(rk, (0, 1, 3, 2, 4)).reshape(l, s * kk, hkv * c)
+    if pool.v is None:
+        return _latent_rows_set(
+            pool, vals_k, page.reshape(1, -1), off.reshape(1, -1)
+        )
     vals_v = jnp.transpose(rv, (0, 1, 3, 2, 4)).reshape(l, s * kk, hkv * c)
     # TP: rows scatter per shard into its own heads' lanes (the head run
     # is untouched by the scatter indices); pin values + result so the
@@ -921,6 +944,9 @@ def write_prompt_pages(
     l, hkv, p, c = ks.shape
     ps = pool.page_size
     assert p % ps == 0, f"prompt length {p} not a multiple of page_size {ps}"
+    assert pool.v is not None, (
+        "a latent pool is written by rows: write_token_rows"
+    )
     n = p // ps
     scale_k, scale_v = pool.scale_k, pool.scale_v
     if pool.quantized:
@@ -988,6 +1014,11 @@ def write_token_rows(
             valid[None], page_raw[None], pool.num_pages, ps
         )
         ks, vs = qk[:, 0], qv[:, 0]
+    if pool.v is None:
+        assert hkv == 1, ks.shape
+        return _latent_rows_set(
+            pool, ks.reshape(l, t, c), page[None, :], off[None, :]
+        )
     # adjacent (page, row) indices: vals arrive [L, T, Hkv*C]
     vals_k = shard_act(
         jnp.transpose(ks, (0, 2, 1, 3)).reshape(l, t, hkv * c),
@@ -1036,6 +1067,11 @@ def copy_page(pool: PagedKVPool, src: Array, dst: Array) -> PagedKVPool:
     # result carries the committed input pool's sharding — and the
     # donated buffer aliases because nothing reshards.
     k_row = jax.lax.dynamic_slice_in_dim(pool.k, src, 1, axis=1)
+    if pool.v is None:  # a latent pool: one payload a page
+        return PagedKVPool(
+            k=jax.lax.dynamic_update_slice_in_dim(pool.k, k_row, dst, axis=1),
+            v=None, page_size=pool.page_size,
+        )
     v_row = jax.lax.dynamic_slice_in_dim(pool.v, src, 1, axis=1)
     scale_k, scale_v = pool.scale_k, pool.scale_v
     if pool.quantized:
@@ -1084,7 +1120,7 @@ def export_pages(
     # each shard gathers its own heads — and np.asarray gathers the
     # full [L, n, PS, Hkv*C] host copy across shards
     k = np.asarray(jnp.take(pool.k, ids, axis=1))
-    v = np.asarray(jnp.take(pool.v, ids, axis=1))
+    v = None if pool.v is None else np.asarray(jnp.take(pool.v, ids, axis=1))
     sk = sv = None
     if pool.quantized:
         sk = np.asarray(jnp.take(pool.scale_k, ids, axis=1))
@@ -1112,12 +1148,15 @@ def import_pages(
     the scatter is shard-local and GSPMD propagation keeps the pool's
     committed sharding, exactly like :func:`copy_page`."""
     n = len(list(page_ids))
-    assert k.shape[1] == n and v.shape[1] == n, (k.shape, n)
+    assert k.shape[1] == n and (v is None or v.shape[1] == n), (k.shape, n)
+    assert (v is None) == (pool.v is None), "a latent pool has one payload"
     ids = jnp.asarray(list(page_ids), jnp.int32)
     # (layer, page) indexed together, like every pool writer: _layer_index
     li, pg = _layer_index(pool.k.shape[0]), ids[None, :]
     new_k = pool.k.at[li, pg].set(jnp.asarray(k, pool.k.dtype))
-    new_v = pool.v.at[li, pg].set(jnp.asarray(v, pool.v.dtype))
+    new_v = None if v is None else pool.v.at[li, pg].set(
+        jnp.asarray(v, pool.v.dtype)
+    )
     scale_k, scale_v = pool.scale_k, pool.scale_v
     if pool.quantized:
         assert sk is not None and sv is not None, (

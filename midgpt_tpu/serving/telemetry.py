@@ -154,6 +154,9 @@ ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "recurrent_slot_steps",
     "recurrent_state_bytes",
     "kv_bytes_live",
+    "kv_pages_distinct",
+    "latent_layers",
+    "latent_bytes_live",
 )
 
 #: ``ServingCluster.stats()`` = the summed engine inventory plus these

@@ -3,10 +3,10 @@
     JAX_PLATFORMS=cpu python scripts/serving_jaxprs.py <checkout> <out_dir>
 
 ``str(jax.make_jaxpr(...))`` of every program of ``serve-xl-decode``,
-``serve-sdar-block4`` and ``serve-olmo-hybrid-decode`` (each prefill-chunk
-bucket, the decode or the block window; a cell whose configuration the
-checkout's ``ModelConfig`` cannot hold is passed over) and of the verify
-program at ``speculate=4`` on ``midgpt-xl``,
+``serve-sdar-block4``, ``serve-olmo-hybrid-decode`` and
+``serve-joyai-flash-docs`` (each prefill-chunk bucket, the decode or the block
+window; a cell whose configuration the checkout's ``ModelConfig`` cannot hold
+is passed over) and of the verify program at ``speculate=4`` on ``midgpt-xl``,
 traced from ``<checkout>`` through the engine's own ``make_*`` factories
 with the cell's configuration and engine settings, ``paged_kernel="pallas"``
 (what ``auto`` resolves to on a TPU), published widths, full depth (shapes
@@ -35,7 +35,7 @@ from midgpt_tpu.serving import engine as eng  # noqa: E402
 from midgpt_tpu.serving import paged  # noqa: E402
 from midgpt_tpu.serving.paged import PagedKVPool, pages_needed  # noqa: E402
 
-PAGE = 16  # ServingEngine's page_size default; no cell sets another
+PAGE = 16  # ServingEngine's page_size default, where a cell sets no other
 sds = jax.ShapeDtypeStruct
 i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
 flag = lambda *shape: sds(shape, jnp.bool_)  # noqa: E731
@@ -50,7 +50,7 @@ def emit(name, fn, *args):
 
 
 for cell in ("serve-xl-decode", "serve-sdar-block4",
-             "serve-olmo-hybrid-decode"):
+             "serve-olmo-hybrid-decode", "serve-joyai-flash-docs"):
     if not os.path.exists(f"benchmark/workloads/{cell}.json"):
         continue
     spec = json.load(open(f"benchmark/workloads/{cell}.json"))
@@ -61,8 +61,13 @@ for cell in ("serve-xl-decode", "serve-sdar-block4",
         print(cell, "passed over:", repr(e)[:120], flush=True)
         continue
     hybrid = bool(getattr(cfg, "linear_layers", 0))
+    latent = bool(getattr(cfg, "latent", False))
+    if spec["kind"] == "serve_latent" and not latent:
+        print(cell, "passed over: no latent attention here", flush=True)
+        continue
     kw = spec["engine"]
     s, window = kw["slots"], kw.get("window", 4)
+    PAGE = kw.get("page_size", 16)
     pmax = pages_needed(cfg.block_size, PAGE)
     model = jax.tree.map(
         lambda a: sds(a.shape, jnp.bfloat16),
@@ -95,8 +100,10 @@ for cell in ("serve-xl-decode", "serve-sdar-block4",
          eng.make_decode_window(model, slots=s, window=window, **geom),
          model, pool, logits, i32(s, pmax), i32(s), flag(s), i32(s), i32(s),
          i32(s), i32(s), sds((2,), jnp.uint32), *state)
-    if hybrid:
-        continue  # no speculation without a rollback of the state
+    if hybrid or latent:
+        # no speculation without a rollback of the state, nor before the
+        # verify rows have a latent form
+        continue
     emit(f"{cell}.verify_spec4",
          eng.make_verify_program(model, slots=s, spec_len=4, **geom),
          model, pool, logits, i32(s, pmax), i32(s), flag(s), i32(s), i32(s),

@@ -201,6 +201,41 @@ def test_paged_decode_kernel_compiles_100k_token_table(one_chip):
     _compiles_to_kernel(fn, one_chip, *shapes)
 
 
+def test_paged_latent_kernel_compiles_at_the_latent_cell(one_chip):
+    """The kernel's latent mode at ``serve-joyai-flash-docs``'s geometry:
+    32 slots, every one of 32 heads' rows against ONE "KV head" of 640-lane
+    pooled rows (the latent's 512, the rotary key's 64, zeros), a
+    33,792-position table at the cell's pages of 64 — resident in VMEM, 41
+    MB, so that one page DMA serves scores and values — 16 recent rows; two
+    layers of it in one program, one ``%mla_decode.N`` custom call a layer:
+    the name ``mla_decode_roofline.serve`` finds the kernel's events by."""
+    import re
+
+    from midgpt_tpu.ops.paged_attn import paged_latent_attention, supported
+
+    slots, heads, row, dc, ps, rr = 32, 32, 640, 512, 64, 16
+    pmax = 33792 // ps
+    assert supported(pmax, ps, row, 2, groups=heads, heads=1, latent=True)
+    shapes = [
+        ((slots, 1, heads, row), jnp.bfloat16),
+        ((L, 4520, ps, row), jnp.bfloat16),
+        ((slots, pmax), jnp.int32), ((slots,), jnp.int32),
+        ((slots, 1, rr, row), jnp.bfloat16), ((), jnp.int32),
+    ]
+
+    def fn(q, pool, bt, ln, rows, r):
+        for layer in range(L):
+            o = paged_latent_attention(
+                q, pool, bt, ln, rows, r, layer, v_lanes=dc, scale_dim=192)
+            q = q.at[..., :dc].set(o)
+        return q
+
+    text = _compiles_to_kernel(fn, one_chip, *shapes)
+    assert len(re.findall(
+        r"%mla_decode(\.\d+)? = \S+ custom-call\(", text
+    )) == L
+
+
 # the block-diffusion cell (serve-sdar-block4): 32 slots of 48 pages, 32
 # query heads over 4 KV heads of 128, blocks of 4; a forward carries two
 # blocks a slot (the block that is committed and the one being denoised)

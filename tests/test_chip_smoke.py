@@ -36,7 +36,8 @@ def test_chip_smoke_failed_phase_prints_no_result(monkeypatch, capsys,
     monkeypatch.setattr(chip_smoke, "write_corpus", lambda d: None)
     monkeypatch.setattr(chip_smoke, "smoke_config", lambda *a: None)
     for name in ("phase_train", "phase_resume", "phase_serve",
-                 "block_forward_vs_gather", "gated_delta_vs_numpy"):
+                 "block_forward_vs_gather", "gated_delta_vs_numpy",
+                 "latent_attention_vs_numpy"):
         monkeypatch.setattr(chip_smoke, name, lambda *a, **k: None)
     monkeypatch.setattr(
         sys, "argv", ["chip_smoke.py", "--workdir", str(tmp_path)]

@@ -141,13 +141,20 @@ def test_engine_stats_key_contract(model):
     # scheduler's own lengths — pages the occupied slots hold, against the
     # pages their block tables could; behind it the recurrent state's keys
     # (PR 32), zero for a model without linear-attention layers
-    assert ENGINE_STATS_KEYS[-8:-6] == ("kv_pages_walked", "kv_pages_table")
-    assert ENGINE_STATS_KEYS[-6:] == (
+    assert ENGINE_STATS_KEYS[-11:-9] == ("kv_pages_walked", "kv_pages_table")
+    assert ENGINE_STATS_KEYS[-9:-3] == (
         "state_resets", "state_reprefill_tokens", "prefix_hits_refused",
         "recurrent_slot_steps", "recurrent_state_bytes", "kv_bytes_live",
     )
-    assert not any(st[k] for k in ENGINE_STATS_KEYS[-6:-1])
+    assert not any(st[k] for k in ENGINE_STATS_KEYS[-9:-4])
     assert 0 < st["kv_pages_walked"] <= st["kv_pages_table"]
+    # behind them the walk's pages counted once each, and a latent cache's
+    # keys (PR 34), zero for a model without latent attention
+    assert ENGINE_STATS_KEYS[-3:] == (
+        "kv_pages_distinct", "latent_layers", "latent_bytes_live",
+    )
+    assert 0 < st["kv_pages_distinct"] <= st["kv_pages_walked"]
+    assert not st["latent_layers"] and not st["latent_bytes_live"]
     assert st["kv_pages_table"] == (
         st["windows"] * eng.window * eng.slots * eng.bt.shape[1]
     )
