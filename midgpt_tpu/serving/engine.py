@@ -1262,6 +1262,7 @@ class HandoffRecord:
 # the metrics snapshot are two views of it.
 _ENGINE_COUNTERS = (
     "decode_dispatches",
+    "device_reads",
     "prefill_dispatches",
     "copy_dispatches",
     "tokens_generated",
@@ -1345,6 +1346,16 @@ class ServingEngine:
     prompt+generated), launch ONE fused K-step decode dispatch for all
     decoding slots, then harvest emitted tokens / finished requests with
     a single device->host read.
+
+    What crosses between host and device in a step, once each: a prefill
+    chunk PUTS its tokens, three scalars and a copy of the slot's
+    block-table row in one ``jax.device_put`` and reads nothing back
+    (:meth:`_dispatch_chunk`); a window — decode, verify or block — PUTS
+    the scheduler's host arrays in one ``jax.device_put`` and READS its
+    results in one ``jax.device_get`` (:meth:`_read_window`, counted as
+    ``device_reads``: over any span of steps it equals
+    ``decode_dispatches``). Pool, logits and a recurrent state never
+    leave the device.
 
     Prefix cache (``prefix_cache=True``): full pages are registered in a
     host-side content index as they fill; admission points the block
@@ -2789,8 +2800,7 @@ class ServingEngine:
                 dst = fresh[0]
                 self.pool = self._copy_fn(
                     self.pool,
-                    jnp.asarray(cow_src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32),
+                    *jax.device_put((np.int32(cow_src), np.int32(dst))),
                 )
                 self.copy_dispatches += 1
                 self._release_pages([cow_src])  # back to cold (or shared)
@@ -2849,16 +2859,46 @@ class ServingEngine:
             elif len(self._first_pages) < 65536:
                 self._first_pages.add(first)
 
-    def _chunk_state(self, s: int) -> tp.Tuple:
-        """The recurrent state's two arguments of a chunk program (none
-        for a model without linear-attention layers); slot ``s`` is no
-        longer fresh behind it."""
-        if not self.hybrid:
-            return ()
-        fresh, self.fresh[s] = bool(self.fresh[s]), False
-        return self.state, jnp.asarray(fresh)
-
     # -- chunked prefill ----------------------------------------------------
+
+    def _dispatch_chunk(
+        self, s: int, toks: np.ndarray, start: int, clen: int,
+        bt_row: np.ndarray,
+    ) -> None:
+        """Enqueue one prefill-chunk program for slot ``s``: ``toks``
+        (``[1, bucket]``, right-padded) from position ``start``, ``clen``
+        of them real, over the block-table row ``bt_row`` — a host array
+        the caller no longer writes. Everything the host hands the program
+        goes to the device in ONE ``jax.device_put``, the three scalars as
+        ``np.int32`` among the arrays (``jnp.asarray(<python int>,
+        jnp.int32)`` launches a ``jit(convert_element_type)`` program
+        each), and nothing is read back: a chunk's results are the pool's
+        rows and the slot's logits row, which stay on the device. A model
+        with linear-attention layers also hands over the recurrent state
+        and whether this is the request's first chunk; slot ``s`` is no
+        longer fresh behind it."""
+        bucket = toks.shape[1]
+        if bucket not in self._chunk_fns:
+            self._chunk_fns[bucket] = make_prefill_chunk_program(
+                self.model,
+                chunk_len=bucket,
+                pmax=self.pmax,
+                rope_len=self.block,
+                mesh=self._mesh,
+                layer_scan=self.layer_scan,
+                prefill_sp=self.prefill_sp,
+            )
+        host = (np.int32(s), toks, np.int32(start), np.int32(clen), bt_row)
+        if self.hybrid:
+            host += (np.bool_(self.fresh[s]),)
+            self.fresh[s] = False
+        staged = jax.device_put(host)
+        self.pool, self.logits, *st = self._chunk_fns[bucket](
+            self.model, self.pool, self.logits, *staged[:5],
+            *((self.state,) if self.hybrid else ()), *staged[5:],
+        )
+        if st:
+            self.state = st[0]
 
     def _prefill_target(self, p: int) -> int:
         """Rows of a ``p``-token prompt that prefill makes resident: all
@@ -2904,35 +2944,13 @@ class ServingEngine:
         ) as sp:
             toks = np.full((1, bucket), self.pad_id, np.int32)
             toks[0, :clen] = req.prompt[start : start + clen]
-            if bucket not in self._chunk_fns:
-                self._chunk_fns[bucket] = make_prefill_chunk_program(
-                    self.model,
-                    chunk_len=bucket,
-                    pmax=self.pmax,
-                    rope_len=self.block,
-                    mesh=self._mesh,
-                    layer_scan=self.layer_scan,
-                    prefill_sp=self.prefill_sp,
-                )
-            self.pool, self.logits, *st = self._chunk_fns[bucket](
-                self.model,
-                self.pool,
-                self.logits,
-                jnp.asarray(s, jnp.int32),
-                jnp.asarray(toks),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(clen, jnp.int32),
-                # a COPY of the row: on the CPU backend ``jnp.asarray``
-                # of a 64-byte-aligned host array shares its memory, the
-                # program runs when the device gets to it, and an
-                # eviction later in this very step resets ``bt[s]`` — the
-                # chunk's rows were then dropped and its pages, already
-                # registered as a cached prefix, never written
-                jnp.asarray(self.bt[s].copy()),
-                *self._chunk_state(s),
-            )
-            if st:
-                self.state = st[0]
+            # a COPY of the row: on the CPU backend a put of a
+            # 64-byte-aligned host array shares its memory, the program
+            # runs when the device gets to it, and an eviction later in
+            # this very step resets ``bt[s]`` — the chunk's rows were
+            # then dropped and its pages, already registered as a cached
+            # prefix, never written
+            self._dispatch_chunk(s, toks, start, clen, self.bt[s].copy())
         self.prefill_dispatches += 1
         self.prefill_tokens_computed += clen
         if tele is not None:
@@ -3258,19 +3276,10 @@ class ServingEngine:
             "midgpt.engine.decode_dispatch", tele, clock=self.clock
         ) as disp:
             drafts, n_draft, draft_probs = self._draft(decoding)
-            args = [
-                self.model,
-                self.pool,
-                self.logits,
-                jnp.asarray(self.bt),
-                jnp.asarray(self.pooled_len),
-                jnp.asarray(self.done),
-                jnp.asarray(self.emitted),
-                jnp.asarray(self.budget),
-                jnp.asarray(self.eos),
-                jnp.asarray(drafts),
-                jnp.asarray(n_draft),
-            ]
+            host = (
+                self.bt, self.pooled_len, self.done, self.emitted,
+                self.budget, self.eos, drafts, n_draft,
+            )
             if self.temperature > 0.0:
                 # sampled verify: per-slot request seeds + the engine's
                 # base key — the program derives every categorical/
@@ -3278,27 +3287,29 @@ class ServingEngine:
                 # the same discipline that makes the plain sampled window
                 # scheduling-invariant carries over to speculation
                 # unchanged
-                args += [jnp.asarray(self.seeds), self._key]
+                host += (self.seeds,)
                 if draft_probs is not None:
-                    args.append(jnp.asarray(draft_probs))
+                    host += (draft_probs,)
+            staged = jax.device_put(host)
+            if self.temperature > 0.0:
+                # the key, the device's already, rides behind the seeds
+                staged = (*staged[:9], self._key, *staged[9:])
             (
                 self.pool, self.logits, cand, emit, done_d, new_len,
                 emitted_d, n_acc,
-            ) = self._verify_fn(*args)
+            ) = self._verify_fn(self.model, self.pool, self.logits, *staged)
         self.verify_dispatches += 1
 
-        # ONE device->host sync per dispatch: the [S, T] outputs
         with self._harvest_span(disp, "verify_dispatch", decoding) as hw:
-            cand_h = np.asarray(cand)
-            emit_h = np.asarray(emit)
-            n_acc_h = np.asarray(n_acc)
-            self._harvest_state(done_d, new_len, emitted_d)
+            cand_h, emit_h, n_acc_h, *state = self._read_window(
+                (cand, emit, n_acc, done_d, new_len, emitted_d)
+            )
+            self._harvest_state(*state)
             if tele is not None:
-                live = np.asarray(decoding)
-                hw.tokens = int(emit_h[live].sum())
+                hw.tokens = int(emit_h[decoding].sum())
                 hw.data.update(
-                    drafted=int(np.asarray(n_draft)[live].sum()),
-                    accepted=int(n_acc_h[live].sum()),
+                    drafted=int(n_draft[decoding].sum()),
+                    accepted=int(n_acc_h[decoding].sum()),
                 )
 
         def harvested(s: int, req: Request) -> tp.List[int]:
@@ -3312,54 +3323,52 @@ class ServingEngine:
         self._harvest_apply(hw, decoding, harvested)
 
     def _run_window(self, decoding: tp.List[int]) -> None:
-        """One K-step decode window dispatch + harvest."""
+        """One K-step decode window dispatch + harvest. In: the seven
+        host arrays the scheduler keeps (block tables, lengths, ``done``,
+        ``emitted``, budgets, EOS ids, seeds) in ONE ``jax.device_put``;
+        pool, logits, key and a recurrent state are the device's already.
+        Out: ONE :meth:`_read_window` of the stacked ``[K, S]`` tokens and
+        emit mask, the three state arrays, and a latent model's expert
+        counters; pool, logits and recurrent state stay on the device."""
         with span(
             "midgpt.engine.decode_dispatch", self.telemetry,
             clock=self.clock,
         ) as disp:
-            (
-                self.pool, self.logits, toks, emit, done_d, new_len,
-                emitted_d, *st,
-            ) = self._window_fn(
+            self.pool, self.logits, *out = self._window_fn(
                 self.model,
                 self.pool,
                 self.logits,
-                jnp.asarray(self.bt),
-                jnp.asarray(self.pooled_len),
-                jnp.asarray(self.done),
-                jnp.asarray(self.emitted),
-                jnp.asarray(self.budget),
-                jnp.asarray(self.eos),
-                jnp.asarray(self.seeds),
+                *jax.device_put((
+                    self.bt, self.pooled_len, self.done, self.emitted,
+                    self.budget, self.eos, self.seeds,
+                )),
                 self._key,
                 *((self.state,) if self.hybrid else ()),
             )
-            if st and self.hybrid:
-                self.state = st[0]
+            if self.hybrid:
+                self.state = out.pop()
                 self.recurrent_slot_steps += (
                     self.window * len(decoding)
                     * self.model.config.linear_layers
                 )
 
-        # ONE device->host sync per window: the stacked [K, S] outputs
         with self._harvest_span(
             disp, "decode_window", decoding, window=self.window
         ) as hw:
-            toks_h = np.asarray(toks)
-            emit_h = np.asarray(emit)
-            self._harvest_state(done_d, new_len, emitted_d)
+            toks_h, emit_h, done_h, len_h, emitted_h, *counters = (
+                self._read_window(out)
+            )
+            self._harvest_state(done_h, len_h, emitted_h)
             if self.telemetry is not None:
-                hw.tokens = int(emit_h[:, np.asarray(decoding)].sum())
-            if st and not self.hybrid:
+                hw.tokens = int(emit_h[:, decoding].sum())
+            for c in counters:
                 # a latent model's expert layers (the leading dense layers
                 # have no router): rows routed in the window's steps
-                routed = int(st[0]["expert_rows"])
+                routed = int(c["expert_rows"])
                 self.expert_rows_routed += routed
-                self.expert_rows_dropped += (
-                    int(st[0]["expert_claims"]) - routed
-                )
-                self.expert_rows_max += int(st[0]["expert_rows_max"])
-                self.experts_touched += int(st[0]["experts_touched"])
+                self.expert_rows_dropped += int(c["expert_claims"]) - routed
+                self.expert_rows_max += int(c["expert_rows_max"])
+                self.experts_touched += int(c["experts_touched"])
                 self.expert_layer_forwards += (
                     self.window * self.model.config.expert_layers
                 )
@@ -3379,32 +3388,22 @@ class ServingEngine:
             "midgpt.engine.decode_dispatch", self.telemetry,
             clock=self.clock,
         ) as disp:
-            carry, (toks, ats, complete, n_emit), counters = self._window_fn(
+            carry, emits, counters = self._window_fn(
                 self.model,
                 self.pool,
-                jnp.asarray(self.bt),
-                jnp.asarray(self.pooled_len),
-                jnp.asarray(self.done),
-                jnp.asarray(self.emitted),
-                jnp.asarray(self.budget),
-                jnp.asarray(self.eos),
-                jnp.asarray(self.blk_tok),
-                jnp.asarray(self.blk_rev),
-                jnp.asarray(self.blk_at),
-                jnp.asarray(self.blk_pend),
-                jnp.asarray(self.blk_pend_tok),
+                *jax.device_put((
+                    self.bt, self.pooled_len, self.done, self.emitted,
+                    self.budget, self.eos, self.blk_tok, self.blk_rev,
+                    self.blk_at, self.blk_pend, self.blk_pend_tok,
+                )),
             )
             self.pool = carry[0]
 
-        # ONE device->host sync per window, and one read: ``device_get`` of
-        # the whole tree starts every leaf's copy before it waits for the
-        # first (a read a leaf is ~1 ms of idle device each, PERF.md PR 25)
         with self._harvest_span(
             disp, "decode_window", decoding, window=self.window
         ) as hw:
             (toks_h, ats_h, comp_h, n_emit_h), state, counters = (
-                jax.device_get(((toks, ats, complete, n_emit), carry[1:],
-                                counters))
+                self._read_window((emits, carry[1:], counters))
             )
             committed = state[0] - self.pooled_len
             self._harvest_state(state[1], state[0], state[2])
@@ -3412,7 +3411,7 @@ class ServingEngine:
             (self.blk_tok, self.blk_rev, self.blk_at, self.blk_pend,
              self.blk_pend_tok) = (np.array(a) for a in state[3:8])
             if self.telemetry is not None:
-                hw.tokens = int(n_emit_h[:, np.asarray(decoding)].sum())
+                hw.tokens = int(n_emit_h[:, decoding].sum())
         forwards = int(counters["denoise_forwards"])
         landed = int(counters["commit_forwards"])
         self.denoise_forwards += forwards
@@ -3492,12 +3491,29 @@ class ServingEngine:
             **stats,
         )
 
-    def _harvest_state(self, done_d, new_len, emitted_d) -> None:
-        # np.array (copy): zero-copy views of jax buffers are read-only,
-        # and the scheduler mutates these in place
-        self.done = np.array(done_d)
+    def _read_window(self, tree):
+        """THE device->host read of the step loop, and the only place that
+        counts one (``device_reads``): ONE ``jax.device_get`` of everything
+        a window returned for the host — tokens, masks, the scheduler's
+        state arrays, counters — as NumPy leaves. ``device_get`` of the
+        whole tree starts every leaf's copy before it waits for the first;
+        a read a leaf (``np.asarray(toks)``, then ``np.asarray(emit)``,
+        ...) is a round trip each that starts when the one before has
+        returned, ~0.7–1 ms of idle device a leaf (PERF.md PR 25, PR 28).
+        The leaves may be read-only views of the device's buffers: what
+        the scheduler writes in place is copied (:meth:`_harvest_state`)."""
+        self.device_reads += 1
+        return jax.device_get(tree)
+
+    def _harvest_state(
+        self, done: np.ndarray, new_len: np.ndarray, emitted: np.ndarray
+    ) -> None:
+        """The scheduler's per-slot state as the window left it, from the
+        HOST arrays of :meth:`_read_window`. np.array (copy): those may be
+        read-only, and the scheduler mutates these in place."""
+        self.done = np.array(done)
         self.pooled_len = np.array(new_len, np.int32)
-        self.emitted = np.array(emitted_d, np.int32)
+        self.emitted = np.array(emitted, np.int32)
 
     def _harvest_apply(
         self,
@@ -3637,31 +3653,12 @@ class ServingEngine:
         buckets = sorted(
             {self._prefill_bucket(n) for n in range(1, cap + 1)}
         )
-        sentinel_row = jnp.full((self.pmax,), self._sentinel, jnp.int32)
+        sentinel_row = np.full((self.pmax,), self._sentinel, np.int32)
         for b in buckets:
-            if b not in self._chunk_fns:
-                self._chunk_fns[b] = make_prefill_chunk_program(
-                    self.model,
-                    chunk_len=b,
-                    pmax=self.pmax,
-                    rope_len=self.block,
-                    mesh=self._mesh,
-                    layer_scan=self.layer_scan,
-                    prefill_sp=self.prefill_sp,
-                )
-            self.pool, self.logits, *st = self._chunk_fns[b](
-                self.model,
-                self.pool,
-                self.logits,
-                jnp.asarray(0, jnp.int32),
-                jnp.full((1, b), self.pad_id, jnp.int32),
-                jnp.asarray(0, jnp.int32),
-                jnp.asarray(b, jnp.int32),
-                sentinel_row,
-                *self._chunk_state(0),
+            # (slot 0's state is scratch too: admission resets it)
+            self._dispatch_chunk(
+                0, np.full((1, b), self.pad_id, np.int32), 0, b, sentinel_row
             )
-            if st:  # (slot 0's state is scratch too: admission resets it)
-                self.state = st[0]
         return buckets
 
     def clear_prefix_cache(self) -> int:
@@ -3758,6 +3755,9 @@ class ServingEngine:
         return {
             "tp": self.tp,
             "decode_dispatches": self.decode_dispatches,
+            # device->host reads of the step loop (_read_window): one a
+            # decode dispatch, none a prefill chunk
+            "device_reads": self.device_reads,
             "prefill_dispatches": self.prefill_dispatches,
             "copy_dispatches": self.copy_dispatches,
             "tokens_generated": self.tokens_generated,

@@ -101,6 +101,7 @@ __all__ = [
 ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "tp",
     "decode_dispatches",
+    "device_reads",
     "prefill_dispatches",
     "copy_dispatches",
     "tokens_generated",

@@ -10,6 +10,7 @@ sample.py:68-95)."""
 
 import dataclasses
 import zlib
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -423,6 +424,96 @@ def test_steady_state_one_dispatch_per_k_tokens():
     assert st["tokens_generated"] == slots * n_new
     assert st["tokens_per_dispatch"] == slots * k
     assert st["slot_occupancy"] == 1.0
+
+
+def _greedy_by_whole_forward(model, prompt, n_new):
+    """Greedy continuation by the model's whole-sequence forward, a token
+    at a time: no cache, no pages, no window (the forward the hybrid and
+    latent test files hold to their plain references)."""
+    fwd = jax.jit(lambda m, t: m(t))
+    seq = [int(t) for t in prompt]
+    for _ in range(n_new):
+        padded = np.zeros((1, model.config.block_size), np.int32)
+        padded[0, : len(seq)] = seq
+        logits = fwd(model, jax.device_put(padded))[0, len(seq) - 1]
+        seq.append(int(np.argmax(logits)))
+    return seq[len(prompt):]
+
+
+def _transfer_case(kind):
+    """``(model, engine keywords, prompts, n_new, oracle)`` of one kind of
+    window: the plain decode window, the verify dispatch, a model with a
+    recurrent state beside the pool, one with a latent pool and expert
+    counters, and the block window — each other file's own small model."""
+    if kind in ("plain", "speculative"):
+        kw = {"slots": 2, "page_size": 8, "window": 4, "prefill_chunk": 8}
+        if kind == "speculative":
+            kw["speculate"] = 4
+        return _model(), kw, _prompts(3, base_len=11), 12, _exact
+    if kind == "block":
+        import test_block_diffusion as t
+        from benchmark import reference_block as rb
+        from benchmark import weights_block as w
+
+        weights = w.make(jax.random.PRNGKey(3), t.SIZES, jnp.float32)
+        forward = rb.make_forward(t.SIZES)
+        kw = {"slots": 3, "page_size": 16, "window": 5, "prefill_chunk": 16}
+        return (
+            t.fill_model(weights, t.CFG), kw,
+            [np.arange(n, dtype=np.int32) * 7 % 510 for n in (22, 35, 9)], 14,
+            lambda _, p, n: rb.generate(
+                weights, p, n, t.SIZES, forward=forward)[0],
+        )
+    if kind == "hybrid":
+        import test_hybrid_serving as t
+        from benchmark import weights_hybrid as w
+    else:
+        import test_latent_serving as t
+        from benchmark import weights_latent as w
+    model = t.fill_model(
+        w.make(jax.random.PRNGKey(3), t.SIZES, jnp.float32), t.CFG)
+    kw = {"slots": 2, "window": 4, "prefill_chunk": 16,
+          "page_size": 16 if kind == "hybrid" else 4}
+    prompts = [np.arange(n, dtype=np.int32) * 7 % 510 for n in (37, 9)]
+    return model, kw, prompts, 8, _greedy_by_whole_forward
+
+
+@pytest.mark.parametrize(
+    "kind", ["plain", "speculative", "hybrid", "latent", "block"])
+def test_one_read_a_window_one_put_a_dispatch(kind, monkeypatch):
+    """What crosses between host and device in an engine step: a window —
+    decode, verify or block, whatever else its model returns — is read
+    with ONE ``jax.device_get`` (``device_reads == decode_dispatches``
+    after every step), a prefill chunk reads nothing, every dispatch puts
+    its host arrays with ONE ``jax.device_put`` and hands its program no
+    host value to transfer on the way in (``jnp.asarray(<python int>)`` and
+    a NumPy argument of a jitted call are such transfers: the guard refuses
+    them) — and the tokens are the plain oracle's."""
+    model, kw, prompts, n_new, oracle = _transfer_case(kind)
+    want = [[int(t) for t in oracle(model, p, n_new)] for p in prompts]
+    eng = ServingEngine(
+        model, cache_dtype=jnp.float32, paged_kernel="xla", **kw)
+    rids = [eng.submit(p, n_new) for p in prompts]
+    put, get = mock.Mock(wraps=jax.device_put), mock.Mock(wraps=jax.device_get)
+    monkeypatch.setattr(jax, "device_put", put)
+    monkeypatch.setattr(jax, "device_get", get)
+    chunk_only_steps = 0
+    with jax.transfer_guard_host_to_device("disallow"):
+        while eng.has_work:
+            chunks, windows = eng.prefill_dispatches, eng.decode_dispatches
+            eng.step()
+            assert eng.device_reads == eng.decode_dispatches
+            chunk_only_steps += (
+                eng.prefill_dispatches > chunks
+                and eng.decode_dispatches == windows)
+    assert chunk_only_steps, "no step of prefill chunks alone: nothing shown"
+    st = eng.stats()
+    assert st["prefill_dispatches"] > len(prompts)  # chunked
+    assert get.call_count == st["device_reads"] == st["decode_dispatches"] > 0
+    assert put.call_count == (
+        st["decode_dispatches"] + st["prefill_dispatches"]
+        + st["copy_dispatches"])
+    assert [list(eng.finished[r].tokens) for r in rids] == want
 
 
 def test_repeated_eviction_rebuilds_context_without_duplication(

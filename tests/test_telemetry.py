@@ -137,6 +137,11 @@ def test_engine_stats_key_contract(model):
         assert st[k] == snap["counters"][k]
     assert st["reject_reasons"] == snap["labeled"]["reject_reasons"]
     json.dumps(snap)
+    # beside the dispatches, the device->host reads the step loop issued
+    # (PR 35): one a window
+    assert ENGINE_STATS_KEYS[1:3] == ("decode_dispatches", "device_reads")
+    assert st["device_reads"] == snap["counters"]["device_reads"]
+    assert st["device_reads"] == st["decode_dispatches"] > 0
     # the paged kernel's walk (PR 26), counted per decode step from the
     # scheduler's own lengths — pages the occupied slots hold, against the
     # pages their block tables could; behind it the recurrent state's keys
