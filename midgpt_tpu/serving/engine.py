@@ -93,6 +93,7 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from midgpt_tpu.models.gpt import (
     GPT,
@@ -127,6 +128,10 @@ from midgpt_tpu.serving.paged import (
 )
 
 Array = jax.Array
+
+# events the log of a profiler session keeps (ServingEngine._session_edge):
+# a step writes one a decoding slot and a few more, so minutes of steps
+SESSION_RING = 65536
 
 
 # ---------------------------------------------------------------------------
@@ -1175,7 +1180,13 @@ class Request:
     )
     eos_id: int = -1  # -1 = no EOS (run to max_new_tokens)
     seed: int = 0
+    # the engine clock's stamps of the time to the first token, kept
+    # whether anything traces or not: submitted, first given a slot, the
+    # prompt resident (the last time before the first token, should an
+    # eviction make it resident twice), first token harvested
     submit_time: float = 0.0
+    admit_time: tp.Optional[float] = None
+    prefill_done_time: tp.Optional[float] = None
     first_token_time: tp.Optional[float] = None
     finish_time: tp.Optional[float] = None
     tokens: tp.List[int] = dataclasses.field(default_factory=list)
@@ -1472,6 +1483,11 @@ class ServingEngine:
             f"got {telemetry!r}"
         )
         self.telemetry = telemetry
+        # a profiler session is the request log's other switch (step()):
+        # whether the log is registered with an open session, and whether
+        # it is the session's own and goes when the session does
+        self._in_session = False
+        self._session_log = False
         # overload degradation knobs: max_queue bounds the wait queue
         # (None = unbounded, the library default); a submit hitting the
         # bound is SHED (AdmissionRejected, the request is dropped for
@@ -2826,6 +2842,8 @@ class ServingEngine:
                 self._admit_state(s, req)
             req.admit_tokens = len(req.tokens)  # livelock-guard baseline
             now = self.clock()
+            if req.admit_time is None:
+                req.admit_time = now
             if not req.tokens and req.evictions == 0:
                 # first admission of a fresh request: the wait it just
                 # paid IS the queue delay (re-admissions are eviction
@@ -2840,6 +2858,8 @@ class ServingEngine:
             if not self.prefilling[s]:
                 # a block-diffusion prompt with no whole block left to
                 # prefill (shorter than a block, or all of it cached)
+                if req.first_token_time is None:
+                    req.prefill_done_time = now
                 self._open_first_block(s)
             admitted += 1
 
@@ -2962,6 +2982,12 @@ class ServingEngine:
         self._register_pages(s)
         if start + clen >= p:
             self.prefilling[s] = False
+            if req.first_token_time is None:
+                # the reading the chunk's event carries, where one was
+                # written: log and request then agree to the digit
+                req.prefill_done_time = (
+                    self.clock() if tele is None else sp.t0 + sp.dur
+                )
             if self.role == "prefill":
                 # disaggregated pools: the slot parks fully-prefilled
                 # (done stays True, so no decode window ever carries
@@ -3601,6 +3627,11 @@ class ServingEngine:
             # host-driven start/stop at step boundaries, no effect on
             # the compiled programs
             self.telemetry.maybe_profile(self.fault_step)
+        if TraceAnnotation.is_enabled() != self._in_session:
+            # a profiler session opened or closed since the last step:
+            # asked once, here, before anything of the step reads
+            # ``self.telemetry``
+            self._session_edge()
         with span("midgpt.engine.step", step=self.fault_step):
             with span("midgpt.engine.schedule"):
                 if self._fault_hook is not None:
@@ -3616,13 +3647,17 @@ class ServingEngine:
                 self._admit()
             self._run_prefills()
             decoding = self._decoding_slots()
-            if not decoding:
+            runnable = bool(decoding)
+            if runnable:
+                with span("midgpt.engine.grow"):
+                    self._ensure_growth()
+                    # eviction may have changed it
+                    decoding = self._decoding_slots()
+            if self.telemetry is not None:
+                self._census(decoding)
+            if not runnable:
                 # progress was prefill-only (or nothing runnable yet)
                 return self.has_work
-            with span("midgpt.engine.grow"):
-                self._ensure_growth()
-                # eviction may have changed it
-                decoding = self._decoding_slots()
             if decoding:
                 if self.block_len:
                     self._run_block_window(decoding)
@@ -3631,6 +3666,73 @@ class ServingEngine:
                 else:
                     self._run_window(decoding)
             return True
+
+    def _census(self, decoding: tp.List[int]) -> None:
+        """The ``step`` event: where this step's window is (or would have
+        been) dispatched, how many slots it carries, how many hold a
+        request it does not carry (still prefilling, or prefilled and
+        waiting to be handed off), how many hold none, and the requests
+        waiting for a slot. Integers the scheduler holds; nothing for a
+        step without work."""
+        held = sum(req is not None for req in self.slot_req)
+        if held or self.queue or self.parked:
+            self._emit(
+                "step", decoding=len(decoding),
+                prefilling=held - len(decoding), empty=self.slots - held,
+                queued=len(self.queue), parked=len(self.parked),
+            )
+
+    def _session_edge(self) -> None:
+        """A profiler session is the request log's second switch. Open,
+        and this engine was built without a log: it attaches one of its
+        own for as long as the session lasts, with the beginning of every
+        request then waiting or in a slot back-filled from the request's
+        own stamps (true ``t``, marked ``backfill``). Either way the log
+        joins ``midgpt_tpu.telemetry.session_logs()``, which keeps it when
+        the engine is gone, and notes this step. Closed: the log notes the
+        step, and one that was the session's is detached."""
+        if not self._in_session:
+            if self.telemetry is None:
+                self.telemetry = EngineTelemetry(ring=SESSION_RING)
+                self._session_log = True
+                self._backfill()
+            self.telemetry.open_session(self.fault_step)
+        else:
+            self.telemetry.close_session(self.fault_step)
+            if self._session_log:
+                self.telemetry, self._session_log = None, False
+        self._in_session = not self._in_session
+
+    def _backfill(self) -> None:
+        """The beginning of every request this engine holds (``_live``:
+        queued, parked or in a slot) into a log that attached after it:
+        ``submit`` and ``queued``, ``admitted`` once it has been, and for
+        one whose prompt is resident the ``prefill_chunk`` that made it
+        so — each at the time the request itself carries."""
+        in_slot = {self.slot_req[s].rid: s for s in self._active_slots()}
+        for req in self._live.values():
+            old = dict(rid=req.rid, backfill=True)
+            n = int(req.prompt0.size)
+            self._emit(
+                "submit", t=req.submit_time, prompt_tokens=n,
+                budget=int(req.max_new_tokens), **old,
+            )
+            self._emit(
+                "queued", t=req.submit_time, prompt_tokens=n,
+                tokens_emitted=0, **old,
+            )
+            if req.admit_time is None:
+                continue
+            slot = in_slot.get(req.rid)  # None: evicted, waiting again
+            self._emit("admitted", t=req.admit_time, slot=slot, **old)
+            if (
+                slot is not None and not self.prefilling[slot]
+                and req.prefill_done_time is not None
+            ):
+                self._emit(
+                    "prefill_chunk", t=req.prefill_done_time, slot=slot,
+                    **old,
+                )
 
     def warm_prefill(self, max_tokens: int) -> tp.List[int]:
         """Pre-compile every prefill-chunk bucket a trace of prompts up

@@ -49,6 +49,19 @@ Four pieces, one design constraint:
    ``jax.profiler`` start/stop hooks around a selected scheduler-step
    window (``profile_dir``/``profile_steps``).
 
+**Two switches, one log**: ``ServingEngine(telemetry=...)``, and a
+profiler session. An engine built without a log attaches one of its own
+at the first ``step()`` that finds a ``jax.profiler`` session open, with
+the beginning of every request then in flight back-filled from the
+stamps the request carries, and drops it at the first step after the
+session closed; either kind of log is kept by
+``midgpt_tpu.telemetry.session_logs()`` past its engine, for whoever
+reads the session's trace. A request's lifetime cannot be a
+``TraceAnnotation`` (lifetimes overlap, annotations nest on a thread):
+its events are on the trace's clock by their engine step — the k-th
+``midgpt.engine.step`` span of the trace is step
+``session_steps[0] + k``.
+
 **The hard constraint**: tracing must not perturb the dispatch
 pipeline. Telemetry is NOT a parameter of any program factory — an
 engine with tracing on selects the *identical cached jitted callables*
@@ -203,7 +216,10 @@ CLUSTER_STATS_KEYS: tp.Tuple[str, ...] = ENGINE_STATS_KEYS + (
 #: source engine, "import" on the destination — disaggregated pools);
 #: ``routed_affinity`` / ``routed_fallback`` = the cluster's admission
 #: decision (prefix-affinity hit vs least-loaded fallback), emitted on
-#: the chosen replica's telemetry.
+#: the chosen replica's telemetry; ``step`` = the slot census of one
+#: engine step that had work, taken where its window is (or would have
+#: been) dispatched: ``decoding`` + ``prefilling`` + ``empty`` = the
+#: engine's slots, ``queued`` and ``parked`` the requests waiting for one.
 EVENT_KINDS: tp.Tuple[str, ...] = (
     "submit",
     "queued",
@@ -224,6 +240,7 @@ EVENT_KINDS: tp.Tuple[str, ...] = (
     "handoff",
     "routed_affinity",
     "routed_fallback",
+    "step",
 )
 
 
@@ -270,12 +287,25 @@ class EngineTelemetry(TelemetryLog):
         harvest-timestamp gaps — see the module docstring's granularity
         note), eviction-stall time (eviction/park -> re-admission, summed
         over preemptions), tokens, and tokens-per-dispatch (dispatches =
-        harvests that included this request)."""
+        harvests that included this request).
+
+        The time to the first token in three parts that add up to it,
+        evictions or none: ``queue_delay_s``; ``prefill_s``, first
+        admission -> prompt resident (its chunks' enqueues and every
+        engine step it waited for the prefill budget between them — the
+        enqueue of its last chunk, or the admission that found the whole
+        prompt cached, whichever came last before the first token); and
+        ``first_window_s``, from there to the harvest that brought the
+        first token: the window it had to wait out. All None where the
+        log does not hold the request's first token (a log that attached
+        later, :meth:`TelemetryLog.open_session`)."""
         evs = self.request_log.get(rid)
         if not evs:
             return None
         submit_t: tp.Optional[float] = None
         first_admit_t: tp.Optional[float] = None
+        resident_t: tp.Optional[float] = None
+        first_tok: tp.Optional[Event] = None
         finish_t: tp.Optional[float] = None
         stall = 0.0
         stall_since: tp.Optional[float] = None
@@ -287,9 +317,14 @@ class EngineTelemetry(TelemetryLog):
             elif ev.kind == "admitted":
                 if first_admit_t is None:
                     first_admit_t = ev.t
+                if first_tok is None:
+                    resident_t = ev.t
                 if stall_since is not None:
                     stall += ev.t - stall_since
                     stall_since = None
+            elif ev.kind == "prefill_chunk":
+                if first_tok is None:
+                    resident_t = ev.t
             elif ev.kind in ("evicted", "parked"):
                 if ev.kind == "evicted":
                     evictions += 1
@@ -297,10 +332,18 @@ class EngineTelemetry(TelemetryLog):
                     stall_since = ev.t
             elif ev.kind == "tokens":
                 dispatches += 1
+                if first_tok is None and ev.data.get("n", 0):
+                    first_tok = ev
             elif ev.kind == "finished":
                 finish_t = ev.t
         tok_ts = self.token_times(rid)
         tbt = [b - a for a, b in zip(tok_ts, tok_ts[1:])]
+        # the first token the log saw is the request's first
+        whole = (
+            first_tok is not None and submit_t is not None
+            and first_tok.data.get("total", 0) <= first_tok.data["n"]
+        )
+        split = whole and first_admit_t is not None
         return {
             "rid": rid,
             "queue_delay_s": (
@@ -308,11 +351,9 @@ class EngineTelemetry(TelemetryLog):
                 if submit_t is not None and first_admit_t is not None
                 else None
             ),
-            "ttft_s": (
-                tok_ts[0] - submit_t
-                if submit_t is not None and tok_ts
-                else None
-            ),
+            "prefill_s": resident_t - first_admit_t if split else None,
+            "first_window_s": first_tok.t - resident_t if split else None,
+            "ttft_s": first_tok.t - submit_t if whole else None,
             "tbt_s": tbt,
             "eviction_stall_s": stall,
             "evictions": evictions,

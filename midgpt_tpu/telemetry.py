@@ -14,7 +14,9 @@ importing the serving stack. The split:
   types, the :class:`TelemetryLog` base (bounded recency ring +
   dispatch-record ring + per-key event log + replay signature + optional
   ``jax.profiler`` window), :func:`write_json`, and
-  :func:`prometheus_text`.
+  :func:`prometheus_text`; :func:`session_logs`, where the logs that
+  recorded during the newest profiler session outlive their owners, and
+  :func:`compile_log`, the programs built in this process.
 - **In serving.telemetry**: the serving lifecycle taxonomy
   (``EVENT_KINDS``), :class:`~midgpt_tpu.serving.telemetry.EngineTelemetry`
   (per-request derived metrics), the request/dispatch-lane Chrome trace
@@ -41,6 +43,7 @@ import re
 import time
 import typing as tp
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 __all__ = [
@@ -52,8 +55,10 @@ __all__ = [
     "LATENCY_BUCKETS_S",
     "MetricsRegistry",
     "TelemetryLog",
+    "compile_log",
     "percentile",
     "prometheus_text",
+    "session_logs",
     "span",
     "write_json",
 ]
@@ -64,11 +69,16 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 #: Fixed latency buckets (seconds) shared by every latency histogram:
-#: sub-ms through 10 s, roughly x2.5 per step. Fixed (not adaptive) so
-#: snapshots from different runs/replicas merge bucket-for-bucket.
+#: 1 ms through 10 s, six a decade (1 - 1.5 - 2 - 3 - 5 - 7.5), so that a
+#: time to the first token of 0.2-0.8 s falls into five of them: without
+#: a profiler session ``--metrics_out`` is the operator's only view of
+#: these waits. Fixed (not adaptive) so snapshots from different
+#: runs/replicas merge bucket-for-bucket.
 LATENCY_BUCKETS_S: tp.Tuple[float, ...] = (
-    0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
-    1.0, 2.5, 5.0, 10.0,
+    0.001, 0.0015, 0.002, 0.003, 0.005, 0.0075,
+    0.01, 0.015, 0.02, 0.03, 0.05, 0.075,
+    0.1, 0.15, 0.2, 0.3, 0.5, 0.75,
+    1.0, 1.5, 2.0, 3.0, 5.0, 7.5, 10.0,
 )
 
 
@@ -328,6 +338,39 @@ class TelemetryLog:
         )
         self.request_log: tp.Dict[int, tp.List[Event]] = {}
         self._seq = 0
+        #: (first, end) owner steps of the newest profiler session this
+        #: log recorded: ``end`` is None while the owner still sees it open
+        self.session_steps: tp.Optional[
+            tp.Tuple[int, tp.Optional[int]]
+        ] = None
+
+    # -- a profiler session ------------------------------------------------
+
+    def open_session(self, step: int) -> None:
+        """The owner found a profiler session open at the top of its step
+        ``step``: from here on this log is one of :func:`session_logs`,
+        which keeps it after its owner is gone. The k-th step span the
+        owner writes into the session's trace is its step ``step + k``, so
+        an event's ``step`` places it on the trace's clock."""
+        if all(log.session_steps[1] is not None for log in _SESSION_LOGS):
+            _SESSION_LOGS.clear()  # every log was closed: a new session
+        elif self in _SESSION_LOGS:
+            _SESSION_LOGS.remove(self)
+        self.session_steps = (step, None)
+        _SESSION_LOGS.append(self)
+        del _SESSION_LOGS[:-SESSION_LOGS_MAX]
+
+    def close_session(self, step: int) -> None:
+        """The session was closed before the owner's step ``step``."""
+        self.session_steps = (self.session_steps[0], step)
+
+    def in_session(self, ev: "Event") -> bool:
+        """Whether the owner recorded ``ev`` in a step of its newest
+        session (an event back-filled when the log attached is not)."""
+        if self.session_steps is None or ev.data.get("backfill"):
+            return False
+        first, end = self.session_steps
+        return ev.step >= first and (end is None or ev.step < end)
 
     # -- recording ---------------------------------------------------------
 
@@ -437,6 +480,48 @@ class TelemetryLog:
             "events": [ev.to_json() for ev in list(self.events)],
             "dispatches": [d.to_json() for d in list(self.dispatches)],
         }
+
+
+# ---------------------------------------------------------------------------
+# What outlives its owner: a session's logs, the programs built
+# ---------------------------------------------------------------------------
+
+SESSION_LOGS_MAX = 8
+_SESSION_LOGS: tp.List[TelemetryLog] = []
+
+
+def session_logs() -> tp.List[TelemetryLog]:
+    """The logs that recorded during the newest profiler session
+    (:meth:`TelemetryLog.open_session`), oldest first and at most
+    ``SESSION_LOGS_MAX``: the list itself, which holds them after their
+    owners are gone. Whoever reads a session's trace reads the requests
+    and steps of that session here."""
+    return _SESSION_LOGS
+
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILES: tp.Deque[tp.Tuple[str, float, bool]] = collections.deque(
+    maxlen=256
+)
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    if event == COMPILE_EVENT:
+        _COMPILES.append(
+            (kw.get("fun_name", "?"), seconds, TraceAnnotation.is_enabled())
+        )
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_log() -> tp.Deque[tp.Tuple[str, float, bool]]:
+    """The last programs this process built, as (``fun_name``, seconds,
+    whether a profiler session was open): the ring itself. JAX reports one
+    a new shape of a jitted function — compiled, or loaded from the
+    persistent cache: either stalls the step that asked for it — and none
+    for a repeated call, so a steady loop adds nothing here."""
+    return _COMPILES
 
 
 class span:

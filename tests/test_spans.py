@@ -21,6 +21,7 @@ from midgpt_tpu.config import ExperimentConfig, MeshConfig, ModelConfig
 from midgpt_tpu.data import Loader, PrefetchLoader, Shard, write_tokens
 from midgpt_tpu.models.gpt import GPT
 from midgpt_tpu.serving import EngineTelemetry, ServingEngine
+from midgpt_tpu import telemetry as shared
 from midgpt_tpu.telemetry import TelemetryLog, span
 from midgpt_tpu.train import train
 
@@ -250,6 +251,306 @@ def test_traced_engine_streams_and_signature_unchanged(model):
     assert plain._window_fn is first._window_fn
     assert (first.telemetry.sequence_signature()
             == second.telemetry.sequence_signature())
+
+
+# ---------------------------------------------------------------------------
+# a profiler session is the request log's switch
+# ---------------------------------------------------------------------------
+
+ENGINE_KW = dict(slots=2, page_size=8, window=4, temperature=0.0,
+                 cache_dtype=jnp.float32, prefill_chunk=8)
+PARTS = ("queue_delay_s", "prefill_s", "first_window_s")
+
+
+@pytest.fixture
+def no_session_logs():
+    """A session of another test may have left a log open on the list."""
+    shared.session_logs().clear()
+    yield shared.session_logs()
+    shared.session_logs().clear()
+
+
+def _warm(model, **kw):
+    eng = ServingEngine(model, **{**ENGINE_KW, **kw})
+    eng.submit(_prompt(0, 20), 6)
+    eng.run()  # every program compiled
+    return eng
+
+
+def _drain(eng, each_step=lambda: None, limit=80):
+    steps = 0
+    while eng.has_work and steps < limit:
+        eng.step()
+        each_step()
+        steps += 1
+    assert not eng.has_work
+    return steps
+
+
+def test_engine_without_a_session_never_holds_a_log(model, monkeypatch,
+                                                    no_session_logs):
+    """With no session open ``step()`` asks once and does nothing else new:
+    no log, nothing on the list, every step."""
+    from midgpt_tpu.serving import engine as engine_module
+
+    asked = []
+
+    class Asked:
+        @staticmethod
+        def is_enabled():
+            asked.append(1)
+            return False
+
+    eng = _warm(model)
+    monkeypatch.setattr(engine_module, "TraceAnnotation", Asked)
+    for i in range(1, 4):
+        eng.submit(_prompt(i, 20), 6, seed=i)
+
+    def check():
+        assert eng.telemetry is None and not no_session_logs
+
+    steps = _drain(eng, check)
+    assert len(asked) == steps > 3
+    assert all(r.admit_time is not None and r.prefill_done_time is not None
+               for r in eng.finished.values())
+
+
+def test_request_log_attaches_for_a_session_and_detaches(
+        model, tmp_path, no_session_logs):
+    def serve(session):
+        eng = _warm(model)
+        rids = [eng.submit(_prompt(i, 20), 9, seed=i) for i in range(1, 4)]
+        eng.step()
+        eng.step()  # rid 1 and 2 admitted, 3 queued: before the session
+        before = eng.fault_step
+        assert eng.telemetry is None
+        if session:
+            with Capture(tmp_path) as cap:
+                eng.step()  # the engine learns of the session at a step
+                rids.append(eng.submit(_prompt(4, 20), 9, seed=4))
+                steps = 1 + _drain(
+                    eng, lambda: eng.telemetry is not None or pytest.fail(
+                        "no log inside the session"))
+            # the log leaves at the first step after the session
+            assert eng.telemetry is not None
+            eng.submit(_prompt(5), 2)
+            eng.step()
+            assert eng.telemetry is None
+            (log,) = shared.session_logs()
+            assert log.session_steps == (before + 1, before + 1 + steps)
+            assert len(cap.named("midgpt.engine.step")) == steps
+            return eng, rids, log
+        eng.step()
+        rids.append(eng.submit(_prompt(4, 20), 9, seed=4))
+        _drain(eng)
+        return eng, rids, None
+
+    plain, rids, _ = serve(False)
+    traced, rids_t, log = serve(True)
+    assert rids == rids_t
+    for r in rids:
+        assert plain.finished[r].tokens == traced.finished[r].tokens
+    assert plain._window_fn is traced._window_fn
+    # the back-fill: each request of before the session has its beginning,
+    # at the times the request itself carries
+    for r in rids[:3]:
+        req = traced.finished[r]
+        evs = {e.kind: e for e in reversed(log.request_log[r])
+               if e.data.get("backfill")}
+        assert evs["submit"].t == evs["queued"].t == req.submit_time
+        assert (evs["submit"].data["prompt_tokens"],
+                evs["submit"].data["budget"]) == (20, 9)
+        if r != rids[2]:  # in a slot when the log attached
+            assert evs["admitted"].t == req.admit_time
+        assert not any(log.in_session(e) for e in evs.values())
+        m = log.request_metrics(r)
+        assert m["ttft_s"] == pytest.approx(
+            req.first_token_time - req.submit_time, abs=1e-12)
+        assert sum(m[k] for k in PARTS) == pytest.approx(
+            m["ttft_s"], abs=1e-12)
+    # the one submitted inside it was logged as it happened
+    assert not any(e.data.get("backfill")
+                   for e in log.request_log[rids_t[3]])
+    # the list keeps the log when the engine is gone
+    del traced
+    import gc
+
+    gc.collect()
+    assert shared.session_logs() == [log] and log.request_log
+
+
+def test_engine_with_its_own_log_registers_it_the_same_way(
+        model, tmp_path, no_session_logs):
+    eng = _warm(model, telemetry=True)
+    own = eng.telemetry
+    assert own.session_steps is None and not no_session_logs
+    eng.submit(_prompt(1, 20), 6)
+    with Capture(tmp_path):
+        steps = _drain(eng)
+    assert eng.telemetry is own and shared.session_logs() == [own]
+    first = own.session_steps[0]
+    assert own.session_steps == (first, None)
+    eng.submit(_prompt(2), 2)
+    eng.step()
+    assert eng.telemetry is own
+    assert own.session_steps == (first, first + steps)
+    # its earlier events are no part of the session, and nothing is
+    # back-filled into a log that saw them happen
+    assert not any(e.data.get("backfill") for e in own.events)
+    inside = [e for e in own.events if own.in_session(e)]
+    assert inside and len(inside) < len(own.events)
+    assert {e.step for e in inside} <= set(range(first, first + steps))
+
+
+def test_session_logs_are_bounded_and_a_new_session_starts_over(
+        no_session_logs):
+    logs = [TelemetryLog() for _ in range(shared.SESSION_LOGS_MAX + 3)]
+    for i, log in enumerate(logs):
+        log.open_session(i)  # eleven owners in one session
+    assert shared.session_logs() == logs[3:]
+    for log in logs:
+        log.close_session(20)
+    late = TelemetryLog()
+    late.open_session(0)  # every log was closed: another session
+    assert shared.session_logs() == [late]
+    late.open_session(5)  # the same owner again, while still open
+    assert shared.session_logs() == [late] and late.session_steps == (5, None)
+
+
+def _served(model, case):
+    """One finished request and the log that saw all of it."""
+    if case in ("block", "whole_prefix_hit"):
+        cfg = dataclasses.replace(CFG, block_len=4, block_steps=4,
+                                  mask_token=CFG.vocab_size - 1)
+        eng = ServingEngine(
+            GPT.init(jax.random.PRNGKey(0), cfg), telemetry=True,
+            paged_kernel="xla", **{**ENGINE_KW, "window": 5})
+        prompt = np.arange(16, dtype=np.int32)  # two whole pages
+        rid = eng.submit(prompt, 6)
+        eng.run()
+        if case == "whole_prefix_hit":
+            rid = eng.submit(prompt, 6)
+            eng.run()
+            assert eng.finished[rid].cached_tokens == 16
+        return eng, rid
+    eng = ServingEngine(model, telemetry=True, **ENGINE_KW)
+    rid = eng.submit(_prompt(1, 20), 6)
+    if case == "evicted":
+        eng.step()
+        eng.step()  # two of three chunks in
+        eng._evict(0)
+    eng.run()
+    return eng, rid
+
+
+@pytest.mark.parametrize(
+    "case", ["plain", "evicted", "whole_prefix_hit", "block"])
+def test_time_to_first_token_splits_into_three_parts(model, case):
+    eng, rid = _served(model, case)
+    req, m = eng.finished[rid], eng.telemetry.request_metrics(rid)
+    assert all(m[k] is not None and m[k] >= 0.0 for k in PARTS)
+    assert sum(m[k] for k in PARTS) == pytest.approx(m["ttft_s"], abs=1e-12)
+    # the log's events and the request's own stamps are one clock reading
+    assert m["queue_delay_s"] == req.admit_time - req.submit_time
+    assert m["prefill_s"] == req.prefill_done_time - req.admit_time
+    assert m["first_window_s"] == (
+        req.first_token_time - req.prefill_done_time)
+    kinds = [e.kind for e in eng.telemetry.request_log[rid]]
+    if case == "evicted":
+        assert m["evictions"] == 1 and kinds.count("admitted") == 2
+        assert kinds.index("evicted") < kinds.index("tokens")
+    if case == "whole_prefix_hit":
+        assert "prefill_chunk" not in kinds and m["prefill_s"] == 0.0
+    else:
+        assert m["prefill_s"] > 0.0
+
+
+def test_request_metrics_without_the_first_token_has_no_ttft():
+    """A log that attached after a request's first token holds its later
+    harvests only: no time to a first token is made up from them."""
+    log = EngineTelemetry()
+    log.emit("submit", step=5, t=1.0, rid=1, backfill=True)
+    log.emit("admitted", step=5, t=2.0, rid=1, backfill=True)
+    log.emit("tokens", step=6, t=9.0, rid=1, n=4, total=12)
+    m = log.request_metrics(1)
+    assert m["queue_delay_s"] == 1.0
+    assert m["ttft_s"] is m["prefill_s"] is m["first_window_s"] is None
+
+
+def test_slot_census_adds_up_on_every_step(model):
+    eng = ServingEngine(model, telemetry=True, **{**ENGINE_KW, "slots": 3})
+    eng.step()  # nothing to do: no census
+    assert not [e for e in eng.telemetry.events if e.kind == "step"]
+    for i in range(5):
+        eng.submit(_prompt(i, 20), 5 + i, seed=i)
+    windows, steps = [], 0
+    while eng.has_work:
+        before = eng.windows
+        eng.step()
+        steps += 1
+        windows.append(eng.windows - before)
+    census = [e for e in eng.telemetry.events if e.kind == "step"]
+    assert [e.step for e in census] == list(range(2, 2 + steps))
+    for e, ran in zip(census, windows):
+        d = e.data
+        assert d["decoding"] + d["prefilling"] + d["empty"] == 3
+        assert (d["decoding"] > 0) == bool(ran)
+        assert e.rid is None and d["parked"] == 0
+    first = census[0].data  # a step of prefill chunks alone
+    assert (first["decoding"], first["prefilling"], first["queued"]) == (
+        0, 3, 2)
+    assert windows[0] == 0
+    assert census[-1].data["queued"] == 0
+    assert sum(e.data["decoding"] for e in census) == eng.occupancy_sum
+
+
+def test_prefill_role_slots_waiting_for_handoff_count_as_prefilling(model):
+    eng = ServingEngine(model, telemetry=True, role="prefill", **ENGINE_KW)
+    eng.submit(_prompt(1, 8), 4)
+    eng.step()
+    assert eng.handoff_ready_slots() == [0]
+    eng.step()
+    for e in eng.telemetry.events:
+        if e.kind == "step":
+            assert (e.data["decoding"], e.data["prefilling"],
+                    e.data["empty"]) == (0, 1, 1)
+
+
+def test_compile_log_counts_a_new_shape_once_and_marks_a_session(tmp_path):
+    log = shared.compile_log()
+
+    @jax.jit
+    def bump(x):
+        return x * 2 + 1
+
+    def built():
+        return [(n, s) for n, _, s in log if n == "jit(bump)"]
+
+    bump(jnp.ones(3)).block_until_ready()
+    assert built() == [("jit(bump)", False)]
+    bump(jnp.ones(3)).block_until_ready()  # a repeated call builds nothing
+    assert built() == [("jit(bump)", False)]
+    with Capture(tmp_path):
+        bump(jnp.ones(3)).block_until_ready()
+        assert built() == [("jit(bump)", False)]
+        bump(jnp.ones(5)).block_until_ready()  # a new shape, in the session
+    assert built() == [("jit(bump)", False), ("jit(bump)", True)]
+    bump(jnp.ones(7)).block_until_ready()
+    assert [s for _, s in built()] == [False, True, False]
+    assert all(sec > 0 for n, sec, _ in log if n == "jit(bump)")
+    assert log.maxlen == 256
+
+
+def test_telemetry_stays_inert_under_a_session(tmp_path, no_session_logs):
+    """``prove_telemetry_inert`` with a session open: the engine built
+    without a log attaches one and still runs the same programs to the
+    same tokens."""
+    from midgpt_tpu.analysis.harness import prove_telemetry_inert
+
+    with Capture(tmp_path):
+        rep = prove_telemetry_inert()
+    assert rep["ok"] and rep["streams_identical"]
+    assert len(shared.session_logs()) == 2  # the engine's own, and its twin's
 
 
 # ---------------------------------------------------------------------------

@@ -109,6 +109,25 @@ def test_histogram_fixed_buckets():
         Histogram("bad", bounds=(1.0, 0.5))  # bounds must ascend
 
 
+def test_latency_buckets_resolve_a_time_to_first_token():
+    """The shared ladder: six buckets a decade from 1 ms to 10 s, so that
+    the waits ``--metrics_out`` shows — a time to the first token of
+    0.2-0.8 s in the serving cells — spread over five buckets, not two."""
+    from midgpt_tpu.telemetry import LATENCY_BUCKETS_S as ladder
+
+    assert (ladder[0], ladder[-1], len(ladder)) == (0.001, 10.0, 25)
+    assert list(ladder) == sorted(set(ladder))
+    for lo, hi in zip(ladder, ladder[6:]):
+        assert hi == pytest.approx(10 * lo)  # one decade on, the same steps
+    h = Histogram("ttft_s")
+    assert h.bounds == ladder
+    for v in (0.2, 0.25, 0.4, 0.6, 0.8):
+        h.observe(v)
+    assert sum(1 for c in h.counts if c) == 5
+    # every bound prints as it is written (a Prometheus ``le`` label)
+    assert all(repr(b) == f"{b:g}" for b in ladder if b < 1)
+
+
 def test_percentile_nearest_rank():
     assert percentile([], 0.5) is None
     vals = [1.0, 2.0, 3.0, 4.0]
