@@ -1275,6 +1275,7 @@ _ENGINE_COUNTERS = (
     "decode_dispatches",
     "device_reads",
     "prefill_dispatches",
+    "prefill_steps",
     "copy_dispatches",
     "tokens_generated",
     "windows",
@@ -1379,9 +1380,12 @@ class ServingEngine:
 
     Chunked prefill (``prefill_chunk=N``): prompts prefill N tokens at a
     time, at most ``prefill_budget`` tokens between consecutive decode
-    windows, so a long prompt cannot stall co-scheduled decode slots for
-    more than one chunk. ``prefill_chunk=None`` keeps the monolithic
-    behavior (the whole uncached suffix in one dispatch).
+    windows. In front of a K-step decode window the budget defaults to
+    one chunk a step (``N * window``), so each token the window emits
+    waits behind at most one chunk; in front of a verify dispatch or a
+    block window (whose ``window`` counts forwards, not token steps) it
+    stays one chunk. ``prefill_chunk=None`` keeps the monolithic behavior
+    (the whole uncached suffix in one dispatch).
 
     Self-speculative decoding (``speculate=N``, greedy only): every
     decode dispatch becomes a VERIFY dispatch — a host-side n-gram
@@ -1761,11 +1765,16 @@ class ServingEngine:
         self.prefill_chunk = prefill_chunk
         # tokens of prefill work allowed between decode windows; the
         # first chunk always runs (progress guarantee), so the effective
-        # floor is one chunk
+        # floor is one chunk. In front of a K-step decode window the
+        # default is one chunk a step, so each token of the window waits
+        # behind at most one chunk; a verify dispatch is one step, and a
+        # block window's `window` counts forwards, so both keep one chunk.
+        # None (monolithic) -> unlimited
+        budget_steps = 1 if speculate or cfg.block_len else window
         self.prefill_budget = (
             prefill_budget
-            if prefill_budget is not None
-            else prefill_chunk  # None (monolithic) -> unlimited
+            if prefill_budget is not None or prefill_chunk is None
+            else prefill_chunk * budget_steps
         )
         # sampling config: temperature == 0 is greedy, temperature > 0
         # samples — in BOTH the plain window and the speculative verify
@@ -3005,11 +3014,16 @@ class ServingEngine:
         """Sarathi-style chunk scheduling: round-robin one chunk per
         prefilling slot until the per-window token budget is spent (the
         first chunk always runs, so prefill can never starve). The
-        rotation cursor persists ACROSS windows — with the default
-        one-chunk budget, restarting at slot 0 every window would feed
-        slot 0's whole prompt before a second prefilling slot saw its
-        first chunk, exactly the TTFT starvation chunking exists to
-        bound."""
+        rotation cursor persists ACROSS windows — with a budget of fewer
+        chunks than there are prefilling slots (one chunk, by default, for
+        ``window=1``, a verify dispatch or a block window), restarting at
+        slot 0 every window would feed slot 0's whole prompt before a
+        second prefilling slot saw its first chunk, exactly the TTFT
+        starvation chunking exists to bound. A step that runs any chunk
+        counts once in ``prefill_steps``."""
+        if not self.prefilling.any():
+            return
+        self.prefill_steps += 1
         spent = 0
         while True:
             pending = [s for s in range(self.slots) if self.prefilling[s]]
@@ -3861,6 +3875,9 @@ class ServingEngine:
             # decode dispatch, none a prefill chunk
             "device_reads": self.device_reads,
             "prefill_dispatches": self.prefill_dispatches,
+            # engine steps that ran at least one chunk: chunks a prefill
+            # step = prefill_dispatches / prefill_steps
+            "prefill_steps": self.prefill_steps,
             "copy_dispatches": self.copy_dispatches,
             "tokens_generated": self.tokens_generated,
             "windows": self.windows,

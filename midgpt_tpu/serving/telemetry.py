@@ -116,6 +116,7 @@ ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "decode_dispatches",
     "device_reads",
     "prefill_dispatches",
+    "prefill_steps",
     "copy_dispatches",
     "tokens_generated",
     "windows",

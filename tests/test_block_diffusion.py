@@ -122,6 +122,17 @@ def test_counters_count_blocks_not_steps(served):
         "expert_layer_forwards"]
 
 
+def test_block_window_keeps_a_one_chunk_budget(served):
+    """A block window's ``window`` counts forwards, not token steps, so with
+    ``prefill_chunk`` and no ``prefill_budget`` the budget stays one chunk
+    (a decode window's is one chunk a step); a step that ran any chunk
+    counts once in ``prefill_steps``."""
+    eng, _ = served
+    assert eng.prefill_budget == eng.prefill_chunk == 16
+    st = eng.stats()
+    assert 0 < st["prefill_steps"] <= st["prefill_dispatches"]
+
+
 # -- (a), (c): logits of every denoising step, and the K/V committed --------
 
 

@@ -258,7 +258,7 @@ def test_traced_engine_streams_and_signature_unchanged(model):
 # ---------------------------------------------------------------------------
 
 ENGINE_KW = dict(slots=2, page_size=8, window=4, temperature=0.0,
-                 cache_dtype=jnp.float32, prefill_chunk=8)
+                 cache_dtype=jnp.float32, prefill_chunk=8, prefill_budget=8)
 PARTS = ("queue_delay_s", "prefill_s", "first_window_s")
 
 
