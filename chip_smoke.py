@@ -1023,7 +1023,7 @@ def phase_serve(params, cfg, seed: int):
             f"(smoke timing {time.perf_counter() - t0:.1f} s)")
         check(st["prefill_tokens_saved"] >= SHARED_PREFIX - 16,
               f"{label}: the shared prefix was not served from the cache")
-        check(st["prefill_dispatches"] > len(prompts),
+        check(st["prefill_chunks"] > len(prompts),
               f"{label}: prefill was not chunked")
         if kw.get("speculate"):
             check(st["verify_dispatches"] > 0,

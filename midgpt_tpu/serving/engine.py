@@ -21,6 +21,9 @@ replaces both:
   instead of monopolizing one, bounding TTFT for co-scheduled requests;
   a chunk resumes mid-prompt from the partially-built block table
   (models.gpt.prefill_chunk_paged), so chunking is exact, not windowed.
+  The budget is handed out in granules of ``prefill_chunk`` tokens, and
+  a slot's granules of one window run as one program, so the weights
+  are read once for them.
 - **Continuous batching**: a host-side scheduler admits queued requests
   into free decode slots at every window boundary, interleaves their
   prefills with decode, and evicts (re-queues with progress kept) under
@@ -132,6 +135,16 @@ Array = jax.Array
 # events the log of a profiler session keeps (ServingEngine._session_edge):
 # a step writes one a decoding slot and a few more, so minutes of steps
 SESSION_RING = 65536
+
+# rows at which a prefill-chunk program stops being bound by its weight
+# stream: a bf16 matmul over M rows does 2·M·K·N FLOP for 2·K·N bytes of
+# weights, M FLOP a byte, and a v5e chip does 197 TFLOP/s over 819 GB/s =
+# ~240 FLOP a byte, rounded to the page buckets' 256. Below it a longer
+# program shares one read of the weights among more rows; above it the
+# matrix unit bounds the program and a longer one only pads more. A slot's
+# prefill granules of one engine step run as one program of at most
+# max(prefill_chunk, PREFILL_MERGE_ROWS) rows (ServingEngine._run_prefills)
+PREFILL_MERGE_ROWS = 256
 
 
 # ---------------------------------------------------------------------------
@@ -1275,6 +1288,7 @@ _ENGINE_COUNTERS = (
     "decode_dispatches",
     "device_reads",
     "prefill_dispatches",
+    "prefill_chunks",
     "prefill_steps",
     "copy_dispatches",
     "tokens_generated",
@@ -1360,7 +1374,7 @@ class ServingEngine:
     a single device->host read.
 
     What crosses between host and device in a step, once each: a prefill
-    chunk PUTS its tokens, three scalars and a copy of the slot's
+    dispatch PUTS its tokens, three scalars and a copy of the slot's
     block-table row in one ``jax.device_put`` and reads nothing back
     (:meth:`_dispatch_chunk`); a window — decode, verify or block — PUTS
     the scheduler's host arrays in one ``jax.device_put`` and READS its
@@ -1378,14 +1392,18 @@ class ServingEngine:
     resident cold until page pressure reclaims them LRU. Token streams
     are identical with the cache on or off.
 
-    Chunked prefill (``prefill_chunk=N``): prompts prefill N tokens at a
-    time, at most ``prefill_budget`` tokens between consecutive decode
-    windows. In front of a K-step decode window the budget defaults to
-    one chunk a step (``N * window``), so each token the window emits
-    waits behind at most one chunk; in front of a verify dispatch or a
-    block window (whose ``window`` counts forwards, not token steps) it
-    stays one chunk. ``prefill_chunk=None`` keeps the monolithic behavior
-    (the whole uncached suffix in one dispatch).
+    Chunked prefill (``prefill_chunk=N``): N tokens is the granule of the
+    prefill budget, at most ``prefill_budget`` tokens between consecutive
+    decode windows, handed out round-robin over the prefilling slots. In
+    front of a K-step decode window the budget defaults to one granule a
+    step (``N * window``), so each token the window emits waits behind at
+    most one granule's prefill; in front of a verify dispatch or a block
+    window (whose ``window`` counts forwards, not token steps) it stays
+    one granule. A slot's granules of one step run as ONE chunk program
+    of at most ``max(N, PREFILL_MERGE_ROWS)`` rows (past that, a next
+    one), so the weights are streamed once for them and not once a
+    granule. ``prefill_chunk=None`` keeps the monolithic behavior (the
+    whole uncached suffix in one dispatch).
 
     Self-speculative decoding (``speculate=N``, greedy only): every
     decode dispatch becomes a VERIFY dispatch — a host-side n-gram
@@ -1763,12 +1781,13 @@ class ServingEngine:
             if spill == "on" else None
         )
         self.prefill_chunk = prefill_chunk
-        # tokens of prefill work allowed between decode windows; the
-        # first chunk always runs (progress guarantee), so the effective
-        # floor is one chunk. In front of a K-step decode window the
-        # default is one chunk a step, so each token of the window waits
-        # behind at most one chunk; a verify dispatch is one step, and a
-        # block window's `window` counts forwards, so both keep one chunk.
+        # tokens of prefill work allowed between decode windows, handed
+        # out in granules of prefill_chunk; the first granule always runs
+        # (progress guarantee), so the effective floor is one granule. In
+        # front of a K-step decode window the default is one granule a
+        # step, so each token of the window waits behind at most one
+        # granule's prefill; a verify dispatch is one step, and a block
+        # window's `window` counts forwards, so both keep one granule.
         # None (monolithic) -> unlimited
         budget_steps = 1 if speculate or cfg.block_len else window
         self.prefill_budget = (
@@ -2497,7 +2516,16 @@ class ServingEngine:
 
     def _prefill_bucket(self, p: int) -> int:
         """Padded chunk length: pages rounded up to a power of two, so the
-        number of compiled prefill programs is O(log(block/page_size))."""
+        number of compiled prefill programs is O(log(block/page_size)).
+        Under a granule below ``PREFILL_MERGE_ROWS`` a program is never
+        shorter than the granule: there its time is its weight stream, so
+        the padding costs next to nothing, and an engine that merges
+        granules builds the programs from the granule up to the cap (on a
+        64-token granule 64, 128 and 256 rows) and none below."""
+        if self.prefill_chunk is not None and (
+            self.prefill_chunk < PREFILL_MERGE_ROWS
+        ):
+            p = max(p, self.prefill_chunk)
         n = pages_needed(p, self.page_size)
         n = 1 << (n - 1).bit_length()
         return min(n * self.page_size, self.pmax * self.page_size)
@@ -2948,22 +2976,21 @@ class ServingEngine:
         self.blk_at[s] = -1
         self.done[s] = False
 
-    def _prefill_one_chunk(self, s: int) -> bool:
-        """Run ONE prefill chunk for slot ``s``; returns True when the
-        slot's prompt is fully resident (the slot becomes decodable)."""
+    def _prefill_one_chunk(self, s: int, clen: int, granules: int) -> None:
+        """Run ONE chunk program for slot ``s`` over its next ``clen``
+        prompt tokens, ``granules`` of the budget's; when they are the
+        last, the slot's prompt is resident and it decodes from the next
+        window on."""
         req = self.slot_req[s]
         assert req is not None and self.prefilling[s]
         # == req.prompt.size at admission (its whole blocks, for a
         # block-diffusion model)
         p = self._prefill_target(len(self.slot_ctx[s]))
         start = int(self.pooled_len[s])
-        remaining = p - start
-        assert remaining >= 1, (s, p, start)
-        clen = (
-            remaining
-            if self.prefill_chunk is None
-            else min(self.prefill_chunk, remaining)
-        )
+        assert 1 <= clen <= p - start, (s, p, start, clen)
+        # padded to a power of two of pages, which may run past the
+        # prompt, the slot's last page and the rope table: the padded
+        # rows write nothing (write_token_rows) and clip their positions
         bucket = self._prefill_bucket(clen)
         tele = self.telemetry
         with span(
@@ -2981,6 +3008,7 @@ class ServingEngine:
             # prefix, never written
             self._dispatch_chunk(s, toks, start, clen, self.bt[s].copy())
         self.prefill_dispatches += 1
+        self.prefill_chunks += granules
         self.prefill_tokens_computed += clen
         if tele is not None:
             tele.emit(
@@ -3007,42 +3035,65 @@ class ServingEngine:
                 self._open_first_block(s)
             else:
                 self.done[s] = False  # decodable from the next window on
-            return True
-        return False
 
     def _run_prefills(self) -> None:
-        """Sarathi-style chunk scheduling: round-robin one chunk per
-        prefilling slot until the per-window token budget is spent (the
-        first chunk always runs, so prefill can never starve). The
-        rotation cursor persists ACROSS windows — with a budget of fewer
-        chunks than there are prefilling slots (one chunk, by default, for
-        ``window=1``, a verify dispatch or a block window), restarting at
-        slot 0 every window would feed slot 0's whole prompt before a
-        second prefilling slot saw its first chunk, exactly the TTFT
-        starvation chunking exists to bound. A step that runs any chunk
-        counts once in ``prefill_steps``."""
+        """Sarathi-style chunk scheduling: round-robin one granule of
+        ``prefill_chunk`` tokens (or the prompt's remainder) per prefilling
+        slot until the per-window token budget is spent (the first granule
+        always runs, so prefill can never starve). The rotation cursor
+        persists ACROSS windows — with a budget of fewer granules than
+        there are prefilling slots (one, by default, for ``window=1``, a
+        verify dispatch or a block window), restarting at slot 0 every
+        window would feed slot 0's whole prompt before a second prefilling
+        slot saw its first granule, exactly the TTFT starvation chunking
+        exists to bound.
+
+        The budget is handed out first; then each slot's granules of this
+        step, which are consecutive in its prompt, run as ONE chunk
+        program, slot by slot in the order of their first granule — up to
+        ``max(prefill_chunk, PREFILL_MERGE_ROWS)`` rows a program, past
+        which a next one begins. ``prefill_chunks`` counts the granules,
+        ``prefill_dispatches`` the programs; a step that runs any counts
+        once in ``prefill_steps``."""
         if not self.prefilling.any():
             return
         self.prefill_steps += 1
+        left = {
+            s: self._prefill_target(len(self.slot_ctx[s]))
+            - int(self.pooled_len[s])
+            for s in range(self.slots) if self.prefilling[s]
+        }
+        budget = (
+            float("inf") if self.prefill_budget is None
+            else self.prefill_budget
+        )
+        granules: tp.Dict[int, tp.List[int]] = {}  # in first-granule order
         spent = 0
-        while True:
-            pending = [s for s in range(self.slots) if self.prefilling[s]]
-            if not pending:
-                return
-            pending.sort(
-                key=lambda s: (s - self._prefill_rr) % self.slots
-            )
-            for s in pending:
-                if not self.prefilling[s]:
-                    continue
-                before = self.prefill_tokens_computed
-                self._prefill_one_chunk(s)
+        while left and (not granules or spent < budget):
+            for s in sorted(
+                left, key=lambda s: (s - self._prefill_rr) % self.slots
+            ):
+                g = (
+                    left[s] if self.prefill_chunk is None
+                    else min(self.prefill_chunk, left[s])
+                )
+                granules.setdefault(s, []).append(g)
+                left[s] -= g
+                if not left[s]:
+                    del left[s]
                 self._prefill_rr = (s + 1) % self.slots
-                spent += self.prefill_tokens_computed - before
-                if self.prefill_budget is not None and (
-                    spent >= self.prefill_budget
-                ):
-                    return
+                spent += g
+                if spent >= budget:
+                    break
+        cap = max(self.prefill_chunk or 0, PREFILL_MERGE_ROWS)
+        for s, gs in granules.items():
+            runs: tp.List[tp.List[int]] = [[]]
+            for g in gs:
+                if runs[-1] and sum(runs[-1]) + g > cap:
+                    runs.append([])
+                runs[-1].append(g)
+            for run in runs:
+                self._prefill_one_chunk(s, sum(run), len(run))
 
     # -- prefix-index registration ------------------------------------------
 
@@ -3749,23 +3800,32 @@ class ServingEngine:
                 )
 
     def warm_prefill(self, max_tokens: int) -> tp.List[int]:
-        """Pre-compile every prefill-chunk bucket a trace of prompts up
-        to ``max_tokens`` (suffix) tokens can dispatch — all powers-of-
-        two page counts up to the largest single chunk. With the prefix
-        cache on, admissions prefill arbitrary SUFFIX lengths (and
-        chunking caps them at ``prefill_chunk``), so warming only the
-        full-prompt buckets leaves compiles inside the measured region
-        on exactly the cache-hit/chunked paths. Each bucket runs one
-        pad-token no-op chunk: an all-sentinel block table drops the
-        page writes, and the engine must be idle (slot 0's logits row is
-        scratch). Returns the warmed bucket lengths."""
+        """Pre-compile every prefill-chunk bucket a dispatch can take —
+        all powers-of-two page counts up to the longest one: with
+        monolithic prefill a trace of prompts up to ``max_tokens``
+        (suffix) tokens; with chunking the most rows a slot's granules of
+        one step merge into (``max(prefill_chunk, PREFILL_MERGE_ROWS)``,
+        or fewer where the budget allows fewer), whatever ``max_tokens``
+        says. With the prefix cache on, admissions prefill arbitrary
+        SUFFIX lengths, so warming only the full-prompt buckets leaves
+        compiles inside the measured region on exactly the
+        cache-hit/chunked paths. Each bucket runs one pad-token no-op
+        chunk: an all-sentinel block table drops the page writes, and the
+        engine must be idle (slot 0's logits row is scratch). Returns the
+        warmed bucket lengths."""
         assert not self._active_slots(), "warm_prefill needs an idle engine"
-        cap = min(
-            max_tokens
-            if self.prefill_chunk is None
-            else min(self.prefill_chunk, max_tokens),
-            self.pmax * self.page_size,
-        )
+        if self.prefill_chunk is None:
+            cap = max_tokens
+        else:
+            # a slot's granules but its last are whole and ran while the
+            # budget was not yet spent: at most ceil(budget / chunk) of
+            # them, and the first always runs
+            c = self.prefill_chunk
+            cap = min(
+                max(c, PREFILL_MERGE_ROWS),
+                c * max(1, -(-self.prefill_budget // c)),
+            )
+        cap = min(cap, self.pmax * self.page_size)
         buckets = sorted(
             {self._prefill_bucket(n) for n in range(1, cap + 1)}
         )
@@ -3875,8 +3935,11 @@ class ServingEngine:
             # decode dispatch, none a prefill chunk
             "device_reads": self.device_reads,
             "prefill_dispatches": self.prefill_dispatches,
-            # engine steps that ran at least one chunk: chunks a prefill
-            # step = prefill_dispatches / prefill_steps
+            # the budget's granules of prefill_chunk tokens the dispatches
+            # ran: granules a dispatch = prefill_chunks / prefill_dispatches
+            "prefill_chunks": self.prefill_chunks,
+            # engine steps that ran at least one chunk program: programs a
+            # prefill step = prefill_dispatches / prefill_steps
             "prefill_steps": self.prefill_steps,
             "copy_dispatches": self.copy_dispatches,
             "tokens_generated": self.tokens_generated,

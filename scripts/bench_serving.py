@@ -709,7 +709,7 @@ def main() -> None:
         e.finished.clear()
         e.clear_prefix_cache()  # measured hit rates: the trace alone
         for attr in ("decode_dispatches", "prefill_dispatches",
-                     "copy_dispatches", "tokens_generated", "windows",
+                     "prefill_chunks", "copy_dispatches", "tokens_generated", "windows",
                      "occupancy_sum", "evictions", "prompt_tokens_total",
                      "prompt_tokens_cached", "prefill_tokens_computed",
                      "cold_reclaims", "verify_dispatches", "spec_drafted",

@@ -166,6 +166,23 @@ def test_a_slot_starts_from_zeros_whoever_was_there(model, prompts, served):
     assert got[1:] != served[0][1:]
 
 
+def test_a_merged_first_program_starts_from_zeros(model, prompts):
+    """One slot, two requests: each request's first granules run as ONE
+    program (70 tokens: 4 granules of 16 in the step that admits it, then
+    6; 55: 4 granules), the state's ``fresh`` flag riding that merged
+    program from position 0 — so the second request starts from zeros
+    although the first left its state in the slot. The tokens are those of
+    a granule a program."""
+    pair = [prompts[4], prompts[2]]
+    got, eng = serve(model, pair, slots=1)
+    st = eng.stats()
+    assert (st["prefill_chunks"], st["prefill_dispatches"]) == (9, 3)
+    assert st["state_resets"] == 2
+    want, one = serve(model, pair, slots=1, prefill_budget=16)
+    assert one.stats()["prefill_dispatches"] == 9
+    assert got == want
+
+
 def test_evicted_request_prefills_again_and_continues(model, prompts, served):
     """Six pages for two slots: growth evicts, the evicted request comes
     back with prompt + generated tokens, prefilled from position 0 (no

@@ -185,6 +185,25 @@ def test_a_hit_on_a_shared_document_serves_the_same_tokens(model, prompts):
     assert st["prefill_tokens_computed"] == st["prompt_tokens_total"] - 2 * DOC
 
 
+def test_a_merged_program_behind_a_hit_serves_the_same_tokens(
+        model, prompts):
+    """Behind a hit on the document (64 positions), a question's granules
+    of one step run as ONE program from position 64 against the pooled
+    document rows (55 tokens: four granules of 16 in one program), and
+    serve the tokens of a granule a program."""
+    doc, asks = _asks(prompts)
+    asks = [asks[0], asks[2]]
+    got, eng = serve(model, asks, slots=1)
+    st = eng.stats()
+    assert st["prefill_tokens_saved"] == DOC
+    # the first ask: 64 + 37 tokens, 7 granules in two programs; the
+    # second: 55 behind the hit, 4 granules in one
+    assert (st["prefill_chunks"], st["prefill_dispatches"]) == (11, 3)
+    want, one = serve(model, asks, slots=1, prefill_budget=16)
+    assert one.stats()["prefill_dispatches"] == 11
+    assert got == want
+
+
 def test_two_requests_on_one_document_share_its_pages(model, prompts):
     """Two slots on one document hold its pages at refcount 2 and walk them
     twice; when they finish the pages go to the index's cold list, not to the

@@ -509,7 +509,7 @@ def test_one_read_a_window_one_put_a_dispatch(kind, monkeypatch):
                 and eng.decode_dispatches == windows)
     assert chunk_only_steps, "no step of prefill chunks alone: nothing shown"
     st = eng.stats()
-    assert st["prefill_dispatches"] > len(prompts)  # chunked
+    assert st["prefill_chunks"] > len(prompts)  # chunked
     assert get.call_count == st["device_reads"] == st["decode_dispatches"] > 0
     assert put.call_count == (
         st["decode_dispatches"] + st["prefill_dispatches"]
@@ -809,8 +809,9 @@ def test_chunked_prefill_interleaves_with_decode():
 def test_default_budget_prefills_a_window_of_chunks():
     """With ``prefill_chunk`` and no ``prefill_budget`` the budget is one
     chunk per token step of the window: a 32-token prompt at chunks of 8
-    and ``window=4`` prefills whole in the step that admits it and decodes
-    in that very step's window — one engine step that ran chunks."""
+    and ``window=4`` prefills whole in the step that admits it, its four
+    granules in ONE chunk program, and decodes in that very step's window
+    — one engine step that ran chunks."""
     model = _model()
     prompt = _prompts(1, base_len=32)[0]
     eng = ServingEngine(
@@ -821,7 +822,9 @@ def test_default_budget_prefills_a_window_of_chunks():
     rid = eng.submit(prompt, 8)
     eng.step()
     st = eng.stats()
-    assert (st["prefill_dispatches"], st["prefill_steps"]) == (4, 1)
+    assert (st["prefill_chunks"], st["prefill_dispatches"],
+            st["prefill_steps"]) == (4, 1, 1)
+    assert st["prefill_tokens_computed"] == 32
     assert not eng.prefilling[0]
     assert st["decode_dispatches"] == 1 and st["tokens_generated"] == 4
     fin = eng.run()
@@ -830,16 +833,20 @@ def test_default_budget_prefills_a_window_of_chunks():
     assert eng.stats()["prefill_steps"] == 1
 
 
-@pytest.mark.parametrize("kw, chunks", [
-    (dict(window=1), 1),  # a one-step window: one chunk, as always
-    (dict(window=4, prefill_budget=8), 1),  # explicit: exactly as before
-    (dict(window=4, prefill_budget=16), 2),
-    (dict(window=4, speculate=4), 1),  # a verify dispatch is one step
-], ids=["window1", "explicit8", "explicit16", "speculative"])
-def test_budget_sets_the_chunks_an_engine_step(kw, chunks):
+@pytest.mark.parametrize("kw, chunks, dispatches, first", [
+    (dict(window=1), 1, 1, [8, 0]),  # a one-step window: one chunk
+    (dict(window=4, prefill_budget=8), 1, 1, [8, 0]),  # explicit: as before
+    (dict(window=4, prefill_budget=16), 2, 2, [8, 8]),
+    (dict(window=4, speculate=4), 1, 1, [8, 0]),  # a verify is one step
+    (dict(window=4), 4, 2, [16, 16]),  # the default: a program a slot
+], ids=["window1", "explicit8", "explicit16", "speculative", "window4"])
+def test_budget_sets_the_chunks_an_engine_step(kw, chunks, dispatches, first):
     """Chunks an engine step under each budget, two prompts of 32 tokens
-    prefilling side by side; ``prefill_steps`` counts the steps that ran
-    one or more, so chunks a prefill step = dispatches / steps."""
+    prefilling side by side: the budget is handed out round-robin, a
+    granule of 8 at a time (``first``: what each slot holds after the first
+    step), and each slot's granules of a step run as one program;
+    ``prefill_steps`` counts the steps that ran one or more, so granules a
+    prefill step = ``prefill_chunks`` / steps."""
     model = _model()
     prompts = _prompts(2, base_len=32, stride=0)
     eng = ServingEngine(
@@ -849,18 +856,110 @@ def test_budget_sets_the_chunks_an_engine_step(kw, chunks):
     rids = [eng.submit(p, 8) for p in prompts]
     per_step = []
     while eng.has_work:
-        before = eng.prefill_dispatches
+        before = eng.prefill_chunks, eng.prefill_dispatches
         eng.step()
-        per_step.append(eng.prefill_dispatches - before)
-    ran = [n for n in per_step if n]
+        if not per_step:
+            assert sorted(eng.pooled_len[:2].tolist()) == sorted(first)
+        per_step.append((eng.prefill_chunks - before[0],
+                         eng.prefill_dispatches - before[1]))
+    ran = [n for n in per_step if n[0]]
     # 2 prompts x 4 chunks, `chunks` in every step that runs any
-    assert ran == [chunks] * (8 // chunks)
+    assert ran == [(chunks, dispatches)] * (8 // chunks)
     st = eng.stats()
     assert st["prefill_steps"] == len(ran)
-    assert st["prefill_dispatches"] == 8
+    assert st["prefill_chunks"] == 8
+    assert st["prefill_dispatches"] == 8 // chunks * dispatches
     for r, p in zip(rids, prompts):
         np.testing.assert_array_equal(np.asarray(eng.finished[r].tokens),
                                       _exact(model, p, 8))
+
+
+@pytest.fixture(scope="module")
+def long_context_case():
+    """A 700-token prompt on the small model at 1,024 positions, and the
+    exact sampler's tokens for it."""
+    model = GPT.init(
+        jax.random.PRNGKey(0), dataclasses.replace(CFG, block_size=1024))
+    prompt = _prompts(1, base_len=700)[0]
+    return model, prompt, _exact(model, prompt, 6)
+
+
+@pytest.mark.parametrize("chunk, chunks, dispatches", [
+    (64, 11, 3),  # 4 + 4 + 3 granules: the last program, 188 rows from
+    # position 512 in a bucket of 256, runs past the slot's last page
+    (256, 3, 3),  # at the cap: 256 + 256 + 188, nothing merges
+    (512, 2, 2),  # above it: 512 + 188, nothing merges
+], ids=["chunk64", "chunk256", "chunk512"])
+def test_granules_merge_up_to_the_cap(long_context_case, chunk, chunks,
+                                      dispatches):
+    """A slot's granules of a step run as one program of at most
+    ``max(prefill_chunk, PREFILL_MERGE_ROWS)`` rows: under the cap they
+    merge, at or above it every granule is a program of its own
+    (``prefill_chunks == prefill_dispatches``) — and the tokens are the
+    exact sampler's either way."""
+    from midgpt_tpu.serving.engine import PREFILL_MERGE_ROWS
+
+    assert PREFILL_MERGE_ROWS == 256
+    model, prompt, want = long_context_case
+    eng = ServingEngine(
+        model, slots=1, page_size=16, window=4, temperature=0.0,
+        cache_dtype=jnp.float32, prefill_chunk=chunk,
+    )
+    rid = eng.submit(prompt, 6)
+    fin = eng.run()
+    st = eng.stats()
+    assert (st["prefill_chunks"], st["prefill_dispatches"]) == (
+        chunks, dispatches)
+    assert st["prefill_tokens_computed"] == len(prompt)
+    np.testing.assert_array_equal(np.asarray(fin[rid].tokens), want)
+
+
+def test_a_merged_bucket_past_the_block_table_drops_its_padding():
+    """A merged program's bucket may run past the slot's block table and
+    the rope table (60 tokens of a 64-position model, granules of 8 under
+    a budget of 40: the second program holds 20 rows from position 40 in
+    a bucket of 32, eight rows past the table): its padded rows write
+    nothing and the tokens are those of a monolithic prefill and of the
+    exact sampler."""
+    model = _model()
+    prompt = _prompts(1, base_len=CFG.block_size - 4)[0]  # 60 tokens
+
+    def run(**kw):
+        eng = ServingEngine(
+            model, slots=1, page_size=8, window=4, temperature=0.0,
+            cache_dtype=jnp.float32, **kw,
+        )
+        rid = eng.submit(prompt, 4)
+        return list(eng.run()[rid].tokens), eng
+
+    got, eng = run(prefill_chunk=8, prefill_budget=40)
+    assert (eng.prefill_chunks, eng.prefill_dispatches) == (8, 2)
+    assert 40 + eng._prefill_bucket(20) > eng.pmax * eng.page_size
+    mono, _ = run()
+    assert got == mono
+    np.testing.assert_array_equal(np.asarray(got), _exact(model, prompt, 4))
+
+
+def test_warm_prefill_warms_the_merged_buckets():
+    """``warm_prefill`` compiles every bucket a dispatch can take: with
+    chunking, up to the rows a slot's granules of a step can merge into,
+    whatever ``max_tokens`` says; monolithic, up to ``max_tokens``."""
+    model = GPT.init(
+        jax.random.PRNGKey(0), dataclasses.replace(CFG, block_size=1024))
+
+    def warm(max_tokens, **kw):
+        eng = ServingEngine(
+            model, slots=1, page_size=16, cache_dtype=jnp.float32, **kw)
+        return eng.warm_prefill(max_tokens)
+
+    # the benchmark's call: the chunk as max_tokens, budget 4 x 64; under
+    # the ridge no program is shorter than the granule
+    assert warm(64, prefill_chunk=64) == [64, 128, 256]
+    assert warm(64, prefill_chunk=64, window=2) == [64, 128]
+    assert warm(64, prefill_chunk=64, prefill_budget=64) == [64]
+    assert warm(64, prefill_chunk=24) == [32, 64, 128]
+    assert warm(16, prefill_chunk=512) == [16, 32, 64, 128, 256, 512]
+    assert warm(100, prefill_chunk=None) == [16, 32, 64, 128]
 
 
 def test_sharing_invariants_property_loop():
