@@ -2,7 +2,7 @@
 """Does the system still start on the chip? One process, the normal entry
 points, random weights from ``--seed``, nothing measured.
 
-    python chip_smoke.py             # one TPU chip: phases 1-8
+    python chip_smoke.py             # one TPU chip: phases 1-9
     python chip_smoke.py --only 6    # phase 1 and the phases named
     python chip_smoke.py --chips 4   # four chips: the two sharded paths only
 
@@ -45,6 +45,12 @@ One chip, in order — any failed assertion ends the run non-zero:
    ``jax.numpy`` gather path; and one 256-row prefill chunk against 33 k
    pooled rows (absorbed, heads in groups) against the published form with
    every key up-projected.
+
+9. selective scan — the Mamba-1 scan kernel (``ops/selective_scan``) at the
+   benchmark's Mamba cell's widths (E 5120, N 16, a state of ``[40, 16,
+   128]`` a slot), compiled, in both call shapes — the decode step over 128
+   slots, in place in a stack of two layers, and a 256-row prefill chunk of
+   one slot — against the ``jax.numpy`` recurrence a token at a time.
 
 Four chips: ``openwebtext`` on an fsdp=2 x tensor=2 mesh against a
 one-device mesh (same seed, data, global batch), and a tp=2 x 2-replica
@@ -591,6 +597,44 @@ def gated_delta_vs_numpy(seed: int) -> None:
           f"{KERNEL_TOL}")
     say(f"  {label}: output and state agree with the rule taken a token at "
         f"a time (max rel. error {err:.2e})")
+
+
+def selective_scan_vs_numpy(seed: int) -> None:
+    """Phase 9: both call shapes of the selective-scan kernel at the
+    published widths against the ``jax.numpy`` recurrence."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from midgpt_tpu.ops import selective_scan as ss
+
+    e, n = 5120, 16
+    g, _, lanes = ss.state_layout(e, n)
+    for name, s, t in (("ssm_step", 128, 1), ("ssm_scan", 1, 256)):
+        ks = jax.random.split(jax.random.PRNGKey(seed + 29 + t), 6)
+        dt = jax.nn.softplus(jax.random.normal(ks[0], (s, t, e)) - 3.0)
+        x = jax.random.normal(ks[1], (s, t, e))
+        b, c = (jax.random.normal(k, (s, t, n)) for k in ks[2:4])
+        a = ss.to_layout(-jnp.arange(1, n + 1, dtype=jnp.float32)
+                         * jnp.ones((e, 1)))
+        stack = jax.random.normal(ks[5], (2, s, g, n, lanes))
+        label = f"{name}[{s} slots x {t} rows, state {g} x {n} x {lanes}]"
+        run = jax.jit(lambda *v, name=name: ss.scan(*v, 1, name=name),
+                      donate_argnums=(5,))
+        check_compiled_kernels(label, run, dt, x, b, c, a, stack)
+        with jax.default_matmul_precision("highest"):
+            y_want, s_want = jax.jit(ss.scan_reference)(
+                dt.reshape(s, t, g, lanes), x.reshape(s, t, g, lanes), b, c,
+                a, stack[1])
+        other = np.asarray(stack[0])
+        y_got, got = run(dt, x, b, c, a, stack)
+        err = max(rel_err(y_got, y_want.reshape(s, t, e)),
+                  rel_err(got[1], s_want))
+        check(err <= 1e-5, f"{label}: rel. error vs the jax.numpy body {err}")
+        check(np.array_equal(np.asarray(got[0]), other),
+              f"{label}: the other layer's states moved")
+        say(f"  {label}: output and state agree with the jax.numpy body "
+            f"(max rel. error {err:.2e}), the other layer's rows untouched")
 
 
 def latent_attention_vs_numpy(seed: int) -> None:
@@ -1196,6 +1240,17 @@ def phase_sharded_serve(cfg, devices, seed: int):
 # ---------------------------------------------------------------------------
 
 
+# the phases of one chip that stand alone: a kernel or a forward of one
+# model's mechanism at its benchmark cell's widths against its reference,
+# by number (looked up by name when run, so that a test can stand one in)
+STANDALONE = {
+    6: ("block-diffusion forward", "block_forward_vs_gather"),
+    7: ("gated delta rule", "gated_delta_vs_numpy"),
+    8: ("latent attention", "latent_attention_vs_numpy"),
+    9: ("selective scan", "selective_scan_vs_numpy"),
+}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
@@ -1207,7 +1262,7 @@ def main() -> int:
     ap.add_argument(
         "--only", default="",
         help="one chip: after phase 1, only the phases numbered here "
-             "(2, 6, 7 and 8 stand alone; 4 needs 3, 5 needs 4), e.g. 2,6",
+             "(2, 6, 7, 8 and 9 stand alone; 4 needs 3, 5 needs 4), e.g. 2,6",
     )
     args = ap.parse_args()
     only = {int(n) for n in args.only.split(",") if n}
@@ -1235,15 +1290,10 @@ def main() -> int:
             if want(5):
                 with phase("5 serve"):
                     phase_serve(params, cfg, args.seed)
-            if want(6):
-                with phase("6 block-diffusion forward"):
-                    block_forward_vs_gather(args.seed)
-            if want(7):
-                with phase("7 gated delta rule"):
-                    gated_delta_vs_numpy(args.seed)
-            if want(8):
-                with phase("8 latent attention"):
-                    latent_attention_vs_numpy(args.seed)
+            for n, (name, fn) in STANDALONE.items():
+                if want(n):
+                    with phase(f"{n} {name}"):
+                        globals()[fn](args.seed)
         else:
             cfg = smoke_config(args.workdir, args.seed, SHARDED_SET)
             with phase("sharded training: fsdp=2 x tensor=2 vs one device"):

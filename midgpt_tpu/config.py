@@ -100,9 +100,10 @@ class ModelConfig:
     block_len: int = 0
     block_steps: int = 0
     mask_token: int = -1
-    # layers of two kinds in one model: per layer "full_attention" or
-    # "linear_attention" (None = every layer full attention). A linear layer
-    # is a gated-delta-rule mixer (models/gpt.GatedDeltaNet, ops/gated_delta):
+    # layers of two kinds in one model: per layer "full_attention" or a
+    # recurrent kind, "linear_attention" or "mamba" (None = every layer full
+    # attention; one recurrent kind a model). A linear layer is a
+    # gated-delta-rule mixer (models/gpt.GatedDeltaNet, ops/gated_delta):
     # ``linear_key_heads`` heads of ``linear_key_dim`` for q and k,
     # ``linear_value_heads`` of ``linear_value_dim`` for v and the state, a
     # causal depthwise convolution of ``linear_conv`` taps in front, beta in
@@ -143,12 +144,27 @@ class ModelConfig:
     expert_bias: bool = False
     expert_scale: float = 1.0
     shared_experts: int = 0
+    # a "mamba" layer is a Mamba-1 selective scan (models/gpt.Mamba,
+    # ops/selective_scan): ``mamba_expand * n_embd`` channels behind a causal
+    # depthwise convolution of ``mamba_d_conv`` taps (a bias where
+    # ``mamba_conv_bias``), a state of ``mamba_d_state`` a channel, the step
+    # size through a rank-``mamba_dt_rank`` bottleneck
+    mamba_d_state: int = 16
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
 
     def __post_init__(self):
         if self.layer_types is not None:
             kinds = tuple(self.layer_types)  # a JSON list: make it hashable
             assert len(kinds) == self.n_layer, (len(kinds), self.n_layer)
-            assert set(kinds) <= {"full_attention", "linear_attention"}, kinds
+            assert set(kinds) <= {
+                "full_attention", "linear_attention", "mamba"
+            }, kinds
+            assert {"linear_attention", "mamba"} - set(kinds), (
+                "one recurrent kind a model", kinds
+            )
             object.__setattr__(self, "layer_types", kinds)
         assert self.norm_order in ("pre", "post"), self.norm_order
         assert self.attention in ("heads", "latent"), self.attention
@@ -171,12 +187,13 @@ class ModelConfig:
     def layer_plan(self) -> tp.Tuple[tp.Tuple[str, int], ...]:
         """Per layer: its mixer's kind ("full" | "linear" | "latent") and its
         row of the cache of that kind (the page pool's layer axis counts the
-        full or latent layers, the recurrent state's the linear ones)."""
+        full or latent layers, the recurrent state's the recurrent ones:
+        "linear" is either recurrent mixer, ``recurrent_mixer`` says which)."""
         kinds = self.layer_types or ("full_attention",) * self.n_layer
         seen = {"full": 0, "linear": 0, "latent": 0}
         plan = []
         for k in kinds:
-            kind = "linear" if k == "linear_attention" else (
+            kind = "linear" if k in ("linear_attention", "mamba") else (
                 "latent" if self.latent else "full"
             )
             plan.append((kind, seen[kind]))
@@ -238,6 +255,39 @@ class ModelConfig:
         convolution runs over."""
         return (2 * self.linear_key_heads * self.linear_key_dim
                 + self.linear_value_heads * self.linear_value_dim)
+
+    @property
+    def recurrent_mixer(self) -> str:
+        """The recurrent layers' mixer: "gated_delta", "mamba", or "" for a
+        model without recurrent layers."""
+        kinds = set(self.layer_types or ())
+        if "mamba" in kinds:
+            return "mamba"
+        return "gated_delta" if "linear_attention" in kinds else ""
+
+    @property
+    def mamba_inner(self) -> int:
+        """A mamba layer's channels E."""
+        return self.mamba_expand * self.n_embd
+
+    @property
+    def recurrent_geometry(self) -> tp.Tuple[tp.Tuple[int, ...],
+                                             tp.Tuple[int, int]]:
+        """One recurrent layer's cache of one slot: the shape of its float32
+        state and of its convolution tail (the last ``taps - 1`` inputs).
+        Gated delta rule: a ``[dk, dv]`` state a value head. Mamba: the
+        ``[E, N]`` state laid as ``[E / L, N, L]``, E's lane tiles of ``L``
+        (128 where E is a multiple of it) each holding the N states of its
+        channels on sublanes, so that the tile has no padding."""
+        if self.recurrent_mixer == "mamba":
+            e, n = self.mamba_inner, self.mamba_d_state
+            lanes = 128 if e % 128 == 0 else e
+            return (e // lanes, n, lanes), (self.mamba_d_conv - 1, e)
+        return (
+            (self.linear_value_heads, self.linear_key_dim,
+             self.linear_value_dim),
+            (self.linear_conv - 1, self.linear_channels),
+        )
 
     @property
     def kv_heads(self) -> int:

@@ -988,6 +988,43 @@ class Attention:
         return self._merge_heads_out(out.reshape(b, h, t, c)), k, v
 
 
+def causal_conv(
+    window: Array, w: Array, bias: tp.Optional[Array] = None
+) -> Array:
+    """A causal depthwise convolution over time, float32, before its
+    activation: ``window`` ``[B, taps - 1 + T, Ch]`` is the tail (the last
+    ``taps - 1`` inputs) and then T new ones, ``w`` ``[taps, Ch]`` (its last
+    row meets the newest input), ``bias`` ``[Ch]``. Returns ``[B, T, Ch]``.
+    Both recurrent mixers run it."""
+    f32 = jnp.float32
+    taps = w.shape[0]
+    t = window.shape[1] - (taps - 1)
+    w = w.astype(f32)
+    y = sum(
+        w[j] * jax.lax.slice_in_dim(window, j, j + t, axis=1).astype(f32)
+        for j in range(taps)
+    )
+    return y if bias is None else y + bias.astype(f32)
+
+
+def advance_tails(
+    tails: Array, layer: int, window: Array, tail: Array, valid: Array
+) -> Array:
+    """A decode step's convolution tails: layer ``layer``'s row of ``tails``
+    ``[Ll, S, taps - 1, Ch]`` (``tail``, as the step read it) becomes the
+    newest ``taps - 1`` inputs of ``window`` ``[S, taps, Ch]`` (the tail,
+    then the step's input) where ``valid`` ``[S]``, and stays as it was
+    elsewhere. Both recurrent mixers run it."""
+    new_tail = jnp.where(
+        valid[:, None, None], window[:, 1:].astype(tails.dtype), tail
+    )
+    zero = jnp.zeros((), jnp.int32)
+    return jax.lax.dynamic_update_slice(
+        tails, new_tail[None],
+        (jnp.asarray(layer, jnp.int32), zero, zero, zero),
+    )
+
+
 @module
 class GatedDeltaNet:
     """The linear-attention mixer: a gated delta rule (ops/gated_delta) over
@@ -1071,17 +1108,11 @@ class GatedDeltaNet:
         Convolution and SiLU in float32, then per head q (L2-normalised,
         over sqrt(dk)), k (L2-normalised) ``[B, T, Hv, dk]`` and v
         ``[B, T, Hv, dv]`` in the inputs' type."""
-        f32 = jnp.float32
         hk, hv, dk = self.key_heads, self.value_heads, self.key_dim
         b, n, ch = window.shape
         t = n - (self.taps - 1)
         with jax.named_scope("gdn_conv"):
-            w = self.conv.astype(f32)
-            y = sum(
-                w[j] * jax.lax.slice_in_dim(window, j, j + t, axis=1).astype(f32)
-                for j in range(self.taps)
-            )
-            y = jax.nn.silu(y)
+            y = jax.nn.silu(causal_conv(window, self.conv))
             q = y[..., : hk * dk].reshape(b, t, hk, dk)
             k = y[..., hk * dk : 2 * hk * dk].reshape(b, t, hk, dk)
             v = y[..., 2 * hk * dk :].reshape(b, t, hv, -1)
@@ -1161,15 +1192,181 @@ class GatedDeltaNet:
         beta = jnp.where(valid[:, None], beta[:, 0], 0.0)
         g = jnp.where(valid[:, None], g[:, 0], 0.0)
         o, states = step(q[:, 0], k[:, 0], v[:, 0], g, beta, states, layer)
-        new_tail = jnp.where(
-            valid[:, None, None], window[:, 1:].astype(tails.dtype), tail
-        )
-        zero = jnp.zeros((), jnp.int32)
-        tails = jax.lax.dynamic_update_slice(
-            tails, new_tail[None],
-            (jnp.asarray(layer, jnp.int32), zero, zero, zero),
-        )
+        tails = advance_tails(tails, layer, window, tail, valid)
         return self._out(o[:, None], gate), states, tails
+
+
+@module
+class Mamba:
+    """The selective-scan mixer of a Mamba-1 layer (Jamba's): ``x | z =
+    u W_in``; ``x~ = silu(conv(x) + b_conv)``, a causal depthwise convolution
+    over E channels; ``dt | B | C = x~ W_x``, each through its own RMSNorm
+    (Jamba's inner norms); ``Delta = softplus(dt W_dt + b_dt)``; the scan
+    (ops/selective_scan) with ``A = -exp(A_log)`` from a zero state; ``y +
+    D x~``, gated by ``silu(z)``, through ``W_out``. What a layer carries
+    from token to token is a float32 state of ``[E, N]`` (laid as ``[E /
+    128, N, 128]``) and the convolution's last ``taps - 1`` inputs.
+
+    The three bodies and the state contract of :class:`GatedDeltaNet`:
+    :meth:`__call__` (a whole sequence from zeros), :meth:`prefill_at` (a
+    chunk of one slot that takes and returns state and tail; right-padding
+    advances neither) and :meth:`decode_at` (one token for S slots against
+    the engine's stacks). Projections run in the model's dtype; the
+    convolution, the inner norms, the step size and the scan in float32."""
+
+    w_in: Linear  # [D, 2E]: x | z
+    conv: Array  # [taps, E]; row taps-1 meets the newest token
+    conv_bias: tp.Optional[Array]  # [E]
+    w_x: Linear  # [E, R + 2N]: dt | B | C
+    dt_norm: RMSNorm  # [R]
+    b_norm: RMSNorm  # [N]
+    c_norm: RMSNorm  # [N]
+    w_dt: Linear  # [R, E]
+    dt_bias: Array  # [E]
+    a_log: Array  # [E, N]
+    d: Array  # [E]
+    w_out: Linear  # [E, D]
+
+    @staticmethod
+    def init(key: KeyArray, cfg: ModelConfig) -> "Mamba":
+        d, e, n = cfg.n_embd, cfg.mamba_inner, cfg.mamba_d_state
+        r, taps = cfg.mamba_dt_rank, cfg.mamba_d_conv
+        assert r >= 1 and n >= 1 and taps >= 1, (r, n, taps)
+        k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
+        # Mamba's conventions: A = -(1 .. N) on every channel, D = 1, the
+        # step size at init log-uniform in [0.001, 0.1]
+        step = jnp.exp(jax.random.uniform(
+            k6, (e,), jnp.float32, math.log(1e-3), math.log(1e-1)
+        ))
+        norm = lambda dim: RMSNorm.init(  # noqa: E731
+            dim, use_weight=True, eps=cfg.norm_eps or 1e-6, impl="jnp"
+        )
+        return Mamba(
+            w_in=Linear.init(k1, d, 2 * e),
+            conv=jax.random.normal(k2, (taps, e), jnp.float32)
+            / math.sqrt(taps),
+            conv_bias=(jnp.zeros((e,), jnp.float32)
+                       if cfg.mamba_conv_bias else None),
+            w_x=Linear.init(k3, e, r + 2 * n),
+            dt_norm=norm(r), b_norm=norm(n), c_norm=norm(n),
+            w_dt=Linear.init(k4, r, e),
+            dt_bias=step + jnp.log(-jnp.expm1(-step)),  # softplus^-1(step)
+            a_log=jnp.log(jnp.broadcast_to(
+                jnp.arange(1, n + 1, dtype=jnp.float32), (e, n)
+            )),
+            d=jnp.ones((e,), jnp.float32),
+            w_out=Linear.init(k5, e, d),
+        )
+
+    @property
+    def taps(self) -> int:
+        return self.conv.shape[0]
+
+    def _project(self, u: Array) -> tp.Tuple[Array, Array]:
+        """``u`` ``[B, T, D]`` -> the convolution's input x and the gate z,
+        ``[B, T, E]`` each, in ``u``'s type."""
+        with jax.named_scope("ssm_proj"):
+            xz = self.w_in(u)
+        e = xz.shape[-1] // 2
+        return xz[..., :e], xz[..., e:]
+
+    def _conv(self, window: Array) -> Array:
+        """``window`` ``[B, taps - 1 + T, E]`` -> x~ ``[B, T, E]`` float32."""
+        with jax.named_scope("ssm_conv"):
+            return jax.nn.silu(causal_conv(window, self.conv, self.conv_bias))
+
+    def _params(self, xc: Array, dtype) -> tp.Tuple[Array, Array, Array]:
+        """x~ -> the step size ``[B, T, E]`` and B, C ``[B, T, N]``, all
+        float32."""
+        f32 = jnp.float32
+        r = self.w_dt.weight.shape[0]
+        n = self.a_log.shape[1]
+        with jax.named_scope("ssm_xproj"):
+            p = self.w_x(xc.astype(dtype)).astype(f32)
+            dt = self.dt_norm(p[..., :r])
+            b = self.b_norm(p[..., r : r + n])
+            c = self.c_norm(p[..., r + n :])
+            dt = jax.nn.softplus(
+                self.w_dt(dt.astype(dtype)).astype(f32)
+                + self.dt_bias.astype(f32)
+            )
+        return dt, b, c
+
+    def _a(self) -> Array:
+        """A = -exp(A_log) in the state's layout."""
+        from midgpt_tpu.ops.selective_scan import to_layout
+
+        return to_layout(-jnp.exp(self.a_log.astype(jnp.float32)))
+
+    def _out(self, y: Array, xc: Array, z: Array) -> Array:
+        """The scan's ``y`` ``[B, T, E]`` float32 plus ``D x~``, gated, through
+        ``W_out``."""
+        with jax.named_scope("ssm_out"):
+            f32 = jnp.float32
+            y = (y + self.d.astype(f32) * xc) * jax.nn.silu(z.astype(f32))
+            return self.w_out(y.astype(z.dtype))
+
+    def prefill_at(
+        self,
+        x: Array,  # [B, T, D]
+        state: Array,  # [B, E / 128, N, 128] float32
+        tail: Array,  # [B, taps - 1, E]
+        real_n: tp.Optional[Array] = None,  # [] int32: rows past it are pad
+    ) -> tp.Tuple[Array, Array, Array]:
+        from midgpt_tpu.ops.selective_scan import scan
+
+        t = x.shape[1]
+        xs, z = self._project(x)
+        window = jnp.concatenate([tail.astype(xs.dtype), xs], axis=1)
+        xc = self._conv(window)
+        dt, b, c = self._params(xc, x.dtype)
+        keep = self.taps - 1
+        if real_n is None:
+            tail = window[:, t:]
+        else:
+            # padding advances nothing: a step of 0 leaves the state as it
+            # was, and the tail is the last inputs of the real rows
+            dt = jnp.where((jnp.arange(t) < real_n)[None, :, None], dt, 0.0)
+            tail = jax.lax.dynamic_slice_in_dim(window, real_n, keep, axis=1)
+        with jax.named_scope("ssm_scan"):
+            y, state = scan(dt, xc, b, c, self._a(), state[None], 0,
+                            name="ssm_scan")
+        return self._out(y, xc, z), state[0], tail.astype(x.dtype)
+
+    def __call__(self, x: Array) -> Array:
+        from midgpt_tpu.ops.selective_scan import state_layout
+
+        b = x.shape[0]
+        e, n = self.a_log.shape
+        out, _, _ = self.prefill_at(
+            x,
+            jnp.zeros((b,) + state_layout(e, n), jnp.float32),
+            jnp.zeros((b, self.taps - 1, e), x.dtype),
+        )
+        return out
+
+    def decode_at(
+        self,
+        x: Array,  # [S, 1, D] — one new token per decode slot
+        states: Array,  # [Ll, S, E / 128, N, 128] float32, every layer's
+        tails: Array,  # [Ll, S, taps - 1, E]
+        layer: int,  # STATIC: this layer's row of both
+        valid: Array,  # [S] bool — a slot whose token is none leaves its
+        # state and tail as they were
+    ) -> tp.Tuple[Array, Array, Array]:
+        from midgpt_tpu.ops.selective_scan import scan
+
+        xs, z = self._project(x)
+        tail = tails[layer]
+        window = jnp.concatenate([tail.astype(xs.dtype), xs], axis=1)
+        xc = self._conv(window)
+        dt, b, c = self._params(xc, x.dtype)
+        dt = jnp.where(valid[:, None, None], dt, 0.0)
+        with jax.named_scope("ssm_scan"):
+            y, states = scan(dt, xc, b, c, self._a(), states, layer,
+                             name="ssm_step")
+        tails = advance_tails(tails, layer, window, tail, valid)
+        return self._out(y, xc, z), states, tails
 
 
 # a prefill chunk's f32 score block ``[heads, T, W + T]`` is held for the
@@ -1934,13 +2131,14 @@ def mlp_call(mlp, x, *, key=None, deterministic=True, with_stats=False,
 @module
 class Block:
     """Pre-norm residual block (parity: model.py:84-105). ``attn`` is the
-    block's mixer: full attention, (``kind="linear"``) a gated delta rule,
-    or (``kind="latent"``) latent attention; ``mlp`` what ``cfg.mlp`` says,
+    block's mixer: full attention, (``kind="linear"``) the model's recurrent
+    mixer — a gated delta rule or a Mamba selective scan — or
+    (``kind="latent"``) latent attention; ``mlp`` what ``cfg.mlp`` says,
     or (``dense=True``: a leading dense layer) a SwiGLU of ``mlp_hidden``.
     ``norm_order="post"`` puts each norm on its sub-layer's OUTPUT, before
     the residual add."""
 
-    attn: tp.Union[Attention, GatedDeltaNet, LatentAttention]
+    attn: tp.Union[Attention, GatedDeltaNet, Mamba, LatentAttention]
     mlp: tp.Union[MLP, "MoEMLP", "ExpertMLP"]
     ln1: RMSNorm
     ln2: RMSNorm
@@ -1958,7 +2156,8 @@ class Block:
         import dataclasses
 
         k1, k2 = jax.random.split(key)
-        mixer = {"linear": GatedDeltaNet, "latent": LatentAttention}.get(
+        recurrent = Mamba if cfg.recurrent_mixer == "mamba" else GatedDeltaNet
+        mixer = {"linear": recurrent, "latent": LatentAttention}.get(
             kind, Attention
         )
         return Block(
@@ -1995,8 +2194,8 @@ class Block:
             jax.random.split(key) if key is not None else (None, None)
         )
         kv = None
-        if isinstance(self.attn, GatedDeltaNet):
-            assert not return_kv, "a linear-attention layer has no K/V"
+        if isinstance(self.attn, (GatedDeltaNet, Mamba)):
+            assert not return_kv, "a recurrent layer has no K/V"
             attn_out = self.attn(self._pre(self.ln1, x))
         else:
             attn_out = self.attn(
@@ -2078,15 +2277,16 @@ class Block:
         return self._mlp_residual(x, out, stacked=experts), rows
 
     def decode_state_at(self, x, states, tails, layer, valid):
-        """A linear-attention block's decode step (GatedDeltaNet.decode_at)."""
+        """A recurrent block's decode step (GatedDeltaNet.decode_at,
+        Mamba.decode_at)."""
         out, states, tails = self.attn.decode_at(
             self._pre(self.ln1, x), states, tails, layer, valid
         )
         return self._mlp_residual(x, out), states, tails
 
     def prefill_state_at(self, x, state, tail, real_n):
-        """A linear-attention block over one slot's prefill chunk
-        (GatedDeltaNet.prefill_at)."""
+        """A recurrent block over one slot's prefill chunk
+        (GatedDeltaNet.prefill_at, Mamba.prefill_at)."""
         out, state, tail = self.attn.prefill_at(
             self._pre(self.ln1, x), state, tail, real_n
         )
@@ -2197,8 +2397,8 @@ class GPT:
     ln_f: RMSNorm
     lm_head: tp.Optional[Linear]  # [D, V]; None when tie_embeddings
     config: ModelConfig = static()
-    # a model with ``config.layer_types``: its linear-attention layers, a
-    # stack of their own; ``config.layer_plan`` says which layer is which
+    # a model with ``config.layer_types``: its recurrent layers, a stack of
+    # their own; ``config.layer_plan`` says which layer is which
     lin_blocks: tp.Optional[Block] = None
     # the leading ``config.dense_layers`` layers, whose MLP is a dense SwiGLU
     # whatever the others' is: one stack a (mixer, MLP) kind present

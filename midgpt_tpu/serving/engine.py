@@ -1336,15 +1336,20 @@ _ENGINE_COUNTERS = (
     "expert_rows_max",
     "experts_touched",
     "expert_layer_forwards",
-    # a model with linear-attention layers (all zero otherwise): admissions
+    # a model with recurrent layers (all zero otherwise): admissions
     # (each starts its slot's recurrent state from zeros), tokens prefilled
     # again after an eviction because no snapshot of the state exists,
     # admissions whose first page the prefix cache had seen (and could not
-    # serve), and slot x linear layer x decode step
+    # serve), and slot x recurrent layer x decode step
     "state_resets",
     "state_reprefill_tokens",
     "prefix_hits_refused",
     "recurrent_slot_steps",
+    # a model with Mamba layers (zero otherwise): the prefill chunks' rows
+    # that were a prompt's, and the rows their programs ran (the bucket's:
+    # the scan walks its padding too)
+    "ssm_rows_scanned",
+    "ssm_rows_run",
 )
 
 
@@ -1589,13 +1594,14 @@ class ServingEngine:
         # equally valid — the programs accept either form through one
         # code path (GPT.project + the block projections).
         assert quant in (None, "int8"), f"unknown quant mode {quant!r}"
-        # layers of two kinds (cfg.layer_types): the linear-attention
-        # layers' cache is a fixed-size state a slot (serving.paged
-        # .RecurrentState), beside the page pool, which then holds the
-        # full-attention layers only. Whatever would need a snapshot of that
-        # state at some earlier position, or the state sharded, is refused
-        # here, by name (ROADMAP Reach)
+        # layers of two kinds (cfg.layer_types): the recurrent layers' cache
+        # (a gated delta rule's or a Mamba selective scan's) is a fixed-size
+        # state a slot (serving.paged.RecurrentState), beside the page pool,
+        # which then holds the full-attention layers only. Whatever would
+        # need a snapshot of that state at some earlier position, or the
+        # state sharded, is refused here, by name (ROADMAP Reach)
         self.hybrid = model.config.linear_layers > 0
+        self._ssm = model.config.recurrent_mixer == "mamba"
         if self.hybrid:
             unsupported = {
                 "temperature > 0": temperature > 0.0,
@@ -1612,7 +1618,7 @@ class ServingEngine:
             bad = [k for k, v in unsupported.items() if v]
             if bad:
                 raise ValueError(
-                    "a model with linear-attention layers does not support "
+                    "a model with recurrent layers does not support "
                     + ", ".join(bad)
                 )
         # latent attention (cfg.attention="latent"): the pool holds one
@@ -2279,7 +2285,7 @@ class ServingEngine:
         by the determinism contract)."""
         if self.hybrid:
             raise ValueError(
-                "a model with linear-attention layers does not support "
+                "a model with recurrent layers does not support "
                 "export_request: the pages would leave without the "
                 "recurrent state"
             )
@@ -2333,7 +2339,7 @@ class ServingEngine:
         the ordinary (also bit-identical) eviction path."""
         if self.hybrid:
             raise ValueError(
-                "a model with linear-attention layers does not support "
+                "a model with recurrent layers does not support "
                 "import_request: a record carries no recurrent state"
             )
         free = [s for s in range(self.slots) if self.slot_req[s] is None]
@@ -2949,6 +2955,9 @@ class ServingEngine:
         if self.hybrid:
             host += (np.bool_(self.fresh[s]),)
             self.fresh[s] = False
+        if self._ssm:
+            self.ssm_rows_scanned += clen
+            self.ssm_rows_run += bucket
         staged = jax.device_put(host)
         self.pool, self.logits, *st = self._chunk_fns[bucket](
             self.model, self.pool, self.logits, *staged[:5],
@@ -4005,8 +4014,8 @@ class ServingEngine:
             # and the block-table pages of the same steps
             "kv_pages_walked": self.kv_pages_walked,
             "kv_pages_table": self.kv_pages_table,
-            # linear-attention layers (RecurrentState; zero otherwise), and
-            # what the two kinds of cache hold right now
+            # recurrent layers (RecurrentState; zero otherwise), and what
+            # the two kinds of cache hold right now (the state as counted)
             "state_resets": self.state_resets,
             "state_reprefill_tokens": self.state_reprefill_tokens,
             "prefix_hits_refused": self.prefix_hits_refused,
@@ -4031,6 +4040,10 @@ class ServingEngine:
                 cfg.kv_layers * (cfg.latent_kv + cfg.latent_rope)
                 * self.pool.k.dtype.itemsize
             ) if self.latent else 0,
+            # a Mamba model's prefill chunks: rows that were a prompt's, and
+            # rows the scan ran
+            "ssm_rows_scanned": self.ssm_rows_scanned,
+            "ssm_rows_run": self.ssm_rows_run,
         }
 
     def _latent_tokens_live(self) -> int:

@@ -167,40 +167,42 @@ class PagedKVPool:
 
 @module
 class RecurrentState:
-    """The cache of a model's linear-attention layers (models.gpt
-    .GatedDeltaNet), beside the page pool in the same engine: per linear
-    layer and SLOT — owned by the slot, not paged, of a fixed size whatever
-    the context — a float32 state a head and the convolution's tail. A slot's
-    rows mean something from its request's first prefill chunk on (which
-    starts from zeros: the chunk program's ``fresh`` flag) and are garbage
-    otherwise. No snapshot of it exists at any position but the newest,
-    which is what the engine refuses for such a model: a prefix-cache hit,
-    a handoff, a rollback."""
+    """The cache of a model's recurrent layers, beside the page pool in the
+    same engine: per recurrent layer and SLOT — owned by the slot, not paged,
+    of a fixed size whatever the context — a float32 state and the
+    convolution's tail. Its geometry is ``ModelConfig.recurrent_geometry``'s:
+    a gated-delta-rule layer (models.gpt.GatedDeltaNet) holds ``[Hv, dk,
+    dv]`` a slot, a Mamba layer (models.gpt.Mamba) its ``[E, N]`` state as
+    ``[E / 128, N, 128]``. A slot's rows mean something from its request's
+    first prefill chunk on (which starts from zeros: the chunk program's
+    ``fresh`` flag) and are garbage otherwise. No snapshot of it exists at
+    any position but the newest, which is what the engine refuses for such a
+    model: a prefix-cache hit, a handoff, a rollback."""
 
-    s: Array  # [Ll, S, Hv, dk, dv] float32
-    conv: Array  # [Ll, S, taps - 1, 2 Hk dk + Hv dv] the cache dtype
+    s: Array  # [Ll, S, *state] float32 (recurrent_geometry[0])
+    conv: Array  # [Ll, S, taps - 1, channels] the cache dtype
 
     @staticmethod
     def init(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16):
-        ll, hv = cfg.linear_layers, cfg.linear_value_heads
+        ll = cfg.linear_layers
         assert ll >= 1 and slots >= 1, (ll, slots)
+        state, tail = cfg.recurrent_geometry
         return RecurrentState(
-            s=jnp.zeros(
-                (ll, slots, hv, cfg.linear_key_dim, cfg.linear_value_dim),
-                jnp.float32,
-            ),
-            conv=jnp.zeros(
-                (ll, slots, cfg.linear_conv - 1, cfg.linear_channels), dtype
-            ),
+            s=jnp.zeros((ll, slots) + state, jnp.float32),
+            conv=jnp.zeros((ll, slots) + tail, dtype),
         )
 
     @property
     def nbytes(self) -> int:
+        """Bytes as counted: the elements times their size. The chip's tiles
+        pad the tail's ``taps - 1`` rows to a sublane tile (8 or 16), and a
+        gated-delta state's ``dv`` of 192 to 256 lanes; a Mamba state has no
+        padding."""
         return int(self.s.nbytes + self.conv.nbytes)
 
     def of_slot(self, slot: Array, fresh: Array):
-        """Slot ``slot``'s rows ``([Ll, 1, Hv, dk, dv], [Ll, 1, taps - 1,
-        Ch])`` as a prefill chunk finds them: zeros where ``fresh`` (the
+        """Slot ``slot``'s rows ``([Ll, 1, *state], [Ll, 1, taps - 1,
+        channels])`` as a prefill chunk finds them: zeros where ``fresh`` (the
         request's first chunk — admission costs no dispatch of its own)."""
         s = jax.lax.dynamic_slice_in_dim(self.s, slot, 1, axis=1)
         conv = jax.lax.dynamic_slice_in_dim(self.conv, slot, 1, axis=1)

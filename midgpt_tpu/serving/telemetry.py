@@ -173,6 +173,8 @@ ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "kv_pages_distinct",
     "latent_layers",
     "latent_bytes_live",
+    "ssm_rows_scanned",
+    "ssm_rows_run",
 )
 
 #: ``ServingCluster.stats()`` = the summed engine inventory plus these

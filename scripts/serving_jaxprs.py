@@ -3,8 +3,8 @@
     JAX_PLATFORMS=cpu python scripts/serving_jaxprs.py <checkout> <out_dir>
 
 ``str(jax.make_jaxpr(...))`` of every program of ``serve-xl-decode``,
-``serve-sdar-block4``, ``serve-olmo-hybrid-decode`` and
-``serve-joyai-flash-docs`` (each prefill-chunk bucket, the decode or the block
+``serve-sdar-block4``, ``serve-olmo-hybrid-decode``,
+``serve-joyai-flash-docs`` and ``serve-jamba-chat`` (each prefill-chunk bucket, the decode or the block
 window; a cell whose configuration the checkout's ``ModelConfig`` cannot hold
 is passed over) and of the verify program at ``speculate=4`` on ``midgpt-xl``,
 traced from ``<checkout>`` through the engine's own ``make_*`` factories
@@ -50,7 +50,8 @@ def emit(name, fn, *args):
 
 
 for cell in ("serve-xl-decode", "serve-sdar-block4",
-             "serve-olmo-hybrid-decode", "serve-joyai-flash-docs"):
+             "serve-olmo-hybrid-decode", "serve-joyai-flash-docs",
+             "serve-jamba-chat"):
     if not os.path.exists(f"benchmark/workloads/{cell}.json"):
         continue
     spec = json.load(open(f"benchmark/workloads/{cell}.json"))

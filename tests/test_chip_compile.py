@@ -619,6 +619,159 @@ def test_hybrid_chunk_compiles_without_a_cache_copy(one_chip, monkeypatch):
     assert not cache_copies(text), cache_copies(text)
 
 
+@pytest.mark.parametrize("name,slots,rows", [
+    ("ssm_step", 128, 1),    # the decode step: 128 slots, one row each
+    ("ssm_scan", 1, 256),    # a prefill chunk: one slot, 256 rows
+])
+def test_ssm_kernel_compiles_in_place(one_chip, name, slots, rows):
+    """The selective scan's one Pallas body in both call shapes at the
+    benchmark cell's widths (E 5120, N 16: a state of [40, 16, 128] a slot,
+    a stack of 26 layers): Mosaic takes it, its events carry the names the
+    benchmark's reduction finds them by (benchmark/metrics/
+    ssm_step_roofline.serve, ssm_scan_share.serve) and not the paged
+    kernel's ``%closed_call``, and the stack is aliased input to output,
+    nothing of a layer's size copied."""
+    import re
+
+    from midgpt_tpu.ops import selective_scan as ss
+
+    g, n, lanes = ss.state_layout(5120, 16)
+    ll = 26 if rows == 1 else 1
+    f32 = jnp.float32
+
+    def fn(dt, x, b, c, a, stack):
+        return ss._CALLS[name](dt, x, b, c, a, stack, layer=ll - 1,
+                               interpret=False)
+
+    args = [
+        jax.ShapeDtypeStruct(shape, f32, sharding=one_chip)
+        for shape in (
+            (slots, rows, g, lanes), (slots, rows, g, lanes),
+            (slots, rows, n, lanes), (slots, rows, n, lanes), (g, n, lanes),
+            (ll, slots, g, n, lanes),
+        )
+    ]
+    compiled = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
+    assert not re.search(r"%closed_call\.\d+ = ", text)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= ll * slots * g * n * lanes * 4
+    assert stats.temp_size_in_bytes < g * n * lanes * 4
+    assert ss.kernel_shapes_ok(g, n, lanes)
+
+
+def _jamba_cell(one_chip, monkeypatch):
+    """Two Mamba layers and one attention layer at the benchmark cell's
+    widths (D 2560, E 5120, N 16, 20 query heads over one KV head of 128,
+    tied V 65536; 128 slots of 112 pages), as described arrays: the
+    program's arguments ``(model, pool, logits, state)``, ``arr``, and
+    ``cache_copies(text)``, the compiled program's copies of the pool's or
+    the state's shape."""
+    import re
+
+    from midgpt_tpu.config import ModelConfig
+    from midgpt_tpu.models import GPT
+    from midgpt_tpu.ops import selective_scan as ss
+    from midgpt_tpu.serving.paged import PagedKVPool, RecurrentState
+
+    monkeypatch.setattr(ss, "is_tpu_backend", lambda: True)
+    slots, pmax, pages = 128, 112, 14336
+    cfg = ModelConfig(
+        block_size=pmax * PS, vocab_size=65536, n_layer=3, n_head=20,
+        n_kv_head=1, head_width=128, n_embd=2560, mlp="swiglu",
+        mlp_hidden=8192, qk_norm=False, rope_style="none", norm_scale=True,
+        norm_eps=1e-6, tie_embeddings=True,
+        layer_types=("mamba", "full_attention", "mamba"), mamba_d_state=16,
+        mamba_expand=2, mamba_dt_rank=160, mamba_d_conv=4,
+    )
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree.map(lambda a: arr(a.shape, a.dtype), tree)
+
+    model = described(jax.eval_shape(
+        lambda k: jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16), GPT.init(k, cfg)
+        ), jax.random.PRNGKey(0),
+    ))
+    pool = described(jax.eval_shape(
+        lambda: PagedKVPool.init(cfg, pages, PS, jnp.bfloat16)
+    ))
+    state = described(jax.eval_shape(
+        lambda: RecurrentState.init(cfg, slots, jnp.bfloat16)
+    ))
+    logits = arr((slots, cfg.vocab_size), jnp.float32)
+
+    def cache_copies(text):
+        shapes = (f"bf16[1,{pages},{PS},128]", "f32[2,128,40,16,128]",
+                  "bf16[2,128,3,5120]")
+        return [
+            line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= \S+ copy\(", line)
+            and line.split("= ", 1)[1].startswith(shapes)
+        ]
+
+    return cfg, (model, pool, logits, state), arr, cache_copies
+
+
+def test_jamba_window_compiles_without_a_cache_copy(one_chip, monkeypatch):
+    """The decode window of :func:`_jamba_cell`: one ``%ssm_step`` call a
+    Mamba layer and one paged-attention call (20 query heads over one KV
+    head) in the window's step, and neither the page pool nor the recurrent
+    state — both donated, both carried through the window's scan — is
+    copied."""
+    import re
+
+    from midgpt_tpu.ops.paged_attn import supported
+    from midgpt_tpu.serving.engine import make_decode_window
+
+    cfg, (model, pool, logits, state), arr, cache_copies = _jamba_cell(
+        one_chip, monkeypatch
+    )
+    slots, pmax = logits.shape[0], cfg.block_size // PS
+    assert supported(pmax, PS, 128, 2, groups=20, heads=1)
+    i32 = lambda *shape: arr(shape, jnp.int32)  # noqa: E731
+    window = make_decode_window(
+        model, slots=slots, window=8, pmax=pmax, rope_len=cfg.block_size,
+        paged_kernel="pallas",
+    )
+    text = window.lower(
+        model, pool, logits, i32(slots, pmax), i32(slots),
+        arr((slots,), jnp.bool_), i32(slots), i32(slots), i32(slots),
+        i32(slots), arr((2,), jnp.uint32), state,
+    ).compile().as_text()
+    assert len(re.findall(r"%ssm_step(?:\.\d+)? = .*? custom-call\(", text)) == 2
+    assert len(re.findall(r"%closed_call\.\d+ = \S+ custom-call\(", text)) == 1
+    assert not cache_copies(text), cache_copies(text)
+
+
+def test_jamba_chunk_compiles_without_a_cache_copy(one_chip, monkeypatch):
+    """A 256-token prefill chunk of :func:`_jamba_cell`: one ``%ssm_scan``
+    call a Mamba layer (a slot's state taken, advanced and put back), no
+    copy of the pool or of the state."""
+    import re
+
+    from midgpt_tpu.serving.engine import make_prefill_chunk_program
+
+    cfg, (model, pool, logits, state), arr, cache_copies = _jamba_cell(
+        one_chip, monkeypatch
+    )
+    pmax = cfg.block_size // PS
+    i32 = lambda *shape: arr(shape, jnp.int32)  # noqa: E731
+    chunk = make_prefill_chunk_program(
+        model, chunk_len=256, pmax=pmax, rope_len=cfg.block_size,
+    )
+    text = chunk.lower(
+        model, pool, logits, i32(), i32(1, 256), i32(), i32(), i32(pmax),
+        state, arr((), jnp.bool_),
+    ).compile().as_text()
+    assert len(re.findall(r"%ssm_scan(?:\.\d+)? = .*? custom-call\(", text)) == 2
+    assert not cache_copies(text), cache_copies(text)
+
+
 def test_flash_attention_compiles_fwd_bwd(one_chip):
     from midgpt_tpu.ops.flash import flash_attention
 

@@ -49,6 +49,36 @@ def model(weights):
     return fill_model(weights, CFG)
 
 
+# a model of the other recurrent mixer, Mamba layers beside attention
+# (benchmark/weights_ssm.py, published names), for the refusals both share
+SSM_SIZES = dict(
+    hidden_size=32, intermediate_size=64, num_attention_heads=4,
+    num_key_value_heads=1, vocab_size=512, num_hidden_layers=3,
+    attn_layer_offset=1, attn_layer_period=3, mamba_d_state=16,
+    mamba_expand=2, mamba_dt_rank=8, mamba_d_conv=4, rms_norm_eps=1e-6,
+    n_layer=3, layer_types=("mamba", "full_attention", "mamba"), n_head=4,
+    n_kv_head=1, head_width=8, n_embd=32, block_size=128, mlp="swiglu",
+    mlp_hidden=64, qk_norm=False, rope_style="none", norm_scale=True,
+    norm_eps=1e-6, tie_embeddings=True, attn_impl="naive", remat="none",
+)
+
+
+@pytest.fixture(scope="module")
+def ssm_model():
+    from benchmark import program, weights_ssm
+    from benchmark.kinds import serve_ssm
+
+    w = weights_ssm.make(jax.random.PRNGKey(5), SSM_SIZES, jnp.float32)
+    return serve_ssm.fill_model(w, program.model_config(SSM_SIZES))
+
+
+@pytest.fixture(scope="module", params=["gated_delta", "mamba"])
+def recurrent(request, model, ssm_model):
+    """Either recurrent mixer's model: the refusals are the engine's, for
+    whatever holds a state no snapshot of exists."""
+    return model if request.param == "gated_delta" else ssm_model
+
+
 @pytest.fixture(scope="module")
 def prompts():
     rng = np.random.default_rng(0)
@@ -195,8 +225,8 @@ def test_evicted_request_prefills_again_and_continues(model, prompts, served):
     assert got == want
 
 
-def test_prefix_cache_is_accepted_and_does_nothing(model, prompts, served):
-    eng = engine(model, prefix_cache=True)
+def test_prefix_cache_is_accepted_and_does_nothing(recurrent, prompts):
+    eng = engine(recurrent, prefix_cache=True)
     assert eng.index is None
     again = [prompts[0], prompts[0], prompts[2]]
     rids = [eng.submit(p, NEW) for p in again]
@@ -204,8 +234,9 @@ def test_prefix_cache_is_accepted_and_does_nothing(model, prompts, served):
     st = eng.stats()
     assert st["prefix_hits_refused"] == 1 and st["prefill_tokens_saved"] == 0
     assert st["prefill_tokens_computed"] == sum(len(p) for p in again)
+    want, _ = serve(recurrent, [prompts[0], prompts[2]])
     assert [list(eng.finished[r].tokens) for r in rids] == [
-        served[0][0], served[0][0], served[0][2]]
+        want[0], want[0], want[1]]
 
 
 def test_counters_of_the_recurrent_state(served):
@@ -231,20 +262,20 @@ def test_counters_of_the_recurrent_state(served):
     ("layer_scan='on'", {"layer_scan": "on"}),
     ("temperature > 0", {"temperature": 0.7}),
 ])
-def test_what_needs_a_snapshot_is_refused_by_name(model, name, kw):
-    with pytest.raises(ValueError, match="linear-attention layers does not "
+def test_what_needs_a_snapshot_is_refused_by_name(recurrent, name, kw):
+    with pytest.raises(ValueError, match="recurrent layers does not "
                        "support .*" + name.replace("(", r"\(")):
-        engine(model, **kw)
+        engine(recurrent, **kw)
 
 
-def test_a_mesh_and_the_handoff_are_refused_by_name(model, prompts):
+def test_a_mesh_and_the_handoff_are_refused_by_name(recurrent, prompts):
     from midgpt_tpu.config import MeshConfig
     from midgpt_tpu.parallel.mesh import create_mesh
 
     mesh = create_mesh(MeshConfig(replica=1, fsdp=4, sequence=1, tensor=2))
     with pytest.raises(ValueError, match="does not support a mesh"):
-        engine(model, mesh=mesh)
-    eng = engine(model)
+        engine(recurrent, mesh=mesh)
+    eng = engine(recurrent)
     eng.submit(prompts[1], 4)
     eng.step()
     with pytest.raises(ValueError, match="export_request"):

@@ -165,18 +165,21 @@ def test_engine_stats_key_contract(model):
     # scheduler's own lengths — pages the occupied slots hold, against the
     # pages their block tables could; behind it the recurrent state's keys
     # (PR 32), zero for a model without linear-attention layers
-    assert ENGINE_STATS_KEYS[-11:-9] == ("kv_pages_walked", "kv_pages_table")
-    assert ENGINE_STATS_KEYS[-9:-3] == (
+    assert ENGINE_STATS_KEYS[-13:-11] == ("kv_pages_walked", "kv_pages_table")
+    assert ENGINE_STATS_KEYS[-11:-5] == (
         "state_resets", "state_reprefill_tokens", "prefix_hits_refused",
         "recurrent_slot_steps", "recurrent_state_bytes", "kv_bytes_live",
     )
-    assert not any(st[k] for k in ENGINE_STATS_KEYS[-9:-4])
+    assert not any(st[k] for k in ENGINE_STATS_KEYS[-11:-6])
     assert 0 < st["kv_pages_walked"] <= st["kv_pages_table"]
     # behind them the walk's pages counted once each, and a latent cache's
     # keys (PR 34), zero for a model without latent attention
-    assert ENGINE_STATS_KEYS[-3:] == (
+    assert ENGINE_STATS_KEYS[-5:-2] == (
         "kv_pages_distinct", "latent_layers", "latent_bytes_live",
     )
+    # last, the prefill scan's rows of a Mamba model (PR 42): zero here
+    assert ENGINE_STATS_KEYS[-2:] == ("ssm_rows_scanned", "ssm_rows_run")
+    assert not st["ssm_rows_scanned"] and not st["ssm_rows_run"]
     assert 0 < st["kv_pages_distinct"] <= st["kv_pages_walked"]
     assert not st["latent_layers"] and not st["latent_bytes_live"]
     assert st["kv_pages_table"] == (
