@@ -1279,6 +1279,18 @@ class HandoffRecord:
         return int(n)
 
 
+class _Flight(tp.NamedTuple):
+    """A decode window enqueued and not yet read: its dispatch span, the
+    slots it carries, its device outputs (tokens, emit mask, ``done``,
+    lengths, ``emitted``, a latent model's counters), and whether it went
+    in before the window in front of it was read."""
+
+    disp: span
+    decoding: tp.List[int]
+    out: tp.List[tp.Any]
+    ahead: bool
+
+
 # Registry-backed counter attributes of ServingEngine: every name here
 # becomes a class-level property reading/writing the engine's
 # MetricsRegistry Counter of the same name (attached right after the
@@ -1350,6 +1362,10 @@ _ENGINE_COUNTERS = (
     # the scan walks its padding too)
     "ssm_rows_scanned",
     "ssm_rows_run",
+    # decode windows enqueued before the window in front of them was read
+    # (ServingEngine._ahead): the engaged share is windows_ahead /
+    # decode_dispatches
+    "windows_ahead",
 )
 
 
@@ -1387,6 +1403,15 @@ class ServingEngine:
     ``device_reads``: over any span of steps it equals
     ``decode_dispatches``). Pool, logits and a recurrent state never
     leave the device.
+
+    A decode window goes in one window ahead of its read wherever no slot
+    can end in the window in front of it (no EOS id, more than ``window``
+    tokens of budget left), every slot decodes, nobody waits for a slot
+    and the pages are there (:meth:`_ahead`): it takes that window's
+    lengths, ``done`` and ``emitted`` as they are on the device, and the
+    host's read, bookkeeping, caller and scheduling then run while it
+    computes. Its tokens are those of the plain order; ``windows_ahead``
+    counts such windows.
 
     Prefix cache (``prefix_cache=True``): full pages are registered in a
     host-side content index as they fill; admission points the block
@@ -1438,6 +1463,10 @@ class ServingEngine:
     failover seam, and every degraded path preserves the bit-identical
     stream contract above.
     """
+
+    # False forces the plain order, every window read before the next is
+    # dispatched (tests compare the two)
+    _dispatch_ahead = True
 
     def __init__(
         self,
@@ -1901,6 +1930,14 @@ class ServingEngine:
         self._key = jax.random.PRNGKey(seed)
         self._sentinel = num_pages
         self._mesh = mesh
+        # where a decode window's host arrays go: on a mesh, replicated
+        # over it as the window's own state outputs are, so that a window
+        # taking those outputs (_ahead) runs the program already compiled
+        self._window_put = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            self._window_put = NamedSharding(mesh, P())
         self._max_prefills = (
             max_prefills_per_window
             if max_prefills_per_window is not None
@@ -1955,6 +1992,10 @@ class ServingEngine:
         # round-robin cursor over prefilling slots (persists across
         # windows so a one-chunk budget still alternates slots)
         self._prefill_rr = 0
+        # the decode window enqueued and not yet read (_run_window): one
+        # that a step dispatched behind the window it then read waits here
+        # for the next step's harvest
+        self._flight: tp.Optional[_Flight] = None
 
         self.queue: tp.Deque[Request] = collections.deque()
         # overload parking lot: requests evicted by the livelock guard or
@@ -2246,7 +2287,10 @@ class ServingEngine:
         boundaries, before any dispatch mutates state — a survivor
         resuming a drained request continues the stream bit-identically
         (the eviction/re-admission contract, plus placement invariance
-        across replicas)."""
+        across replicas). A window in flight is read first: its tokens are
+        the ones the stream would carry next, and a request it finished
+        leaves as finished."""
+        self._settle()
         out: tp.List[Request] = []
         for s in self._active_slots():
             req = self.slot_req[s]
@@ -2283,6 +2327,7 @@ class ServingEngine:
         still intact here and the cluster can abandon this copy and
         re-serve cold from its submission record (streams bit-identical
         by the determinism contract)."""
+        self._settle()
         if self.hybrid:
             raise ValueError(
                 "a model with recurrent layers does not support "
@@ -2337,6 +2382,7 @@ class ServingEngine:
         seed), so the stream is bit-identical to the monolithic engine —
         and a later eviction under pressure re-prefills locally through
         the ordinary (also bit-identical) eviction path."""
+        self._settle()
         if self.hybrid:
             raise ValueError(
                 "a model with recurrent layers does not support "
@@ -2426,7 +2472,9 @@ class ServingEngine:
         the per-slot write watermark already guarantees no stale draft
         K/V ever landed in the pages, and COW refcounts unwind through
         ``_release_slot``'s pins — the allocator/index invariants hold
-        after every cancel (property-tested by the front-door suite).
+        after every cancel (property-tested by the front-door suite). A
+        window in flight is read first, so the request leaves with its
+        tokens, or as finished where that window ended it.
 
         Returns True when ``rid`` was live; False for unknown or
         already-terminal ids (idempotent — a double cancel is a no-op).
@@ -2444,6 +2492,7 @@ class ServingEngine:
                 self.parked.pop(i)
                 self._cancelled(req, where="parked")
                 return True
+        self._settle()
         for s in self._active_slots():
             req = self.slot_req[s]
             if req.rid == rid:
@@ -3282,11 +3331,16 @@ class ServingEngine:
                 self._evict(min(others, key=lambda v: len(self.slot_req[v].tokens)))
             if parked_self:
                 continue
-            if need > 0:
-                pages = self.alloc.alloc(need)
-                start = len(self.slot_pages[s])
-                self.slot_pages[s].extend(pages)
-                self.bt[s, start : start + need] = pages
+            self._add_pages(s, need)
+
+    def _add_pages(self, s: int, need: int) -> None:
+        """Append ``need`` newly allocated pages (reserved already) to
+        slot ``s``'s pages and block table."""
+        if need > 0:
+            pages = self.alloc.alloc(need)
+            start = len(self.slot_pages[s])
+            self.slot_pages[s].extend(pages)
+            self.bt[s, start : start + need] = pages
 
     # -- speculative drafting -----------------------------------------------
 
@@ -3423,26 +3477,108 @@ class ServingEngine:
         self._harvest_apply(hw, decoding, harvested)
 
     def _run_window(self, decoding: tp.List[int]) -> None:
-        """One K-step decode window dispatch + harvest. In: the seven
-        host arrays the scheduler keeps (block tables, lengths, ``done``,
-        ``emitted``, budgets, EOS ids, seeds) in ONE ``jax.device_put``;
-        pool, logits, key and a recurrent state are the device's already.
-        Out: ONE :meth:`_read_window` of the stacked ``[K, S]`` tokens and
-        emit mask, the three state arrays, and a latent model's expert
-        counters; pool, logits and recurrent state stay on the device."""
+        """One K-step decode window harvested: the window already in
+        flight, or else one dispatched now for ``decoding``. Before the
+        read, the next window goes in behind it wherever :meth:`_ahead`
+        finds that the read can change nothing of it; that one stays in
+        flight for the next step's harvest, so the host's read,
+        bookkeeping, caller and scheduling run while the device computes
+        it."""
+        flight = self._flight or self._dispatch_window(decoding)
+        self._flight = None
+        with span("midgpt.engine.grow"):
+            ahead = self._ahead(flight.decoding)
+        if ahead:
+            self._flight = self._dispatch_window(flight.decoding, flight.out)
+        self._harvest_window(flight)
+
+    def _settle(self) -> None:
+        """Harvest the window in flight, if one is: what changes a slot's
+        state between steps (cancel, drain, a handoff, a request that
+        waits, a fault hook, a profiler session's edge) finds every window
+        read."""
+        if self._flight is not None:
+            flight, self._flight = self._flight, None
+            self._harvest_window(flight)
+
+    def _ahead(self, decoding: tp.List[int]) -> bool:
+        """Whether the next window may be enqueued behind the window in
+        flight (which carries ``decoding``, and found the host state as it
+        is now) before that one is read, its pages allocated if so. It may
+        where the read can change nothing of it: no slot can end in the
+        window in flight (more than ``window`` tokens of budget left, a
+        slot that is not done emits one a step, and no EOS id), every slot
+        decodes (none empty, prefilling or waiting for a handoff: a request
+        sent before the next step could join no window sooner), nobody
+        waits for a slot, and the pool gives every slot its pages for the
+        next window without an eviction. Then each slot advances exactly
+        ``window`` positions, and the next window takes the lengths,
+        ``done`` and ``emitted`` of the one in flight as they are on the
+        device. An engine with a fault hook keeps the plain order: a
+        scripted fault lands where it always has."""
+        if (
+            not self._dispatch_ahead or self._fault_hook is not None
+            or self.queue or self.parked
+        ):
+            return False
+        if len(decoding) != self.slots:
+            return False
+        left = self.budget[decoding] - self.emitted[decoding]
+        if np.any(left <= self.window) or np.any(self.eos[decoding] >= 0):
+            return False
+        need = {
+            s: min(
+                pages_needed(
+                    int(self.pooled_len[s]) + self.window
+                    + min(self.window, int(left[i]) - self.window),
+                    self.page_size,
+                ),
+                self.pmax,
+            ) - len(self.slot_pages[s])
+            for i, s in enumerate(decoding)
+        }
+        total = sum(n for n in need.values() if n > 0)
+        # cold prefix pages give way as they do to _ensure_growth; spilling
+        # one would read the pool, which the window in flight still holds
+        if total and not (
+            self.alloc.can_alloc(total)
+            or (self._spill_store is None and self._try_reserve(total))
+        ):
+            return False
+        for s, n in need.items():
+            self._add_pages(s, n)
+        return True
+
+    def _dispatch_window(
+        self, decoding: tp.List[int], behind: tp.Optional[tp.List] = None
+    ) -> _Flight:
+        """Enqueue one K-step decode window. In: the seven host arrays the
+        scheduler keeps (block tables, lengths, ``done``, ``emitted``,
+        budgets, EOS ids, seeds) in ONE ``jax.device_put``; behind a
+        window not yet read (``behind``, its device outputs) lengths,
+        ``done`` and ``emitted`` are that window's outputs and the other
+        four go in the put. Pool, logits, key and a recurrent state are
+        the device's already. The block tables go as a copy: the host
+        grows them for the next window while this one may still run, and
+        a put may alias the host array (the CPU backend's does)."""
         with span(
             "midgpt.engine.decode_dispatch", self.telemetry,
             clock=self.clock,
         ) as disp:
+            bt = self.bt.copy()
+            if behind is None:
+                bt, lens, done, emitted, budget, eos, seeds = jax.device_put(
+                    (bt, self.pooled_len, self.done, self.emitted,
+                     self.budget, self.eos, self.seeds), self._window_put,
+                )
+            else:
+                done, lens, emitted = behind[2:5]
+                bt, budget, eos, seeds = jax.device_put(
+                    (bt, self.budget, self.eos, self.seeds), self._window_put
+                )
             self.pool, self.logits, *out = self._window_fn(
-                self.model,
-                self.pool,
-                self.logits,
-                *jax.device_put((
-                    self.bt, self.pooled_len, self.done, self.emitted,
-                    self.budget, self.eos, self.seeds,
-                )),
-                self._key,
+                self.model, self.pool, self.logits, bt, lens, done, emitted,
+                budget, eos, seeds, self._key,
                 *((self.state,) if self.hybrid else ()),
             )
             if self.hybrid:
@@ -3451,9 +3587,18 @@ class ServingEngine:
                     self.window * len(decoding)
                     * self.model.config.linear_layers
                 )
+        return _Flight(disp, decoding, out, behind is not None)
 
+    def _harvest_window(self, flight: _Flight) -> None:
+        """ONE :meth:`_read_window` of a decode window's stacked ``[K, S]``
+        tokens and emit mask, the three state arrays and a latent model's
+        expert counters, then its slots' bookkeeping; pool, logits and
+        recurrent state stay on the device."""
+        disp, decoding, out, ahead = flight
+        self.windows_ahead += ahead
         with self._harvest_span(
-            disp, "decode_window", decoding, window=self.window
+            disp, "decode_window", decoding, window=self.window,
+            windows_ahead=int(ahead),
         ) as hw:
             toks_h, emit_h, done_h, len_h, emitted_h, *counters = (
                 self._read_window(out)
@@ -3686,7 +3831,8 @@ class ServingEngine:
 
     @property
     def has_work(self) -> bool:
-        """Queued, parked, or in-flight requests remain."""
+        """Queued, parked, or in-flight requests remain (a window in
+        flight keeps its requests in their slots until it is read)."""
         return bool(self.queue or self.parked or self._active_slots())
 
     def step(self) -> bool:
@@ -3694,7 +3840,13 @@ class ServingEngine:
 
         May raise a scripted :mod:`~midgpt_tpu.serving.faults` fault when
         a ``fault_hook`` is installed — always BEFORE any dispatch, so
-        the engine's request state stays consistent and drainable."""
+        the engine's request state stays consistent and drainable.
+
+        A window in flight (dispatched ahead by the step before) is the
+        one this step's :meth:`_run_window` harvests: it carries every
+        slot, none prefilling, and its pages are there, so scheduling,
+        prefill and growth find nothing to do. Where a request waits for a
+        slot, or a fault hook is installed, that window is read first."""
         self.fault_step += 1
         if self.telemetry is not None:
             # optional jax.profiler window (telemetry.profile_steps):
@@ -3707,6 +3859,8 @@ class ServingEngine:
             # ``self.telemetry``
             self._session_edge()
         with span("midgpt.engine.step", step=self.fault_step):
+            if self.queue or self.parked or self._fault_hook is not None:
+                self._settle()
             with span("midgpt.engine.schedule"):
                 if self._fault_hook is not None:
                     self._fault_hook(self)
@@ -3764,7 +3918,9 @@ class ServingEngine:
         own stamps (true ``t``, marked ``backfill``). Either way the log
         joins ``midgpt_tpu.telemetry.session_logs()``, which keeps it when
         the engine is gone, and notes this step. Closed: the log notes the
-        step, and one that was the session's is detached."""
+        step, and one that was the session's is detached. A window in
+        flight is harvested first, into the log that saw it dispatched."""
+        self._settle()
         if not self._in_session:
             if self.telemetry is None:
                 self.telemetry = EngineTelemetry(ring=SESSION_RING)
@@ -3943,6 +4099,9 @@ class ServingEngine:
             # device->host reads of the step loop (_read_window): one a
             # decode dispatch, none a prefill chunk
             "device_reads": self.device_reads,
+            # decode windows enqueued before the one in front of them was
+            # read (_ahead): the engaged share is this / decode_dispatches
+            "windows_ahead": self.windows_ahead,
             "prefill_dispatches": self.prefill_dispatches,
             # the budget's granules of prefill_chunk tokens the dispatches
             # ran: granules a dispatch = prefill_chunks / prefill_dispatches

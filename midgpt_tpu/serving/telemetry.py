@@ -115,6 +115,7 @@ ENGINE_STATS_KEYS: tp.Tuple[str, ...] = (
     "tp",
     "decode_dispatches",
     "device_reads",
+    "windows_ahead",
     "prefill_dispatches",
     "prefill_chunks",
     "prefill_steps",

@@ -75,6 +75,9 @@ def test_two_requests_side_by_side_are_the_reference(weights, model,
     rows in one softmax). The reference without its inner norms misses by
     far more."""
     eng = engine(model, window=1)
+    # the plain order: a window dispatched ahead would move the rows before
+    # the step that reads them returns
+    eng._dispatch_ahead = False
     rids = [eng.submit(p, NEW) for p in prompts[:2]]
     rows = {r: {} for r in rids}
     while eng.has_work:
