@@ -93,6 +93,20 @@ narrowest divisor of at least ``MIN_BAND_ROWS`` rows and at most
 ``MAX_BANDS`` bands), and ``vmem_bytes`` prices the resident table. Events
 under ``%mla_decode.N``.
 
+The latent walk runs ONE SLOT AHEAD. With the table resident, band ``b``'s
+place is free as soon as pass 2 has read its V, so the next slot's live
+pages of band ``b`` are started into it right there (for the first
+``LATENT_AHEAD`` share of the bands; the next slot's pass 1 starts the
+others, as many bands in front of the band it computes): the next slot's stream runs
+behind this slot's pass 2 and no longer waits for it, in no extra VMEM.
+A band's copies count on a DMA semaphore of its own, and the slot axis of
+the grid is sequential (a grid step waits on copies the step before it
+started; a v5e's one TensorCore a chip ran the steps in order anyway).
+Only latent mode does this: the other modes hold two bands in a
+ring, the very places the next slot would need, and keep their parallel
+grid. The copies move no bit — a slot's output is its one-slot call's
+(tests/test_latent_attention.py).
+
 THE CONTRACT (what holds this kernel, in place of "bitwise with the
 gather path", an equality of the INTERPRETED kernel on a CPU that the
 chip never had — there the compiled kernel and the XLA program order
@@ -154,12 +168,14 @@ additive mask before the in-softmax scale, one joint f32 exp per
 layer, f32 probs into the PV product, output rounded to the compute
 dtype once at the end.
 
-Interpret mode is the caller's choice (``interpret=True``, or the
-tests' ``pallas_interpret`` fixture): the program never picks it from
-a device probe, so a kernel the engine reports as "pallas" is a
-compiled one. The XLA gather path stays available as a
-config-selected backend (``ServingEngine(paged_kernel="xla")``),
-exactly as ops/flash.py keeps naive attention for training.
+Interpret mode is the caller's choice (``interpret=True``, a
+``pltpu.InterpretParams`` for the TPU interpreter that runs a copy when
+it is waited for and reports races, or the tests' ``pallas_interpret``
+fixture): the program never picks it from a device probe, so a kernel
+the engine reports as "pallas" is a compiled one. The XLA gather path
+stays available as a config-selected backend
+(``ServingEngine(paged_kernel="xla")``), exactly as ops/flash.py keeps
+naive attention for training.
 """
 
 from __future__ import annotations
@@ -219,6 +235,15 @@ MAX_UNROLL = 256
 # band is the kernel's unit of skipped work; below this the reductions'
 # vregs go part-filled and the unrolled trace grows for nothing.
 MIN_BAND_ROWS = 128
+
+# How far the latent walk runs ahead, as a share of the table's bands: the
+# next slot's first ``round(nb * LATENT_AHEAD)`` bands are started behind
+# this slot's pass 2, the rest by its own pass 1 that many bands ahead.
+# Measured on a v5e at the latent cell's 48 bands, 32 slots of ~33 k rows
+# (PERF.md section 6): every band behind pass 2 2.47 ms a call, 28-36 of
+# them 2.36, 40 or more 2.45-2.47, 20 2.41, one band ahead 2.80. Moving it
+# moves no bit: it orders copies, not arithmetic.
+LATENT_AHEAD = 2 / 3
 
 # Test hook: force the band plan (pages per band) regardless of the
 # VMEM arithmetic, so small-geometry tests can exercise genuinely
@@ -337,7 +362,10 @@ def vmem_bytes(pmax: int, page_size: int, c: int, itemsize: int,
     - the fetch buffer: two band-sized slots of ``[BP, PS, heads*C]``
       pages at pool dtype (K bands, then V bands, take turns in them:
       one computing, one on its way), plus an int8 pool's f32
-      dequantized band — in latent mode every band of the table, resident;
+      dequantized band — in latent mode every band of the table, resident,
+      and the same places hold the next slot's walk ahead: a band's V
+      read, its place takes the next slot's pages, so walking ahead costs
+      no VMEM (module docstring, LATENT MODE);
     - one head's band compute: the band's f32 view and one
       ``[rows, BW]`` f32 tile set (``rows`` = G*T, padded to whole
       sublane tiles);
@@ -491,7 +519,8 @@ def _attend_kernel(
     # dequantized band [BP, PS, HB*C] f32, 2 DMA semaphores. Latent mode
     # (``v_lanes`` > 0): no V rows and no V pool among the inputs — V is
     # the K operand's first ``v_lanes`` lanes — the output [1, G*T,
-    # v_lanes], and the fetch buffer [NB, BP, PS, C], every band resident.
+    # v_lanes], the fetch buffer [NB, BP, PS, C], every band resident, and
+    # NB DMA semaphores, one a band.
     nb: int,
     hkv: int,
     quant: bool,
@@ -523,7 +552,11 @@ def _attend_kernel(
     pmax = nb * bp
     bw, w = bp * ps, pmax * ps
     np_total = pool_k.shape[1]
-    n_live = (len_ref[i] + ps - 1) // ps
+
+    def pages_of(s_):
+        return (len_ref[s_] + ps - 1) // ps
+
+    n_live = pages_of(i)
 
     # THE WALK. K bands 0..nb-1 then V bands 0..nb-1 are one stream of
     # 2*nb fetch units through the buffer's two slots: unit u+1 is on
@@ -532,20 +565,22 @@ def _attend_kernel(
     # one DMA for all ``hb`` heads (side by side in a pool row) — and
     # zeroes the band's other pages: VMEM nobody wrote may hold NaN,
     # which the additive -inf mask and a zero probability both let
-    # through.
-    def walk(u: int, start: bool):
+    # through. In latent mode every band has a place and a semaphore of
+    # its own, and a walk may be another slot's: slot ``s_``, ``n`` live
+    # pages (the walk ahead, below).
+    def walk(u: int, start: bool, s_=i, n=n_live):
         if latent and u >= nb:
             return  # the V bands are the K bands, where the walk left them
         pool, lo, slot = pool_k if u < nb else pool_v, (u % nb) * bp, u % 2
         at = u if latent else slot  # the band's place in the fetch buffer
-        live = jnp.clip(n_live - lo, 0, bp)
+        live = jnp.clip(n - lo, 0, bp)
 
         def page_dma(p, carry):
-            page = jnp.clip(bt_ref[i, lo + p], 0, np_total - 1)
+            page = jnp.clip(bt_ref[s_, lo + p], 0, np_total - 1)
             src = pool.at[layer_ref[0], page]
             if hb < hkv:
                 src = src.at[:, pl.ds(pl.multiple_of(j * hb * c, 128), hb * c)]
-            cp = pltpu.make_async_copy(src, buf.at[at, p], sem.at[slot])
+            cp = pltpu.make_async_copy(src, buf.at[at, p], sem.at[at])
             cp.start() if start else cp.wait()
             return carry
 
@@ -653,18 +688,35 @@ def _attend_kernel(
         lambda: hb * [jnp.full((rows_p, bw), -jnp.inf, jnp.float32)],
         lambda: hb * [jnp.zeros((rows_p, cv), jnp.float32)],
     )
+    # Latent mode walks AHEAD, across the slot boundary. Band b's place is
+    # free once pass 2 has read its V, so the copies of the next slot's
+    # pages of band b start there and then, for its first ``ahead`` bands;
+    # its other bands its own pass 1 starts, ``ahead`` in front of the band
+    # it computes. Grid step 0 starts its own first ``ahead`` bands, the
+    # last step no next slot's (the slot axis is sequential:
+    # ``_paged_call``). The other modes' ring is ``ahead`` = 1.
+    ahead, own = 1, n_live
+    if latent:
+        ahead = max(1, round(nb * LATENT_AHEAD))
+        last = pl.num_programs(0) - 1
+        own = jnp.where(i == 0, n_live, 0)
+        s_next = jnp.minimum(i + 1, last)
+        n_next = jnp.where(i < last, pages_of(s_next), 0)
     parts = [[] for _ in range(hb)]
     opars = [[] for _ in range(hb)]
     probs = []
-    walk(0, True)
+    for u in range(ahead):
+        walk(u, True, n=own)
     for u in range(2 * nb):
-        if u + 1 < 2 * nb:
-            walk(u + 1, True)  # into the slot unit u-1 has just left
+        if u + ahead < 2 * nb:
+            walk(u + ahead, True)  # ring: into the slot unit u-1 has left
         walk(u, False)
         out = jax.lax.cond(
             n_live > (u % nb) * bp,
             functools.partial(scores if u < nb else pv, u), dead[u // nb],
         )
+        if latent and nb <= u < nb + ahead:
+            walk(u - nb, True, s_=s_next, n=n_next)
         for hh in range(hb):
             (parts if u < nb else opars)[hh].append(out[hh])
         if u == nb - 1:
@@ -734,7 +786,7 @@ def _paged_call(
     ) or hkv
     pallas_call = pl.pallas_call
     if interpret:
-        pallas_call = functools.partial(pallas_call, interpret=True)
+        pallas_call = functools.partial(pallas_call, interpret=interpret)
     scalars = list(scalars)
     if quant:
         scalars += [scale_k.reshape(-1), scale_v.reshape(-1)]
@@ -756,12 +808,17 @@ def _paged_call(
         decode=decode, block=block,
     )
     out_shape, rows, pools = q_in.shape, (rows_k, rows_v), (pool_k, pool_v)
+    sems, grid_kind = 2, ("parallel", "parallel")
     if latent:  # one operand of each: V is K's first lanes
+        assert hb == hkv == 1, "the walk ahead is the next slot's"
         kernel = functools.partial(
             kernel, v_lanes=v_lanes, scale_dim=scale_dim
         )
         out_shape = q_in.shape[:-1] + (v_lanes,)
         rows, pools = (rows_k,), (pool_k,)
+        # a semaphore a band, and a grid step that waits for the walk the
+        # step before started: the slots go in order
+        sems, grid_kind = pmax // bp, ("arbitrary", "arbitrary")
     out = pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -771,11 +828,11 @@ def _paged_call(
             + [head_spec(r.shape) for r in rows]
             + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
             out_specs=head_spec(out_shape),
-            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((sems,))],
         ),
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=grid_kind,
             vmem_limit_bytes=VMEM_LIMIT,
         ),
     )(*scalars, q_in, *rows, *pools)

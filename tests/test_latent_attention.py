@@ -2,7 +2,8 @@
 layer by layer at a small size (D 64, 4 heads, q rank 24, latent 16, 8
 unrotated and 4 rotary lanes a head, values of 8; 8 experts of 16, top-2, one
 shared): the absorbed bodies against the published form, the paged kernel's
-latent mode (interpreted) against the gather path, and the router's rules."""
+latent mode (interpreted) against the gather path and its walk ahead against
+the slots called one at a time, and the router's rules."""
 
 import dataclasses
 
@@ -213,6 +214,124 @@ def test_kernel_latent_mode_scales_by_the_heads_width_not_the_rows(attn):
         q, pool, bt, lens, rows, r, 1, v_lanes=16, scale_dim=128,
         interpret=True)
     assert np.abs(np.asarray(other) - got).max() > 1e-2
+
+
+# ragged slots in one call: a slot ending inside its first band (3, 5), an
+# empty one in the middle and one at the end, long before short (30, 5) and
+# short before long (3, 13; 0, 30), and a slot (17) whose last live band has
+# a page no slot before it wrote — the page the walk ahead must zero
+AHEAD_LENS = [3, 13, 17, 0, 30, 5, 0]
+
+
+def _one_at_a_time(q, pool, bt, lens, rows, r, interpret=True, after=None):
+    """The S slots in one call, and one at a time; ``after()`` is called
+    between the two."""
+    def call(sl):
+        return np.asarray(paged_attn.paged_latent_attention(
+            q[sl], pool, bt[sl], lens[sl], rows[sl], r, 1, v_lanes=16,
+            scale_dim=12, interpret=interpret))
+
+    together = call(slice(None))
+    if after is not None:
+        after()
+    return together, np.concatenate(
+        [call(slice(k, k + 1)) for k in range(len(lens))])
+
+
+@pytest.mark.parametrize("band_pages", [None, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_latent_walk_ahead_is_the_slots_one_at_a_time(
+        attn, paged_hook, dtype, band_pages):
+    """A grid step of the latent call walks the NEXT slot's pages behind its
+    own pass 2 (a one-slot call walks nothing ahead): the slots in one call
+    are, to the bit, the same slots called one at a time."""
+    if band_pages:
+        paged_hook("_FORCE_BAND_PAGES", band_pages)
+    together, alone = _one_at_a_time(
+        *_kernel_case(attn, dtype, AHEAD_LENS, seed=5))
+    assert together.shape == (7, 1, 4, 16) and np.isfinite(together).all()
+    np.testing.assert_array_equal(together, alone)
+
+
+@pytest.mark.parametrize("band_pages", [None, 2])
+def test_latent_walk_ahead_waits_on_its_own_copies(attn, paged_hook, band_pages):
+    """The same comparison in TPU interpret mode, which runs a page's copy
+    only when a wait on its semaphore asks for it and reports a copy that
+    lands while pass 2 still reads the band: a wait that another band's
+    semaphore satisfies, a copy started early, or a walk ahead the slot
+    order does not honour reads the wrong pages, races or never ends (the
+    thread's time limit)."""
+    import threading
+
+    from jax.experimental.pallas import tpu as pltpu
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as ipc
+
+    if band_pages:
+        paged_hook("_FORCE_BAND_PAGES", band_pages)
+    case = _kernel_case(attn, jnp.float32, AHEAD_LENS, seed=6)
+    got, races = [], []  # the interpreter's race state is a call's own
+    run = threading.Thread(daemon=True, target=lambda: got.append(
+        _one_at_a_time(*case, interpret=pltpu.InterpretParams(
+            detect_races=True), after=lambda: races.append(
+                ipc.races.races_found))))
+    run.start()
+    run.join(timeout=120)
+    if run.is_alive():
+        pltpu.reset_tpu_interpret_mode_state()
+        pytest.fail("a wait of the walk ahead was never satisfied")
+    assert got, "the interpreted call raised"
+    assert races == [False]
+    np.testing.assert_array_equal(*got[0])
+
+
+def _pallas_eqn(fn, *args):
+    """The ``pallas_call`` equation of ``fn``'s trace."""
+    def find(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                return e
+            for p in e.params.values():
+                sub = getattr(p, "jaxpr", p)
+                if hasattr(sub, "eqns") and (hit := find(sub)) is not None:
+                    return hit
+        return None
+
+    return find(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def _grid_and_semaphores(eqn):
+    sem = eqn.params["grid_mapping"].scratch_avals[-1]
+    assert "dma_sem" in str(sem), sem
+    return (eqn.params["compiler_params"]["mosaic_tpu"].dimension_semantics,
+            sem.shape)
+
+
+def test_only_the_latent_call_walks_ahead(attn, paged_hook):
+    """Read from the traced calls' ``pallas_call`` parameters: decode and
+    verify keep the two-semaphore ring over a parallel grid; the latent call
+    has a semaphore a band and a sequential slot axis."""
+    paged_hook("_FORCE_BAND_PAGES", 2)
+    s, hkv, g, c, pmax, t = 3, 2, 4, 128, 8, 3
+    f32 = jnp.float32
+    pool = jnp.zeros((2, s * pmax + 1, PS, hkv * c), f32)
+    bt, lens = jnp.zeros((s, pmax), jnp.int32), jnp.zeros((s,), jnp.int32)
+    rk = jnp.zeros((s, hkv, 2, c), f32)
+    decode = _pallas_eqn(
+        lambda q: paged_attn.paged_decode_attention(
+            q, pool, pool, bt, lens, rk, rk, jnp.int32(1), 1),
+        jnp.zeros((s, hkv, g, c), f32))
+    kc = jnp.zeros((s, hkv, t, c), f32)
+    verify = _pallas_eqn(
+        lambda q: paged_attn.paged_verify_attention(
+            q, kc, kc, pool, pool, bt, lens, 1),
+        jnp.zeros((s, hkv, g, t, c), f32))
+    for eqn in (decode, verify):
+        assert _grid_and_semaphores(eqn) == (("parallel", "parallel"), (2,))
+    q, lpool, _, _, rows, r = _kernel_case(attn, f32, [5, 9, 0])
+    latent = _pallas_eqn(
+        lambda q: paged_attn.paged_latent_attention(
+            q, lpool, bt, lens, rows, r, 1, v_lanes=16, scale_dim=12), q)
+    assert _grid_and_semaphores(latent) == (("arbitrary", "arbitrary"), (4,))
 
 
 def test_the_cells_geometry_is_supported_and_resident():
