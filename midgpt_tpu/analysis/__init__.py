@@ -12,9 +12,8 @@ Layering:
 - :mod:`~midgpt_tpu.analysis.harness` imports jax and compiles the real
   train step; :mod:`~midgpt_tpu.analysis.choreo` imports jax and traces
   the serving programs to jaxprs, and
-  :mod:`~midgpt_tpu.analysis.fusion` /
-  :mod:`~midgpt_tpu.analysis.dispatch` (the scan-equivalence prover and
-  the launch auditor) build on its flattener. Their names are
+  :mod:`~midgpt_tpu.analysis.dispatch` (the launch auditor) builds on
+  its flattener. Their names are
   re-exported lazily so ``import midgpt_tpu.analysis`` stays light (the
   CLI must configure the platform *before* jax loads).
 
@@ -37,7 +36,7 @@ from midgpt_tpu.analysis.budgets import (
     budget_for,
     check_budget,
     check_dispatch_budget,
-    dispatch_budget_for,
+    dispatch_budget,
 )
 from midgpt_tpu.analysis.pylint_pass import Finding, lint_paths, lint_source
 from midgpt_tpu.analysis.traffic import (
@@ -63,7 +62,6 @@ _HARNESS_NAMES = (
     "compile_eval_sweep",
     "compile_train_step",
     "override_logical_rules",
-    "prove_scan_equivalence",
     "prove_serving_choreography",
     "serving_dispatch_reports",
     "shrink_for_audit",
@@ -84,7 +82,7 @@ __all__ = [
     "budget_for",
     "check_budget",
     "check_dispatch_budget",
-    "dispatch_budget_for",
+    "dispatch_budget",
     "cost_report",
     "floor_decomposition",
     "floor_table_markdown",

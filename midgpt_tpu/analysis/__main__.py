@@ -176,15 +176,6 @@ def build_parser() -> argparse.ArgumentParser:
         "with the float AND the int8 pool (default off)",
     )
     p.add_argument(
-        "--layer-scan", choices=("off", "on", "both"), default="off",
-        help="which layer-loop modes the serving audits compile: 'on' "
-        "builds the programs with the per-layer loop folded into one "
-        "lax.scan (ServingEngine layer_scan knob, ROADMAP item 1); "
-        "'both' compiles and audits each selected precision/kv cell "
-        "both ways (the fused program streams the same bytes, so the "
-        "same budget cells gate it)",
-    )
-    p.add_argument(
         "--prefill-sp", choices=("off", "on", "both"), default="off",
         help="which prefill-chunk sharding modes the serving audits "
         "compile: 'on' additionally audits the SEQUENCE-PARALLEL chunk "
@@ -194,22 +185,7 @@ def build_parser() -> argparse.ArgumentParser:
         "tensor > 1. With --choreo the SP leg is proven per precision "
         "cell: the SP trace must equal the plain chunk trace op for op "
         "(resharding only, zero arithmetic change — the bitwise-"
-        "identity gate). With --fusion the SP program's launch "
-        "structure gates against its own DISPATCH_BUDGETS cells. "
-        "'both' = audit off and on (default off)",
-    )
-    p.add_argument(
-        "--fusion", action="store_true",
-        help="run the SCAN-EQUIVALENCE prover (analysis.fusion) + the "
-        "static dispatch/launch budgets (analysis.dispatch, "
-        "budgets.DISPATCH_BUDGETS): trace the three serving programs "
-        "with the layer loop unrolled AND folded, prove the unrolled "
-        "layers homogeneous (the fold's legality precondition) and the "
-        "fused scan body op-for-op equal to the per-layer trace, then "
-        "gate launches-per-window / scan trip structure / inlined "
-        "layer bodies / host transfers for BOTH layer_scan values. "
-        "Tracing only — no compilation; the sixth audit family. Runs "
-        "standalone (like --choreo) or inside --serving.",
+        "identity gate). 'both' = audit off and on (default off)",
     )
     p.add_argument(
         "--telemetry", choices=("off", "on"), default="off",
@@ -366,69 +342,8 @@ def _kv_modes(args) -> tp.Tuple[bool, ...]:
     }[args.kv_quant]
 
 
-def _layer_scan_modes(args) -> tp.Tuple[str, ...]:
-    return {
-        "off": ("off",), "on": ("on",), "both": ("off", "on"),
-    }[args.layer_scan]
-
-
 def _sp_on(args) -> bool:
     return getattr(args, "prefill_sp", "off") in ("on", "both")
-
-
-def _run_fusion(args, cfg):
-    """The scan-equivalence prover + dispatch budgets (the sixth audit
-    family): prove every selected precision x kv x backend cell, then
-    gate the static launch structure for BOTH layer_scan values.
-    Returns ``(section_dict, ok, violation_strings)``."""
-    from midgpt_tpu.analysis.budgets import precision_key
-    from midgpt_tpu.analysis.harness import (
-        audit_serving_dispatch,
-        prove_scan_equivalence,
-    )
-
-    out: tp.Dict[str, tp.Any] = {"equivalence": {}, "dispatch": {}}
-    ok = True
-    violations: tp.List[str] = []
-    for precision in _precisions(args):
-        for kvq in _kv_modes(args):
-            for backend in ("xla", "pallas"):
-                rep = prove_scan_equivalence(
-                    cfg, quant=(precision == "int8"), kv_quant=kvq,
-                    paged_kernel=backend,
-                )
-                tag = f"{precision_key(precision, kvq)}/{backend}"
-                out["equivalence"][tag] = rep.to_dict()
-                ok = ok and rep.ok
-                violations.extend(
-                    f"[fusion/{tag}] {c.name}: {c.detail}"
-                    for c in rep.checks
-                    if not c.ok
-                )
-    # launch budgets: structure is precision/backend-invariant (dtypes
-    # change, scan nesting does not) — one trace per layer_scan value;
-    # with --prefill-sp the sequence-parallel chunk rides along as its
-    # own prefill_chunk_sp cells (resharding must not change launches)
-    for ls in ("off", "on"):
-        reports, bad = audit_serving_dispatch(
-            cfg, layer_scan=ls,
-            prefill_sp="on" if _sp_on(args) else "off",
-        )
-        out["dispatch"][ls] = {
-            name: rep.to_dict() for name, rep in reports.items()
-        }
-        ok = ok and not bad
-        violations.extend(f"[dispatch/ls={ls}] {v}" for v in bad)
-    return out, ok, violations
-
-
-def _run_fusion_only(args, cfg) -> int:
-    section, ok, violations = _run_fusion(args, cfg)
-    out: tp.Dict[str, tp.Any] = {
-        "config": args.config, "mode": "scan-equivalence",
-        **section, "ok": ok,
-    }
-    return _emit_report(out, ok, violations, args)
 
 
 def _run_choreo(args, cfg):
@@ -636,18 +551,17 @@ def _run_serving(args, cfg, mesh_shape) -> int:
     from midgpt_tpu.analysis.budgets import precision_key
 
     cells = [
-        (precision, kvq, ls)
+        (precision, kvq)
         for precision in precisions
         for kvq in _kv_modes(args)
-        for ls in _layer_scan_modes(args)
     ]
-    for precision, kvq, ls in cells:
+    for precision, kvq in cells:
         pkey = precision_key(precision, kvq)
         for name, fn, kw, steps in program_specs:
             res = fn(
                 cfg, shrink=not args.no_shrink,
                 quant=(precision == "int8"), kv_quant=kvq,
-                layer_scan=ls, mesh_shape=mesh_shape,
+                mesh_shape=mesh_shape,
                 traffic=args.traffic, **kw
             )
             analysis, report = res[0], res[1]
@@ -668,18 +582,11 @@ def _run_serving(args, cfg, mesh_shape) -> int:
 
                 traf = res[2]
                 section["traffic"] = traf.to_dict()
-                # --print-budgets regeneration fragment: record the
-                # FIRST layer_scan leg only (the unrolled one under
-                # 'both' — the convention the checked-in cells were
-                # measured with); letting the fused leg overwrite it
-                # would regenerate cells from fused numbers exactly
-                # when the two legs diverge. The sampled verify leg is
-                # excluded: it gates against (and must match) the
-                # greedy verify_program cells, it does not get its own
-                if (
-                    ls == _layer_scan_modes(args)[0]
-                    and name != "verify_program_sampled"
-                ):
+                # --print-budgets regeneration fragment. The sampled
+                # verify leg is excluded: it gates against (and must
+                # match) the greedy verify_program cells, it does not
+                # get its own
+                if name != "verify_program_sampled":
                     budget_fragment[(name, pkey)] = traf
                 budget_name = (
                     "verify_program"
@@ -706,23 +613,13 @@ def _run_serving(args, cfg, mesh_shape) -> int:
                         "ok": None,
                         "violations": [],
                     }
-            # the fused program streams the same bytes through the same
-            # entry interface, so both layer_scan legs gate against the
-            # same budget cells; the section key records which leg
-            sections[f"{name}/{pkey}" + ("/scan" if ls == "on" else "")] = (
-                section
-            )
+            sections[f"{name}/{pkey}"] = section
 
     choreo_out = None
     if args.choreo:
         choreo_out, choreo_ok, choreo_violations = _run_choreo(args, cfg)
         ok = ok and choreo_ok
         violations.extend(choreo_violations)
-    fusion_out = None
-    if args.fusion:
-        fusion_out, fusion_ok, fusion_violations = _run_fusion(args, cfg)
-        ok = ok and fusion_ok
-        violations.extend(fusion_violations)
     telemetry_out = None
     if args.telemetry == "on":
         telemetry_out, tele_ok, tele_violations = _run_telemetry()
@@ -734,7 +631,6 @@ def _run_serving(args, cfg, mesh_shape) -> int:
         "mode": "serving-audit",
         "precisions": list(precisions),
         "kv_quant": args.kv_quant,
-        "layer_scan": args.layer_scan,
         "ok": ok,
         "geometry": {
             "slots": args.serving_slots,
@@ -748,8 +644,6 @@ def _run_serving(args, cfg, mesh_shape) -> int:
     }
     if choreo_out is not None:
         out["choreography"] = choreo_out
-    if fusion_out is not None:
-        out["fusion"] = fusion_out
     if telemetry_out is not None:
         out["telemetry"] = telemetry_out
     text = json.dumps(out, indent=2)
@@ -933,29 +827,10 @@ def main(argv: tp.Optional[tp.Sequence[str]] = None) -> int:
         return _run_train_audit(args, cfg)
     if args.serving:
         return _run_serving(args, cfg, mesh_shape)
-    if args.choreo and args.fusion:
-        # both tracing-only provers in one invocation, one combined
-        # report (running only one of them here would silently drop the
-        # other's gate)
-        c_sections, c_ok, c_viol = _run_choreo(args, cfg)
-        f_sections, f_ok, f_viol = _run_fusion(args, cfg)
-        ok = c_ok and f_ok
-        out = {
-            "config": args.config,
-            "mode": "serving-choreography+scan-equivalence",
-            "choreography": c_sections,
-            "fusion": f_sections,
-            "ok": ok,
-        }
-        return _emit_report(out, ok, c_viol + f_viol, args)
     if args.choreo:
         # standalone prover: no compilation, jaxpr tracing only — the
         # fast CI gate (--serving --choreo runs it next to the audits)
         return _run_choreo_only(args, cfg)
-    if args.fusion:
-        # standalone scan-equivalence prover + dispatch budgets: also
-        # tracing only — the serving-choreo CI job's sixth-family gate
-        return _run_fusion_only(args, cfg)
     if args.telemetry == "on":
         # standalone telemetry-inertness proof (tiny fixed model — the
         # named config only labels the report)

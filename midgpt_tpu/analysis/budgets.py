@@ -196,64 +196,26 @@ BUDGETS: tp.Dict[tp.Tuple[str, str, str], tp.Dict[str, int]] = {
 TOLERANCE = 0.04
 
 # ---------------------------------------------------------------------------
-# dispatch/launch budgets (analysis.dispatch) — the launch-side twin of
-# the byte budgets above. Keyed (program, layer_scan) at AUDIT_GEOMETRY
-# (n_layer=2 after the audit shrink; the layer-scan trip count IS that
-# depth). Every entry gates EXACTLY (no band — launch structure is
-# integral): the fused cells demand launches_per_window == 1 with the
-# layer loop inside a scan of trip n_layer and ONE inlined layer body;
-# the unrolled cells pin the legacy shape so a half-fused hybrid can't
-# pass either budget. Re-unrolling a fused program moves zero bytes —
-# the byte budgets stay green — but flips inlined_layer_bodies to
-# n_layer and layer_scan_length to 0, tripping the "on" cells.
-# Host transfers are pinned at 0 everywhere (the jaxpr-level twin of
-# the compiled no-host-sync rule).
+# dispatch/launch budget (analysis.dispatch) — the launch-side twin of the
+# byte budgets above, one rule for every serving program of every model
+# family, computed from the traced model's config: the whole window is ONE
+# launch, nothing crosses to the host inside it, and the flat trace holds
+# one inlined attention body per attention layer (a duplicated or lost
+# layer body moves zero bytes at the entry interface, so the byte budgets
+# cannot see it). Exact equality, no band: launch structure is integral.
 # ---------------------------------------------------------------------------
 
-DISPATCH_BUDGETS: tp.Dict[tp.Tuple[str, str], tp.Dict[str, int]] = {
-    ("decode_window", "on"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 1,
-        "layer_scan_length": 2, "host_transfers": 0,
-    },
-    ("decode_window", "off"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 2,
-        "layer_scan_length": 0, "host_transfers": 0,
-    },
-    ("prefill_chunk", "on"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 1,
-        "layer_scan_length": 2, "host_transfers": 0,
-    },
-    ("prefill_chunk", "off"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 2,
-        "layer_scan_length": 0, "host_transfers": 0,
-    },
-    ("verify_program", "on"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 1,
-        "layer_scan_length": 2, "host_transfers": 0,
-    },
-    ("verify_program", "off"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 2,
-        "layer_scan_length": 0, "host_transfers": 0,
-    },
-    # the sequence-parallel chunk: resharding constraints change ZERO
-    # launch structure — the cells are the plain chunk's verbatim, and
-    # that equality is itself the gate (an SP variant that split the
-    # chunk into per-shard dispatches would trip launches_per_window)
-    ("prefill_chunk_sp", "on"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 1,
-        "layer_scan_length": 2, "host_transfers": 0,
-    },
-    ("prefill_chunk_sp", "off"): {
-        "launches_per_window": 1, "inlined_layer_bodies": 2,
-        "layer_scan_length": 0, "host_transfers": 0,
-    },
-}
 
-
-def dispatch_budget_for(
-    program: str, layer_scan: str
-) -> tp.Optional[tp.Dict[str, int]]:
-    return DISPATCH_BUDGETS.get((program, layer_scan))
+def dispatch_budget(model_cfg) -> tp.Dict[str, int]:
+    """The launch structure a serving program of a model of ``model_cfg``
+    must have: one launch per window, no host transfer, and one inlined
+    attention body per attention layer (``kv_layers``: the layers whose
+    cache is pages of the pool)."""
+    return {
+        "launches_per_window": 1,
+        "inlined_layer_bodies": model_cfg.kv_layers,
+        "host_transfers": 0,
+    }
 
 
 def check_dispatch_budget(
@@ -261,28 +223,22 @@ def check_dispatch_budget(
     budget: tp.Mapping[str, int],
 ) -> tp.List[str]:
     """Evaluate one program's measured launch structure against its
-    dispatch budget; returns violation strings (empty = pass). Exact
-    equality, no band: a launch count is an integer, and both
-    directions are regressions (an extra inlined body is re-unrolling;
-    a missing one means the audit traced the wrong program)."""
+    dispatch budget; returns violation strings (empty = pass). A key the
+    budget leaves out is not checked. Both directions are regressions:
+    an extra inlined body is a layer traced twice, a missing one means
+    the audit traced the wrong program."""
     out: tp.List[str] = []
     got = report.to_dict()
     for key in (
-        "launches_per_window", "inlined_layer_bodies",
-        "layer_scan_length", "host_transfers",
+        "launches_per_window", "inlined_layer_bodies", "host_transfers",
     ):
         expect = budget.get(key)
         if expect is None:
             continue
         if got[key] != expect:
             hint = ""
-            if key == "inlined_layer_bodies" and got[key] > expect:
-                hint = (
-                    " — the layer loop re-unrolled (every decode "
-                    "dispatch pays per-layer launch overhead again)"
-                )
-            elif key == "layer_scan_length" and got[key] == 0:
-                hint = " — no folded layer scan found in the program"
+            if key == "launches_per_window":
+                hint = " — the window's steps no longer fit one launch"
             elif key == "host_transfers":
                 hint = " — a host callback joined the hot path"
             out.append(

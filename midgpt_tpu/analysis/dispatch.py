@@ -1,39 +1,27 @@
 """Static dispatch/launch auditor for the serving programs.
 
-The residual between r5's measured 0.905 ms/tok and the 0.278 ms HBM
-floor is LAUNCH structure, not bytes (PERF.md): the whole-model decode
-step unrolls its layer loop, so every window dispatch carries L inlined
-copies of the per-layer kernel set — L times the launch overhead, L
-times the executable size, and [B, 1, D] matmul shapes that cannot
-amortize any of it. The byte budgets (analysis.traffic/budgets) cannot
-see this class of regression: re-unrolling a folded loop moves ZERO
-bytes at the entry interface. This module is the launch-side
-counterpart — count the dispatch structure statically from the traced
-program and gate it against checked-in budgets, exactly like the HBM
-byte budgets:
+The byte budgets (analysis.traffic/budgets) see what a program streams at
+its entry interface; they cannot see how the program is launched. A
+window split into several dispatches, a layer body traced twice, or a host
+callback inside the window moves zero extra bytes there. This module is
+the launch-side counterpart: it counts the dispatch structure statically
+from the traced program, and
+:func:`midgpt_tpu.analysis.budgets.check_dispatch_budget` gates it against
+:func:`midgpt_tpu.analysis.budgets.dispatch_budget` of the traced model:
 
 - **launches per window** — XLA dispatches the engine must issue per
   scheduler window for this program. The decode window's K-step scan
   must cover all ``window_steps`` model steps, or the remainder would
   need extra launches (the PR 2/PR 3 fused-dispatch contract, now
   machine-checked).
-- **scan trip structure** — every attention-carrying ``lax.scan`` in
-  the traced program, with trip count and nesting depth; the fused
-  program must show the layer loop as a scan of trip ``n_layer``
-  (``layer_scan_length``) nested inside the window scan, and a
-  re-unrolled program shows ``layer_scan_length == 0`` and fails the
-  "on" budget.
 - **inlined layer bodies** — how many copies of the per-layer attention
-  arithmetic the flat trace carries (choreo.py's region extractor):
-  1 when folded, ``n_layer`` when unrolled.
+  arithmetic the flat trace carries (choreo.py's region extractor): one
+  per attention layer.
 - **host transfers** — callback/infeed/outfeed primitives anywhere in
   the program (each is a device->host sync per dispatch; the budget
   pins 0, the jaxpr-level twin of the compiled no-host-sync rule).
 
-Operates on jaxprs (no compilation); budgets live in
-:data:`midgpt_tpu.analysis.budgets.DISPATCH_BUDGETS`, keyed by
-``(program, layer_scan)`` at the audit geometry, and are gated by
-:func:`midgpt_tpu.analysis.budgets.check_dispatch_budget`.
+Operates on jaxprs (no compilation).
 """
 
 from __future__ import annotations
@@ -53,32 +41,14 @@ _HOST_TRANSFER_PRIMS = frozenset({
 
 @dataclasses.dataclass(frozen=True)
 class ScanInfo:
-    """One ``lax.scan`` in the traced program."""
+    """One attention-carrying ``lax.scan`` in the traced program."""
 
     length: int  # trip count
     depth: int  # scan-nesting depth (0 = top level)
     attention_regions: int  # inlined layer bodies in its FLAT body
-    has_nested_attention_scan: bool  # an attention scan nests inside
-
-    @property
-    def is_layer_scan(self) -> bool:
-        """The layer fold: an attention-carrying scan whose body holds
-        exactly ONE inlined layer and no deeper attention scan — its
-        trip count is the layer count. (The decode window's K-step scan
-        has a NESTED layer scan when fused, or multiple inlined bodies
-        when unrolled, so it never matches.)"""
-        return (
-            self.attention_regions == 1
-            and not self.has_nested_attention_scan
-        )
 
     def to_dict(self) -> tp.Dict[str, tp.Any]:
-        return {
-            "length": self.length,
-            "depth": self.depth,
-            "attention_regions": self.attention_regions,
-            "is_layer_scan": self.is_layer_scan,
-        }
+        return dataclasses.asdict(self)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,24 +62,13 @@ class DispatchReport:
     host_transfers: int
 
     @property
-    def layer_scan_length(self) -> int:
-        """Trip count of the folded layer loop; 0 = unrolled."""
-        for s in self.scans:
-            if s.is_layer_scan:
-                return s.length
-        return 0
-
-    @property
     def launches_per_window(self) -> int:
-        """XLA dispatches per scheduler window: the outermost NON-layer
-        attention scan must cover all ``window_steps`` model steps in
-        one launch (ceil of the shortfall otherwise). Programs that run
-        one model step per window (prefill chunk, verify) are one
-        launch by construction."""
-        steps_per_launch = max(
-            (s.length for s in self.scans if not s.is_layer_scan),
-            default=1,
-        )
+        """XLA dispatches per scheduler window: the longest attention
+        scan must cover all ``window_steps`` model steps in one launch
+        (ceil of the shortfall otherwise). Programs that run one model
+        step per window (prefill chunk, verify) are one launch by
+        construction."""
+        steps_per_launch = max((s.length for s in self.scans), default=1)
         return -(-self.window_steps // steps_per_launch)
 
     def to_dict(self) -> tp.Dict[str, tp.Any]:
@@ -117,7 +76,6 @@ class DispatchReport:
             "program": self.program,
             "window_steps": self.window_steps,
             "scans": [s.to_dict() for s in self.scans],
-            "layer_scan_length": self.layer_scan_length,
             "inlined_layer_bodies": self.inlined_layer_bodies,
             "launches_per_window": self.launches_per_window,
             "host_transfers": self.host_transfers,
@@ -138,35 +96,26 @@ def _param_jaxprs(params: tp.Mapping[str, tp.Any]) -> tp.Iterator[tp.Any]:
 
 
 def _walk(jpr, depth: int, scans: tp.List[ScanInfo],
-          host: tp.List[int]) -> bool:
-    """Recursive eqn walk; returns True when this jaxpr (transitively)
-    contains attention arithmetic inside a scan at any depth."""
-    found_attn_scan = False
+          host: tp.List[int]) -> None:
+    """Recursive eqn walk: every attention-carrying scan at any depth
+    into ``scans``, every host-transfer primitive counted in ``host``."""
     for eqn in jpr.eqns:
         name = eqn.primitive.name
         if name in _HOST_TRANSFER_PRIMS:
             host[0] += 1
         if name == "scan":
             body = eqn.params.get("jaxpr")
-            inner = getattr(body, "jaxpr", body)
-            nested_attn = _walk(inner, depth + 1, scans, host)
+            _walk(getattr(body, "jaxpr", body), depth + 1, scans, host)
             regions = len(attention_regions(flatten_jaxpr(body)))
             if regions:
                 scans.append(ScanInfo(
                     length=int(eqn.params.get("length", 0)),
                     depth=depth,
                     attention_regions=regions,
-                    has_nested_attention_scan=nested_attn,
                 ))
-                found_attn_scan = True
-            found_attn_scan = found_attn_scan or nested_attn
             continue
         for p in _param_jaxprs(eqn.params):
-            sub = getattr(p, "jaxpr", p)
-            found_attn_scan = (
-                _walk(sub, depth, scans, host) or found_attn_scan
-            )
-    return found_attn_scan
+            _walk(getattr(p, "jaxpr", p), depth, scans, host)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -271,11 +220,7 @@ def dispatch_report(
     """Build the :class:`DispatchReport` for one traced program.
     ``window_steps`` is the number of model steps one scheduler window
     must cover with this program (the decode window's K; 1 for the
-    prefill chunk and the verify program).
-
-    Note the ``n_layer >= 2`` requirement of the audit geometry: at a
-    single layer an unrolled window body is indistinguishable from a
-    folded one (one inlined body either way)."""
+    prefill chunk and the verify program)."""
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     scans: tp.List[ScanInfo] = []
     host = [0]

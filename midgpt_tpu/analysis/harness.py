@@ -445,7 +445,6 @@ def compile_decode_window(
     shrink: bool = True,
     quant: bool = False,
     kv_quant: bool = False,
-    layer_scan: str = "off",
     mesh_shape: tp.Optional[tp.Mapping[str, int]] = None,
 ):
     """Compile the serving engine's fused K-step decode window
@@ -481,7 +480,6 @@ def compile_decode_window(
     window_fn = make_decode_window(
         model, slots=slots, window=window, pmax=pmax,
         rope_len=model_cfg.block_size, mesh=prog_mesh,
-        layer_scan=layer_scan,
     )
     i32 = lambda *shape: np_.zeros(shape, np_.int32)  # noqa: E731
     hlo = window_fn.lower(
@@ -516,7 +514,6 @@ def audit_decode_window(
     shrink: bool = True,
     quant: bool = False,
     kv_quant: bool = False,
-    layer_scan: str = "off",
     mesh_shape: tp.Optional[tp.Mapping[str, int]] = None,
     traffic: bool = False,
 ):
@@ -534,7 +531,7 @@ def audit_decode_window(
         compile_decode_window(
             cfg, slots=slots, window=window, page_size=page_size,
             shrink=shrink, quant=quant, kv_quant=kv_quant,
-            layer_scan=layer_scan, mesh_shape=mesh_shape,
+            mesh_shape=mesh_shape,
         )
     )
     analysis = StepAnalysis.from_text(
@@ -560,7 +557,6 @@ def compile_prefill_chunk(
     shrink: bool = True,
     quant: bool = False,
     kv_quant: bool = False,
-    layer_scan: str = "off",
     prefill_sp: str = "off",
     mesh_shape: tp.Optional[tp.Mapping[str, int]] = None,
 ):
@@ -605,7 +601,7 @@ def compile_prefill_chunk(
     chunk_fn = make_prefill_chunk_program(
         model, chunk_len=chunk_len, pmax=pmax,
         rope_len=model_cfg.block_size, mesh=prog_mesh,
-        layer_scan=layer_scan, prefill_sp=prefill_sp,
+        prefill_sp=prefill_sp,
     )
     i32 = lambda *shape: np_.zeros(shape, np_.int32)  # noqa: E731
     hlo = chunk_fn.lower(
@@ -636,7 +632,6 @@ def audit_prefill_chunk(
     shrink: bool = True,
     quant: bool = False,
     kv_quant: bool = False,
-    layer_scan: str = "off",
     prefill_sp: str = "off",
     mesh_shape: tp.Optional[tp.Mapping[str, int]] = None,
     traffic: bool = False,
@@ -655,7 +650,7 @@ def audit_prefill_chunk(
     hlo, mesh, donated, block, wshapes, payload, keys = (
         compile_prefill_chunk(
             cfg, chunk_len=chunk_len, page_size=page_size, shrink=shrink,
-            quant=quant, kv_quant=kv_quant, layer_scan=layer_scan,
+            quant=quant, kv_quant=kv_quant,
             prefill_sp=prefill_sp, mesh_shape=mesh_shape,
         )
     )
@@ -686,7 +681,6 @@ def compile_verify_program(
     shrink: bool = True,
     quant: bool = False,
     kv_quant: bool = False,
-    layer_scan: str = "off",
     mesh_shape: tp.Optional[tp.Mapping[str, int]] = None,
     temperature: float = 0.0,
     top_k: tp.Optional[int] = None,
@@ -723,7 +717,7 @@ def compile_verify_program(
     verify_fn = make_verify_program(
         model, slots=slots, spec_len=spec_len, pmax=pmax,
         rope_len=model_cfg.block_size, temperature=temperature,
-        top_k=top_k, mesh=prog_mesh, layer_scan=layer_scan,
+        top_k=top_k, mesh=prog_mesh,
     )
     i32 = lambda *shape: np_.zeros(shape, np_.int32)  # noqa: E731
     lower_args = [
@@ -761,7 +755,6 @@ def audit_verify_program(
     shrink: bool = True,
     quant: bool = False,
     kv_quant: bool = False,
-    layer_scan: str = "off",
     mesh_shape: tp.Optional[tp.Mapping[str, int]] = None,
     traffic: bool = False,
     temperature: float = 0.0,
@@ -784,7 +777,7 @@ def audit_verify_program(
         compile_verify_program(
             cfg, slots=slots, spec_len=spec_len, page_size=page_size,
             shrink=shrink, quant=quant, kv_quant=kv_quant,
-            layer_scan=layer_scan, mesh_shape=mesh_shape,
+            mesh_shape=mesh_shape,
             temperature=temperature, top_k=top_k,
         )
     )
@@ -1060,7 +1053,6 @@ def prove_sp_prefill_choreography(
     *,
     quant: bool = False,
     kv_quant: bool = False,
-    layer_scan: str = "off",
     tp_size: int = 2,
     chunk_len: int = 16,
     page_size: int = 16,
@@ -1117,7 +1109,7 @@ def prove_sp_prefill_choreography(
     kw = dict(
         slots=4, window=2, spec_len=2, chunk_len=chunk_len,
         page_size=page_size, kv_quant="int8" if kv_quant else None,
-        layer_scan=layer_scan, mesh=mesh,
+        mesh=mesh,
     )
     off = trace_serving_programs(model, prefill_sp="off", **kw)
     on = trace_serving_programs(model, prefill_sp="on", **kw)
@@ -1127,96 +1119,37 @@ def prove_sp_prefill_choreography(
     )
 
 
-def prove_scan_equivalence(
-    name_or_cfg: tp.Union[str, ExperimentConfig],
-    *,
-    quant: bool = False,
-    kv_quant: bool = False,
-    paged_kernel: str = "xla",
-    n_layer: int = 3,
-):
-    """Run the scan-equivalence prover (:mod:`midgpt_tpu.analysis.fusion`)
-    over the three serving programs of ``cfg``'s model family: trace
-    each program BOTH ways (``layer_scan`` off and on, through the very
-    jitted factories the engine launches), prove the unrolled traces
-    layer-homogeneous (the fold's legality precondition), and prove the
-    fused scan BODY's normalized trace op-for-op equal to the unrolled
-    per-layer trace — attention region, full layer segment, softmax
-    signature, lm-head choreography. Returns a
-    :class:`~midgpt_tpu.analysis.fusion.FusionReport`.
-
-    Traced at depth 3 (not the choreography size's 2): homogeneity
-    needs a TRUE MIDDLE layer — at depth 2 every layer is first or
-    last, and a first/last-layer special case would have nothing
-    identical to be compared against. No compilation; a full proof of
-    all six traces is seconds on CPU."""
+def _dispatch_audit_config(name_or_cfg: tp.Union[str, ExperimentConfig]):
+    """The model the dispatch audit traces: ``cfg``'s family at two
+    layers, the byte budgets' audit shrink."""
     import dataclasses as _dc
-
-    import jax
-    import jax.numpy as jnp
-
-    from midgpt_tpu.analysis.fusion import prove_scan_fusion
-    from midgpt_tpu.models.gpt import GPT
-    from midgpt_tpu.pytree import cast_floating
-    from midgpt_tpu.serving.engine import trace_serving_programs
 
     cfg = (
         get_config(name_or_cfg)
         if isinstance(name_or_cfg, str)
         else name_or_cfg
     )
-    model_cfg = _dc.replace(
-        cfg.model, n_layer=n_layer, block_size=64, vocab_size=128,
+    return _dc.replace(
+        cfg.model, n_layer=2, block_size=256, vocab_size=1024,
         remat="none", scan_unroll=1,
     )
-    model = cast_floating(
-        GPT.init(jax.random.PRNGKey(0), model_cfg), jnp.bfloat16
-    )
-    if quant:
-        from midgpt_tpu.quant import quantize_model
-
-        model = quantize_model(model)
-    kw = dict(
-        slots=4, window=2, spec_len=2, chunk_len=16, page_size=16,
-        kv_quant="int8" if kv_quant else None, paged_kernel=paged_kernel,
-    )
-    off = trace_serving_programs(model, layer_scan="off", **kw)
-    on = trace_serving_programs(model, layer_scan="on", **kw)
-    return prove_scan_fusion(off, on)
 
 
 def serving_dispatch_reports(
     name_or_cfg: tp.Union[str, ExperimentConfig],
     *,
-    layer_scan: str = "off",
-    prefill_sp: str = "off",
-    quant: bool = False,
-    kv_quant: bool = False,
     paged_kernel: str = "xla",
     slots: int = 4,
     window: int = 4,
     spec_len: int = 4,
     chunk_len: int = 64,
     page_size: int = 16,
-    temperature: float = 0.0,
-    top_k: tp.Optional[int] = None,
 ) -> tp.Dict[str, tp.Any]:
     """Trace the three serving programs at the audit geometry (the same
     n_layer=2 shrink the byte budgets were measured at) and build their
     static :class:`~midgpt_tpu.analysis.dispatch.DispatchReport`\\ s,
     keyed by the budget program names (``decode_window`` /
-    ``prefill_chunk`` / ``verify_program``). Launch structure is
-    precision-independent (quant/kv-quant change dtypes, not the scan
-    nesting) — the flags exist so fault-injection tests can audit any
-    cell they traced. ``temperature > 0`` audits the SAMPLED programs
-    against the same cells: rejection-sampling acceptance is in-program
-    arithmetic and must not change the launch structure.
-    ``prefill_sp="on"`` additionally traces the sequence-parallel chunk
-    program on a tensor=2 mesh and reports it as ``prefill_chunk_sp``:
-    SP is resharding only, so its launch structure must equal the plain
-    chunk's (its own DISPATCH_BUDGETS cells pin exactly that)."""
-    import dataclasses as _dc
-
+    ``prefill_chunk`` / ``verify_program``)."""
     import jax
     import jax.numpy as jnp
 
@@ -1225,30 +1158,16 @@ def serving_dispatch_reports(
     from midgpt_tpu.pytree import cast_floating
     from midgpt_tpu.serving.engine import trace_serving_programs
 
-    cfg = (
-        get_config(name_or_cfg)
-        if isinstance(name_or_cfg, str)
-        else name_or_cfg
-    )
-    model_cfg = _dc.replace(
-        cfg.model, n_layer=2, block_size=256, vocab_size=1024,
-        remat="none", scan_unroll=1,
-    )
+    model_cfg = _dispatch_audit_config(name_or_cfg)
     model = cast_floating(
         GPT.init(jax.random.PRNGKey(0), model_cfg), jnp.bfloat16
     )
-    if quant:
-        from midgpt_tpu.quant import quantize_model
-
-        model = quantize_model(model)
     jaxprs = trace_serving_programs(
         model, slots=slots, window=window, spec_len=spec_len,
         chunk_len=chunk_len, page_size=page_size,
-        kv_quant="int8" if kv_quant else None,
-        paged_kernel=paged_kernel, layer_scan=layer_scan,
-        temperature=temperature, top_k=top_k,
+        paged_kernel=paged_kernel,
     )
-    out = {
+    return {
         "decode_window": dispatch_report(
             jaxprs["decode_window"], program="decode_window",
             window_steps=window,
@@ -1260,55 +1179,27 @@ def serving_dispatch_reports(
             jaxprs["verify"], program="verify_program",
         ),
     }
-    if prefill_sp == "on":
-        from midgpt_tpu.config import MeshConfig
-        from midgpt_tpu.parallel.mesh import create_mesh
-
-        assert model_cfg.kv_heads % 2 == 0, model_cfg.kv_heads
-        mesh = create_mesh(
-            MeshConfig(replica=1, fsdp=1, sequence=1, tensor=2),
-            devices=jax.devices()[:2],
-        )
-        sp_jaxprs = trace_serving_programs(
-            model, slots=slots, window=window, spec_len=spec_len,
-            chunk_len=chunk_len, page_size=page_size,
-            kv_quant="int8" if kv_quant else None,
-            paged_kernel=paged_kernel, layer_scan=layer_scan,
-            prefill_sp="on", mesh=mesh,
-            temperature=temperature, top_k=top_k,
-        )
-        out["prefill_chunk_sp"] = dispatch_report(
-            sp_jaxprs["prefill_chunk"], program="prefill_chunk_sp",
-        )
-    return out
 
 
 def audit_serving_dispatch(
     name_or_cfg: tp.Union[str, ExperimentConfig],
-    *,
-    layer_scan: str = "off",
     **kw,
 ) -> tp.Tuple[tp.Dict[str, tp.Any], tp.List[str]]:
-    """One-call dispatch audit: trace the three programs with the given
-    ``layer_scan`` and gate their launch structure against the
-    checked-in :data:`~midgpt_tpu.analysis.budgets.DISPATCH_BUDGETS`
-    cells for that value. Returns ``(reports, violations)`` — the CI
-    serving-choreo job runs this for BOTH values, so a re-unrolled
-    fused program (zero byte movement, L× launch structure) fails the
-    "on" cells before any hardware sees it."""
+    """One-call dispatch audit: trace the serving programs and gate each
+    one's launch structure against
+    :func:`~midgpt_tpu.analysis.budgets.dispatch_budget` of the traced
+    model (the audit's n_layer=2 shrink). Returns ``(reports,
+    violations)``."""
     from midgpt_tpu.analysis.budgets import (
         check_dispatch_budget,
-        dispatch_budget_for,
+        dispatch_budget,
     )
 
-    reports = serving_dispatch_reports(
-        name_or_cfg, layer_scan=layer_scan, **kw
-    )
+    reports = serving_dispatch_reports(name_or_cfg, **kw)
+    budget = dispatch_budget(_dispatch_audit_config(name_or_cfg))
     violations: tp.List[str] = []
-    for name, rep in reports.items():
-        budget = dispatch_budget_for(name, layer_scan)
-        if budget is not None:
-            violations.extend(check_dispatch_budget(rep, budget))
+    for rep in reports.values():
+        violations.extend(check_dispatch_budget(rep, budget))
     return reports, violations
 
 

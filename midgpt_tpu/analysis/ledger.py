@@ -259,7 +259,6 @@ BANDS: tp.Dict[str, Band] = {
     "serve_floor_ms_per_tok_static": Band(STATIC, 1e-3),
     "serve_static_launches_per_window": Band(STATIC, 0.0),
     "serve_static_inlined_layer_bodies": Band(STATIC, 0.0),
-    "serve_static_layer_scan_length": Band(STATIC, 0.0),
     "serve_static_host_transfers": Band(STATIC, 0.0),
     "serve_comms_bytes_per_dispatch": Band(STATIC, 1e-6),
     # --- static: training floors / FLOP accounting --------------------

@@ -59,8 +59,8 @@ RULES = {
         "parameter"
     ),
     "no-unrolled-layer-loop": (
-        "serving jit unrolls a Python for-loop over model layers "
-        "instead of using the lax.scan layer fold"
+        "serving jit runs a Python for-loop over model layers outside "
+        "the models.gpt forward it calls"
     ),
 }
 
@@ -380,16 +380,14 @@ class _ModuleLint:
     def check_unrolled_layer_loop(self) -> None:
         """``no-unrolled-layer-loop`` (serving modules only, waivable):
         a Python-level ``for`` over the model's layers inside jitted/
-        traced serving code. The layer fold exists
-        (models.gpt ``layer_scan="on"``, proven bitwise and gated by
-        analysis.fusion/dispatch) — a new serving program body that
-        unrolls ``for i in range(cfg.n_layer)`` re-introduces the L×
-        per-layer launch structure the fold removed, silently (zero
-        byte movement, so only the dispatch budget or this lint sees
-        it). The models/ drivers keep their unrolled ``layer_scan=
-        "off"`` branches on purpose (the off path is the fold's
-        bitwise reference); this rule scopes to ``midgpt_tpu/serving/``
-        where program BODIES live."""
+        traced serving code. Each serving program has exactly one layer
+        loop, in the models/ forward it calls
+        (``models.gpt.decode_step_paged``, ``prefill_chunk_paged``,
+        ``verify_tokens_paged``); a second one in a serving program
+        body would run the layers twice or fork the path, silently
+        (zero byte movement, so only the dispatch budget or this lint
+        sees it). This rule scopes to ``midgpt_tpu/serving/`` where
+        program BODIES live."""
         def mentions_layers(node: ast.AST) -> bool:
             for sub in ast.walk(node):
                 if isinstance(sub, ast.Attribute) and sub.attr == "n_layer":
@@ -411,10 +409,9 @@ class _ModuleLint:
                         node.lineno,
                         "no-unrolled-layer-loop",
                         "Python for-loop over model layers in a traced "
-                        "serving body — use the lax.scan layer fold "
-                        "(models.gpt layer_scan) so decode dispatch "
-                        "structure stays 1 inlined body per program "
-                        "(gated by analysis.dispatch budgets)",
+                        "serving body — the layer loop belongs in the "
+                        "models.gpt forward the program calls, one per "
+                        "program (gated by analysis.dispatch budgets)",
                     )
 
 

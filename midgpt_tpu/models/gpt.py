@@ -2099,9 +2099,7 @@ def stacked_experts(model, layer) -> tp.Optional[tp.Tuple]:
     ``config.dense_layers`` leading dense layers, which have none."""
     mlp = model.blocks.mlp
     dense = model.config.dense_layers
-    if not isinstance(mlp, ExpertMLP) or (
-        isinstance(layer, int) and layer < dense
-    ):
+    if not isinstance(mlp, ExpertMLP) or layer < dense:
         return None
     return mlp.w_in, mlp.w_out, layer - dense if dense else layer
 
@@ -2722,7 +2720,6 @@ def decode_step_paged(
     pool_sk: tp.Optional[Array] = None,  # [L, NP, Hkv] f32 (int8 pool)
     pool_sv: tp.Optional[Array] = None,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
     state: tp.Optional[tp.Tuple[Array, Array]] = None,
     valid: tp.Optional[Array] = None,
 ) -> tp.Tuple[Array, ...]:
@@ -2735,22 +2732,6 @@ def decode_step_paged(
     ``Attention.decode_paged_at``). There is no sliding window: pages
     are append-only and the engine caps each request at ``block_size``
     total tokens.
-
-    ``layer_scan="on"`` folds the layer loop into ONE ``lax.scan`` over
-    the stacked block params (ROADMAP item 1: the unrolled loop
-    re-dispatches the whole per-layer kernel set L times per step —
-    launch overhead the [B, 1, D] decode shapes cannot hide). The
-    read-only pool planes, scale planes and recent buffers ride as
-    per-layer scan ``xs`` (a dynamic-slice read per iteration — nothing
-    re-stacks, unlike carrying a written cache through a scan, the
-    decode_step trap) and each iteration returns its layer's updated
-    recent rows as ``ys``. The body calls the very same
-    ``Block.decode_paged_at`` as the unrolled path, on a ``[1, ...]``
-    per-layer view with ``layer=0``, so the scan BODY's arithmetic is
-    op-for-op the per-layer trace — which the scan-equivalence prover
-    (midgpt_tpu.analysis.fusion, CI serving-choreo job) proves
-    statically and the bitwise on-vs-off token-identity matrix pins at
-    runtime.
 
     A model with linear-attention layers (``config.layer_plan``) also takes
     ``state`` — every linear layer's per-slot states ``[Ll, S, Hv, dk, dv]``
@@ -2799,51 +2780,27 @@ def decode_step_paged(
 
     h = embed_tokens(model.wte, tokens[:, None])  # [S, 1, D]
     sin_h, cos_h = sin_rows.astype(h.dtype), cos_rows.astype(h.dtype)
-    assert layer_scan in ("on", "off"), layer_scan
     assert (state is None) == (model.lin_blocks is None)
     expert_rows = []
-    if layer_scan == "on":
-        assert state is None and not cfg.latent, (
-            "layer_scan='on' needs identical full-attention layers"
+    for n, (kind, i) in enumerate(cfg.layer_plan):
+        block = model.layer(n)
+        if kind == "linear":
+            h, *state = block.decode_state_at(h, *state, i, valid)
+            continue
+        if kind == "latent":
+            h, rk, *rows = block.decode_latent_at(
+                h, pool_k, bt, rk, i, r, mask_pool, mask_rec, sin_h,
+                cos_h, pooled_len, paged_kernel=paged_kernel,
+                experts=stacked_experts(model, n),
+                live=None if valid is None else valid[:, None],
+            )
+            expert_rows += rows
+            continue
+        h, rk, rv = block.decode_paged_at(
+            h, pool_k, pool_v, bt, rk, rv, i, r, mask_pool, mask_rec,
+            sin_h, cos_h, pooled_len=pooled_len, pool_sk=pool_sk,
+            pool_sv=pool_sv, paged_kernel=paged_kernel,
         )
-        quant = pool_sk is not None
-
-        def body(hc, xs):
-            block, pk_l, pv_l, rk_l, rv_l = xs[:5]
-            sk_l = xs[5][None] if quant else None
-            sv_l = xs[6][None] if quant else None
-            hc, rk1, rv1 = block.decode_paged_at(
-                hc, pk_l[None], pv_l[None], bt, rk_l[None], rv_l[None],
-                0, r, mask_pool, mask_rec, sin_h, cos_h,
-                pooled_len=pooled_len, pool_sk=sk_l, pool_sv=sv_l,
-                paged_kernel=paged_kernel,
-            )
-            return hc, (rk1[0], rv1[0])
-
-        xs = (model.blocks, pool_k, pool_v, rk, rv)
-        if quant:
-            xs = xs + (pool_sk, pool_sv)
-        h, (rk, rv) = jax.lax.scan(body, h, xs)
-    else:
-        for n, (kind, i) in enumerate(cfg.layer_plan):
-            block = model.layer(n)
-            if kind == "linear":
-                h, *state = block.decode_state_at(h, *state, i, valid)
-                continue
-            if kind == "latent":
-                h, rk, *rows = block.decode_latent_at(
-                    h, pool_k, bt, rk, i, r, mask_pool, mask_rec, sin_h,
-                    cos_h, pooled_len, paged_kernel=paged_kernel,
-                    experts=stacked_experts(model, n),
-                    live=None if valid is None else valid[:, None],
-                )
-                expert_rows += rows
-                continue
-            h, rk, rv = block.decode_paged_at(
-                h, pool_k, pool_v, bt, rk, rv, i, r, mask_pool, mask_rec,
-                sin_h, cos_h, pooled_len=pooled_len, pool_sk=pool_sk,
-                pool_sv=pool_sv, paged_kernel=paged_kernel,
-            )
     h = model.ln_f(h)
     # vocab-sharded logits (TP lm head is column-parallel): nothing here
     # gathers the [S, V] row — greedy argmax partitions over 'tensor'
@@ -2865,7 +2822,6 @@ def prefill_chunk_paged(
     rope_len: int,
     pool_sk: tp.Optional[Array] = None,  # [L, NP, Hkv] f32 (int8 pool)
     pool_sv: tp.Optional[Array] = None,
-    layer_scan: str = "off",
     sp: bool = False,
     block_len: int = 0,
     state: tp.Optional[tp.Tuple[Array, Array]] = None,
@@ -2947,61 +2903,32 @@ def prefill_chunk_paged(
         h = shard_act(h, None, None, None)
         h = shard_act(h, None, "sp", None)
     sin_h, cos_h = sin_rows.astype(h.dtype), cos_rows.astype(h.dtype)
-    assert layer_scan in ("on", "off"), layer_scan
     assert (state is None) == (model.lin_blocks is None)
-    if layer_scan == "on":
-        assert state is None and not cfg.latent, (
-            "layer_scan='on' needs identical full-attention layers"
-        )
-        # layer loop folded into one lax.scan (see decode_step_paged):
-        # read-only pool/scale planes ride as xs, the chunk's per-layer
-        # K/V land as scan ys — exactly the jnp.stack of the unrolled
-        # loop, produced a layer at a time
-        quant = pool_sk is not None
-
-        def body(hc, xs):
-            block, pk_l, pv_l = xs[:3]
-            sk_l = xs[3][None] if quant else None
-            sv_l = xs[4][None] if quant else None
-            hc, k, v = block.prefill_paged_at(
-                hc, pk_l[None], pv_l[None], bt, 0, mask_pool, mask_self,
-                sin_h, cos_h, start=start, pool_sk=sk_l, pool_sv=sv_l,
-                sp=sp, experts=stacked_experts(model, xs[-1]),
+    ks, vs, states, tails = [], [], [], []
+    for n, (kind, i) in enumerate(cfg.layer_plan):
+        block = model.layer(n)  # static slices
+        if kind == "linear":
+            h, s_i, t_i = block.prefill_state_at(
+                h, state[0][i], state[1][i], real_n
             )
-            return hc, (k, v)
-
-        xs = (model.blocks, pool_k, pool_v)
-        if quant:
-            xs = xs + (pool_sk, pool_sv)
-        if stacked_experts(model, 0) is not None:
-            xs = xs + (jnp.arange(cfg.n_layer, dtype=jnp.int32),)
-        h, (ks, vs) = jax.lax.scan(body, h, xs)
-    else:
-        ks, vs, states, tails = [], [], [], []
-        for n, (kind, i) in enumerate(cfg.layer_plan):
-            block = model.layer(n)  # static slices
-            if kind == "linear":
-                h, s_i, t_i = block.prefill_state_at(
-                    h, state[0][i], state[1][i], real_n
-                )
-                states.append(s_i)
-                tails.append(t_i)
-                continue
-            if kind == "latent":
-                h, k = block.prefill_latent_at(
-                    h, pool_k, bt, i, mask_pool, mask_self, sin_h, cos_h,
-                    experts=stacked_experts(model, n),
-                )
-                ks.append(k)
-                continue
-            h, k, v = block.prefill_paged_at(
-                h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
-                cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
-                sp=sp, experts=stacked_experts(model, i),
+            states.append(s_i)
+            tails.append(t_i)
+            continue
+        if kind == "latent":
+            h, k = block.prefill_latent_at(
+                h, pool_k, bt, i, mask_pool, mask_self, sin_h, cos_h,
+                experts=stacked_experts(model, n),
             )
             ks.append(k)
-            vs.append(v)
-        ks, vs = jnp.stack(ks), jnp.stack(vs) if vs else None
+            continue
+        h, k, v = block.prefill_paged_at(
+            h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
+            cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
+            sp=sp, experts=stacked_experts(model, i),
+        )
+        ks.append(k)
+        vs.append(v)
+    ks, vs = jnp.stack(ks), jnp.stack(vs) if vs else None
     h = model.ln_f(h)
     if cfg.latent:
         return h, ks, None  # ks: [L, 1, 1, T, row]
@@ -3029,7 +2956,6 @@ def verify_tokens_paged(
     pool_sk: tp.Optional[Array] = None,  # [L, NP, Hkv] f32 (int8 pool)
     pool_sv: tp.Optional[Array] = None,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
     block_len: int = 0,
     expert_rows: bool = False,
     live: tp.Optional[Array] = None,  # [S, T] bool
@@ -3111,45 +3037,19 @@ def verify_tokens_paged(
 
     h = embed_tokens(model.wte, tokens)  # [S, T, D]
     sin_h, cos_h = sin_rows.astype(h.dtype), cos_rows.astype(h.dtype)
-    assert layer_scan in ("on", "off"), layer_scan
     assert model.lin_blocks is None, "verify needs full attention only"
-    if layer_scan == "on":
-        # layer loop folded into one lax.scan (see decode_step_paged):
-        # the candidate rows' per-layer K/V land as scan ys
-        quant = pool_sk is not None
-
-        def body(hc, xs):
-            blk, pk_l, pv_l = xs[:3]
-            sk_l = xs[3][None] if quant else None
-            sv_l = xs[4][None] if quant else None
-            hc, *out = blk.verify_paged_at(
-                hc, pk_l[None], pv_l[None], bt, 0, mask_pool, mask_self,
-                sin_h, cos_h, start=start, pool_sk=sk_l, pool_sv=sv_l,
-                paged_kernel=paged_kernel, block=block,
-                expert_rows=expert_rows,
-                experts=stacked_experts(model, xs[-1]), live=live,
-            )
-            return hc, tuple(out)
-
-        xs = (model.blocks, pool_k, pool_v)
-        if quant:
-            xs = xs + (pool_sk, pool_sv)
-        if stacked_experts(model, 0) is not None:
-            xs = xs + (jnp.arange(cfg.n_layer, dtype=jnp.int32),)
-        h, outs = jax.lax.scan(body, h, xs)
-    else:
-        per_layer = []
-        for i in range(cfg.n_layer):
-            blk = jax.tree.map(lambda a: a[i], model.blocks)  # static
-            h, *out = blk.verify_paged_at(
-                h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
-                cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
-                paged_kernel=paged_kernel, block=block,
-                expert_rows=expert_rows, experts=stacked_experts(model, i),
-                live=live,
-            )
-            per_layer.append(out)
-        outs = tuple(jnp.stack(o) for o in zip(*per_layer))
+    per_layer = []
+    for i in range(cfg.n_layer):
+        blk = jax.tree.map(lambda a: a[i], model.blocks)  # static
+        h, *out = blk.verify_paged_at(
+            h, pool_k, pool_v, bt, i, mask_pool, mask_self, sin_h,
+            cos_h, start=start, pool_sk=pool_sk, pool_sv=pool_sv,
+            paged_kernel=paged_kernel, block=block,
+            expert_rows=expert_rows, experts=stacked_experts(model, i),
+            live=live,
+        )
+        per_layer.append(out)
+    outs = tuple(jnp.stack(o) for o in zip(*per_layer))
     ks, vs = outs[:2]
     if head_block is not None:
         h = h.reshape(s, t // block, block, -1)[jnp.arange(s), head_block]

@@ -207,7 +207,6 @@ def generate_served(
     quant: tp.Optional[str] = None,
     kv_quant: tp.Optional[str] = None,
     paged_kernel: str = "auto",
-    layer_scan: str = "off",
     mesh=None,
 ) -> tp.List[np.ndarray]:
     """One-shot batch generation routed through the serving engine: submit
@@ -243,7 +242,6 @@ def generate_served(
         quant=quant,
         kv_quant=kv_quant,
         paged_kernel=paged_kernel,
-        layer_scan=layer_scan,
         mesh=mesh,
     )
     rids = [

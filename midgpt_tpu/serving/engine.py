@@ -66,14 +66,6 @@ replaces both:
   swap boundary. Dispatches per generated token drop from 1 per token to
   1/K per active batch.
 
-- **Fused layer scan** (``layer_scan="on"``, models.gpt): every
-  program's per-layer loop folds into ONE ``lax.scan`` over the stacked
-  block params — one inlined layer body per program instead of L, the
-  launch structure the decode residual over the HBM floor is made of.
-  Bitwise the unrolled programs (the scan body calls the same per-layer
-  methods on per-layer xs views), gated by the analysis.fusion
-  scan-equivalence prover + the analysis.dispatch launch budgets.
-
 Determinism contract: per-request sampling keys derive from
 ``fold_in(fold_in(key, request_seed), tokens_emitted_so_far)``
 (sampling.derive_request_key) — the token stream of a request is a
@@ -231,15 +223,13 @@ def make_decode_window(
     top_k: tp.Optional[int] = None,
     mesh=None,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
 ):
-    # paged_kernel/layer_scan sit BEFORE the mesh fingerprint: the
+    # paged_kernel sits BEFORE the mesh fingerprint: the
     # fingerprint stays the key's last element (the cache-distinctness
     # test and any cache introspection key off that position)
     key = (
         "decode_window", model.config, slots, window, pmax, rope_len,
-        pad_id, temperature, top_k, paged_kernel, layer_scan,
-        _mesh_key(mesh),
+        pad_id, temperature, top_k, paged_kernel, _mesh_key(mesh),
     )
     return _cached_program(
         key,
@@ -247,7 +237,6 @@ def make_decode_window(
             model.config, slots=slots, window=window, pmax=pmax,
             rope_len=rope_len, pad_id=pad_id, temperature=temperature,
             top_k=top_k, mesh=mesh, paged_kernel=paged_kernel,
-            layer_scan=layer_scan,
         ),
     )
 
@@ -264,14 +253,9 @@ def _build_decode_window(
     top_k: tp.Optional[int],
     mesh,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
 ):
     """The fused K-step decode program: ONE jitted, pool/logits-donating
     ``lax.scan`` over ``window`` whole-model decode steps.
-    ``layer_scan="on"`` additionally folds each step's layer loop into
-    one inner ``lax.scan`` (models.gpt.decode_step_paged — bitwise the
-    unrolled program, gated by the analysis.fusion scan-equivalence
-    prover and the analysis.dispatch launch budgets).
 
     Per scan step: sample each slot's next token from the carried logits,
     mark slots that just hit EOS/length done, run the paged decode step
@@ -363,7 +347,6 @@ def _build_decode_window(
                     model, tok, pos, pool.k, pool.v, bt, rk, rv, r,
                     pooled_len, rope_len, pool_sk=pool.scale_k,
                     pool_sv=pool.scale_v, paged_kernel=paged_kernel,
-                    layer_scan=layer_scan,
                     **({"state": st[0], "valid": write_valid} if st else {}),
                     **({"valid": write_valid} if latent else {}),
                 )
@@ -413,25 +396,24 @@ def _build_decode_window(
 
 def make_prefill_chunk_program(
     model: GPT, *, chunk_len: int, pmax: int, rope_len: int, mesh=None,
-    layer_scan: str = "off", prefill_sp: str = "off",
+    prefill_sp: str = "off",
 ):
     key = (
         "prefill_chunk", model.config, chunk_len, pmax, rope_len,
-        layer_scan, prefill_sp, _mesh_key(mesh),
+        prefill_sp, _mesh_key(mesh),
     )
     return _cached_program(
         key,
         lambda: _build_prefill_chunk_program(
             model.config, chunk_len=chunk_len, pmax=pmax,
-            rope_len=rope_len, mesh=mesh, layer_scan=layer_scan,
-            prefill_sp=prefill_sp,
+            rope_len=rope_len, mesh=mesh, prefill_sp=prefill_sp,
         ),
     )
 
 
 def _build_prefill_chunk_program(
     cfg, *, chunk_len: int, pmax: int, rope_len: int, mesh,
-    layer_scan: str = "off", prefill_sp: str = "off",
+    prefill_sp: str = "off",
 ):
     """A prefill-chunk program for one padded chunk length: one forward
     over the chunk's tokens attending to the slot's already-resident
@@ -467,7 +449,7 @@ def _build_prefill_chunk_program(
             h, ks, vs, *new = prefill_chunk_paged(
                 model, tokens, start, pool.k, pool.v, bt_row[None, :],
                 rope_len, pool_sk=pool.scale_k, pool_sv=pool.scale_v,
-                layer_scan=layer_scan, sp=(prefill_sp == "on"),
+                sp=(prefill_sp == "on"),
                 block_len=cfg.block_len,
                 **({} if state is None else {
                     "state": state.of_slot(slot, fresh), "real_n": real_n,
@@ -493,12 +475,10 @@ def _build_prefill_chunk_program(
             )
             logits = shard_act(logits, None, "vocab")
             # page write AFTER the head projection (no data dependence
-            # between them — a pure trace reorder): the lm head is the
-            # trace's last weight projection in every serving program,
-            # which is the layer-boundary structure the scan-equivalence
-            # prover's per-layer segmentation keys on (an int8 pool's
-            # page-birth quantization arithmetic would otherwise land
-            # inside the LAST layer's segment and break homogeneity)
+            # between them — a pure trace order): the lm head is the
+            # trace's last weight projection in every serving program
+            # (analysis.choreo takes the last one as the head), and an
+            # int8 pool's page-birth quantization follows it
             pool = write_token_rows(
                 pool, ks[:, 0], None if vs is None else vs[:, 0], bt_row,
                 start, real_n,
@@ -525,7 +505,6 @@ def make_verify_program(
     soft_drafts: bool = False,
     mesh=None,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
 ):
     # temperature == 0.0 builds the exact greedy program (same signature,
     # same arithmetic — no seeds/key entry args); sampling params join
@@ -536,8 +515,7 @@ def make_verify_program(
     # materializes (see _build_verify_program).
     key = (
         "verify", model.config, slots, spec_len, pmax, rope_len, pad_id,
-        temperature, top_k, soft_drafts, paged_kernel, layer_scan,
-        _mesh_key(mesh),
+        temperature, top_k, soft_drafts, paged_kernel, _mesh_key(mesh),
     )
     return _cached_program(
         key,
@@ -545,7 +523,7 @@ def make_verify_program(
             model.config, slots=slots, spec_len=spec_len, pmax=pmax,
             rope_len=rope_len, pad_id=pad_id, temperature=temperature,
             top_k=top_k, soft_drafts=soft_drafts, mesh=mesh,
-            paged_kernel=paged_kernel, layer_scan=layer_scan,
+            paged_kernel=paged_kernel,
         ),
     )
 
@@ -560,7 +538,6 @@ def _build_verify_program(
     pad_id: int,
     mesh,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
     temperature: float = 0.0,
     top_k: tp.Optional[int] = None,
     soft_drafts: bool = False,
@@ -682,7 +659,7 @@ def _build_verify_program(
             all_logits, ks, vs = verify_tokens_paged(
                 model, cand, pooled_len, pool.k, pool.v, bt, rope_len,
                 pool_sk=pool.scale_k, pool_sv=pool.scale_v,
-                paged_kernel=paged_kernel, layer_scan=layer_scan,
+                paged_kernel=paged_kernel,
             )  # all_logits: [S, T, V]; ks/vs: [L, S, Hkv, T, C]
             if temperature == 0.0:
                 preds = jnp.argmax(all_logits, axis=-1).astype(jnp.int32)
@@ -881,25 +858,23 @@ def make_block_window(
     rope_len: int,
     mesh=None,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
 ):
     key = (
         "block_window", model.config, slots, window, pmax, rope_len,
-        paged_kernel, layer_scan, _mesh_key(mesh),
+        paged_kernel, _mesh_key(mesh),
     )
     return _cached_program(
         key,
         lambda: _build_block_window(
             model.config, slots=slots, window=window, pmax=pmax,
             rope_len=rope_len, mesh=mesh, paged_kernel=paged_kernel,
-            layer_scan=layer_scan,
         ),
     )
 
 
 def _build_block_window(
     cfg, *, slots: int, window: int, pmax: int, rope_len: int, mesh,
-    paged_kernel: str = "xla", layer_scan: str = "off",
+    paged_kernel: str = "xla",
 ):
     """The decode window of a BLOCK-DIFFUSION model (``cfg.block_len`` =
     B > 0): ONE jitted, pool-donating ``lax.scan`` over ``window``
@@ -997,7 +972,7 @@ def _build_block_window(
                 )
                 out = verify_tokens_paged(
                     model, rows, pooled_len, pool.k, pool.v, bt, rope_len,
-                    paged_kernel=paged_kernel, layer_scan=layer_scan,
+                    paged_kernel=paged_kernel,
                     block_len=blk, expert_rows=with_rows, live=live,
                     head_block=land.astype(jnp.int32),
                 )
@@ -1089,7 +1064,6 @@ def trace_serving_programs(
     mesh=None,
     kv_quant: tp.Optional[str] = None,
     paged_kernel: str = "xla",
-    layer_scan: str = "off",
     prefill_sp: str = "off",
     temperature: float = 0.0,
     top_k: tp.Optional[int] = None,
@@ -1127,7 +1101,7 @@ def trace_serving_programs(
     window_fn = make_decode_window(
         model, slots=slots, window=window, pmax=pmax,
         rope_len=cfg.block_size, temperature=temperature, top_k=top_k,
-        mesh=mesh, paged_kernel=paged_kernel, layer_scan=layer_scan,
+        mesh=mesh, paged_kernel=paged_kernel,
     )
     decode_jaxpr = jax.make_jaxpr(window_fn)(
         model, pool, logits, i32(slots, pmax), i32(slots), pred(slots),
@@ -1136,7 +1110,7 @@ def trace_serving_programs(
     )
     chunk_fn = make_prefill_chunk_program(
         model, chunk_len=chunk_len, pmax=pmax, rope_len=cfg.block_size,
-        mesh=mesh, layer_scan=layer_scan, prefill_sp=prefill_sp,
+        mesh=mesh, prefill_sp=prefill_sp,
     )
     chunk_jaxpr = jax.make_jaxpr(chunk_fn)(
         model, pool, logits, i32(), i32(1, chunk_len), i32(), i32(),
@@ -1145,7 +1119,7 @@ def trace_serving_programs(
     verify_fn = make_verify_program(
         model, slots=slots, spec_len=spec_len, pmax=pmax,
         rope_len=cfg.block_size, temperature=temperature, top_k=top_k,
-        mesh=mesh, paged_kernel=paged_kernel, layer_scan=layer_scan,
+        mesh=mesh, paged_kernel=paged_kernel,
     )
     verify_args = [
         model, pool, logits, i32(slots, pmax), i32(slots), pred(slots),
@@ -1382,6 +1356,27 @@ def _counter_property(name: str) -> property:
     )
 
 
+# What each kind of model has no engine form for yet, refused by
+# ServingEngine at construction: kind -> (the subject of the refusal, the
+# features it refuses). A recurrent layer's state has no snapshot at an
+# earlier position and is not sharded; the latent pool and the block
+# forward have no form for the rest yet (ROADMAP Reach).
+_REFUSED: tp.Dict[str, tp.Tuple[str, tp.Tuple[str, ...]]] = {
+    "recurrent": ("a model with recurrent layers", (
+        "temperature > 0", "speculate", "quant", "kv_quant", "a mesh",
+        "role != 'both'", "spill", "prefill_sp", "block_len",
+    )),
+    "latent": ("a model with latent attention", (
+        "speculate", "quant", "kv_quant", "a mesh", "role != 'both'",
+        "spill", "prefill_sp", "block_len",
+    )),
+    "block": ("block-diffusion generation", (
+        "temperature > 0", "speculate", "kv_quant", "quant", "a mesh",
+        "role != 'both'", "spill",
+    )),
+}
+
+
 class ServingEngine:
     """Continuous-batching scheduler over ``slots`` decode lanes.
 
@@ -1490,7 +1485,6 @@ class ServingEngine:
         quant: tp.Optional[str] = None,
         kv_quant: tp.Optional[str] = None,
         paged_kernel: str = "auto",
-        layer_scan: str = "off",
         prefill_sp: str = "auto",
         spill: str = "off",
         spill_budget_pages: tp.Optional[int] = None,
@@ -1593,15 +1587,6 @@ class ServingEngine:
         # geometry, xla otherwise (same dispatch philosophy as
         # ops/attention's flash-vs-naive)
         assert paged_kernel in ("auto", "pallas", "xla"), paged_kernel
-        # fused layer loop (ROADMAP item 1): "on" folds every program's
-        # per-layer loop into one lax.scan (models.gpt layer_scan=) —
-        # bitwise the unrolled program (token-identity matrix), gated
-        # statically by the analysis.fusion scan-equivalence prover and
-        # the analysis.dispatch launch budgets. Default "off" until the
-        # r6 hardware rungs measure the dispatch-overhead win (the
-        # bench ladder runs both).
-        assert layer_scan in ("on", "off"), layer_scan
-        self.layer_scan = layer_scan
         # sequence-parallel prefill (ROADMAP item 4): "on" compiles the
         # SP prefill-chunk variant (models.gpt.prefill_chunk_paged
         # sp=True) whose replicated per-token segments shard the chunk's
@@ -1623,56 +1608,40 @@ class ServingEngine:
         # equally valid — the programs accept either form through one
         # code path (GPT.project + the block projections).
         assert quant in (None, "int8"), f"unknown quant mode {quant!r}"
+        # what a kind of model has no form for yet is refused here, by
+        # name (_REFUSED, ROADMAP Reach)
+        kinds = {
+            "recurrent": model.config.linear_layers > 0,
+            "latent": model.config.latent,
+            "block": bool(model.config.block_len),
+        }
+        asked = {
+            "temperature > 0": temperature > 0.0,
+            "speculate": bool(speculate),
+            "quant": quant is not None,
+            "kv_quant": kv_quant is not None,
+            "a mesh": mesh is not None,
+            "role != 'both'": role != "both",
+            "spill": spill == "on",
+            "prefill_sp": prefill_sp == "on",
+            "block_len": kinds["block"],
+        }
+        for kind, (subject, refused) in _REFUSED.items():
+            bad = [f for f in refused if asked[f]]
+            if kinds[kind] and bad:
+                raise ValueError(
+                    f"{subject} does not support " + ", ".join(bad)
+                )
         # layers of two kinds (cfg.layer_types): the recurrent layers' cache
         # (a gated delta rule's or a Mamba selective scan's) is a fixed-size
         # state a slot (serving.paged.RecurrentState), beside the page pool,
-        # which then holds the full-attention layers only. Whatever would
-        # need a snapshot of that state at some earlier position, or the
-        # state sharded, is refused here, by name (ROADMAP Reach)
-        self.hybrid = model.config.linear_layers > 0
+        # which then holds the full-attention layers only
+        self.hybrid = kinds["recurrent"]
         self._ssm = model.config.recurrent_mixer == "mamba"
-        if self.hybrid:
-            unsupported = {
-                "temperature > 0": temperature > 0.0,
-                "speculate": bool(speculate),
-                "quant": quant is not None,
-                "kv_quant": kv_quant is not None,
-                "a mesh": mesh is not None,
-                "role != 'both'": role != "both",
-                "spill": spill == "on",
-                "prefill_sp": prefill_sp == "on",
-                "layer_scan='on'": layer_scan == "on",
-                "block_len": bool(model.config.block_len),
-            }
-            bad = [k for k, v in unsupported.items() if v]
-            if bad:
-                raise ValueError(
-                    "a model with recurrent layers does not support "
-                    + ", ".join(bad)
-                )
         # latent attention (cfg.attention="latent"): the pool holds one
         # payload a page, a token's pooled row, and the decode window runs
-        # the paged kernel's latent mode. What has no latent form yet is
-        # refused here, by name (ROADMAP Reach A3); prefix_cache=True works
+        # the paged kernel's latent mode; prefix_cache=True works
         self.latent = model.config.latent
-        if self.latent:
-            unsupported = {
-                "speculate": bool(speculate),
-                "quant": quant is not None,
-                "kv_quant": kv_quant is not None,
-                "a mesh": mesh is not None,
-                "role != 'both'": role != "both",
-                "spill": spill == "on",
-                "prefill_sp": prefill_sp == "on",
-                "layer_scan='on'": layer_scan == "on",
-                "block_len": bool(model.config.block_len),
-            }
-            bad = [k for k, v in unsupported.items() if v]
-            if bad:
-                raise ValueError(
-                    "a model with latent attention does not support "
-                    + ", ".join(bad)
-                )
         if quant is not None:
             from midgpt_tpu.quant import is_quantized, quantize_model
 
@@ -1868,26 +1837,10 @@ class ServingEngine:
         self._grow = (self.speculate + 1) if self.speculate else window
         # generation by diffusion over blocks (cfg.block_len > 0): a
         # slot's unit of work is a forward of its current block — see
-        # _build_block_window. What it does not compose with yet is
-        # refused here, by name (ROADMAP Reach)
+        # _build_block_window
         self.block_len = int(cfg.block_len)
         if self.block_len:
             b = self.block_len
-            unsupported = {
-                "temperature > 0": temperature > 0.0,
-                "speculate": bool(speculate),
-                "kv_quant": kv_quant is not None,
-                "quant": quant is not None,
-                "a mesh": mesh is not None,
-                "role != 'both'": role != "both",
-                "spill": spill == "on",
-            }
-            bad = [k for k, v in unsupported.items() if v]
-            if bad:
-                raise ValueError(
-                    "block-diffusion generation does not support "
-                    + ", ".join(bad)
-                )
             assert cfg.block_steps >= 1 and b % cfg.block_steps == 0, (
                 b, cfg.block_steps
             )
@@ -2023,7 +1976,7 @@ class ServingEngine:
             self._window_fn = make_block_window(
                 model, slots=slots, window=window, pmax=self.pmax,
                 rope_len=self.block, mesh=mesh,
-                paged_kernel=self.paged_kernel, layer_scan=self.layer_scan,
+                paged_kernel=self.paged_kernel,
             )
         elif self.speculate:
             # speculation REPLACES the K-step window: every decode
@@ -2040,7 +1993,6 @@ class ServingEngine:
                 soft_drafts=self._soft_drafts,
                 mesh=mesh,
                 paged_kernel=self.paged_kernel,
-                layer_scan=self.layer_scan,
             )
             self._window_fn = None
         else:
@@ -2056,7 +2008,6 @@ class ServingEngine:
                 top_k=top_k,
                 mesh=mesh,
                 paged_kernel=self.paged_kernel,
-                layer_scan=self.layer_scan,
             )
         self._chunk_fns: tp.Dict[int, tp.Any] = {}
         self._copy_fn = make_copy_page_program()
@@ -2997,7 +2948,6 @@ class ServingEngine:
                 pmax=self.pmax,
                 rope_len=self.block,
                 mesh=self._mesh,
-                layer_scan=self.layer_scan,
                 prefill_sp=self.prefill_sp,
             )
         host = (np.int32(s), toks, np.int32(start), np.int32(clen), bt_row)

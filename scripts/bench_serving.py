@@ -218,15 +218,6 @@ def main() -> None:
                     "'xla' keeps the gather path, 'auto' = pallas on "
                     "TPU where ops.paged_attn.supported() takes the "
                     "geometry")
-    ap.add_argument("--layer_scan", choices=("on", "off"), default="off",
-                    help="fold each program's per-layer loop into one "
-                    "lax.scan (models.gpt layer_scan, ROADMAP item 1): "
-                    "one inlined layer body per program instead of L, "
-                    "shrinking the per-dispatch launch structure the "
-                    "decode residual over the HBM floor is made of — "
-                    "bitwise the unrolled program (gated by the "
-                    "analysis.fusion prover + dispatch budgets); run "
-                    "on/off on the same trace to ladder the win")
     ap.add_argument("--quant", choices=("on", "off"), default="off",
                     help="serve the int8 per-channel quantized weight "
                     "path (midgpt_tpu.quant): dequant fused into each "
@@ -364,7 +355,7 @@ def main() -> None:
         f"{f' topk={args.top_k}' if args.top_k else ''}"
         f"{' rep' if args.repetitive else ''}"
         f" quant={args.quant} kv_quant={args.kv_quant}"
-        f" kernel={args.paged_kernel} ls={args.layer_scan}"
+        f" kernel={args.paged_kernel}"
         f" tp={args.tp} dp={args.dp_replicas}"
         f"{f' plen={args.prompt_len}' if args.prompt_len else ''}"
         f" sp={args.prefill_sp}"
@@ -611,7 +602,6 @@ def main() -> None:
         speculate=args.spec_len if args.spec == "on" else 0,
         kv_quant="int8" if args.kv_quant == "on" else None,
         paged_kernel=args.paged_kernel,
-        layer_scan=args.layer_scan,
         prefill_sp=args.prefill_sp,
         spill=args.spill,
         spill_budget_pages=args.spill_budget_pages or None,
@@ -885,11 +875,10 @@ def main() -> None:
     # static dispatch/launch structure of THIS trace's decode program
     # (analysis.dispatch — the launch-side twin of the byte
     # decomposition below): trace the engine's own decode/verify
-    # program geometry and record launches-per-window, the folded
-    # layer-scan trip, inlined layer bodies and host transfers next to
-    # the measured tok/s, so fused-vs-unfused rows carry their
-    # static structure in-band. Best-effort like the comms summary —
-    # tracing only, after the timed region.
+    # program geometry and record launches-per-window, inlined layer
+    # bodies and host transfers next to the measured tok/s, so each
+    # row carries its static structure in-band. Best-effort like the
+    # comms summary — tracing only, after the timed region.
     disp = {}
     try:
         from midgpt_tpu.analysis.dispatch import dispatch_report
@@ -901,7 +890,6 @@ def main() -> None:
             page_size=args.page_size,
             kv_quant="int8" if args.kv_quant == "on" else None,
             paged_kernel=engines[0].paged_kernel,
-            layer_scan=args.layer_scan,
         )
         key = "verify" if args.spec == "on" else "decode_window"
         rep = dispatch_report(
@@ -1163,12 +1151,10 @@ def main() -> None:
         # long-context row claiming pallas must not hide an XLA fallback
         "serve_paged_kernel": args.paged_kernel,
         "serve_paged_kernel_resolved": engines[0].paged_kernel,
-        "serve_layer_scan": args.layer_scan,
         "serve_static_launches_per_window": disp.get("launches_per_window"),
         "serve_static_inlined_layer_bodies": disp.get(
             "inlined_layer_bodies"
         ),
-        "serve_static_layer_scan_length": disp.get("layer_scan_length"),
         "serve_static_host_transfers": disp.get("host_transfers"),
         "serve_peak_hbm_bytes": peak_hbm,
         "serve_bytes_per_token_static": static["bytes_per_token"],

@@ -455,9 +455,8 @@ def test_unrolled_layer_loop_in_serving_flagged():
 
 
 def test_unrolled_layer_loop_outside_serving_not_flagged():
-    """Scoped to midgpt_tpu/serving/: the models/ drivers keep their
-    unrolled layer_scan="off" branch on purpose (it is the fold's
-    bitwise reference, selected by the engine knob)."""
+    """Scoped to midgpt_tpu/serving/: the layer loop of each serving
+    program lives in its models/ forward on purpose."""
     fs = lint_source(
         textwrap.dedent(_LAYER_LOOP_SRC), path="midgpt_tpu/models/probe.py"
     )

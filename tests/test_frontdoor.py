@@ -141,27 +141,27 @@ def test_background_driver_streams_match_sync_loop(model):
 
 
 _MATRIX = [
-    # (prefix_cache, chunk, spec, kvq, layer_scan)
-    (False, None, 0, None, "off"),
-    (True, 8, 0, None, "off"),
-    (True, 8, 4, None, "on"),
-    (True, None, 4, "int8", "off"),
-    (False, 8, 0, "int8", "on"),
+    # (prefix_cache, chunk, spec, kvq)
+    (False, None, 0, None),
+    (True, 8, 0, None),
+    (True, 8, 4, None),
+    (True, None, 4, "int8"),
+    (False, 8, 0, "int8"),
 ]
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize(
-    "cache,chunk,spec,kvq,ls", _MATRIX,
-    ids=["nocache", "cache-chunk", "chunk-spec-ls", "spec-kvq8",
-         "nocache-chunk-kvq8-ls"],
+    "cache,chunk,spec,kvq", _MATRIX,
+    ids=["nocache", "cache-chunk", "chunk-spec", "spec-kvq8",
+         "nocache-chunk-kvq8"],
 )
-def test_stream_identity_matrix(model, cache, chunk, spec, kvq, ls):
+def test_stream_identity_matrix(model, cache, chunk, spec, kvq):
     """The acceptance bit-identity gate across cache x chunk x spec x
-    kv-quant x layer_scan: front-door streams == synchronous loop."""
+    kv-quant: front-door streams == synchronous loop."""
     kw = dict(
         _KW, prefix_cache=cache, prefill_chunk=chunk, speculate=spec,
-        kv_quant=kvq, layer_scan=ls,
+        kv_quant=kvq,
     )
     prompts = _prompts(5, base_len=5, stride=2)
     refs = _sync_refs(model, prompts, n_new=12, kw=kw)

@@ -255,22 +255,6 @@ def test_counters_of_the_recurrent_state(served):
     assert st["kv_bytes_live"] == 0  # nothing is resident once all finished
 
 
-@pytest.mark.parametrize("name,kw", [
-    ("speculate", {"speculate": 2}),
-    ("quant", {"quant": "int8"}),
-    ("kv_quant", {"kv_quant": "int8"}),
-    ("role != 'both'", {"role": "prefill"}),
-    ("spill", {"spill": "on"}),
-    ("prefill_sp", {"prefill_sp": "on"}),
-    ("layer_scan='on'", {"layer_scan": "on"}),
-    ("temperature > 0", {"temperature": 0.7}),
-])
-def test_what_needs_a_snapshot_is_refused_by_name(recurrent, name, kw):
-    with pytest.raises(ValueError, match="recurrent layers does not "
-                       "support .*" + name.replace("(", r"\(")):
-        engine(recurrent, **kw)
-
-
 def test_a_mesh_and_the_handoff_are_refused_by_name(recurrent, prompts):
     from midgpt_tpu.config import MeshConfig
     from midgpt_tpu.parallel.mesh import create_mesh

@@ -259,21 +259,6 @@ def test_counters_of_the_expert_layers_and_the_latent_cache(served):
     assert st["kv_bytes_live"] == 0 and st["latent_layers"] == 3
 
 
-@pytest.mark.parametrize("name,kw", [
-    ("speculate", {"speculate": 2}),
-    ("quant", {"quant": "int8"}),
-    ("kv_quant", {"kv_quant": "int8"}),
-    ("role != 'both'", {"role": "prefill"}),
-    ("spill", {"spill": "on"}),
-    ("prefill_sp", {"prefill_sp": "on"}),
-    ("layer_scan='on'", {"layer_scan": "on"}),
-])
-def test_what_has_no_latent_form_is_refused_by_name(model, name, kw):
-    with pytest.raises(ValueError, match="latent attention does not "
-                       "support .*" + name.replace("(", r"\(")):
-        engine(model, **kw)
-
-
 def test_a_mesh_and_the_other_kinds_of_layer_are_refused_by_name(model):
     import dataclasses
 

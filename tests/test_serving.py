@@ -9,6 +9,7 @@ reference (its sampler is fixed-batch, full-re-forward per token,
 sample.py:68-95)."""
 
 import dataclasses
+import re
 import zlib
 from unittest import mock
 
@@ -36,6 +37,7 @@ from midgpt_tpu.serving import (
     pages_needed,
     write_prompt_pages,
 )
+from midgpt_tpu.serving.engine import _REFUSED
 
 CFG = ModelConfig(
     block_size=64, vocab_size=96, n_layer=2, n_head=4, n_embd=32,
@@ -629,51 +631,6 @@ def test_prefix_cache_and_chunking_token_identity(shared_prefix_case):
         assert toks == base, f"variant {variant} diverged"
     for i, r in enumerate(base):
         np.testing.assert_array_equal(np.asarray(r), refs[i], err_msg=f"req {i}")
-
-
-def _run_layer_scan(model, prompts, lens, ls, **kw):
-    kw.setdefault("cache_dtype", jnp.float32)
-    eng = ServingEngine(
-        model, slots=2, page_size=8, window=4, temperature=0.0,
-        layer_scan=ls, **kw,
-    )
-    rids = [eng.submit(p, n) for p, n in zip(prompts, lens)]
-    fin = eng.run()
-    return [fin[r].tokens for r in rids]
-
-
-def test_layer_scan_token_identity(shared_prefix_case):
-    """Landing gate of the fused layer loop (ROADMAP item 1): greedy
-    streams with ``layer_scan="on"`` are bit-identical to the unrolled
-    engine AND to the exact fixed-batch sampler — mid-run admission,
-    shared prefixes, speculation. The chunked / kv-quant / cache-off
-    legs ride the slow tier below; tp=2/4 lives in
-    test_serving_sharded.py."""
-    model, prompts, lens, refs = shared_prefix_case
-    for kw in (dict(), dict(speculate=3)):
-        on = _run_layer_scan(model, prompts, lens, "on", **kw)
-        off = _run_layer_scan(model, prompts, lens, "off", **kw)
-        assert on == off, kw
-    for i, r in enumerate(on):  # spec-on fused vs the exact sampler
-        np.testing.assert_array_equal(np.asarray(r), refs[i])
-
-
-@pytest.mark.slow
-def test_layer_scan_token_identity_matrix_slow(shared_prefix_case):
-    """The remaining single-chip layer_scan cells: chunked prefill,
-    prefix-cache off, and the int8 KV pool (each a fresh fused-program
-    compile)."""
-    model, prompts, lens, _ = shared_prefix_case
-    for kw in (
-        dict(prefill_chunk=8),
-        dict(prefix_cache=False),
-        dict(kv_quant="int8", cache_dtype=jnp.bfloat16),
-        dict(kv_quant="int8", cache_dtype=jnp.bfloat16, speculate=3,
-             prefill_chunk=5),
-    ):
-        on = _run_layer_scan(model, prompts, lens, "on", **kw)
-        off = _run_layer_scan(model, prompts, lens, "off", **kw)
-        assert on == off, kw
 
 
 def test_shared_prefix_skips_prefill_compute():
@@ -1328,6 +1285,52 @@ def test_sampling_config_typed_errors():
         ServingEngine(model, slots=1, temperature=0.8, top_k=0)
 
 
+# what asks a ServingEngine for each feature of _REFUSED (a mesh is the
+# mesh8 fixture's, and block_len is the model's own)
+_FEATURE_KW = {
+    "temperature > 0": {"temperature": 0.7},
+    "speculate": {"speculate": 2},
+    "quant": {"quant": "int8"},
+    "kv_quant": {"kv_quant": "int8"},
+    "a mesh": {},
+    "role != 'both'": {"role": "prefill"},
+    "spill": {"spill": "on"},
+    "prefill_sp": {"prefill_sp": "on"},
+    "block_len": {},
+}
+# the small test models of each kind (both recurrent mixers for
+# "recurrent")
+_KIND_FAMILIES = {
+    "recurrent": ("gated_delta", "mamba"),
+    "latent": ("latent",),
+    "block": ("block",),
+}
+
+
+@pytest.mark.parametrize("kind,family,feature", [
+    (kind, family, feature)
+    for kind, (_, refused) in _REFUSED.items()
+    for family in _KIND_FAMILIES[kind]
+    for feature in refused
+])
+def test_what_a_kind_of_model_lacks_is_refused_by_name(
+        family_config, mesh8, kind, family, feature):
+    """Every entry of the engine's one refusal table: a model of that kind
+    asked for that feature is refused at construction, with the kind's
+    wording and the feature's name. The model is abstract (shapes only):
+    nothing of it is read before the refusal."""
+    cfg = family_config(family)
+    if feature == "block_len":
+        cfg = dataclasses.replace(cfg, block_len=4, block_steps=4,
+                                  mask_token=cfg.vocab_size - 1)
+    model = jax.eval_shape(lambda: GPT.init(jax.random.PRNGKey(0), cfg))
+    kw = {"mesh": mesh8} if feature == "a mesh" else _FEATURE_KW[feature]
+    subject = _REFUSED[kind][0]
+    with pytest.raises(ValueError, match=re.escape(subject)
+                       + " does not support .*" + re.escape(feature)):
+        ServingEngine(model, slots=2, page_size=16, **kw)
+
+
 @pytest.mark.slow
 def test_spec_identity_with_bf16_cache_under_f32_model():
     """Regression (code review): the decode window reads even in-window
@@ -1406,9 +1409,8 @@ def test_decode_window_audit_donation_and_host_sync():
 # (a) SCHEDULING INVARIANCE: the sampled spec-on stream is a pure function
 #     of (request seed, engine seed, sampling knobs), bitwise identical
 #     across slots / window / batch composition / chunking / prefix cache /
-#     eviction / layer_scan — within each arithmetic cell (kv-quant changes
-#     the arithmetic, so cells are compared within themselves, exactly like
-#     the greedy layer_scan matrix above);
+#     eviction — within each arithmetic cell (kv-quant changes the
+#     arithmetic, so cells are compared within themselves);
 # (b) DISTRIBUTIONAL EXACTNESS: accept-with-min(1, p/q) + residual
 #     resample + bonus row reproduce the spec-off sampling distribution for
 #     ANY honest proposer (statistical test below);
@@ -1582,10 +1584,9 @@ def test_spec_sampled_invariance_matrix_slow():
     """Contract (a), full single-chip matrix: within each arithmetic
     cell (f32 pool; int8-quantized bf16 pool) the sampled spec-on
     stream is bitwise identical across slots, prefix cache on/off,
-    chunked prefill, page pressure with eviction/re-admission, and
-    layer_scan on/off. Cross-cell equality is NOT asserted — kv-quant
-    changes the arithmetic (same contract as the greedy layer_scan
-    matrix). tp=2 rides test_serving_sharded.py."""
+    chunked prefill, and page pressure with eviction/re-admission.
+    Cross-cell equality is NOT asserted — kv-quant changes the
+    arithmetic. tp=2 rides test_serving_sharded.py."""
     model = _model()
     prompts = _rep_prompts(3)
     lens = [10, 12, 8]
@@ -1600,18 +1601,13 @@ def test_spec_sampled_invariance_matrix_slow():
         dict(kv_quant="int8", cache_dtype=jnp.bfloat16),
     ):
         base = None
-        for ls in ("off", "on"):
-            for sched in scheds:
-                toks, eng = _run_sampled(
-                    model, prompts, lens, layer_scan=ls, **arith, **sched
-                )
-                if "num_pages" in sched:
-                    assert eng.evictions > 0, (
-                        "pressure leg was sized to evict"
-                    )
-                if base is None:
-                    base = toks
-                assert toks == base, (arith, ls, sched)
+        for sched in scheds:
+            toks, eng = _run_sampled(model, prompts, lens, **arith, **sched)
+            if "num_pages" in sched:
+                assert eng.evictions > 0, "pressure leg was sized to evict"
+            if base is None:
+                base = toks
+            assert toks == base, (arith, sched)
 
 
 @pytest.mark.slow

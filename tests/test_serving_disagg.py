@@ -5,8 +5,8 @@
   (prefill pool -> page handoff -> decode pool) equals the monolithic
   single-engine reference token for token, and is invariant to the pool
   split (1+1 / 2+1 / 2+2). Fast tier pins the greedy/cache case; the
-  slow tier crosses cache x chunk x spec(greedy+sampled) x kv-quant x
-  layer_scan, plus eviction-under-pressure around the handoff.
+  slow tier crosses cache x chunk x spec(greedy+sampled) x kv-quant,
+  plus eviction-under-pressure around the handoff.
 - **Handoff hygiene**: the allocator identity (free + held + cached +
   quarantined == num_pages) and the PrefixIndex structural invariants
   re-check on EVERY engine after EVERY cluster step — i.e. after every
@@ -301,19 +301,18 @@ MATRIX_SLOW = (
     dict(kv_quant="int8"),
     dict(speculate=2),
     dict(speculate=2, temperature=0.8, top_k=12),
-    dict(layer_scan="on"),
 )
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize(
     "extra", MATRIX_SLOW,
-    ids=["cache-off", "chunk", "kvq", "spec", "spec-sampled", "scan"],
+    ids=["cache-off", "chunk", "kvq", "spec", "spec-sampled"],
 )
 def test_disagg_matrix_bit_identical_across_splits(model, extra):
     """The full landing gate: cache x chunk x spec(greedy+sampled) x
-    kv-quant x layer_scan, each bit-identical to the monolithic engine
-    across every pool split."""
+    kv-quant, each bit-identical to the monolithic engine across every
+    pool split."""
     prompts = _prompts(4, base_len=5, stride=2)
     kw = dict(BASE_KW, **extra)
     ref = _mono_ref(model, prompts, 8, **kw)

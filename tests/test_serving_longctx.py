@@ -6,8 +6,8 @@ host-RAM cold-page spill — the landing gates asserted directly.
   arithmetic verbatim (the choreo prover's sp leg proves zero added
   arithmetic; these tests pin the streams). Greedy AND sampled streams
   are bitwise identical to ``prefill_sp="off"`` — and to the
-  single-chip engine — across cache x chunk x spec x kv-quant x
-  layer_scan at tp=2 (fast) and tp=4 (slow). Decode programs are
+  single-chip engine — across cache x chunk x spec x kv-quant at tp=2
+  (fast) and tp=4 (slow). Decode programs are
   untouched by construction (separate ``_PROGRAM_CACHE`` entries; the
   resolved sp value forks only the prefill-chunk key).
 - **Spill bit-identity**: with ``spill="on"`` cold prefix pages move to
@@ -121,12 +121,12 @@ def test_sp_resolution_and_program_cache_fork(model):
     )
     assert tp_off.prefill_sp == "off"
     # run one tiny prompt through each resolved mode: the cache must
-    # hold prefill_chunk entries for BOTH sp values (key slot 6), and
+    # hold prefill_chunk entries for BOTH sp values (key slot 5), and
     # no decode/verify key carries an sp field at all
     for eng in (tp_auto, tp_off):
         eng.submit(_prompts(1)[0], 2, seed=0)
         eng.run()
-    sps = {k[6] for k in _PROGRAM_CACHE if k[0] == "prefill_chunk"}
+    sps = {k[5] for k in _PROGRAM_CACHE if k[0] == "prefill_chunk"}
     assert {"on", "off"} <= sps
     assert all(
         k[0] in ("prefill_chunk", "decode_window", "verify_program")
@@ -586,22 +586,22 @@ def test_long_prompt_completes_in_undersized_pool_no_wedge(model):
 # ---------------------------------------------------------------------------
 
 MATRIX_SLOW = [
-    pytest.param(True, None, 0, None, "off", id="cache"),
-    pytest.param(False, 8, 0, None, "off", id="chunked-nocache"),
-    pytest.param(True, 8, 3, None, "off", id="chunked-spec"),
-    pytest.param(True, 8, 0, "int8", "off", id="chunked-kv8"),
-    pytest.param(True, 8, 3, "int8", "on", id="chunked-spec-kv8-scan"),
-    pytest.param(True, 8, 0, None, "on", id="chunked-scan"),
+    pytest.param(True, None, 0, None, id="cache"),
+    pytest.param(False, 8, 0, None, id="chunked-nocache"),
+    pytest.param(True, 8, 3, None, id="chunked-spec"),
+    pytest.param(True, 8, 0, "int8", id="chunked-kv8"),
+    pytest.param(True, 8, 3, "int8", id="chunked-spec-kv8"),
+    pytest.param(True, 8, 0, None, id="chunked"),
 ]
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("cache,chunk,spec,kvq,ls", MATRIX_SLOW)
-def test_sp_identity_matrix_tp2_slow(model, cache, chunk, spec, kvq, ls):
+@pytest.mark.parametrize("cache,chunk,spec,kvq", MATRIX_SLOW)
+def test_sp_identity_matrix_tp2_slow(model, cache, chunk, spec, kvq):
     prompts = _prompts(3, base_len=18, stride=4, seed0=1200)
     kw = dict(
         page_size=8, prefix_cache=cache, prefill_chunk=chunk,
-        speculate=spec, kv_quant=kvq, layer_scan=ls,
+        speculate=spec, kv_quant=kvq,
     )
     off, _ = _run(model, _mesh(2), prompts, 10, prefill_sp="off", **kw)
     on, _ = _run(model, _mesh(2), prompts, 10, prefill_sp="on", **kw)
@@ -625,19 +625,19 @@ def test_sp_identity_tp4_slow(model, temperature):
 
 @pytest.mark.slow
 @pytest.mark.parametrize(
-    "spec,kvq,ls",
+    "spec,kvq",
     [
-        pytest.param(0, None, "off", id="plain"),
-        pytest.param(3, None, "off", id="spec"),
-        pytest.param(0, "int8", "on", id="kv8-scan"),
-        pytest.param(3, "int8", "off", id="spec-kv8"),
+        pytest.param(0, None, id="plain"),
+        pytest.param(3, None, id="spec"),
+        pytest.param(0, "int8", id="kv8"),
+        pytest.param(3, "int8", id="spec-kv8"),
     ],
 )
-def test_spill_identity_matrix_slow(model, spec, kvq, ls):
+def test_spill_identity_matrix_slow(model, spec, kvq):
     prompts = _prompts(4, base_len=22, stride=0, seed0=1400)
     kw = dict(
         page_size=8, prefill_chunk=8, prefix_cache=True,
-        speculate=spec, kv_quant=kvq, layer_scan=ls,
+        speculate=spec, kv_quant=kvq,
     )
     ref, _ = _run(model, None, prompts, 12, **kw)
     got, eng = _run(

@@ -162,39 +162,6 @@ def test_tp4_token_identity_matrix_slow(model, matrix_refs, cache, chunk,
     _assert_tp_identity(model, matrix_refs, 4, cache, chunk, spec, quant)
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("tp", [2, 4])
-def test_tp_layer_scan_token_identity(model, matrix_refs, tp):
-    """The fused layer loop under TP (ROADMAP item 1's landing gate,
-    sharded leg): a tp=2/4 engine with ``layer_scan="on"`` stays greedy
-    token-identical to the single-chip UNROLLED engine — proving
-    on == off transitively through the existing sharded matrix — on
-    the cache and chunked combos."""
-    prompts, ref = matrix_refs
-    for cache, chunk, spec, quant in (
-        (True, None, 0, None), (True, 3, 0, None),
-    ):
-        got, eng = _run(
-            model, _mesh(tp), prompts, 10, prefix_cache=cache,
-            prefill_chunk=chunk, speculate=spec, quant=quant,
-            layer_scan="on",
-        )
-        assert got == ref(cache, chunk, spec, quant), (tp, chunk)
-        assert eng.layer_scan == "on" and eng.tp == tp
-
-
-@pytest.mark.slow
-def test_tp2_layer_scan_kv_quant_identity(model):
-    """Fused layer loop x int8 KV pool x tp=2: the scan slices the
-    pool's scale planes as per-layer xs — streams must stay identical
-    to the unrolled single-chip engine with the same pool precision."""
-    prompts = _prompts(3)
-    kw = dict(kv_quant="int8", cache_dtype=jnp.bfloat16)
-    base, _ = _run(model, None, prompts, 10, layer_scan="off", **kw)
-    got, _ = _run(model, _mesh(2), prompts, 10, layer_scan="on", **kw)
-    assert got == base
-
-
 def test_tp2_eviction_readmission_identity(model):
     """Mid-run eviction + re-admission under page pressure on the
     sharded engine: same evictions, same streams as single-chip (the

@@ -390,21 +390,19 @@ def test_telemetry_identity_spec_chunked(model):
 @pytest.mark.parametrize(
     "kw",
     [
-        dict(prefix_cache=False, layer_scan="on"),
+        dict(prefix_cache=False),
         dict(prefill_chunk=8, kv_quant="int8"),
-        dict(prefill_chunk=8, speculate=4, kv_quant="int8",
-             layer_scan="on"),
-        dict(prefix_cache=False, prefill_chunk=8, speculate=4,
-             layer_scan="on"),
-        dict(kv_quant="int8", layer_scan="on", cache_dtype=jnp.bfloat16),
+        dict(prefill_chunk=8, speculate=4, kv_quant="int8"),
+        dict(prefix_cache=False, prefill_chunk=8, speculate=4),
+        dict(kv_quant="int8", cache_dtype=jnp.bfloat16),
     ],
-    ids=["nocache-ls", "chunk-kv8", "chunk-spec-kv8-ls",
-         "nocache-chunk-spec-ls", "kv8-ls-bf16"],
+    ids=["nocache", "chunk-kv8", "chunk-spec-kv8", "nocache-chunk-spec",
+         "kv8-bf16"],
 )
 def test_telemetry_identity_matrix(model, kw):
     """Acceptance: greedy streams with telemetry on are bitwise
-    identical to telemetry off across cache x chunk x spec x kv-quant x
-    layer_scan."""
+    identical to telemetry off across cache x chunk x spec x
+    kv-quant."""
     _identity_case(model, **kw)
 
 
